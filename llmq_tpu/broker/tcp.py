@@ -226,9 +226,7 @@ class BrokerServer:
 
     async def stop(self) -> None:
         if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+            self._server.close()  # stop accepting
         for writer in list(self._conn_writers):
             writer.close()
             try:
@@ -236,6 +234,12 @@ class BrokerServer:
             except (ConnectionResetError, BrokenPipeError, OSError):
                 pass
         self._conn_writers.clear()
+        if self._server is not None:
+            # After the connections: since Python 3.12.1 wait_closed()
+            # returns only once every connection of the server is closed,
+            # so waiting first blocks for ever with a client attached.
+            await self._server.wait_closed()
+            self._server = None
         if self._journal_file is not None:
             self._journal_file.close()
             self._journal_file = None

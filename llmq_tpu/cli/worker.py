@@ -12,7 +12,7 @@ from llmq_tpu.core.pipeline import load_pipeline_config
 from llmq_tpu.utils.logging import setup_logging
 
 
-def run_tpu_worker(
+def build_tpu_worker(
     model: str,
     queue: str,
     *,
@@ -32,8 +32,10 @@ def run_tpu_worker(
     tp_overlap: Optional[str] = None,
     mixed_step: Optional[str] = None,
     role: Optional[str] = None,
-) -> None:
-    """Launch the TPU inference worker (reference run_vllm_worker)."""
+):
+    """The TPU inference worker exactly as ``llmq-tpu worker run`` builds
+    it (reference run_vllm_worker) — also what ``chip_smoke.py`` runs, so
+    the smoke and the CLI cannot drift apart."""
     setup_logging(structured=True)
     if role is not None:
         # Role rides Config (LLMQ_WORKER_ROLE) so the broker manager and
@@ -52,7 +54,7 @@ def run_tpu_worker(
         + (f" role={role}" if role else ""),
         err=True,
     )
-    worker = TPUWorker(
+    return TPUWorker(
         queue,
         model=model,
         tensor_parallel=tensor_parallel,
@@ -71,7 +73,11 @@ def run_tpu_worker(
         tp_overlap=tp_overlap,
         mixed_step=mixed_step,
     )
-    _run(worker)
+
+
+def run_tpu_worker(model: str, queue: str, **options) -> None:
+    """Launch the TPU inference worker and serve until stopped."""
+    _run(build_tpu_worker(model, queue, **options))
 
 
 def run_dummy_worker(

@@ -80,6 +80,17 @@ class LogitGuardError(RuntimeError):
         self.kind = kind
 
 
+class StepCompileError(RuntimeError):
+    """A step program the compiler refused: it does not fit the device's
+    memory, a Pallas kernel failed to lower, or Mosaic rejected it.
+
+    Not a device fault. The same program fails the same way on a rebuilt
+    engine and for every job, so nothing is retried: the engine stops,
+    in-flight jobs go back to the queue, and the worker exits non-zero
+    with this error — a worker that can compile nothing must not look
+    like a worker that is up."""
+
+
 class DeviceFaultError(RuntimeError):
     """A classified device fault the engine could not recover from
     in-process (rebuild unavailable, rebuild failed, or the OOM
@@ -89,6 +100,29 @@ class DeviceFaultError(RuntimeError):
     def __init__(self, failure_reason: str, message: str):
         super().__init__(message)
         self.failure_reason = failure_reason
+
+
+# What the installed toolchain says when it refuses a program
+# (tests/test_tpu_compile.py pins the first two against the real
+# compiler): XLA's compile-time memory check, Mosaic's kernel compile,
+# and the Pallas→Mosaic lowering's own shape rules.
+_COMPILE_MARKERS = (
+    "xla:tpu compile",
+    "mosaic failed to compile",
+    "pallas tpu lowering",
+    "loweringexception",
+)
+
+
+def is_compile_failure(exc: BaseException) -> bool:
+    """True when ``exc`` says a program could not be *compiled* — as
+    opposed to a fault while a compiled program ran, which keeps the
+    device-fault handling below. Textual for the same reason as
+    :func:`classify_failure`."""
+    if isinstance(exc, StepCompileError):
+        return True
+    text = f"{type(exc).__name__}: {exc}".lower()
+    return any(marker in text for marker in _COMPILE_MARKERS)
 
 
 def classify_failure(exc: BaseException) -> Optional[str]:
@@ -104,6 +138,8 @@ def classify_failure(exc: BaseException) -> Optional[str]:
         return FAULT_NUMERICAL
     if isinstance(exc, DeviceFaultError):
         return exc.failure_reason
+    if is_compile_failure(exc):
+        return None  # never retried as a device fault: see StepCompileError
     text = f"{type(exc).__name__}: {exc}".lower()
     # Order matters: a real HBM OOM *is* an XlaRuntimeError, so the
     # allocation signature must win over the generic XLA match.

@@ -16,8 +16,8 @@ TPU-native design differs from vLLM's CUDA core on purpose:
   host dispatches step ``k`` while asynchronously fetching the sampled
   tokens of step ``k - runahead``. Steady-state decode therefore ships
   **zero** host→device bytes and never blocks on a device→host sync —
-  critical when dispatch latency is high (remote TPU tunnels), and it
-  removes host jitter everywhere else. Correctness pieces:
+  critical when dispatch latency is high, and it removes host jitter
+  everywhere else. Correctness pieces:
     * *Page lookahead*: KV pages are allocated at dispatch time for every
       position any in-flight step may write (`Scheduler.ensure_pages`),
       so the device block tables are never stale when a sequence crosses
@@ -66,13 +66,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.5: Format pairs a per-device Layout with a sharding
-    from jax.experimental.layout import Format, Layout
-except ImportError:  # jax 0.4.x: same pair, pre-rename names
-    from jax.experimental.layout import (
-        DeviceLocalLayout as Layout,
-        Layout as Format,
-    )
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from llmq_tpu.core.faults import (
@@ -80,7 +74,9 @@ from llmq_tpu.core.faults import (
     FAULT_OOM,
     DeviceFaultError,
     LogitGuardError,
+    StepCompileError,
     classify_failure,
+    is_compile_failure,
 )
 from llmq_tpu.engine import sampling as sampling_mod
 from llmq_tpu.engine import snapshot as snapshot_mod
@@ -117,6 +113,7 @@ from llmq_tpu.obs.metrics import (
 from llmq_tpu.obs.trace import emit_trace_event
 from llmq_tpu.ops import dispatch as _dispatch
 from llmq_tpu.utils.host_mem import get_governor
+from llmq_tpu.utils.platform import on_tpu
 from llmq_tpu.ops.attention import mixed_query_grid
 from llmq_tpu.parallel import pipeline as pp_mod
 from llmq_tpu.parallel.mesh import (
@@ -139,6 +136,55 @@ ITL_BUCKETS: Tuple[float, ...] = (0.0001, 0.00025, 0.0005) + DEFAULT_BUCKETS
 #: emitted, or a disconnect raced the publish) age out of the sweep map
 #: after this many seconds so it can never grow unboundedly.
 _CANCEL_TTL_S = 5.0
+
+
+class _StepProgram:
+    """One jitted step program that can tell a failure to *compile* from
+    a fault while a compiled program runs.
+
+    The two must not share a handler: a device fault is retried on a
+    rebuilt engine (``core/faults.py``), but a program the compiler
+    refuses — it does not fit HBM, Mosaic rejects a kernel — fails the
+    same way on every rebuild and for every job. ``jax.jit`` runs the
+    Python body only on a cache miss, which marks the call cold; when a
+    cold call raises, compiling the program once more on its own says
+    which of the two it was. Zero cost on the warm path.
+    """
+
+    def __init__(self, fn, **jit_kwargs) -> None:
+        inner = fn.func if isinstance(fn, partial) else fn
+        self.name = getattr(inner, "__name__", "step")
+        self._cold = False
+
+        def traced(*args, **kwargs):
+            self._cold = True
+            return fn(*args, **kwargs)
+
+        # Keep the step's own name on the compiled module (profiles and
+        # compile-cache entries are read by it).
+        traced.__name__ = self.name
+        traced.__qualname__ = getattr(inner, "__qualname__", self.name)
+        self._jit = jax.jit(traced, **jit_kwargs)
+        self.lower = self._jit.lower
+
+    def __call__(self, *args):
+        self._cold = False
+        try:
+            return self._jit(*args)
+        except Exception as exc:
+            if self._cold:
+                self._raise_if_uncompilable(args, exc)
+            raise
+
+    def _raise_if_uncompilable(self, args, exc: Exception) -> None:
+        try:
+            self._jit.lower(*args).compile()
+        except Exception as compile_exc:  # noqa: BLE001 — whatever the
+            # tracer, the lowering or the compiler raises
+            raise StepCompileError(
+                f"step program {self.name!r} does not compile: "
+                f"{type(compile_exc).__name__}: {compile_exc}"
+            ) from exc
 
 
 @dataclasses.dataclass
@@ -466,6 +512,30 @@ def _prefill_buckets(cfg: EngineConfig, sp: int = 1) -> List[int]:
     return sorted(set(rounded))
 
 
+def kv_page_bytes_per_device(
+    model_config: ModelConfig, page_size: int, kv_dtype, kv_format
+) -> int:
+    """HBM one KV page (K and V, all layers) takes on each device, as the
+    compiler lays the pool out — asked of the compiler, not computed
+    from the shape: the kv-head axis sits on the sublanes, and a shard
+    left with fewer heads than one packed tile holds (one bf16 head at
+    tp == num_kv_heads, two fp8 heads) is padded to it, i.e. twice the
+    bytes the shape says (tests/test_tpu_compile.py)."""
+    probe_pages = 8
+    shape = (
+        model_config.num_layers,
+        probe_pages,
+        page_size,
+        model_config.num_kv_heads,
+        model_config.head_dim_,
+    )
+    alloc = jax.jit(
+        lambda: jnp.zeros(shape, kv_dtype), out_shardings=kv_format
+    )
+    pool = alloc.lower().compile().memory_analysis().output_size_in_bytes
+    return 2 * pool // probe_pages
+
+
 # Pipeline entry: (dispatch index, kind "prefill"|"decode", device
 #                  out-token array — or a (candidates, accept-counts)
 #                  pair under speculative decoding —,
@@ -584,18 +654,6 @@ class EngineCore:
                 "chunked prefill can start mid-prompt (the bucketed "
                 "executables always compute positions 0..T)"
             )
-        num_pages = self.cfg.num_pages or self._auto_num_pages()
-        sched_cfg = SchedulerConfig(
-            max_num_seqs=self.cfg.max_num_seqs,
-            num_pages=num_pages,
-            page_size=self.cfg.page_size,
-            max_model_len=self.cfg.max_model_len,
-            enable_prefix_caching=self.cfg.enable_prefix_caching,
-        )
-        self.scheduler = Scheduler(sched_cfg)
-        self.scheduler.on_preempt = self._on_scheduler_preempt
-        self._pages_per_seq = sched_cfg.pages_per_seq
-
         # Pin the KV pool to row-major layout at every jit boundary. Left
         # to itself XLA picks a different parameter layout than the Pallas
         # custom call's required default, then inserts FOUR full-pool
@@ -608,11 +666,35 @@ class EngineCore:
             NamedSharding(m, kv_page_pspec(model_config, m.shape[TP_AXIS]))
             for m in self._stage_meshes
         ]
+        # A model that takes the XLA attention path on a TPU (the shape
+        # rules of ops/dispatch._tp_heads_ok) runs no custom call, and
+        # the pin would only force the compiler's own compact layout
+        # through a padded copy: its pool stays in the default layout.
+        kernel, _ = _dispatch.decode_kernel_plan(
+            model_config.num_heads,
+            model_config.num_kv_heads,
+            mesh=self.mesh,
+            backend=self.model.attn_backend,
+        )
+        pin = not (on_tpu() and kernel == "xla")
         self._kv_formats = [
-            Format(Layout(tuple(range(5))), sh) for sh in self._kv_shardings
+            Format(Layout(tuple(range(5))), sh) if pin else sh
+            for sh in self._kv_shardings
         ]
         self._kv_sharding = self._kv_shardings[-1]
         self._kv_format = self._kv_formats[-1]
+        num_pages = self.cfg.num_pages or self._auto_num_pages()
+        sched_cfg = SchedulerConfig(
+            max_num_seqs=self.cfg.max_num_seqs,
+            num_pages=num_pages,
+            page_size=self.cfg.page_size,
+            max_model_len=self.cfg.max_model_len,
+            enable_prefix_caching=self.cfg.enable_prefix_caching,
+        )
+        self.scheduler = Scheduler(sched_cfg)
+        self.scheduler.on_preempt = self._on_scheduler_preempt
+        self._pages_per_seq = sched_cfg.pages_per_seq
+
         if self.pp > 1:
             self.k_pages = []
             self.v_pages = []
@@ -624,10 +706,12 @@ class EngineCore:
                     self.cfg.page_size,
                     dtype=self.cfg.kv_dtype,
                     num_layers=hi - lo,
+                    placement=self._kv_formats[s],
                 )
-                self.k_pages.append(jax.device_put(k_s, self._kv_formats[s]))
-                self.v_pages.append(jax.device_put(v_s, self._kv_formats[s]))
+                self.k_pages.append(k_s)
+                self.v_pages.append(v_s)
                 total_bytes += 2 * k_s.size * k_s.dtype.itemsize
+            self.kv_pool_bytes = total_bytes
             logger.info(
                 "KV cache: %d pages x %d tokens (%.2f GiB total over %d "
                 "pipeline stages), %d slots",
@@ -638,19 +722,21 @@ class EngineCore:
                 self.cfg.max_num_seqs,
             )
         else:
-            k_pages, v_pages = make_kv_pages(
+            self.k_pages, self.v_pages = make_kv_pages(
                 model_config,
                 num_pages,
                 self.cfg.page_size,
                 dtype=self.cfg.kv_dtype,
+                placement=self._kv_format,
             )
-            self.k_pages = jax.device_put(k_pages, self._kv_format)
-            self.v_pages = jax.device_put(v_pages, self._kv_format)
+            self.kv_pool_bytes = (
+                2 * self.k_pages.size * self.k_pages.dtype.itemsize
+            )
             logger.info(
                 "KV cache: %d pages x %d tokens (%.2f GiB total), %d slots",
                 num_pages,
                 self.cfg.page_size,
-                2 * k_pages.size * k_pages.dtype.itemsize / 2**30,
+                self.kv_pool_bytes / 2**30,
                 self.cfg.max_num_seqs,
             )
 
@@ -1861,7 +1947,7 @@ class EngineCore:
             out0 = (out0, guard_sh)
         p_out = (repl, guard_sh) if g_on else repl
         self._decode_jits = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(fn, mode=mode),
                 in_shardings=(param_spec, kv, kv, st_sh),
                 out_shardings=(out0, kv, kv, st_sh),
@@ -1893,7 +1979,7 @@ class EngineCore:
             if g_on:
                 s_out0 = (s_out0, guard_sh)
             self._decode_jits_small = {
-                mode: jax.jit(
+                mode: _StepProgram(
                     partial(s_fn, mode=mode, k=ik),
                     in_shardings=(param_spec, kv, kv, st_sh),
                     out_shardings=(s_out0, kv, kv, st_sh),
@@ -1905,7 +1991,7 @@ class EngineCore:
         # speculation; the trailing decode-state arg shifts with them.
         nP = len(self._prefill_arg_shardings)  # 13 if spec else 12
         self._prefill_jits = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(self._prefill_fn, mode=mode),
                 in_shardings=(param_spec, kv, kv) + (repl,) * nP + (st_sh,),
                 out_shardings=(p_out, kv, kv, st_sh),
@@ -1915,7 +2001,7 @@ class EngineCore:
         }
         nC = nP + 3  # chunk args: 5 per-chunk + (10|11) group-invariant
         self._chunkfill_jits = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(self._chunkfill_fn, mode=mode),
                 in_shardings=(param_spec, kv, kv) + (repl,) * nC + (st_sh,),
                 out_shardings=(p_out, kv, kv, st_sh),
@@ -1930,7 +2016,7 @@ class EngineCore:
         # compose with run-ahead dispatch. Retraces per distinct page
         # count; restores are rare (preemption under pressure, handoff),
         # so the retrace cost is noise.
-        self._kv_insert_jit = jax.jit(
+        self._kv_insert_jit = _StepProgram(
             _dispatch.insert_kv_pages,
             in_shardings=(kv, repl, repl),
             out_shardings=kv,
@@ -1942,7 +2028,7 @@ class EngineCore:
         if self.mixed_step == "on":
             nM = nP + 3  # 4 per-iteration [K, ...] + (11|12) piggy-row args
             self._mixedfill_jits = {
-                mode: jax.jit(
+                mode: _StepProgram(
                     partial(self._mixedfill_fn, mode=mode),
                     in_shardings=(param_spec, kv, kv)
                     + (repl,) * nM
@@ -1993,7 +2079,7 @@ class EngineCore:
             kv_s = self._kv_formats[s]
             repl_s = self._stage_repl[s]
             n = n_data + (1 if with_h else 0)
-            return jax.jit(
+            return _StepProgram(
                 fn,
                 in_shardings=(stage_params[s], kv_s, kv_s)
                 + (repl_s,) * n,
@@ -2101,7 +2187,7 @@ class EngineCore:
 
         modes = ("greedy", "stochastic", "filtered")
         self._pp_decode_head = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(head_decode, mode=mode),
                 in_shardings=(head_sh, kv, kv, repl, st_sh),
                 out_shardings=(self._slot1, kv, kv, st_sh),
@@ -2111,7 +2197,7 @@ class EngineCore:
         }
         nP = len(self._prefill_arg_shardings)  # 12 (spec gated off)
         self._pp_prefill_head = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(head_prefill, mode=mode),
                 in_shardings=(head_sh, kv, kv, repl)
                 + (repl,) * nP
@@ -2123,7 +2209,7 @@ class EngineCore:
         }
         nC = nP + 3
         self._pp_chunkfill_head = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(head_chunkfill, mode=mode),
                 in_shardings=(head_sh, kv, kv, repl)
                 + (repl,) * nC
@@ -2135,7 +2221,7 @@ class EngineCore:
         }
         nM = nP + 3  # 4 per-iteration seg args + m_bt + 10 piggy-row args
         self._pp_mixed_head = {
-            mode: jax.jit(
+            mode: _StepProgram(
                 partial(mixed_iter, mode=mode),
                 in_shardings=(head_sh, kv, kv, repl)
                 + (repl,) * nM
@@ -2147,7 +2233,7 @@ class EngineCore:
         }
         # Per-stage KV whole-page scatter (restore/prefix-ingest path).
         self._kv_insert_jits = [
-            jax.jit(
+            _StepProgram(
                 _dispatch.insert_kv_pages,
                 in_shardings=(
                     self._kv_formats[s],
@@ -2425,37 +2511,35 @@ class EngineCore:
 
     def _auto_num_pages(self) -> int:
         """Size the KV pool from device HBM (vLLM gpu_memory_utilization
-        parity, ``vllm_worker.py:107``); conservative fallback off-TPU."""
-        cfg = self.model_config
-        tp = self.mesh.shape[TP_AXIS]
-        kv_frac = 1.0 / tp if cfg.num_kv_heads % tp == 0 and tp > 1 else 1.0
-        itemsize = jnp.dtype(self.cfg.kv_dtype).itemsize
-        page_bytes_dev = int(
-            2  # K and V
-            * cfg.num_layers
-            * self.cfg.page_size
-            * cfg.num_kv_heads
-            * cfg.head_dim_
-            * itemsize
-            * kv_frac
-        )
-        limit, used = None, 0
-        try:
-            stats = self.mesh.devices.flat[0].memory_stats()
-            if stats:
-                limit = stats.get("bytes_limit")
-                used = stats.get("bytes_in_use", 0)
-        except Exception:  # noqa: BLE001 — CPU backend has no memory_stats
-            pass
+        parity, ``vllm_worker.py:107``). A CPU run (tests) has no HBM to
+        read and gets a fixed small pool; a TPU that reports no
+        ``bytes_limit`` is an error — a guessed pool there would hide
+        the device."""
         max_useful = (
             self.cfg.max_num_seqs
             * (-(-self.cfg.max_model_len // self.cfg.page_size) + 1)
             + 1
         )
-        if limit is None:
+        if not on_tpu():
             return min(max_useful, 4096)
-        budget = int(limit * self.cfg.hbm_utilization) - used
-        num = max(2, budget // page_bytes_dev)
+        device = self.mesh.devices.flat[0]
+        stats = device.memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if not limit:
+            raise RuntimeError(
+                f"{device} reports no bytes_limit (memory_stats={stats!r}): "
+                "cannot size the KV pool; pass num_pages explicitly"
+            )
+        budget = int(limit * self.cfg.hbm_utilization) - stats.get(
+            "bytes_in_use", 0
+        )
+        page_bytes = kv_page_bytes_per_device(
+            self.model_config,
+            self.cfg.page_size,
+            self.cfg.kv_dtype,
+            self._kv_format,
+        )
+        num = max(2, budget // page_bytes)
         return int(min(num, max_useful))
 
     # --- request intake ---------------------------------------------------
@@ -4656,18 +4740,13 @@ class EngineCore:
                 except Exception:  # noqa: BLE001
                     dead = True
                 if dead:
-                    k_s, v_s = make_kv_pages(
+                    self.k_pages[s], self.v_pages[s] = make_kv_pages(
                         self.model_config,
                         self.scheduler.config.num_pages,
                         self.cfg.page_size,
                         dtype=self.cfg.kv_dtype,
                         num_layers=hi - lo,
-                    )
-                    self.k_pages[s] = jax.device_put(
-                        k_s, self._kv_formats[s]
-                    )
-                    self.v_pages[s] = jax.device_put(
-                        v_s, self._kv_formats[s]
+                        placement=self._kv_formats[s],
                     )
             return
         try:
@@ -4675,14 +4754,13 @@ class EngineCore:
         except Exception:  # noqa: BLE001
             dead = True
         if dead:
-            k_pages, v_pages = make_kv_pages(
+            self.k_pages, self.v_pages = make_kv_pages(
                 self.model_config,
                 self.scheduler.config.num_pages,
                 self.cfg.page_size,
                 dtype=self.cfg.kv_dtype,
+                placement=self._kv_format,
             )
-            self.k_pages = jax.device_put(k_pages, self._kv_format)
-            self.v_pages = jax.device_put(v_pages, self._kv_format)
 
     # --- metrics ----------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
@@ -4741,11 +4819,20 @@ class EngineCore:
             prefix_chunks_ingested=self.prefix_chunks_ingested,
             tokens_per_sec=self.total_generated_tokens / elapsed,
             devices=int(np.prod(list(self.full_mesh.shape.values()))),
-            # What this engine actually runs — the autotuned kernel and
-            # the pool dtype — so operators can see the calibration in
-            # heartbeats instead of guessing from env vars.
+            # What this engine actually runs — the attention backend,
+            # the decode kernel and the pool's dtype and geometry — so
+            # operators (and chip_smoke.py) see the device path itself
+            # instead of guessing from env vars.
+            attn_backend=(
+                _dispatch.resolve_backend()
+                if self.model.attn_backend == "auto"
+                else self.model.attn_backend
+            ),
             decode_kernel=kern,
             kv_dtype=str(jnp.dtype(self.cfg.kv_dtype)),
+            page_size=self.cfg.page_size,
+            num_pages=self.scheduler.config.num_pages,
+            kv_pool_bytes=self.kv_pool_bytes,
             # Resolved at build time (env pin / config / autotune) — may
             # differ from cfg.tp_overlap ("auto", or forced off on tp=1).
             tp_overlap=self.tp_overlap,
@@ -4854,6 +4941,12 @@ class EngineCore:
         if self.hbm_oom_events:
             s["hbm_oom_events"] = self.hbm_oom_events
             s["oom_degradations"] = list(self._oom_ladder_log)
+        # Device memory as the backend reports it (TPU; the CPU backend
+        # reports nothing and the keys are absent).
+        mem = self.mesh.devices.flat[0].memory_stats()
+        if mem and "bytes_limit" in mem:
+            s["hbm_bytes_limit"] = mem["bytes_limit"]
+            s["hbm_peak_bytes_in_use"] = mem.get("peak_bytes_in_use")
         # Numerics-integrity plane (superset-only: each block appears
         # once its knob is on / its counter moved — default-off
         # heartbeats stay byte-identical to pre-integrity builds).
@@ -4934,6 +5027,10 @@ class AsyncEngine:
         # thread-safe).
         self.rebuild_core: Optional[Any] = None
         self.on_device_fault: Optional[Any] = None
+        # A step program the compiler refused (StepCompileError) ends the
+        # engine: on_fatal(exc) tells the owner, fatal_error keeps it.
+        self.on_fatal: Optional[Any] = None
+        self.fatal_error: Optional[StepCompileError] = None
         self.engine_rebuilds = 0
         self.last_fault_reason: Optional[str] = None
         # Trips recorded by watchdogs of cores already rebuilt away;
@@ -4979,6 +5076,8 @@ class AsyncEngine:
     ) -> RequestOutput:
         import asyncio
 
+        if self.fatal_error is not None:
+            raise self.fatal_error
         if self._draining:
             raise RuntimeError("engine is draining for handoff")
         fut: Future = Future()
@@ -5005,6 +5104,8 @@ class AsyncEngine:
         may itself resolve with a HandoffOutput if THIS engine drains."""
         import asyncio
 
+        if self.fatal_error is not None:
+            raise self.fatal_error
         if self._draining:
             raise RuntimeError("engine is draining for handoff")
         fut: Future = Future()
@@ -5020,6 +5121,8 @@ class AsyncEngine:
             self._futures.pop(rid, None)
 
     def generate_sync(self, *, rid: str, **kwargs) -> RequestOutput:
+        if self.fatal_error is not None:
+            raise self.fatal_error
         fut: Future = Future()
         self._futures[rid] = fut
         self._intake.put(
@@ -5407,6 +5510,31 @@ class AsyncEngine:
         finally:
             timer.cancel()
 
+    def _fail_fatally(self, exc: Exception) -> None:
+        """On the engine thread: a program does not compile (a step
+        program caught by ``_StepProgram``, or any other whose error says
+        so — ``is_compile_failure``). No rebuild, no retry — fail every
+        pending request with the error (the worker leaves the jobs for
+        redelivery), refuse new ones, tell the owner (``on_fatal``; the
+        worker exits non-zero) and let the loop end."""
+        if not isinstance(exc, StepCompileError):
+            exc = StepCompileError(f"{type(exc).__name__}: {exc}")
+        logger.critical("engine stopping: %s", exc)
+        self.fatal_error = exc
+        self._draining = True
+        self._stop = True
+        if self.on_fatal is not None:
+            self.on_fatal(exc)  # the owner stops taking work first
+        self.core.abort_all("error")
+        while True:
+            try:
+                self._intake.get_nowait()
+            except queue.Empty:
+                break
+        for fut in list(self._futures.values()):
+            if not fut.done():
+                fut.set_exception(exc)
+
     # --- engine thread ----------------------------------------------------
     def _run_handoff(self) -> None:
         """On the engine thread: drain, extract, resolve. Outputs that
@@ -5526,6 +5654,9 @@ class AsyncEngine:
                     if fut is not None and not fut.done():
                         fut.set_result(out)
             except Exception as exc:  # noqa: BLE001 — keep the loop alive
+                if is_compile_failure(exc):
+                    self._fail_fatally(exc)
+                    break
                 reason = classify_failure(exc)
                 if reason == FAULT_OOM and self._degrade_and_restore(exc):
                     continue

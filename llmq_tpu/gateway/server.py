@@ -62,6 +62,7 @@ _FORWARDED_FIELDS = (
     "min_p",
     "stop",
     "seed",
+    "ignore_eos",  # vLLM's extra sampling parameter of the same name
     "deadline_ms",
 )
 

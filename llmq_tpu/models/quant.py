@@ -58,6 +58,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from llmq_tpu.utils.platform import on_tpu
+
 Params = Dict[str, Any]
 
 # Keys quantized under --dtype int8: every large matmul operand. Norms,
@@ -254,7 +256,7 @@ def matmul(x: jnp.ndarray, w: Any) -> jnp.ndarray:
                 w["q"],
                 w["scale"],
                 w["zero"],
-                interpret=jax.default_backend() != "tpu",
+                interpret=not on_tpu(),
             )
             return out.reshape(*lead, out.shape[-1])
         # XLA fallback: affine zero-points do not commute with the dot,
@@ -269,7 +271,7 @@ def matmul(x: jnp.ndarray, w: Any) -> jnp.ndarray:
                 x.reshape(-1, x.shape[-1]),
                 w["q"],
                 w["scale"],
-                interpret=jax.default_backend() != "tpu",
+                interpret=not on_tpu(),
             )
             return out.reshape(*lead, out.shape[-1])
         s = w["scale"].astype(x.dtype)

@@ -534,7 +534,7 @@ class Transformer:
                 # written in one block-scatter row each (~10 ms/chunk
                 # cheaper than the token scatter at 3B/8x256, measured).
                 kps, vps = attn_ops.write_prompt_kv_pages(
-                    kps, vps, k, v, block_tables, li
+                    kps, vps, k, v, block_tables, li, mesh=self.mesh
                 )
             else:
                 kps, vps = attn_ops.write_kv_pages(
@@ -950,11 +950,15 @@ def make_kv_pages(
     dtype=jnp.bfloat16,
     *,
     num_layers: Optional[int] = None,
+    placement: Any = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Allocate the paged KV cache: [L, P, page, n_kv, d] ×2.
 
     ``num_layers`` overrides the leading depth for per-stage pools under
-    pipeline parallelism (each stage caches only its own layers)."""
+    pipeline parallelism (each stage caches only its own layers).
+    ``placement`` (a sharding or layout ``Format``) creates the pools
+    already placed: a tp-sharded pool is sized per device and, whole,
+    would not fit the one device an unplaced ``zeros`` lands on."""
     shape = (
         config.num_layers if num_layers is None else num_layers,
         num_pages,
@@ -962,4 +966,7 @@ def make_kv_pages(
         config.num_kv_heads,
         config.head_dim_,
     )
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    if placement is None:
+        return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    alloc = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=placement)
+    return alloc(), alloc()

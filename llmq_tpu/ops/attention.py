@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps softmax NaN-free
 
@@ -243,34 +244,68 @@ def write_prompt_kv_pages(
     v_new: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, pages_per_seq]
     layer: jnp.ndarray,
+    mesh=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Page-granular prefill KV write (whole pages, not token rows).
 
     Prefill always covers positions ``[0, T)`` of each row, so when the
-    bucket ``T`` is a page multiple the scatter can write whole
-    ``[page_size, n_kv, d]`` blocks — one scatter row per *page* instead
-    of per *token*. Measured on v5e at 3B/8x256: the token scatter costs
-    ~10.5 ms per prefill chunk (2048 rows x 512 B); this page form is
-    ~64 KB per row and drops it to noise.
+    bucket ``T`` is a page multiple each page is one contiguous
+    ``[page_size, n_kv, d]`` block — one write per *page* instead of per
+    *token* (the token scatter measured ~10.5 ms per 8x256 chunk at 3B on
+    v5e: 2048 rows x 512 B).
+
+    The pages go in with a loop of ``dynamic_update_slice``, not one
+    block scatter: for a scatter whose window spans the page axis the TPU
+    compiler re-tiles the whole pool (tokens, not kv heads, on the
+    sublanes) for the duration of the layer scan, i.e. a transposed copy
+    of BOTH pools before and after it — a temporary as large as the
+    pools, which at the worker's 90%-of-HBM pool size fails to compile
+    (``RESOURCE_EXHAUSTED``; tests/test_tpu_compile.py guards the temp
+    size). The slice update is in place in the pool's own layout, and
+    GSPMD partitions it over a kv-head-sharded pool without a gather.
 
     Rows shorter than ``T`` write garbage into the tail of their last
     page(s); that space is never read (attention masks by context length)
     and is overwritten token-by-token as decode extends the sequence.
     Padded rows carry an all-zero block table and land on the reserved
-    scratch page 0 (same convention as ``write_kv_pages``).
+    scratch page 0 (same convention as ``write_kv_pages``). Block-table
+    entries are allocator page ids, always inside the pool.
     """
     B, T, n_kv, d = k_new.shape
     page_size = k_pages.shape[-3]
     assert T % page_size == 0, "bucket must be page-aligned for page writes"
     n_lp = T // page_size
     phys = block_tables[:, :n_lp].reshape(B * n_lp)
+    if mesh is not None:
+        # The pool is replicated over every mesh axis but the kv heads',
+        # so each device writes every page: under sequence parallelism
+        # gather the token axis once, here, instead of paying a
+        # collective per page inside the loop. The other axes stay
+        # GSPMD's to place.
+        free = PartitionSpec.UNCONSTRAINED
+        whole_prompt = NamedSharding(mesh, PartitionSpec(free, None, free, free))
+        k_new = jax.lax.with_sharding_constraint(k_new, whole_prompt)
+        v_new = jax.lax.with_sharding_constraint(v_new, whole_prompt)
     # Cast to the pool dtype (fp8 KV caches quantize on write).
-    k_new = k_new.astype(k_pages.dtype)
-    v_new = v_new.astype(v_pages.dtype)
-    k_blocks = k_new.reshape(B * n_lp, page_size, n_kv, d)
-    v_blocks = v_new.reshape(B * n_lp, page_size, n_kv, d)
-    k_pages = k_pages.at[layer, phys].set(k_blocks, mode="drop")
-    v_pages = v_pages.at[layer, phys].set(v_blocks, mode="drop")
+    k_blocks = k_new.astype(k_pages.dtype).reshape(
+        B * n_lp, 1, 1, page_size, n_kv, d
+    )
+    v_blocks = v_new.astype(v_pages.dtype).reshape(
+        B * n_lp, 1, 1, page_size, n_kv, d
+    )
+
+    def write_page(pools, page):
+        kp, vp = pools
+        where, k_block, v_block = page
+        at = (layer, where, 0, 0, 0)
+        return (
+            jax.lax.dynamic_update_slice(kp, k_block, at),
+            jax.lax.dynamic_update_slice(vp, v_block, at),
+        ), None
+
+    (k_pages, v_pages), _ = jax.lax.scan(
+        write_page, (k_pages, v_pages), (phys, k_blocks, v_blocks)
+    )
     return k_pages, v_pages
 
 

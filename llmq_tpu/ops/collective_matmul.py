@@ -56,13 +56,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):  # jax 0.4.x: pre-promotion location
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    jax.shard_map = _shard_map_impl
-
 from llmq_tpu.models import quant as qm
 from llmq_tpu.parallel.mesh import DP_AXIS, TP_AXIS
+from llmq_tpu.utils.platform import on_tpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,20 +87,13 @@ def ring_plan(mesh: Optional[Mesh]) -> Optional[TpRingPlan]:
 
 
 def _shard_mapped(fn, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the rep-check rename: jax 0.4.x takes
-    ``check_rep``, newer releases renamed it ``check_vma``. The check is
-    off either way — the ring treats its ``all_gather`` output as
-    replicated, which the checker cannot always prove."""
-    try:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except TypeError:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
+    """``jax.shard_map`` with the varying-type check off: the ring treats
+    its ``all_gather`` output as replicated, which the checker cannot
+    always prove."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _pallas_chunk_matmul() -> bool:
@@ -243,7 +232,7 @@ def row_parallel_matmul(
 
                 return int4_matmul_pallas(
                     x_local, qc, sc, zc,
-                    interpret=jax.default_backend() != "tpu",
+                    interpret=not on_tpu(),
                 )
             return x_local @ qm.dequantize_int4_parts(
                 qc, sc, zc, x_local.dtype
@@ -273,7 +262,7 @@ def row_parallel_matmul(
 
                 return int8_matmul_pallas(
                     x_local, qc, sc,
-                    interpret=jax.default_backend() != "tpu",
+                    interpret=not on_tpu(),
                 )
             return (x_local @ qc.astype(x_local.dtype)) * sc.astype(
                 x_local.dtype

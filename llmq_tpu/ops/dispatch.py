@@ -1,24 +1,31 @@
-"""Attention backend dispatch: Pallas kernels on TPU, XLA elsewhere.
+"""Attention backend dispatch: Pallas kernels on TPU, XLA on a CPU run.
 
 The model code (``models/transformer.py``) calls these two functions; the
 backend is resolved once at trace time:
 
 - ``LLMQ_ATTN_BACKEND`` env var: ``auto`` (default) | ``pallas`` | ``xla``.
-- ``auto`` → Pallas on TPU, pure-XLA reference elsewhere.
-- ``pallas`` off-TPU runs the kernels in interpreter mode (slow, for
-  numerics tests — tests/test_pallas_attention.py).
+- ``auto`` → compiled Pallas on a TPU; the pure-XLA reference when the
+  process was started with ``JAX_PLATFORMS=cpu``; an error on anything
+  else (``utils/platform.on_tpu`` — a process that lost its chip must
+  not carry on as a CPU run).
+- ``pallas`` on such a CPU run runs the kernels in interpreter mode
+  (slow, for numerics tests — tests/test_pallas_attention.py). On a TPU a
+  kernel is never interpreted.
 
 Tensor parallelism: under GSPMD a ``pallas_call`` is an opaque custom
 call XLA cannot partition, so when a mesh with a >1 ``tp`` axis is
 passed, the kernel is wrapped in ``jax.shard_map`` sharded over the
 head axes (attention is embarrassingly parallel over heads). Head counts
-that don't divide tp fall back to the XLA path, which GSPMD partitions
-however it likes — mirrors the replication fallback in
-``parallel/sharding.py``.
+that don't divide tp, or that leave a shard a single kv head, take the
+XLA path, which GSPMD partitions however it likes — mirrors the
+replication rule in ``parallel/sharding.py`` — and say so once in the
+log (``_tp_heads_ok``).
 """
 
 from __future__ import annotations
 
+import functools
+import logging
 import os
 from typing import Optional
 
@@ -26,15 +33,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):  # jax 0.4.x: pre-promotion location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    jax.shard_map = _shard_map
-
 from llmq_tpu.ops import attention as xla_ops
 from llmq_tpu.ops import pallas_attention as pk
 from llmq_tpu.ops import ring_attention as ring
 from llmq_tpu.parallel.mesh import SP_AXIS, TP_AXIS
+from llmq_tpu.utils.platform import on_tpu
+
+logger = logging.getLogger(__name__)
 
 _WINDOW_DISABLED = 1 << 30
 
@@ -42,14 +47,56 @@ _WINDOW_DISABLED = 1 << 30
 def resolve_backend() -> str:
     env = os.environ.get("LLMQ_ATTN_BACKEND", "auto").lower()
     if env == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "pallas" if on_tpu() else "xla"
     if env not in ("pallas", "xla"):
         raise ValueError(f"LLMQ_ATTN_BACKEND={env!r} (want auto|pallas|xla)")
     return env
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
+
+
+@functools.lru_cache(maxsize=None)
+def _tp_heads_ok(n_heads: int, n_kv: int, tp: int) -> bool:
+    """The documented shape rules for the head-sharded kernels under tp:
+    both head counts divide tp, and a shard keeps at least two kv heads.
+
+    The second is the pool's layout: the kernels read pages as
+    ``[page, n_kv, d]`` blocks with the kv heads on the sublanes, and one
+    bf16 head does not fill a packed sublane pair. The compiler then pads
+    the shard's pool to twice its bytes AND, preferring its own compact
+    layout for the KV scatter, copies the whole pool into the kernel's
+    layout in every layer (compiled for v5e, qwen2.5-7b at tp=4: a
+    temporary the size of the pools; tests/test_tpu_compile.py). Such a
+    model takes the XLA path with the pool in the compiler's layout.
+
+    Cached per shape so giving way is logged once, not at every trace."""
+    if tp == 1:
+        return True
+    if n_heads % tp or n_kv % tp:
+        why = "do not divide"
+    elif n_kv // tp < 2:
+        why = "leave fewer than two kv heads a shard at"
+    else:
+        return True
+    logger.warning(
+        "attention: %d query / %d kv heads %s tp=%d; this model takes the "
+        "XLA attention path under GSPMD, not the Pallas kernels",
+        n_heads, n_kv, why, tp,
+    )
+    return False
+
+
+def _shard_over_heads(call, *, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` for a head-sharded kernel call. The varying-type
+    check is off: a ``pallas_call`` declares plain output shapes, and the
+    checker (on by default since shard_map left ``jax.experimental``)
+    refuses an output that does not say how it varies over the mesh."""
+    return jax.shard_map(
+        call, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _window_scalar(sliding_window) -> jnp.ndarray:
@@ -88,7 +135,7 @@ def prefill_attention(
             sliding_window=sliding_window, softcap=softcap,
         )
     tp = _tp_degree(mesh)
-    tp_ok = tp == 1 or (n_heads % tp == 0 and n_kv % tp == 0)
+    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
         return xla_ops.full_prefill_attention(
             q, k, v, scale=scale, lengths=lengths,
@@ -107,7 +154,7 @@ def prefill_attention(
     if tp > 1:
         assert mesh is not None
         head = P(None, None, TP_AXIS, None)
-        call = jax.shard_map(
+        call = _shard_over_heads(
             call,
             mesh=mesh,
             in_specs=(head, head, head, P(), P()),
@@ -145,7 +192,7 @@ def chunked_prefill_attention(
     backend = resolve_backend() if backend == "auto" else backend
     n_heads, n_kv = q.shape[2], k_pages.shape[-2]
     tp = _tp_degree(mesh)
-    tp_ok = tp == 1 or (n_heads % tp == 0 and n_kv % tp == 0)
+    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     stacked = k_pages.ndim == 5
     if backend != "pallas" or not tp_ok:
         return xla_ops.paged_prefill_attention(
@@ -176,7 +223,7 @@ def chunked_prefill_attention(
             if stacked
             else P(None, None, TP_AXIS, None)
         )
-        call = jax.shard_map(
+        call = _shard_over_heads(
             call,
             mesh=mesh,
             in_specs=(
@@ -210,7 +257,7 @@ def decode_kernel_plan(
     if kern not in ("v1", "v2", "v3"):
         raise ValueError(f"LLMQ_DECODE_KERNEL={kern!r} (want v1|v2|v3)")
     tp = _tp_degree(mesh)
-    tp_ok = tp == 1 or (n_heads % tp == 0 and n_kv % tp == 0)
+    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
         return "xla", False
     return kern, kern == "v3"
@@ -236,7 +283,7 @@ def verify_kernel_plan(
     the fused verify ``lax.scan``."""
     backend = resolve_backend() if backend == "auto" else backend
     tp = _tp_degree(mesh)
-    tp_ok = tp == 1 or (n_heads % tp == 0 and n_kv % tp == 0)
+    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
         return "xla", False
     return "chunked_prefill", False
@@ -263,7 +310,7 @@ def mixed_kernel_plan(
     the fused mixed-block ``lax.scan``."""
     backend = resolve_backend() if backend == "auto" else backend
     tp = _tp_degree(mesh)
-    tp_ok = tp == 1 or (n_heads % tp == 0 and n_kv % tp == 0)
+    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
         return "xla", False
     return "chunked_prefill", False
@@ -303,9 +350,9 @@ def resolve_tp_overlap(
         return "off"
     if mode != "auto":
         return mode
-    if jax.default_backend() != "tpu" or not (hidden_size and intermediate_size):
-        # Nothing to measure off-TPU (ICI overlap is the whole point),
-        # and without shapes an A/B would be meaningless.
+    if not on_tpu() or not (hidden_size and intermediate_size):
+        # Nothing to measure on a CPU run (ICI overlap is the whole
+        # point), and without shapes an A/B would be meaningless.
         return "off"
     from llmq_tpu.engine.kernel_autotune import autotune_tp_overlap
 
@@ -362,7 +409,7 @@ def decode_attention_fused_write(
             else P(None, None, TP_AXIS, None)
         )
         row_spec = P(None, TP_AXIS, None)
-        call = jax.shard_map(
+        call = _shard_over_heads(
             call,
             mesh=mesh,
             in_specs=(
@@ -396,7 +443,7 @@ def decode_attention(
     stacked = k_pages.ndim == 5
     n_heads, n_kv = q.shape[1], k_pages.shape[-2]
     tp = _tp_degree(mesh)
-    tp_ok = tp == 1 or (n_heads % tp == 0 and n_kv % tp == 0)
+    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
         return xla_ops.paged_decode_attention(
             q, k_pages, v_pages, block_tables, context_lens,
@@ -435,7 +482,7 @@ def decode_attention(
             if stacked
             else P(None, None, TP_AXIS, None)
         )
-        call = jax.shard_map(
+        call = _shard_over_heads(
             call,
             mesh=mesh,
             in_specs=(

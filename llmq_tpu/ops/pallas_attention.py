@@ -40,9 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # pre-rename name on jax 0.4.x
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 _LANES = 128  # VPU lane count: scratch m/l are stored lane-replicated
 

@@ -42,9 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # pre-rename name on jax 0.4.x
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 
 def _kahan_add(acc_ref, comp_ref, p):
     """Compensated accumulation: acc += p with the rounding error of each
@@ -101,8 +98,10 @@ def _int4_matmul_kernel(
     # Unpack: even K rows sit in the low nibble, odd in the high —
     # stacking on a new axis then collapsing restores the row order
     # (same layout as models/quant.py::unpack_int4).
-    lo = (qp & 0xF).astype(jnp.float32)
-    hi = (qp >> 4).astype(jnp.float32)
+    # (Through int32: Mosaic has no uint8 -> float32 cast.)
+    qi = qp.astype(jnp.int32)
+    lo = (qi & 0xF).astype(jnp.float32)
+    hi = (qi >> 4).astype(jnp.float32)
     w4 = jnp.stack([lo, hi], axis=1).reshape(bk, bn)
     # Affine dequant per group in f32 (the single definition of the
     # math lives in models/quant.py::dequantize_int4_parts — this block
@@ -255,6 +254,11 @@ def int4_matmul_pallas(
         zero = jnp.pad(zero, ((0, 0), (0, np_ - N)))
     nk = K // bk
     gpb = bk // group
+    # One K block covers gpb (<= 4 at group 128) group rows, and a TPU
+    # block's second-minor dim must be a multiple of 8 or the whole axis:
+    # split the group axis [G, N] -> [nk, gpb, N] (a free reshape) so each
+    # block IS a whole axis. The kernel still sees [gpb, bn].
+    group_spec = pl.BlockSpec((None, gpb, bn), lambda i, j, k: (k, 0, j))
 
     out = pl.pallas_call(
         functools.partial(_int4_matmul_kernel, nk=nk, group=group),
@@ -262,8 +266,8 @@ def int4_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((gpb, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((gpb, bn), lambda i, j, k: (k, j)),
+            group_spec,
+            group_spec,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
@@ -275,5 +279,5 @@ def int4_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, q, scale, zero)
+    )(x, q, scale.reshape(nk, gpb, np_), zero.reshape(nk, gpb, np_))
     return out[:M, :N]

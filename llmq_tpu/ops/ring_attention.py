@@ -26,11 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):  # jax 0.4.x: pre-promotion location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    jax.shard_map = _shard_map
-
 from llmq_tpu.parallel.mesh import SP_AXIS, TP_AXIS
 
 NEG_INF = -1e30
@@ -75,10 +70,7 @@ def _ring_body(
         # (they depend on axis_index and the sharded q), so their initial
         # values must be marked varying over every manual mesh axis for
         # shard_map's type checker.
-        # jax 0.4.x has no varying-type checker (pcast) — the marker is
-        # an identity there.
-        pcast = getattr(jax.lax, "pcast", lambda x, axes, to: x)
-        m0, l0, acc0 = pcast(
+        m0, l0, acc0 = jax.lax.pcast(
             (
                 jnp.full((B, H, L, 1), NEG_INF, jnp.float32),
                 jnp.zeros((B, H, L, 1), jnp.float32),

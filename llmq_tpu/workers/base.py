@@ -50,7 +50,7 @@ from llmq_tpu.broker.manager import (
     kv_fetch_queue_name,
 )
 from llmq_tpu.core.config import Config, get_config
-from llmq_tpu.core.faults import DeviceFaultError
+from llmq_tpu.core.faults import DeviceFaultError, StepCompileError
 from llmq_tpu.core.models import Job, Result, WorkerHealth, utcnow
 from llmq_tpu.core.pipeline import PipelineConfig
 from llmq_tpu.obs import (
@@ -149,6 +149,7 @@ class BaseWorker(abc.ABC):
         self.jobs_deadline_exceeded = 0
         self.jobs_quarantined = 0
         self.breaker_tripped = False
+        self._fatal_error: Optional[BaseException] = None
         # Disaggregated serving: the configured role ("unified" runs the
         # monolith path unchanged) and the role currently served (differs
         # from `role` only for "auto", whose controller flips role_active
@@ -254,6 +255,17 @@ class BaseWorker(abc.ABC):
                 await asyncio.sleep(1.0)
         finally:
             await self.shutdown()
+        if self._fatal_error is not None:
+            raise self._fatal_error
+
+    def fail_fatally(self, exc: BaseException) -> None:
+        """Stop this worker for a fault that no retry cures (the engine
+        cannot compile a step program). In-flight jobs drain back to the
+        queue as usual; ``run()`` then raises ``exc``, so the process
+        exits non-zero instead of idling as a worker that looks up."""
+        self.logger.critical("Worker stopping on a fatal error: %s", exc)
+        self._fatal_error = exc
+        self.request_shutdown()
 
     def request_shutdown(self) -> None:
         if self.running:
@@ -785,6 +797,24 @@ class BaseWorker(abc.ABC):
                 job.id, "dropped", worker_id=self.worker_id, reason=str(exc)
             )
             await message.ack()
+        except StepCompileError as exc:
+            # The engine cannot compile a step program: the worker is
+            # stopping (fail_fatally) and the job is not at fault.
+            # Settling it here would only hand it straight back to this
+            # worker until its redeliveries run out, so leave it
+            # unsettled, as a crash would: the broker returns it to the
+            # queue when this connection closes, for a worker that can
+            # compile.
+            self.logger.error(
+                "Job %s not run, left for redelivery: %s",
+                job.id,
+                exc,
+                extra={"job_id": job.id},
+            )
+            emit_trace_event(
+                job.id, "requeued", worker_id=self.worker_id,
+                reason="step_compile_error",
+            )
         except DeviceFaultError as exc:
             # Classified device fault the engine could not absorb
             # in-process (rebuild unavailable/failed, OOM ladder dry).

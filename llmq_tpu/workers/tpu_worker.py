@@ -24,6 +24,7 @@ import os
 import socket
 import time
 from collections import OrderedDict
+from functools import partial
 from pathlib import Path
 from typing import Any, Callable, List, Optional
 
@@ -128,6 +129,11 @@ class TPUWorker(BaseWorker):
         # accelerator. None (the default) builds the real AsyncEngine.
         self._engine_factory = engine_factory
         self.engine = None
+        # Seconds the decode-kernel probing child cost this start (it
+        # runs before the engine exists); None when no child ran (env
+        # pin, CPU run, or this process already held the chip). Rides
+        # _engine_stats beside the decode_kernel it chose.
+        self._kernel_probe_s: Optional[float] = None
         self._usage: dict = {}
         # Terminal finish_reason held between generate() and
         # _build_result, which pops it onto the result as an extra so the
@@ -227,20 +233,25 @@ class TPUWorker(BaseWorker):
         # Engine construction compiles XLA programs and possibly loads a
         # multi-GB checkpoint: run off the event loop so broker heartbeats
         # and signals stay live. The kernel A/B runs FIRST, while no JAX
-        # backend is initialised in this process (libtpu is exclusive).
+        # backend is initialised in this process: a chip belongs to one
+        # process, so the probing child can only have it before we do
+        # (kernel_autotune refuses, loudly, once we hold it).
         loop = asyncio.get_running_loop()
         if self._engine_factory is None:
             await loop.run_in_executor(None, self._autotune_kernel)
             await loop.run_in_executor(None, self._autotune_tp_overlap)
         self.engine = await loop.run_in_executor(None, self._build_engine)
-        # The fault callback fires on the engine thread mid-recovery;
-        # breaker accounting belongs on the event loop.
+        # The fault callbacks fire on the engine thread; breaker
+        # accounting and shutdown belong on the event loop.
         self.engine.on_device_fault = (
             lambda reason: loop.call_soon_threadsafe(
                 self._note_device_fault, reason
             )
         )
-        self.logger.info("Engine ready: %s", self.engine.stats())
+        self.engine.on_fatal = lambda exc: loop.call_soon_threadsafe(
+            self.fail_fatally, exc
+        )
+        self.logger.info("Engine ready: %s", self._engine_stats())
 
     def _model_config_host(self):
         """Resolve the model architecture host-side (no device contact):
@@ -267,6 +278,7 @@ class TPUWorker(BaseWorker):
         cfg = self._model_config_host()
         if cfg is None:
             return
+        t0 = time.monotonic()
         choice = autotune_decode_kernel(
             num_heads=cfg.num_heads,
             num_kv_heads=cfg.num_kv_heads,
@@ -283,6 +295,7 @@ class TPUWorker(BaseWorker):
         )
         if choice is not None:
             os.environ["LLMQ_DECODE_KERNEL"] = choice
+            self._kernel_probe_s = round(time.monotonic() - t0, 1)
 
     def _autotune_tp_overlap(self) -> None:
         """Resolve ``tp_overlap=auto`` by A/B-ing the ppermute rings
@@ -335,12 +348,15 @@ class TPUWorker(BaseWorker):
         — the unit the device-fault recovery path rebuilds in-process.
         First build and post-fault rebuilds share this exact code so a
         recovered engine is configured identically to the original."""
+        import jax
         import jax.numpy as jnp
 
         from llmq_tpu.engine.engine import EngineConfig, EngineCore
         from llmq_tpu.engine.tokenizer import ByteTokenizer, HFTokenizer
         from llmq_tpu.models.transformer import init_params
         from llmq_tpu.parallel import make_mesh
+        from llmq_tpu.parallel.sharding import param_shardings
+        from llmq_tpu.utils.platform import on_tpu
 
         mesh = make_mesh(
             tensor_parallel=self.tensor_parallel,
@@ -367,12 +383,25 @@ class TPUWorker(BaseWorker):
 
             name = spec.split("://", 1)[1] or "tiny"
             model_config = get_preset(name)
-            import jax
-
             self.logger.info("Preset model %s (random weights)", name)
-            params = init_params(
-                model_config, jax.random.key(0), dtype=dtype, quantize=quantize
+            init = partial(
+                init_params, model_config, dtype=dtype, quantize=quantize
             )
+            if quantize or self.pipeline_parallel > 1:
+                # Eager: quantize-at-init frees each full-precision
+                # tensor by donation as it goes (a 9B int8 tree fits a
+                # chip its bf16 tree does not), and pp stages place their
+                # own slices in EngineCore.
+                params = init(jax.random.key(0))
+            else:
+                # Build every weight already on its shards: unsharded on
+                # the default device, a model sized for the whole mesh
+                # (qwen2.5-7b bf16 is 15.2 GB) cannot start on one 16 GB
+                # chip. Same values either way — the random bits do not
+                # depend on the sharding.
+                params = jax.jit(
+                    init, out_shardings=param_shardings(mesh, model_config)
+                )(jax.random.key(0))
             tokenizer = ByteTokenizer()
         else:
             from llmq_tpu.engine.weights import load_checkpoint
@@ -410,16 +439,13 @@ class TPUWorker(BaseWorker):
             )
         if self._page_size:
             overrides["page_size"] = self._page_size
-        else:
-            import jax
-
-            if jax.default_backend() == "tpu":
-                # 128-token pages: the decode kernel moves one page per
-                # grid step, and 16 KB transfers are latency-bound ~6x
-                # off the HBM bandwidth floor (measured round 2); 128
-                # tokens make them 64 KB and quarter the grid. The
-                # engine's 32-token default is CPU-test-friendly only.
-                overrides["page_size"] = 128
+        elif on_tpu():
+            # 128-token pages: the decode kernel moves one page per
+            # grid step, and 16 KB transfers are latency-bound ~6x
+            # off the HBM bandwidth floor (measured round 2); 128
+            # tokens make them 64 KB and quarter the grid. The
+            # engine's 32-token default is CPU-test-friendly only.
+            overrides["page_size"] = 128
         if self._num_pages:
             overrides["num_pages"] = self._num_pages
         chunk = self._prefill_chunk_size or self.config.prefill_chunk_size
@@ -475,7 +501,10 @@ class TPUWorker(BaseWorker):
         if self._engine_factory is not None:
             return self._engine_factory(self)
         from llmq_tpu.engine.engine import AsyncEngine
+        from llmq_tpu.utils.platform import enable_compile_cache
 
+        cache_dir = enable_compile_cache()  # before the first compile
+        self.logger.info("Compile cache: %s", cache_dir or "off")
         engine = AsyncEngine(self._build_core())
         # Device-fault containment wiring: the engine thread calls
         # rebuild_core() to replace a faulted EngineCore in-process.
@@ -1066,7 +1095,12 @@ class TPUWorker(BaseWorker):
                     break
                 text = tokenizer.decode(st["tokens"][:n])
                 st["flushed_n"] = n
-                delta = text[st["sent"] :]
+                # An output that ends inside a multi-byte character
+                # decodes to trailing U+FFFDs which the next token may
+                # still turn into one real character. A sent frame cannot
+                # be taken back, so hold them: the done frame
+                # (_stream_finish) flushes whatever is left as it stands.
+                delta = text.rstrip("\ufffd")[st["sent"] :]
                 if not delta:
                     continue
                 frame = {
@@ -1426,6 +1460,8 @@ class TPUWorker(BaseWorker):
         if self.engine is None:
             return None
         stats = self.engine.stats()
+        if self._kernel_probe_s is not None:
+            stats["decode_kernel_probe_s"] = self._kernel_probe_s
         # Superset-only: rebuild accounting appears once a fault happened.
         if self.engine.engine_rebuilds:
             stats["engine_rebuilds"] = self.engine.engine_rebuilds

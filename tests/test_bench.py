@@ -6,7 +6,6 @@ preset picking — gets unit coverage beyond the CPU smoke runs.
 """
 
 import json
-import os
 import subprocess
 
 import pytest
@@ -29,28 +28,47 @@ def _reset_fallback():
     bench._QUANT_FALLBACK = None
 
 
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
 class TestBackendStamp:
-    def test_healthy_backend(self):
-        stamp = bench._backend_stamp("tpu", None)
-        assert stamp == {"platform": "tpu", "fallback": False}
+    """A metric line says which device measured it, as JAX reports it;
+    and there is no line at all from a run that wanted a chip and did
+    not get one (bench.init_devices)."""
 
-    def test_cpu_fallback_is_structured(self):
-        stamp = bench._backend_stamp(
-            "cpu", "fell back to cpu: probe failed or hung"
-        )
-        assert stamp["platform"] == "cpu"
-        assert stamp["fallback"] is True
-        assert "probe failed" in stamp["probe_note"]
+    def test_chip_run_is_stamped_with_the_device(self):
+        stamp = bench._backend_stamp([_Dev("tpu", "TPU v5 lite")])
+        assert stamp == {
+            "platform": "tpu", "device_kind": "TPU v5 lite", "count": 1,
+        }
 
-    def test_requested_cpu_is_not_a_fallback(self):
-        # JAX_PLATFORMS=cpu (tests, CI) returns no note: the platform is
-        # cpu by request, and the stamp must not smell like a failure.
-        stamp = bench._backend_stamp("cpu", None)
-        assert stamp == {"platform": "cpu", "fallback": False}
+    def test_no_chip_exits_nonzero_instead_of_falling_back(self, monkeypatch):
+        # JAX came up on the CPU although nobody asked for it (chip absent
+        # or held): no CPU number may come out under the bench's name.
+        import jax
+
+        from llmq_tpu.utils import platform
+
+        monkeypatch.setattr(platform, "cpu_requested", lambda: False)
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev("cpu", "cpu")])
+        with pytest.raises(SystemExit) as exit_info:
+            bench.init_devices()
+        assert exit_info.value.code not in (0, None)
+        assert "not a TPU" in str(exit_info.value.code)
+
+    def test_requested_cpu_is_the_cpu_not_a_failure(self):
+        # JAX_PLATFORMS=cpu (tests, CI): the rehearsal runs, and is
+        # stamped as what it is.
+        _jax, devices = bench.init_devices()
+        stamp = bench._backend_stamp(devices)
+        assert stamp["platform"] == "cpu" and stamp["count"] == len(devices)
 
     def test_stamp_is_json_serializable(self):
-        stamp = bench._backend_stamp("cpu", "fell back to cpu: x")
+        stamp = bench._backend_stamp([_Dev("tpu", "TPU v5 lite")] * 4)
         assert json.loads(json.dumps(stamp)) == stamp
+        assert stamp["count"] == 4
 
 
 class TestQuantAttemptParsing:
@@ -140,56 +158,33 @@ class TestPickPreset:
         assert bench.pick_preset(gb8, "tpu", int8=True) == "qwen2.5-3b"
 
 
-class TestLastHardwareMetricLine:
-    """bench._last_hardware_metric_line: the CPU-fallback re-emit source.
-    Newest PERF_RESULTS/*.log wins; within a file the last valid metric
-    line (value > 0, no error) wins; watchdog/failure lines never
-    qualify."""
+class TestNoBorrowedNumbers:
+    """What replaced the CPU-fallback re-emit: the bench prints what it
+    measured on the device it ran on, or nothing. No old hardware line is
+    re-emitted under a new run, no peak is assumed for a device the
+    table does not know, no backend failure turns into a metric line."""
 
-    def _log(self, root, name, payloads, mtime):
-        path = root / "PERF_RESULTS" / name
-        path.parent.mkdir(exist_ok=True)
-        path.write_text(
-            "\n".join(
-                p if isinstance(p, str) else json.dumps(p) for p in payloads
-            )
-            + "\n"
-        )
-        os.utime(path, (mtime, mtime))
+    def test_no_backend_exits_nonzero(self, monkeypatch):
+        import jax
 
-    def test_no_results_dir(self, tmp_path, monkeypatch):
-        monkeypatch.chdir(tmp_path)
-        assert bench._last_hardware_metric_line() is None
+        def boom():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
 
-    def test_last_valid_line_of_newest_log_wins(self, tmp_path, monkeypatch):
-        monkeypatch.chdir(tmp_path)
-        old = {"metric": "m", "value": 4000.0, "vs_baseline": 0.8}
-        early = {"metric": "m", "value": 4500.0, "vs_baseline": 0.9}
-        final = {"metric": "m", "value": 4700.0, "vs_baseline": 0.94}
-        self._log(tmp_path, "bench_old.log", [old], mtime=1000)
-        self._log(
-            tmp_path, "bench_new.log",
-            ["bench: noise line", early, final], mtime=2000,
-        )
-        assert bench._last_hardware_metric_line() == final
+        monkeypatch.setattr(jax, "devices", boom)
+        with pytest.raises(SystemExit) as exit_info:
+            bench.init_devices()
+        assert "no backend" in str(exit_info.value.code)
 
-    def test_failure_lines_never_qualify(self, tmp_path, monkeypatch):
-        monkeypatch.chdir(tmp_path)
-        self._log(
-            tmp_path, "bench_bad.log",
-            [
-                {"metric": "m", "value": 0.0, "vs_baseline": 0.0,
-                 "error": "hung"},
-                {"metric": "m", "value": 0.0, "vs_baseline": 0.0},
-                "not json {",
-            ],
-            mtime=3000,
-        )
-        good = {"metric": "m", "value": 4800.0, "vs_baseline": 0.96}
-        self._log(tmp_path, "bench_good.log", [good], mtime=1000)
-        # The newest file holds only disqualified lines; the older
-        # hardware measurement is still the answer.
-        assert bench._last_hardware_metric_line() == good
+    def test_known_device_kinds_have_their_published_peak(self):
+        assert bench.peak_flops_per_chip([_Dev("tpu", "TPU v5 lite")]) == 197e12
+        assert bench.peak_flops_per_chip([_Dev("tpu", "TPU v5e")]) == 197e12
+        assert bench.peak_flops_per_chip([_Dev("tpu", "TPU v4")]) == 275e12
+
+    def test_unknown_device_kind_is_an_error_not_a_default(self):
+        for dev in (_Dev("tpu", "TPU v9 mystery"), _Dev("cpu", "cpu")):
+            with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+                bench.peak_flops_per_chip([dev])
+        assert not hasattr(bench, "_last_hardware_metric_line")
 
 
 class TestTrimPlan:

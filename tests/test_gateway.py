@@ -443,11 +443,12 @@ class TestGatewayValidation:
 
         p = h._build_payload(
             {"prompt": "x", "max_tokens": 5, "stop": ["\n"],
-             "priority": "batch", "internal_field": 1},
+             "ignore_eos": True, "priority": "batch", "internal_field": 1},
             chat=False,
         )
         assert p["priority"] == "batch"
         assert p["max_tokens"] == 5 and p["stop"] == ["\n"]
+        assert p["ignore_eos"] is True
         assert "internal_field" not in p
         assert p["id"].startswith("gw-")
 

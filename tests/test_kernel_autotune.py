@@ -42,32 +42,82 @@ def test_disabled_by_flag(monkeypatch):
     assert ka.autotune_decode_kernel(**SHAPES) is None
 
 
-def test_probe_choice_from_child(monkeypatch):
+def _probe_applies(monkeypatch, *, holds_chip=False):
+    """Pretend a TPU host whose calling process has (not) touched JAX."""
     monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # pretend: probe applies
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     monkeypatch.delenv("LLMQ_KERNEL_AUTOTUNE", raising=False)
+    monkeypatch.setattr(
+        ka, "_probe_blocked", lambda: "holds the chip" if holds_chip else None
+    )
+
+
+def test_probe_choice_from_child(monkeypatch):
+    _probe_applies(monkeypatch)
     monkeypatch.setattr(subprocess, "run", _fake_run("v2"))
     assert ka.autotune_decode_kernel(**SHAPES) == "v2"
 
 
-def test_child_failure_falls_back(monkeypatch):
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    monkeypatch.delenv("LLMQ_KERNEL_AUTOTUNE", raising=False)
-    monkeypatch.setattr(subprocess, "run", _fake_run("junk", rc=3))
+def test_child_failure_is_loud_and_starts_on_default(monkeypatch, capsys):
+    """A failed probe is an ERROR with the child's output, never a quiet
+    'v1 won': non-zero exit (a kernel did not compile), junk answer,
+    timeout."""
+    _probe_applies(monkeypatch)
+    monkeypatch.setattr(
+        subprocess, "run",
+        _fake_run("junk", rc=3, detail="MosaicError: kernel refused"),
+    )
     assert ka.autotune_decode_kernel(**SHAPES) == "v1"
+    err = capsys.readouterr().err
+    assert "probe FAILED" in err and "exit 3" in err
+    assert "MosaicError: kernel refused" in err
 
     def boom(*a, **k):
         raise subprocess.TimeoutExpired(cmd="x", timeout=1)
 
     monkeypatch.setattr(subprocess, "run", boom)
     assert ka.autotune_decode_kernel(**SHAPES) == "v1"
+    assert "probe FAILED: no answer" in capsys.readouterr().err
+
+
+def test_no_child_from_a_process_that_holds_the_chip(monkeypatch, capsys):
+    """One process per chip: once the caller has initialised JAX a child
+    that needs the chip can only fail or hang, so none is started."""
+    _probe_applies(monkeypatch, holds_chip=True)
+
+    def never(*a, **k):
+        raise AssertionError("spawned a child that needs the chip")
+
+    monkeypatch.setattr(subprocess, "run", never)
+    assert ka.autotune_decode_kernel(**SHAPES) is None
+    assert ka.autotune_tp_overlap(
+        hidden_size=64, intermediate_size=128
+    ) is None
+    assert capsys.readouterr().err.count("probe NOT RUN") == 2
+
+
+def test_probe_blocked_tracks_backend_init():
+    """The real check: this test process has initialised JAX (conftest
+    asks for devices), so a child would be refused."""
+    import jax
+
+    jax.devices()
+    assert "holds the chip" in ka._probe_blocked()
 
 
 class TestChildCache:
     """resolve_choice: the child-side cache keyed by shapes AND the
-    measuring chip/toolchain identity (~/.cache may be NFS-shared across
-    a fleet mixing chip generations)."""
+    measuring chip/toolchain identity (a checkout may be NFS-shared
+    across a fleet mixing chip generations)."""
+
+    def test_default_path_is_inside_the_checkout(self, monkeypatch):
+        from pathlib import Path
+
+        monkeypatch.delenv("LLMQ_AUTOTUNE_CACHE", raising=False)
+        monkeypatch.setenv("HOME", "/nonexistent-home")
+        path = ka.cache_path_from_env()
+        repo = Path(ka.__file__).resolve().parents[2]
+        assert repo in path.parents and "nonexistent-home" not in str(path)
 
     def test_measure_then_cache_roundtrip(self, monkeypatch, tmp_path):
         cache = tmp_path / "autotune.json"

@@ -1,0 +1,330 @@
+"""The main path, compiled for a TPU v5e that is described, not attached.
+
+libtpu's compiler is installed wherever the tests run, and it compiles
+for a topology given by name (``on-chip-measurement`` guide, section 2).
+Interpret-mode tests cannot see what it refuses: a block shape that
+breaks the tiling rules, a kernel over its VMEM budget, a step program
+that does not fit HBM. Every case here is a program the worker really
+runs at qwen2.5-3b widths (192 slots, 16/2 heads, d=128, 128-token
+pages), or the per-shard shapes of tp=4. Nothing executes,
+so nothing here is a time or a result — a pass means "the chip's
+compiler accepts it", no more.
+
+``ops/dispatch`` asks ``on_tpu()`` whether to interpret; the process is
+a CPU run, so the cases that go through it steer ``_interpret`` here, in
+the test, rather than through an option of the program.
+"""
+
+import dataclasses
+import os
+from functools import partial
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.layout import Format, Layout
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from llmq_tpu.core.faults import classify_failure, is_compile_failure
+from llmq_tpu.models.presets import get_preset
+from llmq_tpu.models.transformer import Transformer, init_params
+from llmq_tpu.ops import dispatch
+from llmq_tpu.ops import pallas_attention as pk
+from llmq_tpu.ops import pallas_matmul as pm
+from llmq_tpu.parallel.mesh import TP_AXIS, make_mesh
+
+Q3B = get_preset("qwen2.5-3b")
+Q7B = get_preset("qwen2.5-7b")
+L8B = get_preset("llama3.1-8b")
+SLOTS, PAGE, POOL_PAGES, PAGES_PER_SEQ = 192, 128, 1024, 65
+H, NKV, D = Q3B.num_heads, Q3B.num_kv_heads, Q3B.head_dim_
+SCALE = D**-0.5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described chip; the whole file skips where it cannot be
+    described. The persistent compile cache is off around the cases: an
+    entry written by a compile-only client cannot be read back without a
+    chip, and the next run would warn instead of staying silent."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+class _Shapes:
+    """ShapeDtypeStructs placed on the described devices."""
+
+    def __init__(self, topo, mesh=None):
+        self.mesh = mesh
+        self.one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def __call__(self, shape, dtype, spec=None):
+        sharding = (
+            self.one_chip
+            if self.mesh is None
+            else NamedSharding(self.mesh, spec if spec is not None else P())
+        )
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def pool(self, layers=Q3B.num_layers, dtype=jnp.bfloat16, n_kv=NKV):
+        return self((layers, POOL_PAGES, PAGE, n_kv, D), dtype)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel in it"
+    return compiled
+
+
+def _decode(kernel, pool_dtype=jnp.bfloat16):
+    def case(topo, monkeypatch):
+        s = _Shapes(topo)
+        pool = s.pool(dtype=pool_dtype)
+        _compile(
+            partial(kernel, scale=SCALE),
+            s((SLOTS, H, D), jnp.bfloat16), pool, pool,
+            s((SLOTS, PAGES_PER_SEQ), jnp.int32), s((SLOTS,), jnp.int32),
+            s((1,), jnp.int32), s((1,), jnp.int32),
+        )
+
+    return case
+
+
+def _decode_v3(topo, monkeypatch):
+    s = _Shapes(topo)
+    row = s((SLOTS, NKV, D), jnp.bfloat16)
+    _compile(
+        partial(pk.paged_decode_attention_pallas_v3, scale=SCALE),
+        s((SLOTS, H, D), jnp.bfloat16), s.pool(), s.pool(), row, row,
+        s((SLOTS, PAGES_PER_SEQ), jnp.int32), s((SLOTS,), jnp.int32),
+        s((1,), jnp.int32), s((1,), jnp.int32),
+    )
+
+
+def _flash_prefill(T):
+    def case(topo, monkeypatch):
+        s = _Shapes(topo)
+        kv = s((4, T, NKV, D), jnp.bfloat16)
+        _compile(
+            partial(pk.flash_prefill_attention_pallas, scale=SCALE),
+            s((4, T, H, D), jnp.bfloat16), kv, kv,
+            s((4,), jnp.int32), s((1,), jnp.int32),
+        )
+
+    return case
+
+
+def _paged_prefill(topo, monkeypatch):
+    s = _Shapes(topo)
+    _compile(
+        partial(pk.paged_prefill_attention_pallas, scale=SCALE),
+        s((4, 512, H, D), jnp.bfloat16), s.pool(), s.pool(),
+        s((4, PAGES_PER_SEQ), jnp.int32), s((4,), jnp.int32),
+        s((4,), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32),
+    )
+
+
+def _int8_matmul(topo, monkeypatch):
+    s = _Shapes(topo)
+    K, N = Q3B.hidden_size, Q3B.intermediate_size
+    _compile(
+        pm.int8_matmul_pallas,
+        s((SLOTS, K), jnp.bfloat16), s((K, N), jnp.int8), s((N,), jnp.bfloat16),
+    )
+
+
+def _int4_matmul(K, N):
+    """Both MLP shapes: up (K=2048, whole 1024-row K tiles do not exist
+    for the group axis) and down (K=11008 = 86 groups, no multiple of 8
+    divides it). The group tile was never legal before PR 24."""
+
+    def case(topo, monkeypatch):
+        s = _Shapes(topo)
+        G = K // 128
+        _compile(
+            pm.int4_matmul_pallas,
+            s((SLOTS, K), jnp.bfloat16), s((K // 2, N), jnp.uint8),
+            s((G, N), jnp.bfloat16), s((G, N), jnp.bfloat16),
+        )
+
+    return case
+
+
+def _decode_tp4_shard_map(topo, monkeypatch):
+    """tp=4 through the dispatch the engine uses: the v1 kernel under
+    ``shard_map`` on a 4-device mesh at llama3.1-8b widths (8 query / 2 kv
+    heads a shard), the pool sharded on its kv-head axis."""
+    monkeypatch.setattr(dispatch, "_interpret", lambda: False)
+    cfg = L8B
+    mesh = make_mesh(tensor_parallel=4, devices=topo.devices)
+    s = _Shapes(topo, mesh)
+    heads = P(None, TP_AXIS, None)
+    pool = s(
+        (cfg.num_layers, POOL_PAGES, PAGE, cfg.num_kv_heads, D),
+        jnp.bfloat16,
+        P(None, None, None, TP_AXIS, None),
+    )
+
+    def step(q, kp, vp, bt, cl, layer):
+        return dispatch.decode_attention(
+            q, kp, vp, bt, cl, scale=SCALE, mesh=mesh, backend="pallas",
+            layer=layer,
+        )
+
+    # The engine pins the pool row-major at every jit boundary
+    # (EngineCore._kv_formats); so does the test, or the compiler picks a
+    # parameter layout of its own and copies both pools into the kernel's.
+    kv = Format(Layout(tuple(range(5))), pool.sharding)
+    compiled = (
+        jax.jit(step, in_shardings=(None, kv, kv, None, None, None))
+        .lower(
+            s((SLOTS, cfg.num_heads, D), jnp.bfloat16, heads), pool, pool,
+            s((SLOTS, PAGES_PER_SEQ), jnp.int32), s((SLOTS,), jnp.int32),
+            s((), jnp.int32),
+        )
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    # Per device: a quarter of both pools as arguments, no copy of either.
+    pool_bytes = 2 * cfg.num_layers * POOL_PAGES * PAGE * cfg.num_kv_heads * D * 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 0.3 * pool_bytes
+    assert mem.temp_size_in_bytes < 0.01 * pool_bytes
+
+
+def _one_kv_head_a_shard_takes_the_xla_path(topo, monkeypatch):
+    """qwen2.5-7b at tp=4 (28/4 heads) leaves a shard one kv head. The
+    kernels' pool layout cannot hold that without padding and a per-layer
+    pool copy (the page-bytes case below), so the dispatch gives way —
+    and says so, once."""
+    mesh = make_mesh(tensor_parallel=4, devices=topo.devices)
+    for plan in (
+        dispatch.decode_kernel_plan,
+        dispatch.verify_kernel_plan,
+        dispatch.mixed_kernel_plan,
+    ):
+        assert plan(Q7B.num_heads, Q7B.num_kv_heads, mesh, "pallas") == (
+            "xla", False,
+        )
+    assert dispatch.decode_kernel_plan(
+        L8B.num_heads, L8B.num_kv_heads, mesh, "pallas"
+    ) == ("v1", False)
+
+
+def _pool_page_bytes_come_from_the_compiler(topo, monkeypatch):
+    """The pool is sized in pages of what a page really takes on a
+    device. In the kernels' row-major layout one bf16 kv head a shard
+    (qwen2.5-7b at tp=4) is padded to a packed pair — twice the bytes the
+    shape says — which is why that model's pool is left in the compiler's
+    own layout (unpadded) and its attention to XLA. qwen2.5-3b on one chip
+    (two heads) is unpadded; its fp8 pool is padded back to the bf16
+    size."""
+    from llmq_tpu.engine.engine import kv_page_bytes_per_device
+    from llmq_tpu.parallel.sharding import kv_page_pspec
+
+    def page_bytes(cfg, tp, dtype, pinned=True):
+        mesh = make_mesh(tensor_parallel=tp, devices=topo.devices[:tp])
+        sharding = NamedSharding(mesh, kv_page_pspec(cfg, tp))
+        fmt = Format(Layout(tuple(range(5))), sharding) if pinned else sharding
+        return kv_page_bytes_per_device(cfg, PAGE, dtype, fmt)
+
+    def by_shape(cfg, tp, itemsize):
+        return (
+            2 * cfg.num_layers * PAGE * cfg.num_kv_heads * D * itemsize // tp
+        )
+
+    assert page_bytes(Q3B, 1, jnp.bfloat16) == by_shape(Q3B, 1, 2)
+    assert page_bytes(Q7B, 4, jnp.bfloat16) == 2 * by_shape(Q7B, 4, 2)
+    assert page_bytes(Q7B, 4, jnp.bfloat16, pinned=False) == by_shape(Q7B, 4, 2)
+    assert page_bytes(Q3B, 1, jnp.float8_e5m2) == 2 * by_shape(Q3B, 1, 1)
+
+
+def _prefill_step(topo, monkeypatch, *, layers=2, pool_pages=POOL_PAGES):
+    monkeypatch.setattr(dispatch, "_interpret", lambda: False)
+    cfg = dataclasses.replace(Q3B, num_layers=layers)
+    s = _Shapes(topo)
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(
+            partial(init_params, cfg, dtype=jnp.bfloat16), jax.random.key(0)
+        ),
+    )
+    pool = s((layers, pool_pages, PAGE, NKV, D), jnp.bfloat16)
+    step = jax.jit(
+        Transformer(cfg, attn_backend="pallas").prefill, donate_argnums=(3, 4)
+    )
+    return step.lower(
+        params, s((4, 256), jnp.int32), s((4,), jnp.int32), pool, pool,
+        s((4, PAGES_PER_SEQ), jnp.int32),
+    ).compile()
+
+
+def _aligned_prefill_writes_pages_in_place(topo, monkeypatch):
+    """The regression guard for the pool copy: a page-aligned bucket
+    (256 = 2 pages) must not reserve a temporary the size of the KV
+    pools. As one block scatter the page write made the compiler
+    re-tile both pools around the layer scan — temp == both pools, and
+    at the worker's 90%-of-HBM pool the step did not fit the chip."""
+    layers = 2
+    compiled = _prefill_step(topo, monkeypatch, layers=layers)
+    pool_bytes = 2 * layers * POOL_PAGES * PAGE * NKV * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+def _refusal_is_a_compile_failure_not_a_device_fault(topo, monkeypatch):
+    """What the compiler says when a step does not fit HBM (a pool of
+    2 x 16 GiB on a 16 GiB chip) must never be classified as a device
+    fault: the fault plane would rebuild the engine and retry a program
+    that can never compile."""
+    with pytest.raises(Exception) as refused:  # noqa: PT011 — jaxlib's type
+        _prefill_step(topo, monkeypatch, layers=1, pool_pages=2**18)
+    assert "RESOURCE_EXHAUSTED" in str(refused.value)
+    assert is_compile_failure(refused.value)
+    assert classify_failure(refused.value) is None
+
+
+CASES = {
+    "decode_v1": _decode(pk.paged_decode_attention_pallas),
+    "decode_v2": _decode(pk.paged_decode_attention_pallas_v2),
+    "decode_v3_fused_write": _decode_v3,
+    "decode_v1_fp8_pool": _decode(
+        pk.paged_decode_attention_pallas, jnp.float8_e5m2
+    ),
+    "flash_prefill_T256": _flash_prefill(256),
+    "flash_prefill_T2048": _flash_prefill(2048),
+    "paged_prefill_C512": _paged_prefill,
+    "int8_matmul_192x2048x11008": _int8_matmul,
+    "int4_matmul_up_192x2048x11008": _int4_matmul(2048, 11008),
+    "int4_matmul_down_192x11008x2048": _int4_matmul(11008, 2048),
+    "decode_v1_tp4_shard_map_llama8b": _decode_tp4_shard_map,
+    "qwen7b_tp4_one_kv_head_a_shard_is_xla": (
+        _one_kv_head_a_shard_takes_the_xla_path
+    ),
+    "kv_page_bytes_padded_as_compiled": _pool_page_bytes_come_from_the_compiler,
+    "aligned_prefill_step_temp_far_below_pool": (
+        _aligned_prefill_writes_pages_in_place
+    ),
+    "hbm_refusal_is_compile_failure": (
+        _refusal_is_a_compile_failure_not_a_device_fault
+    ),
+}
+
+
+@pytest.mark.parametrize("case", CASES.values(), ids=CASES.keys())
+def test_compiles_for_v5e(case, topo, monkeypatch):
+    case(topo, monkeypatch)

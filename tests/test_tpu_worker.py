@@ -215,3 +215,48 @@ async def test_tpu_worker_result_carries_engine_trace(mem_url):
     assert walls == sorted(walls), f"timeline not monotone: {names}"
     decode = next(e for e in trace["events"] if e["name"] == "decode")
     assert decode["tokens"] == 4
+
+
+async def test_stream_frames_hold_back_a_split_multibyte_character(mem_url):
+    """The streamed frames must add up to the text of the final Result.
+    A character whose bytes arrive in different tokens decodes, while
+    incomplete, to U+FFFD — which must not be published, because a frame
+    cannot be taken back once the character completes."""
+    import json
+    from types import SimpleNamespace
+
+    from llmq_tpu.engine.tokenizer import ByteTokenizer
+
+    tok = ByteTokenizer()
+    engine = SimpleNamespace(
+        core=SimpleNamespace(tokenizer=tok),
+        set_token_callback=lambda rid, cb: None,
+        clear_token_callback=lambda rid: None,
+    )
+    worker = make_worker(mem_url, engine_factory=lambda w: engine)
+    worker.engine = engine
+    await worker.broker.connect()
+    try:
+        job = Job(id="s-utf8", prompt="x", stream=True)
+        assert await worker._stream_begin(job)
+        ids = tok.encode("a\u20acb")  # the euro sign is three bytes
+        for n_out, token in enumerate(ids, 1):
+            worker._note_stream_token(job.id, token, n_out)
+            await asyncio.sleep(0)  # one flush per token: the worst case
+            while worker._streams[job.id]["flushing"]:
+                await asyncio.sleep(0)
+        queue = worker._streams[job.id]["queue"]
+        await worker._stream_finish(
+            job, SimpleNamespace(finish_reason="length", text="a\u20acb")
+        )
+        text, frames = "", 0
+        while (msg := await worker.broker.broker.get(queue)) is not None:
+            frame = json.loads(msg.body)
+            assert frame["text_offset"] == len(text)
+            text += frame["text"]
+            frames += 1
+            await msg.ack()
+        assert text == "a\u20acb" and "\ufffd" not in text
+        assert frames >= 3  # "a", the euro sign once whole, "b", done
+    finally:
+        await worker.broker.disconnect()

@@ -19,6 +19,7 @@ from llmq_tpu.core.faults import (
     FAULT_XLA,
     DeviceFaultError,
     HungDispatchError,
+    StepCompileError,
     classify_failure,
 )
 from llmq_tpu.engine.watchdog import NO_GUARD, DispatchWatchdog
@@ -198,6 +199,29 @@ class TestClassifyFailure:
             (RuntimeError("mesh shape mismatch for collective"), FAULT_MESH),
             (ValueError("bad argument"), None),
             (KeyError("nope"), None),
+            # What the compiler says when it REFUSES a program is not a
+            # device fault (core/faults.is_compile_failure): the same
+            # program fails on every rebuild. Texts as libtpu 0.0.34 and
+            # jax 0.9.0 print them (tests/test_tpu_compile.py pins the
+            # first against the real compiler).
+            (
+                RuntimeError(
+                    "JaxRuntimeError: RESOURCE_EXHAUSTED: XLA:TPU compile "
+                    "permanent error. Ran out of memory in memory space hbm. "
+                    "Used 18.93G of 15.75G hbm."
+                ),
+                None,
+            ),
+            (RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel"), None),
+            (
+                ValueError(
+                    "The Pallas TPU lowering currently requires that the last "
+                    "two dimensions of your block shape are divisible by 8 "
+                    "and 128"
+                ),
+                None,
+            ),
+            (StepCompileError("step program 'decode_step' does not compile"), None),
         ],
     )
     def test_mapping(self, exc, want):

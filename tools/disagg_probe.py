@@ -270,6 +270,9 @@ async def main_async():
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     asyncio.run(main_async())
 
 

@@ -27,8 +27,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 import jax.numpy as jnp
 
@@ -218,6 +216,9 @@ async def run_xla_error_leg(baseline: dict):
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     baseline = run_baseline()
     asyncio.run(run_hang_leg(baseline))
     asyncio.run(run_oom_ladder_leg(baseline))

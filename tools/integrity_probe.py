@@ -27,8 +27,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 import jax.numpy as jnp
 
@@ -182,6 +180,9 @@ def run_canary_leg():
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     baseline = run_baseline()
     asyncio.run(run_guard_trip_leg(baseline))
     run_weight_audit_leg()

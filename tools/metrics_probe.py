@@ -148,6 +148,9 @@ async def run_trace_leg():
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     run_engine_leg()
     run_scrape_leg()
     asyncio.run(run_trace_leg())

@@ -107,6 +107,9 @@ def run_wire_leg(ref):
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     assert bubble_fraction(4, 2) == 1 / 5  # host-side math sanity
     if len(jax.devices()) < 2:
         print(

@@ -227,6 +227,9 @@ async def run_ship_leg():
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     run_reuse_leg()
     run_host_tier_leg()
     asyncio.run(run_ship_leg())

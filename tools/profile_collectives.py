@@ -34,16 +34,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 if os.environ.get("JAX_PLATFORMS", "") == "cpu":
     # CPU smoke mode: the collectives need >1 device, so force a virtual
     # 8-way host platform (same trick as tests/conftest.py) before any
-    # backend initialises. See profile_int8_matmul.py for why the config
-    # must also be pinned.
+    # backend initialises.
     _flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in _flags:
         os.environ["XLA_FLAGS"] = (
             _flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    from llmq_tpu.utils.platform import force_cpu_platform
-
-    force_cpu_platform()
 
 import jax
 import jax.numpy as jnp

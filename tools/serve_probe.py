@@ -30,8 +30,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 import jax.numpy as jnp
 
@@ -232,6 +230,9 @@ def run_cancel_leg():
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     run_sse_leg()
     run_preempt_leg()
     run_cancel_leg()

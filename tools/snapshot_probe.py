@@ -264,6 +264,9 @@ async def run_kill_resume_leg():
 
 
 def main():
+    from llmq_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     run_roundtrip_leg()
     run_swap_leg()
     asyncio.run(run_kill_resume_leg())
