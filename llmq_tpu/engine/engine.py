@@ -56,6 +56,7 @@ import dataclasses
 import logging
 import os
 import queue
+import re
 import threading
 import time
 from collections import deque
@@ -67,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Format, Layout
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from llmq_tpu.core.faults import (
@@ -110,6 +112,7 @@ from llmq_tpu.obs.metrics import (
     get_registry,
     to_ms,
 )
+from llmq_tpu.obs.spans import SpanRing
 from llmq_tpu.obs.trace import emit_trace_event
 from llmq_tpu.ops import dispatch as _dispatch
 from llmq_tpu.utils.host_mem import get_governor
@@ -138,6 +141,11 @@ ITL_BUCKETS: Tuple[float, ...] = (0.0001, 0.00025, 0.0005) + DEFAULT_BUCKETS
 _CANCEL_TTL_S = 5.0
 
 
+#: Whether a profile of this process is being taken: an atomic load, which
+#: ``AsyncEngine._run`` looks at once a turn (its span ring follows it).
+_profile_active = TraceAnnotation.is_enabled
+
+
 class _StepProgram:
     """One jitted step program that can tell a failure to *compile* from
     a fault while a compiled program runs.
@@ -155,10 +163,21 @@ class _StepProgram:
         inner = fn.func if isinstance(fn, partial) else fn
         self.name = getattr(inner, "__name__", "step")
         self._cold = False
+        #: variant (``variant_of``) -> the abstract arguments it was
+        #: compiled for, kept at each cold call: what ``scope_map`` needs
+        #: to look the compiled program up again. One entry a compiled
+        #: variant, like jit's own cache.
+        self.variants: Dict[str, tuple] = {}  # llmq: ignore[unbounded-host-buffer]
+        self._scope_maps: Dict[str, Dict[str, str]] = {}  # llmq: ignore[unbounded-host-buffer]
+        # Metadata only: the compiled program is the same with or without.
+        scope = "llmq." + self.name.replace("_block", "").replace(
+            "mixedfill", "mixed"
+        )
 
         def traced(*args, **kwargs):
             self._cold = True
-            return fn(*args, **kwargs)
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
 
         # Keep the step's own name on the compiled module (profiles and
         # compile-cache entries are read by it).
@@ -170,11 +189,47 @@ class _StepProgram:
     def __call__(self, *args):
         self._cold = False
         try:
-            return self._jit(*args)
+            out = self._jit(*args)
         except Exception as exc:
             if self._cold:
                 self._raise_if_uncompilable(args, exc)
             raise
+        if self._cold:  # compiled just now: seconds ago, once a variant
+            self.variants[self.variant_of(args)] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)
+                ),
+                args,
+            )
+        return out
+
+    @staticmethod
+    def variant_of(args) -> str:
+        """Which compiled variant of the program these arguments run:
+        the shape of the first argument after (params, k pool, v pool),
+        ``4x256`` for a prefill of 4 rows in the 256 bucket, and ``""``
+        where that is no array (the decode step takes its state)."""
+        shape = getattr(args[3], "shape", None) if len(args) > 3 else None
+        return "x".join(str(n) for n in shape) if shape else ""
+
+    def scope_map(self, variant: str) -> Optional[Dict[str, str]]:
+        """HLO instruction name -> the innermost ``llmq.*`` scope it was
+        traced under, for one compiled variant: read from the compiled
+        program's text, which a device trace names its events by. None
+        for a variant that never ran. Never on the hot path: a ring's
+        ``dump`` asks. Compiling again is a lookup where the compile
+        cache is on, and a compile where it is not. The cache's key
+        leaves metadata out: an entry written before the scopes existed
+        is found and carries none, until that cache is cleared."""
+        if variant not in self._scope_maps:
+            avals = self.variants.get(variant)
+            if avals is None:
+                return None
+            found = scopes_from_hlo_text(
+                self._jit.lower(*avals).compile().as_text()
+            )
+            self._scope_maps[variant] = found
+        return self._scope_maps[variant]
 
     def _raise_if_uncompilable(self, args, exc: Exception) -> None:
         try:
@@ -187,6 +242,34 @@ class _StepProgram:
             ) from exc
 
 
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name=\"([^\"]*)\"")
+_SCOPE = re.compile(r"llmq\.[\w.]+")
+_COLLECTIVE = re.compile(r"all-reduce|reduce-scatter|all-gather")
+
+
+def scopes_from_hlo_text(text: str) -> Dict[str, str]:
+    """``{instruction: scope}`` from a compiled module's text: each
+    instruction's ``op_name`` metadata carries the ``jax.named_scope``
+    path it was traced under; the innermost ``llmq.*`` name is its scope."""
+    out: Dict[str, str] = {}
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            scopes = _SCOPE.findall(m.group(2))
+            if not scopes:
+                continue
+            scope = scopes[-1]
+            if _COLLECTIVE.match(m.group(1)):
+                # The all-reduce GSPMD puts after a row-parallel matmul
+                # carries that matmul's scope.
+                if scope == "llmq.o_proj":
+                    scope = "llmq.tp.allreduce.o_proj"
+                elif scope == "llmq.mlp":
+                    scope = "llmq.tp.allreduce.down_proj"
+            out[m.group(1)] = scope
+    return out
+
+
 @dataclasses.dataclass
 class RequestOutput:
     """Final result of one generation request."""
@@ -197,10 +280,11 @@ class RequestOutput:
     prompt_tokens: int
     completion_tokens: int
     finish_reason: str  # "stop" | "length"
-    # Host-side monotonic lifecycle stamps (enqueued/admitted/
-    # prefill_start/first_token/last_token/finished + preempt_count),
-    # filled when the engine recorded them; workers project these onto
-    # the request trace. None for sequences that predate instrumentation.
+    # Host-side monotonic lifecycle stamps (engine_submit/enqueued/
+    # admitted/prefill_start/first_token/last_token/finished +
+    # preempt_count; the worker adds claimed), filled when the engine
+    # recorded them; workers project these onto the request trace. None
+    # for sequences that predate instrumentation.
     timing: Optional[Dict[str, float]] = None
     # Prefill-only requests (finish_reason="prefill_done") carry the
     # prompt-KV snapshot here for the decode-pool handoff; None always
@@ -1032,6 +1116,10 @@ class EngineCore:
         self._pending: Deque[_Pending] = deque()
         self._pending_decodes = 0  # decode entries within _pending
         self._defer_since: Optional[float] = None  # admission-deferral start
+        # The engine thread's span ring (obs/spans.py): off by default,
+        # and every site tests ``spans.on`` in place before it writes.
+        self.spans = SpanRing("engine")
+        self.spans.extra = self._dump_scopes
         self._deferred_pages: List[Tuple[int, List[int], int]] = []
         # Swap-to-host captures awaiting their deferred-release watermark:
         # (dispatch_idx, seq, pages, kv_valid, epoch-at-preemption). Each
@@ -1173,9 +1261,7 @@ class EngineCore:
             buckets=ITL_BUCKETS,
             labels={"class": "interactive"},
         )
-        # Keyed by dispatch kind ("prefill"/"decode"/"mixed") — a fixed
-        # set; the ring deques themselves carry maxlen.
-        self._dispatch_rings: Dict[str, Deque[float]] = {}  # llmq: ignore[unbounded-host-buffer]
+        # Keyed by dispatch kind ("prefill"/"decode"/"mixed"): a fixed set.
         self._dispatch_hists: Dict[str, Histogram] = {}  # llmq: ignore[unbounded-host-buffer]
         reg = get_registry()
         for metric in (
@@ -2783,6 +2869,8 @@ class EngineCore:
         can_admit = bool(want) and free > 0
         full = free >= (want if self.scheduler.running else 1)
         if not can_admit or full:
+            if self.spans.on and self._defer_since is not None:
+                self._span_admit_hold(free, expired=False)
             self._defer_since = None
         elif self._defer_since is None:
             self._defer_since = time.monotonic()
@@ -2795,6 +2883,10 @@ class EngineCore:
         # the latency that deferral trades away is exactly their SLO.
         if not (can_admit and (full or overdue or int_waiting)):
             return False
+        if self.spans.on:
+            if self._defer_since is not None:
+                self._span_admit_hold(free, expired=overdue)
+            self.spans.begin("admit", free=free)
         self._defer_since = None
         admitted = self.scheduler.admit(max_new=self.cfg.max_prefill_batch)
         # Host-tier promotion runs BEFORE anything else touches the wave:
@@ -2820,7 +2912,20 @@ class EngineCore:
             self._restore_batch(restored)
         if todo:
             self._prefill_batch(todo, finished)
+        if self.spans.on:
+            self.spans.end(
+                rows=len(admitted), waiting=len(self.scheduler.waiting)
+            )
         return bool(admitted)
+
+    def _span_admit_hold(self, free: int, *, expired: bool) -> None:
+        """An admission hold ends (the ring is on): it becomes a span from
+        the instant work first could have been admitted and was deferred."""
+        self.spans.add(
+            "admit_hold", int(self._defer_since * 1e9), time.monotonic_ns(),
+            free=free, waiting=len(self.scheduler.waiting),
+            expired=int(expired),
+        )
 
     # --- run-ahead pipeline ----------------------------------------------
     def _drain(self, finished: List[RequestOutput]) -> None:
@@ -2832,6 +2937,13 @@ class EngineCore:
         idx, kind, out, snapshot, g = self._pending.popleft()
         if kind in ("decode", "mixed"):
             self._pending_decodes -= 1
+        if self.spans.on:
+            # ``fetch``: the wait for this dispatch's tokens, caused by
+            # the dispatch; once they are on the host it becomes ``emit``:
+            # what the host then does with them, caused by the fetch.
+            self.spans.begin(
+                "fetch", self.spans.cause_of(idx), kind=kind, seq=idx
+            )
         if g is not None:
             # Evaluate the guard verdict BEFORE appending any of this
             # dispatch's tokens: a tripped dispatch's outputs are suspect
@@ -2846,6 +2958,8 @@ class EngineCore:
             block, starts = out
             with self._wd("mixed"):
                 tokens = np.asarray(block)
+            if self.spans.on:
+                self.spans.then("emit")
             for k in range(tokens.shape[0]):
                 for row, seq, epoch in snapshot:
                     if k < starts[row]:
@@ -2858,6 +2972,8 @@ class EngineCore:
                         continue
                     self._append_and_check(seq, int(tokens[k, row]), finished)
             self._processed_idx = idx
+            if self.spans.on:
+                self.spans.end()
             return
         if isinstance(out, tuple):
             # Speculative verify block: ([K, S, Q] candidates, [K, S]
@@ -2870,6 +2986,8 @@ class EngineCore:
             with self._wd("verify"):
                 emit = np.asarray(out[0])
                 counts = np.asarray(out[1])
+            if self.spans.on:
+                self.spans.then("emit")
             for k in range(emit.shape[0]):
                 for row, seq, epoch in snapshot:
                     n = int(counts[k, row])
@@ -2894,9 +3012,13 @@ class EngineCore:
                             seq, int(emit[k, row, i]), finished
                         )
             self._processed_idx = idx
+            if self.spans.on:
+                self.spans.end()
             return
         with self._wd("decode_block" if kind == "decode" else "prefill"):
             tokens = np.asarray(out)  # transfer started at dispatch; ~ready
+        if self.spans.on:
+            self.spans.then("emit")
         # Normalise to a [K, rows] block: prefill outputs and K=1 decode
         # steps are 1-D [rows]; fused decode blocks are already [K, S].
         # Iterating k-major reproduces exactly the per-step processing
@@ -2919,6 +3041,8 @@ class EngineCore:
                     continue
                 self._append_and_check(seq, int(k_tokens[row]), finished)
         self._processed_idx = idx
+        if self.spans.on:
+            self.spans.end()
 
     def _eval_guard(
         self,
@@ -3456,6 +3580,14 @@ class EngineCore:
                 chunk_args = jax.device_put(
                     (tokens, positions, bt, final, last), (repl,) * 5
                 )
+                if self.spans.on:
+                    self.spans.begin(
+                        "prefill_dispatch",
+                        program=getattr(self._chunkfill_jits[chunk_mode], "name", ""),
+                        mode=chunk_mode, variant=f"{B}x{C}", rows=len(rows),
+                        rids=[seq.rid for seq in rows],
+                        pending=len(self._pending),
+                    )
                 t0 = time.monotonic()
                 for seq in rows:
                     if seq.t_prefill_start == 0.0:
@@ -3483,6 +3615,12 @@ class EngineCore:
                     # still needs its drain-time verdict: ride the
                     # pipeline with an empty row snapshot.
                     self._push_pending("prefill", out, [], g)
+                if self.spans.on:
+                    # A chunk that ends no row and carries no guard pushes
+                    # nothing: no fetch will name it.
+                    self.spans.end_dispatch(
+                        self._dispatch_idx if snapshot or g is not None else 0
+                    )
                 # Interleave: let pre-wave sequences advance while the
                 # next chunk queues behind this one on the device stream
                 # (an idle engine's long first prompt must not pay an
@@ -3611,6 +3749,13 @@ class EngineCore:
                 # The executable must cover the piggy's sampler needs as
                 # well as the batch's (its first token samples here).
                 mode = sampling_mod.join_modes((self._mode, seq_mode))
+                if self.spans.on:
+                    self.spans.begin(
+                        "prefill_dispatch",
+                        program=getattr(self._mixedfill_jits[mode], "name", ""),
+                        mode=mode, variant=f"{K}x{C}", rows=1,
+                        rids=[seq.rid], pending=len(self._pending),
+                    )
                 t0 = time.monotonic()
                 if seq.t_prefill_start == 0.0:
                     seq.t_prefill_start = t0
@@ -3649,6 +3794,8 @@ class EngineCore:
                     ],
                     g,
                 )
+                if self.spans.on:
+                    self.spans.end_dispatch(self._dispatch_idx)
                 while len(self._pending) > self.cfg.runahead:
                     self._process_oldest(finished)
                 cur = pos
@@ -3711,6 +3858,13 @@ class EngineCore:
         chunk_mode = sampling_mod.join_modes(
             sampling_mod.required_mode(s.params) for s in chunk
         )
+        if self.spans.on:
+            self.spans.begin(
+                "prefill_dispatch",
+                program=getattr(self._prefill_jits[chunk_mode], "name", ""),
+                mode=chunk_mode, variant=f"{B}x{bucket}", rows=len(chunk),
+                rids=[seq.rid for seq in chunk], pending=len(self._pending),
+            )
         t0 = time.monotonic()
         for seq in chunk:
             if seq.t_prefill_start == 0.0:
@@ -3729,6 +3883,8 @@ class EngineCore:
         self.prefills += len(chunk)
         out, g = self._split_guard(out)
         self._push_pending("prefill", out, list(enumerate(chunk)), g)
+        if self.spans.on:
+            self.spans.end_dispatch(self._dispatch_idx)
         # The new rows' sampler mode must be honored from the next decode.
         self._mode = sampling_mod.join_modes((self._mode, chunk_mode))
 
@@ -3835,23 +3991,44 @@ class EngineCore:
             self._resync()
         return True
 
+    def _dump_scopes(self, dump: Dict[str, Any]) -> None:
+        """Add ``scopes`` to the ring's dump: ``{program: {"<mode>/
+        <variant>": {instruction: scope}}}``, every compiled variant of
+        each step program that a dispatch span still in the ring names:
+        what names the events of a device trace, whose runs may have been
+        launched before the ring was on (``_StepProgram.scope_map``)."""
+        named = {s.get("program") for s in dump["spans"]}
+        scopes: Dict[str, Dict[str, Dict[str, str]]] = {}
+        for jits in (
+            self._decode_jits, self._decode_jits_small, self._prefill_jits,
+            self._chunkfill_jits, getattr(self, "_mixedfill_jits", None),
+        ):
+            for mode, prog in (jits or {}).items():
+                if not isinstance(prog, _StepProgram) or prog.name not in named:
+                    continue
+                for variant in list(prog.variants):
+                    try:
+                        found = prog.scope_map(variant)
+                    except Exception:  # noqa: BLE001 — a dump never fails for this
+                        logger.exception("scope map of %s %s", prog.name, variant)
+                        continue
+                    scopes.setdefault(prog.name, {})[f"{mode}/{variant}"] = found
+        dump["scopes"] = scopes
+
     def _record_dispatch(self, kind: str, seconds: float) -> None:
         """Record the host wall-time of one device dispatch call into the
-        per-kind ring buffer + histogram. Dispatch is asynchronous, so
-        this measures the host-side launch cost, not device execution —
-        spikes mean the host blocked on the device (pipeline stalls)."""
-        ring = self._dispatch_rings.get(kind)
-        if ring is None:
-            ring = self._dispatch_rings[kind] = deque(maxlen=256)
-            hist = Histogram(
+        per-kind histogram. Dispatch is asynchronous, so this measures
+        the host-side launch cost, not device execution — spikes mean
+        the host blocked on the device (pipeline stalls)."""
+        hist = self._dispatch_hists.get(kind)
+        if hist is None:
+            hist = self._dispatch_hists[kind] = Histogram(
                 "llmq_dispatch_seconds",
                 "Host wall-time of one device dispatch call",
                 labels={"kind": kind},
             )
-            self._dispatch_hists[kind] = hist
             get_registry().register(hist)
-        ring.append(seconds)
-        self._dispatch_hists[kind].observe(seconds)
+        hist.observe(seconds)
         if self.on_dispatch is not None:
             self.on_dispatch(kind)
 
@@ -3871,6 +4048,13 @@ class EngineCore:
             # decode_block. Pure-batch steps keep the big fused K.
             jits, k_steps = self._decode_jits_small, self.interactive_decode_block
             kind += "_small"
+        if self.spans.on:
+            self.spans.begin(
+                "decode_dispatch", program=getattr(jits[self._mode], "name", ""),
+                mode=self._mode, variant="",
+                rows=len(self._decodable_seqs()), k_steps=k_steps,
+                pending=len(self._pending),
+            )
         with self._wd(kind):
             out, self.k_pages, self.v_pages, self._dev_state = (
                 jits[self._mode](
@@ -3891,6 +4075,8 @@ class EngineCore:
             ],
             g,
         )
+        if self.spans.on:
+            self.spans.end_dispatch(self._dispatch_idx)
         while len(self._pending) > self.cfg.runahead:
             self._process_oldest(finished)
 
@@ -4139,6 +4325,7 @@ class EngineCore:
         timing: Optional[Dict[str, float]] = None
         if seq.t_enqueue > 0.0:
             timing = {
+                "engine_submit": seq.t_submit,
                 "enqueued": seq.t_enqueue,
                 "admitted": seq.t_admit,
                 "prefill_start": seq.t_prefill_start,
@@ -4147,6 +4334,8 @@ class EngineCore:
                 "finished": time.monotonic(),
                 "preempt_count": float(seq.preempt_count),
             }
+            if self.spans.on:
+                self.spans.note_request(seq.rid, **timing)
         return RequestOutput(
             rid=seq.rid,
             text=text,
@@ -4836,23 +5025,11 @@ class EngineCore:
             # Resolved at build time (env pin / config / autotune) — may
             # differ from cfg.tp_overlap ("auto", or forced off on tp=1).
             tp_overlap=self.tp_overlap,
-            # Latency percentiles (ms; None until the histogram has data)
-            # and per-kind recent dispatch wall-times from the 256-entry
-            # ring buffers.
+            # Latency percentiles (ms; None until the histogram has data).
             ttft_p50_ms=to_ms(self.ttft_hist.percentile(0.50)),
             ttft_p95_ms=to_ms(self.ttft_hist.percentile(0.95)),
-            ttft_p99_ms=to_ms(self.ttft_hist.percentile(0.99)),
             itl_p50_ms=to_ms(self.itl_hist.percentile(0.50)),
             itl_p95_ms=to_ms(self.itl_hist.percentile(0.95)),
-            itl_p99_ms=to_ms(self.itl_hist.percentile(0.99)),
-            dispatch_ms={
-                kind: {
-                    "recent_avg": round(sum(ring) / len(ring) * 1000.0, 3),
-                    "count": self._dispatch_hists[kind].total,
-                }
-                for kind, ring in self._dispatch_rings.items()
-                if ring
-            },
         )
         if self.cfg.spec_tokens > 0:
             # What speculation actually dispatches: the multi-query
@@ -5030,6 +5207,10 @@ class AsyncEngine:
         # A step program the compiler refused (StepCompileError) ends the
         # engine: on_fatal(exc) tells the owner, fatal_error keeps it.
         self.on_fatal: Optional[Any] = None
+        # on_tracing(on): a profile of the process began or ended and the
+        # engine's ring followed it (called on the engine thread); the
+        # worker switches its own ring.
+        self.on_tracing: Optional[Any] = None
         self.fatal_error: Optional[StepCompileError] = None
         self.engine_rebuilds = 0
         self.last_fault_reason: Optional[str] = None
@@ -5084,7 +5265,7 @@ class AsyncEngine:
         self._futures[rid] = fut
         self._intake.put(
             (rid, prompt, messages, prompt_ids, params, None, deadline_at,
-             prefill_only, priority)
+             prefill_only, priority, time.monotonic())
         )
         self._wake.set()
         try:
@@ -5112,7 +5293,7 @@ class AsyncEngine:
         self._futures[rid] = fut
         self._intake.put(
             (rid, None, None, None, None, snapshot, deadline_at, False,
-             "batch")
+             "batch", time.monotonic())
         )
         self._wake.set()
         try:
@@ -5136,6 +5317,7 @@ class AsyncEngine:
                 kwargs.get("deadline_at"),
                 kwargs.get("prefill_only", False),
                 kwargs.get("priority", "batch"),
+                time.monotonic(),
             )
         )
         self._wake.set()
@@ -5464,6 +5646,9 @@ class AsyncEngine:
                     "fault recovery: rebuild failed; aborting the batch"
                 )
                 return False
+            # One ring per engine thread, whatever core it steps.
+            new_core.spans = self.core.spans
+            new_core.spans.extra = new_core._dump_scopes
             self.core = new_core
             new_core.on_token = self._dispatch_token  # streams survive rebuild
             del old  # free the faulted backend's buffers before stepping
@@ -5585,8 +5770,33 @@ class AsyncEngine:
             if ev is not None:
                 ev.set()
 
+    def set_tracing(self, on: bool) -> None:
+        """Switch the engine thread's span ring (``obs/spans.py``) on or
+        off, from any thread; it then stays so whether or not a profile
+        is being taken."""
+        self.call_on_engine(lambda: self.core.spans.set(on))
+
+    def trace_dump(self) -> Dict[str, Any]:
+        """The engine ring's dump: spans, request stamps, counters and
+        ``scopes`` (``EngineCore._dump_scopes``)."""
+        return self.core.spans.dump()
+
     def _run(self) -> None:
         while not self._stop:
+            ring = self.core.spans
+            if _profile_active() != ring.profiled:
+                # A profile of the process began or ended: the rings are
+                # on for as long as it is taken (on_tracing: the worker's).
+                ring.follow_profiler(not ring.profiled)
+                if self.on_tracing is not None:
+                    self.on_tracing(ring.profiled)
+            if ring.on:
+                ring.close_all()  # the last turn
+                ring.begin(
+                    "turn", 0, pending=len(self.core._pending),
+                    waiting=len(self.core.scheduler.waiting),
+                    running=len(self.core.scheduler.running),
+                )
             if self._handoff_requested:
                 self._run_handoff()
             while True:  # marshalled calls (prefix export/ingest)
@@ -5599,6 +5809,8 @@ class AsyncEngine:
                 except Exception as exc:  # noqa: BLE001 — caller's error
                     call_fut.set_exception(exc)
             drained = False
+            if ring.on and not self._intake.empty():
+                ring.begin("intake", n=self._intake.qsize())
             while True:
                 try:
                     item = self._intake.get_nowait()
@@ -5607,12 +5819,12 @@ class AsyncEngine:
                 if item is None:
                     continue
                 (rid, prompt, messages, prompt_ids, params, snapshot, dl,
-                 prefill_only, prio) = item
+                 prefill_only, prio, t_submit) = item
                 try:
                     if snapshot is not None:
-                        self.core.insert_request(snapshot, deadline_at=dl)
+                        seq = self.core.insert_request(snapshot, deadline_at=dl)
                     else:
-                        self.core.add_request(
+                        seq = self.core.add_request(
                             rid,
                             prompt=prompt,
                             messages=messages,
@@ -5622,11 +5834,14 @@ class AsyncEngine:
                             prefill_only=prefill_only,
                             priority=prio,
                         )
+                    seq.t_submit = t_submit
                     drained = True
                 except Exception as exc:  # tokenization/validation error
                     fut = self._futures.get(rid)
                     if fut is not None and not fut.done():
                         fut.set_exception(exc)
+            if ring.on and ring.inside("intake"):
+                ring.end()
             if not self.core.has_work and not drained:
                 # Idle integrity sweep (weight audit / KV spot-check /
                 # canary replay on their cadences; no-op at defaults).
@@ -5649,10 +5864,14 @@ class AsyncEngine:
                 continue
             try:
                 for out in self.core.step():
+                    if ring.on and not ring.inside("resolve"):
+                        ring.begin("resolve")
                     self._numerical_probation.pop(out.rid, None)
                     fut = self._futures.get(out.rid)
                     if fut is not None and not fut.done():
                         fut.set_result(out)
+                if ring.on and ring.inside("resolve"):
+                    ring.end()
             except Exception as exc:  # noqa: BLE001 — keep the loop alive
                 if is_compile_failure(exc):
                     self._fail_fatally(exc)
@@ -5690,6 +5909,8 @@ class AsyncEngine:
                 for fut in list(self._futures.values()):
                     if not fut.done():
                         fut.set_exception(failure)
+        if self.core.spans.on:
+            self.core.spans.close_all()
         # Loop exit (shutdown): catch the host up so in-flight steps are
         # processed and deferred pages release — the last futures resolve
         # several iterations before the run-ahead pipeline fully lands,

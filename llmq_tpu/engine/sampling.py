@@ -128,6 +128,7 @@ def _step_gumbel(key_data, steps, shape) -> jnp.ndarray:
     )(step_keys)
 
 
+@jax.named_scope("llmq.sample")
 def sample_tokens(
     logits: jnp.ndarray,  # [S, V] float32
     key_data: jnp.ndarray,  # [S, ...] per-slot PRNG key data (see make_base_key)
@@ -197,6 +198,7 @@ def sample_tokens(
     return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
+@jax.named_scope("llmq.sample")
 def spec_verify_tokens(
     logits: jnp.ndarray,  # [S, Q, V] float32 — Q = spec_tokens + 1 positions
     drafts: jnp.ndarray,  # [S, Q-1] int32 — proposed tokens (-1 = no draft)

@@ -229,6 +229,9 @@ class Sequence:
     t_first_token: float = 0.0
     t_last_token: float = 0.0
     t_preempt: float = 0.0
+    # AsyncEngine.generate's entry, on the caller's thread (0.0 for a
+    # sequence that came another way).
+    t_submit: float = 0.0
 
     @property
     def num_tokens(self) -> int:

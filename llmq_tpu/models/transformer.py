@@ -153,6 +153,7 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
+@jax.named_scope("llmq.mlp")
 def _mlp(
     h: jnp.ndarray,
     lp: Params,
@@ -170,7 +171,7 @@ def _mlp(
     # blocking all-reduce; with a tp-overlap plan it runs as the chunked
     # ppermute ring instead (plan=None is the literal qm.matmul).
     return _tap(
-        cm.row_parallel_matmul(act * up, lp["down_proj"], plan),
+        cm.row_parallel_matmul(act * up, lp["down_proj"], plan, "down_proj"),
         "mlp.down",
         layer,
     )
@@ -228,6 +229,7 @@ def _moe_token_pins(mesh):
     return pin_rows, pin_repl
 
 
+@jax.named_scope("llmq.moe")
 def _moe_mlp(
     h: jnp.ndarray,
     lp: Params,
@@ -385,6 +387,7 @@ class Transformer:
         return self._stage_range()[1] == self.config.num_layers
 
     # --- shared layer body -------------------------------------------------
+    @jax.named_scope("llmq.qkv")
     def _qkv(
         self, lp: Params, h: jnp.ndarray, positions: jnp.ndarray, inv_freq,
         layer=-1,
@@ -419,11 +422,12 @@ class Transformer:
         *lead, _, _ = attn_out.shape
         attn_flat = attn_out.reshape(*lead, cfg.num_heads * cfg.head_dim_)
         attn_flat = _tap(attn_flat, "attn.out", layer)
-        attn_proj = _tap(
-            cm.row_parallel_matmul(attn_flat, lp["o_proj"], plan),
-            "attn.o_proj",
-            layer,
-        )
+        with jax.named_scope("llmq.o_proj"):
+            attn_proj = _tap(
+                cm.row_parallel_matmul(attn_flat, lp["o_proj"], plan, "o_proj"),
+                "attn.o_proj",
+                layer,
+            )
         if cfg.post_norms:
             attn_proj = rms_norm(
                 attn_proj, lp["post_attn_norm"], cfg.rms_norm_eps, one_plus=one_plus
@@ -464,6 +468,7 @@ class Transformer:
         lo, hi = self._stage_range()
         return jnp.arange(hi - lo, dtype=jnp.int32)
 
+    @jax.named_scope("llmq.embed")
     def _embed(self, params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
         cfg = self.config
         h = qm.embed_lookup(params["embed"], tokens)
@@ -473,6 +478,7 @@ class Transformer:
             )
         return h
 
+    @jax.named_scope("llmq.lm_head")
     def _logits(self, params: Params, h: jnp.ndarray) -> jnp.ndarray:
         cfg = self.config
         one_plus = cfg.model_type.startswith("gemma")
@@ -529,17 +535,18 @@ class Transformer:
             lp, window, li = xs
             x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps, one_plus=one_plus)
             q, k, v = self._qkv(lp, x, positions, inv_freq, li)
-            if page_aligned:
-                # Prompt positions are 0..T-1, so whole pages can be
-                # written in one block-scatter row each (~10 ms/chunk
-                # cheaper than the token scatter at 3B/8x256, measured).
-                kps, vps = attn_ops.write_prompt_kv_pages(
-                    kps, vps, k, v, block_tables, li, mesh=self.mesh
-                )
-            else:
-                kps, vps = attn_ops.write_kv_pages(
-                    kps, vps, k, v, block_tables, positions, layer=li
-                )
+            with jax.named_scope("llmq.kv_write"):
+                if page_aligned:
+                    # Prompt positions are 0..T-1, so whole pages can be
+                    # written in one block-scatter row each (~10 ms/chunk
+                    # cheaper than the token scatter at 3B/8x256, measured).
+                    kps, vps = attn_ops.write_prompt_kv_pages(
+                        kps, vps, k, v, block_tables, li, mesh=self.mesh
+                    )
+                else:
+                    kps, vps = attn_ops.write_kv_pages(
+                        kps, vps, k, v, block_tables, positions, layer=li
+                    )
             attn_out = attn_dispatch.prefill_attention(
                 q,
                 k,
@@ -599,9 +606,10 @@ class Transformer:
             lp, window, li = xs
             x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps, one_plus=one_plus)
             q, k, v = self._qkv(lp, x, positions, inv_freq, li)
-            kps, vps = attn_ops.write_kv_pages(
-                kps, vps, k, v, block_tables, positions, layer=li
-            )
+            with jax.named_scope("llmq.kv_write"):
+                kps, vps = attn_ops.write_kv_pages(
+                    kps, vps, k, v, block_tables, positions, layer=li
+                )
             attn_out = attn_dispatch.chunked_prefill_attention(
                 q,
                 kps,
@@ -795,9 +803,11 @@ class Transformer:
                     layer=li,
                 )
             else:
-                kps, vps = attn_ops.write_kv_pages(
-                    kps, vps, k, v, block_tables, positions[:, None], layer=li
-                )
+                with jax.named_scope("llmq.kv_write"):
+                    kps, vps = attn_ops.write_kv_pages(
+                        kps, vps, k, v, block_tables, positions[:, None],
+                        layer=li,
+                    )
                 attn_out = attn_dispatch.decode_attention(
                     q[:, 0],
                     kps,

@@ -191,12 +191,15 @@ def _lead_axis(plan: TpRingPlan, m: int) -> Optional[str]:
 
 
 def row_parallel_matmul(
-    x: jnp.ndarray, w: Any, plan: Optional[TpRingPlan]
+    x: jnp.ndarray, w: Any, plan: Optional[TpRingPlan], name: str = "row"
 ) -> jnp.ndarray:
     """``x @ w`` for a row-parallel weight ([K, N] per layer, K sharded
     on tp) as a chunked ppermute ring; falls back to the literal
     ``qm.matmul`` (GSPMD inserts the all-reduce) when ``plan`` is None
-    or the static shapes don't split over the ring."""
+    or the static shapes don't split over the ring. ``name`` (``o_proj``,
+    ``down_proj``) names the ring in a profile:
+    ``llmq.tp.allreduce.<name>``; the all-reduce GSPMD inserts carries
+    the caller's scope, and ``scopes_from_hlo_text`` gives it that name."""
     quantized = qm.is_quantized(w)
     int4 = qm.is_int4(w)
     arr = w["q"] if quantized else w
@@ -290,7 +293,8 @@ def row_parallel_matmul(
         in_specs=(P(lead_axis, TP_AXIS), *operand_specs),
         out_specs=P(lead_axis, None),
     )
-    return fn(x2, *operands).reshape(*lead, N)
+    with jax.named_scope(f"llmq.tp.allreduce.{name}"):
+        return fn(x2, *operands).reshape(*lead, N)
 
 
 def row_parallel_ragged_matmul(

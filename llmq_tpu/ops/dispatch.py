@@ -130,17 +130,19 @@ def prefill_attention(
     # full-T activations per device.
     sp = int(mesh.shape.get(SP_AXIS, 1)) if mesh is not None else 1
     if sp > 1 and q.shape[1] % sp == 0:
-        return ring.ring_prefill_attention(
-            q, k, v, scale=scale, mesh=mesh, lengths=lengths,
-            sliding_window=sliding_window, softcap=softcap,
-        )
+        with jax.named_scope("llmq.attn.ring"):
+            return ring.ring_prefill_attention(
+                q, k, v, scale=scale, mesh=mesh, lengths=lengths,
+                sliding_window=sliding_window, softcap=softcap,
+            )
     tp = _tp_degree(mesh)
     tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
-        return xla_ops.full_prefill_attention(
-            q, k, v, scale=scale, lengths=lengths,
-            sliding_window=sliding_window, softcap=softcap,
-        )
+        with jax.named_scope("llmq.attn.xla"):
+            return xla_ops.full_prefill_attention(
+                q, k, v, scale=scale, lengths=lengths,
+                sliding_window=sliding_window, softcap=softcap,
+            )
     if lengths is None:
         lengths = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
     window = _window_scalar(sliding_window)
@@ -160,7 +162,8 @@ def prefill_attention(
             in_specs=(head, head, head, P(), P()),
             out_specs=head,
         )
-    return call(q, k, v, lengths, window)
+    with jax.named_scope("llmq.attn.flash_prefill"):
+        return call(q, k, v, lengths, window)
 
 
 def chunked_prefill_attention(
@@ -195,11 +198,12 @@ def chunked_prefill_attention(
     tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     stacked = k_pages.ndim == 5
     if backend != "pallas" or not tp_ok:
-        return xla_ops.paged_prefill_attention(
-            q, k_pages, v_pages, block_tables, q_positions,
-            scale=scale, sliding_window=sliding_window, softcap=softcap,
-            layer=layer,
-        )
+        with jax.named_scope("llmq.attn.xla"):
+            return xla_ops.paged_prefill_attention(
+                q, k_pages, v_pages, block_tables, q_positions,
+                scale=scale, sliding_window=sliding_window, softcap=softcap,
+                layer=layer,
+            )
     window = _window_scalar(sliding_window)
     li = (
         jnp.asarray(layer, jnp.int32).reshape(1)
@@ -232,9 +236,11 @@ def chunked_prefill_attention(
             ),
             out_specs=P(None, None, TP_AXIS, None),
         )
-    return call(
-        q, k_pages, v_pages, block_tables, chunk_start, num_valid, window, li
-    )
+    with jax.named_scope("llmq.attn.paged_prefill"):
+        return call(
+            q, k_pages, v_pages, block_tables, chunk_start, num_valid,
+            window, li,
+        )
 
 
 def decode_kernel_plan(
@@ -419,10 +425,11 @@ def decode_attention_fused_write(
             ),
             out_specs=(P(None, TP_AXIS, None), kv_spec, kv_spec),
         )
-    return call(
-        q, k_pages, v_pages, k_new, v_new, block_tables, context_lens,
-        window, li,
-    )
+    with jax.named_scope("llmq.attn.paged_decode"):
+        return call(
+            q, k_pages, v_pages, k_new, v_new, block_tables, context_lens,
+            window, li,
+        )
 
 
 def decode_attention(
@@ -445,11 +452,12 @@ def decode_attention(
     tp = _tp_degree(mesh)
     tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
-        return xla_ops.paged_decode_attention(
-            q, k_pages, v_pages, block_tables, context_lens,
-            scale=scale, sliding_window=sliding_window, softcap=softcap,
-            layer=layer,
-        )
+        with jax.named_scope("llmq.attn.xla"):
+            return xla_ops.paged_decode_attention(
+                q, k_pages, v_pages, block_tables, context_lens,
+                scale=scale, sliding_window=sliding_window, softcap=softcap,
+                layer=layer,
+            )
     window = _window_scalar(sliding_window)
     li = (
         jnp.asarray(layer, jnp.int32).reshape(1)
@@ -496,7 +504,10 @@ def decode_attention(
             ),
             out_specs=P(None, TP_AXIS, None),
         )
-    return call(q, k_pages, v_pages, block_tables, context_lens, window, li)
+    with jax.named_scope("llmq.attn.paged_decode"):
+        return call(
+            q, k_pages, v_pages, block_tables, context_lens, window, li
+        )
 
 
 # --- snapshot plane: whole-page KV movement ---------------------------------

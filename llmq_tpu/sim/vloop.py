@@ -95,6 +95,8 @@ class LoopClock(clock.Clock):
     wall == a fixed epoch plus loop time (so wall-time policy — deadline
     stamps, heartbeat staleness — advances in lockstep)."""
 
+    virtual = True
+
     def __init__(self, loop: VirtualTimeLoop) -> None:
         self._loop = loop
 

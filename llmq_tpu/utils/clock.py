@@ -26,6 +26,11 @@ class Clock:
     """A monotonic + wall clock pair. The default reads the real clocks;
     the sim installs a subclass that reads virtual loop time."""
 
+    #: True on a clock that reads a virtual loop's time: nothing there
+    #: can run late, so marks of real lateness (``obs.spans.LoopLag``)
+    #: stay off.
+    virtual = False
+
     def monotonic(self) -> float:
         """Monotonic seconds (durations, cadences, deadlines-in-process)."""
         return _time.monotonic()

@@ -55,6 +55,7 @@ from llmq_tpu.core.models import Job, Result, WorkerHealth, utcnow
 from llmq_tpu.core.pipeline import PipelineConfig
 from llmq_tpu.obs import (
     TRACE_FIELD,
+    Gauge,
     emit_trace_event,
     get_registry,
     maybe_start_exporter,
@@ -62,6 +63,7 @@ from llmq_tpu.obs import (
     trace_event,
     trace_from_payload,
 )
+from llmq_tpu.obs.spans import LoopLag, SpanRing, spans_path, stall_stacks_path
 from llmq_tpu.utils import clock
 from llmq_tpu.utils.logging import ContextLogAdapter
 from llmq_tpu.workers.resume import (
@@ -137,6 +139,12 @@ class BaseWorker(abc.ABC):
         # TPU worker) can attach engine lifecycle events to the record
         # that rides back in the Result.
         self._job_traces: dict = {}
+        # The event loop's span ring (obs/spans.py; off unless LLMQ_SPANS
+        # is set, set_tracing switches it on or a profile is being
+        # taken) and its lag mark, which runs from run() to shutdown()
+        # whatever the ring does.
+        self.spans = SpanRing("worker")
+        self._loop_lag = LoopLag(self.spans)
         # Exactly-one-result guard: (job_id, resume offset) pairs this
         # worker already published for. Redelivered or resumed jobs that
         # land on this worker twice publish once.
@@ -229,6 +237,9 @@ class BaseWorker(abc.ABC):
                 pass  # non-main thread or platform without signal support
         try:
             await self.initialize()
+            self._start_loop_lag()
+            if spans_path() is not None:
+                await self.set_tracing(True)
             self.running = True
             await self._start_role_consumers()
             await self._start_extra_consumers()
@@ -257,6 +268,48 @@ class BaseWorker(abc.ABC):
             await self.shutdown()
         if self._fatal_error is not None:
             raise self._fatal_error
+
+    # --- span rings and the loop-lag mark (obs/spans.py) -------------------
+    def _start_loop_lag(self) -> None:
+        """From here to shutdown every 100 ms timer on this loop notes how
+        late it ran: ``loop_lag_max_ms`` in the heartbeat's stats and on
+        ``/metrics``, a warning at once when one ran 0.5 s late. With
+        ``LLMQ_STALL_STACKS=<file>`` a loop that stands still that long
+        also has every thread's stack written there while it stands."""
+        lag = self._loop_lag
+        lag.gauge = get_registry().register(
+            Gauge(
+                "llmq_loop_lag_max_ms",
+                "Latest a 100 ms timer ran on the worker's event loop since start",
+            )
+        )
+        sink = stall_stacks_path()
+        if sink is not None:
+            try:
+                lag.stack_sink = open(sink, "a", encoding="utf-8")
+            except OSError:  # observability is best-effort
+                self.logger.debug("no stack sink", exc_info=True)
+        lag.start()
+
+    async def set_tracing(self, on: bool) -> None:
+        """Switch this worker's span rings on or off (event-loop thread).
+        ``LLMQ_SPANS=<file>`` switches them on at start; the dump is then
+        written to that file at shutdown."""
+        self.spans.set(on)
+
+    def trace_dump(self) -> dict:
+        """``{"spans": [...], "requests": {rid: {stamp: t}}, "counters":
+        {...}}`` of this worker's rings, merged."""
+        return self.spans.dump()
+
+    def _write_span_dump(self, path: str) -> None:
+        """``LLMQ_SPANS``: the rings' dump as one JSON file, at shutdown
+        (the loop has nothing left to serve)."""
+        try:
+            with open(path, "w", encoding="utf-8") as fh:
+                json.dump(dict(self.trace_dump(), worker_id=self.worker_id), fh)
+        except (OSError, TypeError, ValueError):  # observability is best-effort
+            self.logger.debug("span dump not written", exc_info=True)
 
     def fail_fatally(self, exc: BaseException) -> None:
         """Stop this worker for a fault that no retry cures (the engine
@@ -310,6 +363,9 @@ class BaseWorker(abc.ABC):
             await self._retire_affinity_queue()
         if self.role != "unified" and self.broker.connected:
             await self._retire_adopt_queue()
+        if spans_path() is not None and self.spans.on:
+            self._write_span_dump(spans_path())
+        self._loop_lag.stop()
         await self._cleanup_processor()
         if self.broker.connected:
             await self.broker.disconnect()
@@ -1253,6 +1309,9 @@ class BaseWorker(abc.ABC):
                 stats[name] = value
         if self.breaker_tripped:
             stats["breaker_tripped"] = True
+        if self._loop_lag.ticks:
+            # A wedged event loop is an operator's signal.
+            stats["loop_lag_max_ms"] = round(self._loop_lag.max_ms, 3)
         # Disaggregated-serving counters (superset-only, like the rest).
         if self.role == "auto":
             stats["role_mode"] = "auto"

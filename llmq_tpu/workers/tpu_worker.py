@@ -38,6 +38,7 @@ from llmq_tpu.broker.manager import (
 )
 from llmq_tpu.core.models import Job
 from llmq_tpu.obs import emit_trace_event, trace_event, trace_event_at
+from llmq_tpu.obs.spans import merge_dumps
 from llmq_tpu.utils import clock
 from llmq_tpu.utils.hashing import (
     text_prefix_chain,
@@ -250,6 +251,11 @@ class TPUWorker(BaseWorker):
         )
         self.engine.on_fatal = lambda exc: loop.call_soon_threadsafe(
             self.fail_fatally, exc
+        )
+        # The engine's ring goes on for as long as a profile of the
+        # process is being taken; the loop's ring goes with it.
+        self.engine.on_tracing = lambda on: loop.call_soon_threadsafe(
+            self.spans.follow_profiler, on
         )
         self.logger.info("Engine ready: %s", self._engine_stats())
 
@@ -524,6 +530,21 @@ class TPUWorker(BaseWorker):
         except Exception:  # noqa: BLE001 — stale cache entries are inert
             self.logger.debug("jax.clear_caches failed", exc_info=True)
         return self._build_core()
+
+    async def set_tracing(self, on: bool) -> None:
+        """Both rings: the event loop's and the engine thread's."""
+        self.spans.set(on)
+        if self.engine is not None:
+            loop = asyncio.get_running_loop()
+            await loop.run_in_executor(None, self.engine.set_tracing, on)
+
+    def trace_dump(self) -> dict:
+        """The loop's ring and the engine's, merged; the engine's adds
+        ``scopes`` (``EngineCore._dump_scopes``)."""
+        dumps = [self.spans.dump()]
+        if self.engine is not None:
+            dumps.append(self.engine.trace_dump())
+        return merge_dumps(dumps)
 
     def _note_device_fault(self, reason: str) -> None:
         """Event-loop side of a device fault: count it against the
@@ -1401,6 +1422,13 @@ class TPUWorker(BaseWorker):
         timing = getattr(out, "timing", None)
         if trace is None or not timing:
             return
+        # The claim is the worker's stamp; it joins the engine's here so
+        # that one record holds claimed <= engine_submit <= enqueued ...
+        for event in trace["events"]:
+            if event.get("name") == "claimed":
+                timing["claimed"] = event["t_mono"]
+        if self.spans.on and "claimed" in timing:
+            self.spans.note_request(job_id, claimed=timing["claimed"])
         trace_event_at(trace, "tokenized", timing.get("enqueued"))
         trace_event_at(trace, "admitted", timing.get("admitted"))
         trace_event_at(trace, "prefill_start", timing.get("prefill_start"))
