@@ -203,7 +203,7 @@ def pick_decode_kernel() -> str:
     one process — the probe must run (and exit) before this process
     initialises the backend. The child derives its own preset/shape from
     the same env knobs main() uses. An explicit LLMQ_DECODE_KERNEL always
-    wins; a failed or timed-out A/B is reported and the run uses v1.
+    wins; a failed or timed-out A/B is reported and the run uses the default.
     """
     import subprocess
 
@@ -219,14 +219,14 @@ def pick_decode_kernel() -> str:
         )
         sys.stderr.write(proc.stderr[-600:])
         choice = proc.stdout.strip().splitlines()[-1] if proc.stdout else ""
-        if proc.returncode == 0 and choice in ("v1", "v2", "v3"):
+        if proc.returncode == 0 and choice in ("live", "v1", "v2", "v3"):
             return choice
-        print(f"bench: kernel A/B rc={proc.returncode}; using v1", file=sys.stderr)
+        print(f"bench: kernel A/B rc={proc.returncode}; using live", file=sys.stderr)
     except subprocess.TimeoutExpired:
-        print("bench: kernel A/B timed out; using v1", file=sys.stderr)
+        print("bench: kernel A/B timed out; using live", file=sys.stderr)
     except Exception as exc:  # noqa: BLE001
-        print(f"bench: kernel A/B failed ({exc!r}); using v1", file=sys.stderr)
-    return "v1"
+        print(f"bench: kernel A/B failed ({exc!r}); using live", file=sys.stderr)
+    return "live"
 
 
 def _kernel_ab_probe_main() -> None:
@@ -1595,7 +1595,7 @@ def main() -> None:
             if kv_env not in ("", "auto")
             else {}
         ),
-        "decode_kernel": ab_choice or os.environ.get("LLMQ_DECODE_KERNEL") or "v1",
+        "decode_kernel": ab_choice or os.environ.get("LLMQ_DECODE_KERNEL") or "live",
     }
     if (
         _QUANT_FALLBACK is not None

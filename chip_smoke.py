@@ -260,7 +260,7 @@ def phase_kernels(mode: Mode) -> dict:
     # under this script — the script holds the chip — so that is the
     # LLMQ_DECODE_KERNEL / default choice), through the engine's dispatch.
     kernel, fused = dispatch.decode_kernel_plan(H, NKV, backend="pallas")
-    check(not fused, "the smoke covers the unfused decode kernels (v1, v2)")
+    check(not fused, "the smoke covers the unfused decode kernels (live, v1, v2)")
     pages_per_seq = 8192 // PAGE + 1  # the worker's default max_model_len
     live = 8  # pages a slot may touch here: contexts up to 8 pages
     P = 1 + S * live
@@ -557,7 +557,7 @@ async def _serve(mode: Mode, meter, n_batch: int, n_http_prompts: int) -> dict:
         # qwen2.5-7b at tp=4 leaves one kv head a shard: XLA attention by
         # the shape rule of ops/dispatch._tp_heads_ok. Everything else
         # the smoke serves runs the decode kernel.
-        kernels = ("xla",) if mode.chips == 4 else ("v1", "v2", "v3")
+        kernels = ("xla",) if mode.chips == 4 else ("live", "v1", "v2", "v3")
         check(
             stats["decode_kernel"] in kernels,
             f"decode kernel {stats['decode_kernel']!r}, not one of {kernels}",

@@ -2736,6 +2736,20 @@ class EngineCore:
         mid-prefill rows are in ``running`` but have no decode state)."""
         return [s for s in self.scheduler.running.values() if s.prefilled]
 
+    def _live_pages(self, seqs: List[Sequence]) -> int:
+        """KV pages the decode kernel visits a layer for ``seqs``: the page
+        places that overlap each one's attended span (all of its context,
+        or its window where every layer of the model slides)."""
+        page, mc = self.cfg.page_size, self.model_config
+        window = (
+            mc.sliding_window if mc.sliding_window_pattern <= 1 else None
+        )
+        return sum(
+            -(-s.num_tokens // page)
+            - (max(s.num_tokens - window, 0) // page if window else 0)
+            for s in seqs
+        )
+
     def _expire_deadlines(self, finished: List[RequestOutput]) -> None:
         """Between-steps deadline sweep: waiting or running sequences
         whose wall-clock deadline has passed finish with
@@ -4049,11 +4063,12 @@ class EngineCore:
             jits, k_steps = self._decode_jits_small, self.interactive_decode_block
             kind += "_small"
         if self.spans.on:
+            seqs = self._decodable_seqs()
             self.spans.begin(
                 "decode_dispatch", program=getattr(jits[self._mode], "name", ""),
                 mode=self._mode, variant="",
-                rows=len(self._decodable_seqs()), k_steps=k_steps,
-                pending=len(self._pending),
+                rows=len(seqs), live_pages=self._live_pages(seqs),
+                k_steps=k_steps, pending=len(self._pending),
             )
         with self._wd(kind):
             out, self.k_pages, self.v_pages, self._dev_state = (

@@ -243,6 +243,29 @@ def chunked_prefill_attention(
         )
 
 
+# LLMQ_DECODE_KERNEL -> the unfused kernel behind the name. Unset means
+# "live": the schedule that visits only live pages. v1 and v2 walk a fixed
+# grid over every page place; v3 is v2 plus the fused KV write and exists
+# only on the decode_attention_fused_write path (a caller who scattered KV
+# separately gets its base, v2).
+_DECODE_KERNELS = {
+    "live": pk.paged_decode_attention_live,
+    "v1": pk.paged_decode_attention_pallas,
+    "v2": pk.paged_decode_attention_pallas_v2,
+    "v3": pk.paged_decode_attention_pallas_v2,
+}
+
+
+def _decode_kernel_name() -> str:
+    # Empty string = unset (the `VAR= cmd` shell idiom must mean default).
+    kern = (os.environ.get("LLMQ_DECODE_KERNEL") or "live").lower()
+    if kern not in _DECODE_KERNELS:
+        raise ValueError(
+            f"LLMQ_DECODE_KERNEL={kern!r} (want {'|'.join(_DECODE_KERNELS)})"
+        )
+    return kern
+
+
 def decode_kernel_plan(
     n_heads: int, n_kv: int, mesh: Optional[Mesh] = None,
     backend: str = "auto",
@@ -258,10 +281,7 @@ def decode_kernel_plan(
     resolve identically on every call within one process or the scan
     body would diverge between iterations."""
     backend = resolve_backend() if backend == "auto" else backend
-    # Empty string = unset (the `VAR= cmd` shell idiom must mean default).
-    kern = (os.environ.get("LLMQ_DECODE_KERNEL") or "v1").lower()
-    if kern not in ("v1", "v2", "v3"):
-        raise ValueError(f"LLMQ_DECODE_KERNEL={kern!r} (want v1|v2|v3)")
+    kern = _decode_kernel_name()
     tp = _tp_degree(mesh)
     tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
     if backend != "pallas" or not tp_ok:
@@ -465,17 +485,7 @@ def decode_attention(
         else jnp.zeros((1,), jnp.int32)
     )
 
-    # Empty string = unset (the `VAR= cmd` shell idiom must mean default).
-    kern_name = (os.environ.get("LLMQ_DECODE_KERNEL") or "v1").lower()
-    if kern_name not in ("v1", "v2", "v3"):
-        raise ValueError(f"LLMQ_DECODE_KERNEL={kern_name!r} (want v1|v2|v3)")
-    # v3 (fused KV write) only exists on the decode_attention_fused_write
-    # path; a caller who scattered KV separately gets v3's base, v2.
-    kern = (
-        pk.paged_decode_attention_pallas_v2
-        if kern_name in ("v2", "v3")
-        else pk.paged_decode_attention_pallas
-    )
+    kern = _DECODE_KERNELS[_decode_kernel_name()]
 
     def call(q, kp, vp, bt, cl, window, li):
         return kern(

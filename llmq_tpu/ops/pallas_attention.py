@@ -679,6 +679,329 @@ def paged_decode_attention_pallas_v3(
 
 
 # ---------------------------------------------------------------------------
+# Paged decode, live pages only: the default decode kernel
+# ---------------------------------------------------------------------------
+#
+# v1 and v2 walk a grid that spans every page PLACE of every slot
+# (``S x pages_per_seq`` steps a layer; 128 x 64 at the worker's default
+# ``max_model_len``), and ``pl.when`` only skips a dead step's arithmetic.
+# On the chip a dead step still costs ~0.09 us, which at short caches is
+# most of a decode step (PERF.md section 5). Here the grid is ``(S,)`` and
+# everything inside a step has a trip count read from ``context_lens``:
+# a sequence's live pages come in chunks of ``C`` pages, every copy of a
+# chunk is started before the first is waited on, the next chunk (or the
+# next live sequence's first chunk) is in flight while this one computes,
+# and an empty slot is one step that writes zeros.
+#
+# Two things set the time of a live page, and neither is the copy
+# (measured on v5e, PERF.md section 6 "PR 30"):
+#
+# * One online-softmax update is a chain of dependent steps (MXU round
+#   trip, two cross-lane reductions, exp, MXU round trip) of ~0.5 us
+#   whatever it folds in. So an update folds in ``G`` pages at once, not
+#   one: 8 pages of qwen2.5-3b's, and the kernel runs at the speed of its
+#   copies. The pages of a group past the sequence's last are masked like
+#   positions past the context (the buffers are zeroed once, so what they
+#   hold is finite).
+# * A page is computed on as ONE 2-D matrix. Where the kv heads of a token
+#   fill whole 32-bit words (``n_kv * itemsize`` a multiple of 4: two bf16
+#   heads, four fp8 heads, any float32 pool) the pool's ``[page, n_kv, d]``
+#   page is a row-major ``[page * n_kv, d]`` matrix on the chip, byte for
+#   byte, so the launcher's reshape is free (compiled for v5e: a bitcast)
+#   and the kernel never separates the heads: every query head is scored
+#   against every row, and a row of another kv head is masked like a
+#   position past the context. That costs the MXU nothing it would not
+#   spend anyway, and saves the sublane gather ``k[:, g, :]`` that v1 pays
+#   a page. A pool whose heads do not fill a word (one bf16 head, two fp8
+#   heads) is padded on the chip, Mosaic refuses to slice a page of it for
+#   a hand-issued copy, and it stays on v1's BlockSpec pipeline (the
+#   launcher hands it over).
+#
+# The arithmetic is v1's: K, V and the queries upcast to float32, float32
+# running max, sum and accumulator. On the chip the float32 dots cost
+# 0.7 % over bf16 operands, so there was nothing to buy with them.
+
+_DECODE_STEP_BYTES = 512 * 1024  # K bytes one softmax update folds in
+_DECODE_CHUNK_BYTES = 1024 * 1024  # K bytes in flight (x2 for V, x2 buffers)
+_NOT_A_POSITION = 1 << 29  # a column of another kv head: past any context
+
+
+def _decode_schedule(page_bytes: int) -> tuple:
+    """(C, G): pages a chunk copies and pages one softmax update folds in,
+    from the bytes of one K page alone. More than 8 pages an update or 16
+    in flight bought nothing on the chip; a page of 1 MiB gets (1, 1)."""
+    G = max(1, min(8, _DECODE_STEP_BYTES // page_bytes))
+    return G * max(1, min(2, _DECODE_CHUNK_BYTES // (G * page_bytes))), G
+
+
+def _paged_decode_live_kernel(
+    # scalar prefetch
+    li_ref,  # [1] int32 — layer index into the stacked page pool
+    bt_ref,  # [S, pages_per_seq] int32
+    cl_ref,  # [S] int32 — context length INCLUDING the new token
+    w_ref,  # [1] int32 — sliding window (huge = disabled)
+    # inputs
+    q_ref,  # [1, n_heads, d]
+    k_hbm,  # [L, P, page * n_kv, d], left in HBM
+    v_hbm,
+    # output
+    o_ref,  # [1, n_heads, d]
+    # scratch
+    m_ref,  # [n_heads, LANES] f32, lane-replicated running max
+    l_ref,  # [n_heads, LANES] f32, lane-replicated running denom
+    acc_ref,  # [n_heads, d] f32
+    k_buf,  # [2, C * page * n_kv, d] pool dtype: two chunks of pages
+    v_buf,
+    k_sem,  # DMA [2, C]
+    v_sem,
+    slot_ref,  # SMEM [1] int32: parity of the chunks consumed so far
+    *,
+    scale: float,
+    page_size: int,
+    chunk: int,
+    group: int,
+    n_kv: int,
+    softcap: Optional[float],
+):
+    C, G = chunk, group
+    s = pl.program_id(0)
+    S = pl.num_programs(0)
+    li = li_ref[0]
+    window = w_ref[0]
+    H = q_ref.shape[1]
+    rows = page_size * n_kv  # buffer rows a page takes
+
+    def live_span(seq):
+        """(first, last + 1) page places of ``seq`` that overlap the
+        attended span [ctx - window, ctx); empty for an inactive slot."""
+        ctx = cl_ref[seq]
+        first = jnp.maximum(ctx - window, 0) // page_size
+        return first, (ctx + page_size - 1) // page_size
+
+    def next_live(t):
+        """The first sequence at or after ``t`` with a context, or S."""
+        return jax.lax.while_loop(
+            lambda t: jnp.logical_and(
+                t < S, cl_ref[jnp.minimum(t, S - 1)] == 0
+            ),
+            lambda t: t + 1,
+            t,
+        )
+
+    def page_copies(pid, slot, i):
+        dst = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[li, pid], k_buf.at[slot, dst], k_sem.at[slot, i]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[li, pid], v_buf.at[slot, dst], v_sem.at[slot, i]
+            ),
+        )
+
+    def issue_chunk(seq, first_page, last_page, slot):
+        """Start the copies of up to C pages from ``first_page``: all of
+        them before anything waits."""
+
+        def start(i, _):
+            for copy in page_copies(bt_ref[seq, first_page + i], slot, i):
+                copy.start()
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(C, last_page - first_page), start, 0)
+
+    def issue_first_chunk(seq, slot):
+        @pl.when(seq < S)
+        def _go():
+            first, last = live_span(seq)
+            issue_chunk(seq, first, last, slot)
+
+    @pl.when(s == 0)
+    def _prime():
+        if G > 1:  # a dead page beside a live one must hold finite values
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        issue_first_chunk(next_live(0), 0)
+
+    ctx = cl_ref[s]
+    first, last = live_span(s)
+    n_chunks = (last - first + C - 1) // C
+    slot0 = slot_ref[0]
+    # The sequence whose first chunk rides behind this one's last. An
+    # empty slot starts nothing: the live step before it already did.
+    successor = next_live(jnp.where(n_chunks > 0, s + 1, S))
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0].astype(jnp.float32)  # [H, d]
+    # Column -> token offset from the group's first page, or "not a
+    # position" where the column's kv head is not the row's.
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, G * rows), 1)
+    row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, G * rows), 0) // (
+        H // n_kv
+    )
+    col_pos = jnp.where(col % n_kv == row_kv, col // n_kv, _NOT_A_POSITION)
+
+    def attend_group(page, slot, i):
+        """Fold the G page places from ``page`` (in buffer ``slot`` from
+        ``i``) into the running softmax of all heads."""
+        at = pl.ds(pl.multiple_of(i * rows, rows), G * rows)
+        k = k_buf[slot, at].astype(jnp.float32)  # [G * page * n_kv, d]
+        v = v_buf[slot, at].astype(jnp.float32)
+        scores = (
+            jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            * scale
+        )
+        scores = _apply_softcap(scores, softcap)
+        kpos = page * page_size + col_pos
+        mask = jnp.logical_and(kpos < ctx, kpos >= ctx - window)
+        scores = jnp.where(mask, scores, NEG_INF)
+
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.exp(scores - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(probs, axis=1, keepdims=True),
+            l_ref.shape,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            probs, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    def attend_chunk(j, _):
+        slot = jax.lax.rem(slot0 + j, 2)
+        base = first + j * C
+        n_here = jnp.minimum(C, last - base)
+
+        # Keep the other buffer busy: this sequence's next chunk, or after
+        # its last the successor's first.
+        @pl.when(j + 1 < n_chunks)
+        def _ahead():
+            issue_chunk(s, base + C, last, 1 - slot)
+
+        @pl.when(j + 1 == n_chunks)
+        def _successor():
+            issue_first_chunk(successor, 1 - slot)
+
+        def attend(g, _):
+            for i in range(G):
+
+                @pl.when(g * G + i < n_here)
+                def _wait(i=i):
+                    for copy in page_copies(0, slot, g * G + i):
+                        copy.wait()
+
+            attend_group(base + g * G, slot, g * G)
+            return 0
+
+        jax.lax.fori_loop(0, (n_here + G - 1) // G, attend, 0)
+        return 0
+
+    jax.lax.fori_loop(0, n_chunks, attend_chunk, 0)
+    slot_ref[0] = jax.lax.rem(slot0 + n_chunks, 2)
+
+    l = l_ref[:, :1]
+    l = jnp.where(l == 0.0, 1.0, l)  # inactive slot: zeros, never NaN
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "softcap", "interpret"),
+)
+def paged_decode_attention_live(
+    q: jnp.ndarray,  # [S, n_heads, d]
+    k_pages: jnp.ndarray,  # [P, page_size, n_kv, d] or [L, P, page, n_kv, d]
+    v_pages: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [S, pages_per_seq] int32
+    context_lens: jnp.ndarray,  # [S] int32, INCLUDING the new token
+    sliding_window: jnp.ndarray,  # [] or [1] int32 (huge = disabled)
+    layer: Optional[jnp.ndarray] = None,  # traced layer index when stacked
+    *,
+    scale: float,
+    softcap: Optional[float] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Paged decode attention whose work follows the live cache (see the
+    notes above). Same contract as :func:`paged_decode_attention_pallas`,
+    which also serves the pools this schedule cannot copy by hand;
+    nothing in the schedule depends on ``block_tables.shape[1]``."""
+    n_kv, d = k_pages.shape[-2:]
+    itemsize = jnp.dtype(k_pages.dtype).itemsize
+    if (n_kv * itemsize) % 4:  # pages padded on the chip: see the notes
+        return paged_decode_attention_pallas(
+            q, k_pages, v_pages, block_tables, context_lens, sliding_window,
+            layer, scale=scale, softcap=softcap, interpret=interpret,
+        )
+    S, n_heads, _ = q.shape
+    if k_pages.ndim == 4:  # single-layer callers: view as a 1-layer stack
+        k_pages = k_pages[None]
+        v_pages = v_pages[None]
+        layer = jnp.zeros((), jnp.int32)
+    assert layer is not None, "stacked pages need a layer index"
+    L, P, page_size = k_pages.shape[:3]
+    rows = page_size * n_kv
+    C, G = _decode_schedule(rows * d * itemsize)
+
+    kernel = functools.partial(
+        _paged_decode_live_kernel,
+        scale=scale,
+        page_size=page_size,
+        chunk=C,
+        group=G,
+        n_kv=n_kv,
+        softcap=softcap,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, n_heads, d), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, n_heads, d), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((n_heads, _LANES), jnp.float32),
+            pltpu.VMEM((n_heads, _LANES), jnp.float32),
+            pltpu.VMEM((n_heads, d), jnp.float32),
+            pltpu.VMEM((2, C * rows, d), k_pages.dtype),
+            pltpu.VMEM((2, C * rows, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, C)),
+            pltpu.SemaphoreType.DMA((2, C)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((S, n_heads, d), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32),
+        context_lens.astype(jnp.int32),
+        jnp.asarray(sliding_window, jnp.int32).reshape(1),
+        q,
+        k_pages.reshape(L, P, rows, d),
+        v_pages.reshape(L, P, rows, d),
+    )
+
+
+# ---------------------------------------------------------------------------
 # Paged chunked prefill
 # ---------------------------------------------------------------------------
 

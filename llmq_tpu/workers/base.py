@@ -124,6 +124,7 @@ class BaseWorker(abc.ABC):
         )
         self.broker = BrokerManager(self.config)
         self.running = False
+        self._stop_requested = False  # a shutdown asked for before run() got going
         self.jobs_processed = 0
         self.jobs_failed = 0
         self.jobs_timed_out = 0
@@ -240,7 +241,9 @@ class BaseWorker(abc.ABC):
             self._start_loop_lag()
             if spans_path() is not None:
                 await self.set_tracing(True)
-            self.running = True
+            # A shutdown asked for while the engine was still being built
+            # (SIGTERM during start-up) holds: consume nothing, go down.
+            self.running = not self._stop_requested
             await self._start_role_consumers()
             await self._start_extra_consumers()
             self.logger.info(
@@ -266,6 +269,7 @@ class BaseWorker(abc.ABC):
                 await asyncio.sleep(1.0)
         finally:
             await self.shutdown()
+            self._stop_requested = False
         if self._fatal_error is not None:
             raise self._fatal_error
 
@@ -324,6 +328,7 @@ class BaseWorker(abc.ABC):
         if self.running:
             self.logger.info("Shutdown requested; draining in-flight jobs")
         self.running = False
+        self._stop_requested = True
 
     async def shutdown(self) -> None:
         for attr in (

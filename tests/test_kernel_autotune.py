@@ -60,14 +60,14 @@ def test_probe_choice_from_child(monkeypatch):
 
 def test_child_failure_is_loud_and_starts_on_default(monkeypatch, capsys):
     """A failed probe is an ERROR with the child's output, never a quiet
-    'v1 won': non-zero exit (a kernel did not compile), junk answer,
-    timeout."""
+    win, and the answer is the default kernel: non-zero exit (a kernel
+    did not compile), junk answer, timeout."""
     _probe_applies(monkeypatch)
     monkeypatch.setattr(
         subprocess, "run",
         _fake_run("junk", rc=3, detail="MosaicError: kernel refused"),
     )
-    assert ka.autotune_decode_kernel(**SHAPES) == "v1"
+    assert ka.autotune_decode_kernel(**SHAPES) == "live"
     err = capsys.readouterr().err
     assert "probe FAILED" in err and "exit 3" in err
     assert "MosaicError: kernel refused" in err
@@ -76,7 +76,7 @@ def test_child_failure_is_loud_and_starts_on_default(monkeypatch, capsys):
         raise subprocess.TimeoutExpired(cmd="x", timeout=1)
 
     monkeypatch.setattr(subprocess, "run", boom)
-    assert ka.autotune_decode_kernel(**SHAPES) == "v1"
+    assert ka.autotune_decode_kernel(**SHAPES) == "live"
     assert "probe FAILED: no answer" in capsys.readouterr().err
 
 
@@ -181,10 +181,10 @@ class TestChildCache:
 
 def test_run_ab_off_tpu_is_unmeasured():
     """On the CPU backend run_ab must report measured=False so the child
-    never caches the v1 fallback."""
+    never caches the unmeasured default."""
     pytest.importorskip("jax")
     choice, measured = ka.run_ab(
         num_heads=4, num_kv_heads=2, head_dim=8, num_layers=1,
         max_seqs=2, page_size=8,
     )
-    assert choice == "v1" and measured is False
+    assert choice == "live" and measured is False

@@ -104,8 +104,12 @@ class TestFp8XlaPaths:
 class TestFp8PallasKernels:
     @pytest.mark.parametrize(
         "kernel",
-        [pk.paged_decode_attention_pallas, pk.paged_decode_attention_pallas_v2],
-        ids=["v1", "v2"],
+        [
+            pk.paged_decode_attention_pallas,
+            pk.paged_decode_attention_pallas_v2,
+            pk.paged_decode_attention_live,
+        ],
+        ids=["v1", "v2", "live"],
     )
     def test_decode_kernels_match_reference_on_fp8_pool(self, kernel):
         S, n_heads, n_kv, d, page_size, pps = 4, 8, 2, 16, 8, 4

@@ -7,6 +7,8 @@ kernels in ops/pallas_attention.py run through the Pallas interpreter on
 the CPU backend.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,11 +20,14 @@ from llmq_tpu.ops.dispatch import _WINDOW_DISABLED
 
 pytestmark = pytest.mark.unit
 
-# Both decode kernels share one contract; every decode test runs against
-# each. v2 additionally takes a chunk size — exercised separately below.
+# The decode kernels share one contract; every decode test runs against
+# each. v2 additionally takes a chunk size — exercised separately below;
+# so is what is new in "live", the default (its schedule follows the
+# live cache).
 DECODE_KERNELS = {
     "v1": pk.paged_decode_attention_pallas,
     "v2": pk.paged_decode_attention_pallas_v2,
+    "live": pk.paged_decode_attention_live,
 }
 
 
@@ -176,6 +181,221 @@ def test_paged_decode_v2_dead_chunk_then_live():
         scale=scale, pages_per_chunk=C, interpret=True,
     )
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+# --- the default decode kernel: work that follows the live cache ------------
+#
+# Pages here are 8 tokens x 2 kv heads x 16 x float32 = 1 KiB, so
+# ``_decode_schedule`` gives chunks of C = 16 pages (128 tokens) folded in
+# groups of G = 8 (64 tokens): the boundaries the cases below sit on.
+
+LIVE_CASES = {
+    "empty_slots_between_live_ones": dict(ctx=[0, 17, 0, 0, 300, 1, 0]),
+    "ctx_on_a_page_a_group_and_a_chunk_boundary": dict(
+        ctx=[8, 64, 128, 256, 129]
+    ),
+    "ctx_of_one_token": dict(ctx=[1, 1, 1]),
+    "64_page_places_3_live_pages": dict(ctx=[20, 24, 17], pages_per_seq=64),
+    "window_starts_inside_a_chunk": dict(ctx=[300, 140, 9], window=100),
+    "window_and_softcap": dict(ctx=[300, 64, 0, 33], window=70, softcap=20.0),
+    "softcap": dict(ctx=[200, 5], softcap=30.0),
+    "fp8_pool_4_kv_heads": dict(
+        ctx=[150, 0, 9], n_kv=4, pool=jnp.float8_e5m2
+    ),
+    "fp8_pool_2_kv_heads_padded_on_chip_goes_to_v1": dict(
+        ctx=[150, 0, 9], pool=jnp.float8_e5m2
+    ),
+    "bf16_pool_and_queries": dict(
+        ctx=[150, 0, 9], pool=jnp.bfloat16, q_dtype=jnp.bfloat16, tol=1e-2
+    ),
+    "mha_16_heads": dict(ctx=[70, 3], n_heads=16, n_kv=16),
+}
+
+
+def _live_setup(
+    ctx, *, n_heads=8, n_kv=2, pages_per_seq=40, pool=jnp.float32,
+    q_dtype=jnp.float32, layers=None, seed=30,
+):
+    """Queries, a pool whose pages are scattered, and the block table."""
+    S, d, page = len(ctx), 16, 8
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    P = 1 + S * pages_per_seq
+    lead = () if layers is None else (layers,)
+    q = _rand(kq, (S, n_heads, d)).astype(q_dtype)
+    k_pages = _rand(kk, lead + (P, page, n_kv, d)).astype(pool)
+    v_pages = _rand(kv, lead + (P, page, n_kv, d)).astype(pool)
+    bt = np.random.default_rng(seed).permutation(np.arange(1, P))
+    bt = jnp.asarray(bt.reshape(S, pages_per_seq).astype(np.int32))
+    return q, k_pages, v_pages, bt, jnp.asarray(ctx, jnp.int32)
+
+
+def _assert_live_matches(out, ref, ctx, tol=2e-5):
+    live = np.asarray(ctx) > 0
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+    assert (out[~live] == 0).all(), "an inactive slot must read zeros"
+
+
+@pytest.mark.parametrize("case", LIVE_CASES.values(), ids=LIVE_CASES)
+def test_paged_decode_live_cases(case):
+    case = dict(case)
+    ctx, window = case.pop("ctx"), case.pop("window", None)
+    softcap, tol = case.pop("softcap", None), case.pop("tol", 2e-5)
+    q, k_pages, v_pages, bt, cl = _live_setup(ctx, **case)
+    scale = q.shape[-1] ** -0.5
+    ref = ref_ops.paged_decode_attention(
+        q, k_pages, v_pages, bt, cl, scale=scale, sliding_window=window,
+        softcap=softcap,
+    )
+    out = pk.paged_decode_attention_live(
+        q, k_pages, v_pages, bt, cl,
+        jnp.asarray([window if window else _WINDOW_DISABLED], jnp.int32),
+        scale=scale, softcap=softcap, interpret=True,
+    )
+    _assert_live_matches(out, ref, ctx, tol)
+
+
+def test_paged_decode_live_stacked_pool_traced_layer_and_window():
+    """The model's layer scan: the whole stacked pool, the layer and the
+    window traced scalars of one jitted program."""
+    ctx = [130, 0, 64, 7]
+    q, k_pages, v_pages, bt, cl = _live_setup(ctx, layers=3)
+    scale = q.shape[-1] ** -0.5
+
+    @jax.jit
+    def both_layers(k_pages, v_pages, windows):
+        def layer(_, xs):
+            li, window = xs
+            return None, pk.paged_decode_attention_live(
+                q, k_pages, v_pages, bt, cl, window, li,
+                scale=scale, interpret=True,
+            )
+
+        return jax.lax.scan(layer, None, (jnp.arange(3), windows))[1]
+
+    windows = [_WINDOW_DISABLED, 50, 9]
+    outs = both_layers(k_pages, v_pages, jnp.asarray(windows, jnp.int32))
+    for li, window in enumerate(windows):
+        ref = ref_ops.paged_decode_attention(
+            q, k_pages, v_pages, bt, cl, scale=scale, layer=li,
+            sliding_window=None if window == _WINDOW_DISABLED else window,
+        )
+        _assert_live_matches(outs[li], ref, ctx)
+
+
+def test_paged_decode_live_under_shard_over_heads():
+    """tp=2 through the engine's dispatch: the kernel under ``shard_map``,
+    queries and pool sharded over the heads (4 query / 2 kv a shard)."""
+    from llmq_tpu.ops import dispatch
+    from llmq_tpu.parallel.mesh import make_mesh
+
+    ctx = [130, 0, 64, 7]
+    q, k_pages, v_pages, bt, cl = _live_setup(ctx, n_kv=4, layers=2)
+    mesh = make_mesh(tensor_parallel=2, devices=jax.devices()[:2])
+    assert dispatch.decode_kernel_plan(8, 4, mesh, "pallas") == ("live", False)
+    scale = q.shape[-1] ** -0.5
+    li = jnp.asarray(1, jnp.int32)
+    out = jax.jit(
+        lambda *a: dispatch.decode_attention(
+            *a, scale=scale, mesh=mesh, backend="pallas", layer=li
+        )
+    )(q, k_pages, v_pages, bt, cl)
+    ref = ref_ops.paged_decode_attention(
+        q, k_pages, v_pages, bt, cl, scale=scale, layer=li
+    )
+    _assert_live_matches(out, ref, ctx)
+
+
+def _pallas_grids(fn, *args, **kwargs):
+    """The grid of every ``pallas_call`` in ``fn``'s jaxpr."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(functools.partial(fn, **kwargs))(*args).jaxpr)
+    return grids
+
+
+def test_the_default_decode_schedule_follows_the_live_set(monkeypatch):
+    """No grid axis spans the page places, and the copies a call starts
+    are the live pages' (K and V), whatever ``pages_per_seq`` is: a later
+    PR cannot bring the fixed grid back unnoticed."""
+    from llmq_tpu.ops import dispatch
+
+    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
+    assert dispatch.decode_kernel_plan(8, 2, None, "pallas") == ("live", False)
+    assert dispatch._DECODE_KERNELS["live"] is pk.paged_decode_attention_live
+
+    started = []
+    real_copy = pk.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, copy):
+            self.copy = copy
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(
+        pk.pltpu, "make_async_copy", lambda *a: Counted(real_copy(*a))
+    )
+    ctx = [20, 0, 300, 64, 1]
+    live_pages = sum(-(-c // 8) for c in ctx)
+    window = jnp.asarray([_WINDOW_DISABLED], jnp.int32)
+    copies, grids = {}, {}
+    for pages_per_seq in (40, 64):
+        q, k_pages, v_pages, bt, cl = _live_setup(
+            ctx, pages_per_seq=pages_per_seq
+        )
+        # A softcap no other test uses: this kernel is traced afresh,
+        # with the counting copies.
+        call = dict(scale=0.25, softcap=29.5 + pages_per_seq, interpret=True)
+        grids[pages_per_seq] = _pallas_grids(
+            pk.paged_decode_attention_live,
+            q, k_pages, v_pages, bt, cl, window, **call,
+        )
+        started.clear()
+        jax.block_until_ready(
+            pk.paged_decode_attention_live(
+                q, k_pages, v_pages, bt, cl, window, **call
+            )
+        )
+        jax.effects_barrier()
+        copies[pages_per_seq] = len(started)
+    assert grids[40] == grids[64] == [(len(ctx),)]
+    assert copies[40] == copies[64] == 2 * live_pages
+    # v1, for contrast, spans the places.
+    q, k_pages, v_pages, bt, cl = _live_setup(ctx, pages_per_seq=64)
+    assert _pallas_grids(
+        pk.paged_decode_attention_pallas,
+        q, k_pages, v_pages, bt, cl, window, scale=0.25, interpret=True,
+    ) == [(len(ctx), 64)]
+
+
+@pytest.mark.parametrize(
+    "page_bytes,schedule",
+    [
+        (64 * 1024, (16, 8)),  # qwen2.5-3b: 128 tokens x 2 heads x 128 bf16
+        (128 * 1024, (8, 4)),  # llama3.1-8b at tp=2, qwen2.5-7b
+        (512 * 1024, (2, 1)),
+        (1024 * 1024, (1, 1)),
+        (4 * 1024 * 1024, (1, 1)),
+        (1024, (16, 8)),  # the tiny pages of these tests
+    ],
+)
+def test_decode_schedule_comes_from_the_page_bytes(page_bytes, schedule):
+    C, G = pk._decode_schedule(page_bytes)
+    assert (C, G) == schedule and C % G == 0
+    assert 4 * C * page_bytes <= max(4 * page_bytes, 4 * 1024 * 1024)
 
 
 def test_flash_prefill_bf16_matches_reference():

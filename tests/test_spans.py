@@ -20,6 +20,7 @@ from llmq_tpu.engine.engine import (
     scopes_from_hlo_text,
 )
 from llmq_tpu.engine.sampling import SamplingParams
+from llmq_tpu.engine.scheduler import Sequence
 from llmq_tpu.engine.tokenizer import ByteTokenizer
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.transformer import init_params
@@ -312,6 +313,60 @@ def test_a_served_request_yields_the_engine_spans(traced):
     assert sum(s["n"] for s in dump["spans"] if s["name"] == "intake") == 6
 
 
+def test_decode_dispatch_counts_the_live_pages(traced):
+    """``live_pages``: what the decode kernel has to visit a layer. The
+    prompts are 9 bytes and 6 tokens come out: every sequence sits in its
+    second 8-token page for the whole run."""
+    _, dump = traced
+    decodes = [s for s in dump["spans"] if s["name"] == "decode_dispatch"]
+    assert decodes
+    for s in decodes:
+        assert s["live_pages"] == 2 * s["rows"] > 0
+
+
+def test_live_pages_follow_the_window_of_a_model_that_slides():
+    core = make_core()
+    seqs = [
+        Sequence(rid=f"w{n}", prompt_ids=[1] * n, params=greedy())
+        for n in (1, 8, 9, 40)
+    ]
+    assert core._live_pages(seqs) == 1 + 1 + 2 + 5
+    core.model_config = dataclasses.replace(CFG, sliding_window=10)
+    # ctx 40, window 10: positions 30..39, pages 3 and 4 of 0..4.
+    assert core._live_pages(seqs) == 1 + 1 + 2 + 2
+    core.model_config = dataclasses.replace(
+        CFG, sliding_window=10, sliding_window_pattern=2
+    )  # every second layer sees the whole context: count those
+    assert core._live_pages(seqs) == 1 + 1 + 2 + 5
+
+
+def test_the_benchmarks_span_stat_reads_live_pages(traced, monkeypatch):
+    """``decode_live_pages_mean`` is ``span_stat`` over this field."""
+    from types import SimpleNamespace
+
+    from benchmark import span_join
+    from benchmark.readers import span_stat
+
+    _, dump = traced
+    monkeypatch.setattr(span_join, "process_dump", lambda: dump)
+    spec = json.loads(
+        open("benchmark/layer_metrics/decode_live_pages_mean.json").read()
+    )
+    assert spec["reader"] == "span_stat"
+    ctx = SimpleNamespace(
+        records=SimpleNamespace(rows=[], t0=0.0, t1=float("inf")), trace=None
+    )
+    decodes = [s for s in dump["spans"] if s["name"] == "decode_dispatch"]
+    assert span_stat.read(ctx, **spec["args"]) == pytest.approx(
+        sum(s["live_pages"] for s in decodes) / len(decodes)
+    )
+    # A program without the field (the parent): nothing, and no error.
+    for s in dump["spans"]:
+        s.pop("live_pages", None)
+    ctx = SimpleNamespace(records=ctx.records, trace=None)
+    assert span_stat.read(ctx, **spec["args"]) is None
+
+
 def test_every_fetch_is_caused_by_an_earlier_dispatch(traced):
     _, dump = traced
     by_id = {s["id"]: s for s in dump["spans"]}
@@ -424,6 +479,14 @@ def test_scopes_change_the_compiled_step_in_metadata_only(monkeypatch):
     compile cache, whose key leaves metadata out, hands back an entry
     written before the scopes existed: it carries none.)"""
     from jax._src import source_info_util as siu
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # Not through the persistent cache, which an earlier test file of this
+    # process may have switched on: it would hand the second compile the
+    # first one's entry, metadata and all (that is the docstring's point).
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
 
     def compiled_text() -> str:
         jax.clear_caches()
@@ -447,6 +510,8 @@ def test_scopes_change_the_compiled_step_in_metadata_only(monkeypatch):
     bare = compiled_text()
     monkeypatch.undo()
     jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
     assert "llmq.mlp" in scoped and "llmq." not in bare
     assert without_metadata(scoped) == without_metadata(bare)
     assert "fusion" in without_metadata(bare)

@@ -105,6 +105,37 @@ def _decode(kernel, pool_dtype=jnp.bfloat16):
     return case
 
 
+def _decode_default_128_slots_64_places(topo, monkeypatch):
+    """What the benchmark's 3B cells run: the dispatch's default decode
+    kernel at 128 slots x 64 page places over the whole 36-layer pool,
+    the layer traced. It is the live-pages schedule, under the name the
+    benchmark's trace readers look for, and the pool reaches it as it
+    lies (the launcher's 2-D view of a page is a bitcast, not a copy)."""
+    monkeypatch.setattr(dispatch, "_interpret", lambda: False)
+    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
+    s = _Shapes(topo)
+    pool = s((Q3B.num_layers, 1915, PAGE, NKV, D), jnp.bfloat16)
+    kv = Format(Layout(tuple(range(5))), pool.sharding)
+
+    def step(q, kp, vp, bt, cl, layer):
+        return dispatch.decode_attention(
+            q, kp, vp, bt, cl, scale=SCALE, backend="pallas", layer=layer
+        )
+
+    compiled = (
+        jax.jit(step, in_shardings=(None, kv, kv, None, None, None))
+        .lower(
+            s((128, H, D), jnp.bfloat16), pool, pool,
+            s((128, 64), jnp.int32), s((128,), jnp.int32), s((), jnp.int32),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%paged_decode_attention_live" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def _decode_v3(topo, monkeypatch):
     s = _Shapes(topo)
     row = s((SLOTS, NKV, D), jnp.bfloat16)
@@ -166,8 +197,8 @@ def _int4_matmul(K, N):
 
 
 def _decode_tp4_shard_map(topo, monkeypatch):
-    """tp=4 through the dispatch the engine uses: the v1 kernel under
-    ``shard_map`` on a 4-device mesh at llama3.1-8b widths (8 query / 2 kv
+    """tp=4 through the dispatch the engine uses: the default (live)
+    kernel under ``shard_map`` on a 4-device mesh at llama3.1-8b widths (8 query / 2 kv
     heads a shard), the pool sharded on its kv-head axis."""
     monkeypatch.setattr(dispatch, "_interpret", lambda: False)
     cfg = L8B
@@ -223,7 +254,7 @@ def _one_kv_head_a_shard_takes_the_xla_path(topo, monkeypatch):
         )
     assert dispatch.decode_kernel_plan(
         L8B.num_heads, L8B.num_kv_heads, mesh, "pallas"
-    ) == ("v1", False)
+    ) == ("live", False)
 
 
 def _pool_page_bytes_come_from_the_compiler(topo, monkeypatch):
@@ -299,6 +330,11 @@ def _refusal_is_a_compile_failure_not_a_device_fault(topo, monkeypatch):
 
 
 CASES = {
+    "decode_live": _decode(pk.paged_decode_attention_live),
+    "decode_live_fp8_pool_handed_to_v1": _decode(
+        pk.paged_decode_attention_live, jnp.float8_e5m2
+    ),
+    "decode_default_128_slots_64_places": _decode_default_128_slots_64_places,
     "decode_v1": _decode(pk.paged_decode_attention_pallas),
     "decode_v2": _decode(pk.paged_decode_attention_pallas_v2),
     "decode_v3_fused_write": _decode_v3,
