@@ -1,6 +1,13 @@
-"""The plain reference of the Qwen2 architecture, and its control.
+"""The Qwen2 architecture: its served tree, how each leaf is made, its
+plain reference and that reference's controls (see ``__init__.py`` for
+what the harness asks of an architecture).
 
-Straightforward ``jax.numpy`` in float32 under
+The tree has the layout the program's ``Transformer`` reads (``embed``,
+``layers/<name>`` stacked on a leading layer axis, ``final_norm``,
+``lm_head`` where the embedding is not tied); the shapes are computed here
+from the configuration file.
+
+The reference is straightforward ``jax.numpy`` in float32 under
 ``jax.default_matmul_precision("highest")``: no kernels, no cache, no
 batching, nothing imported from the program. One sequence at a time, one
 layer at a time (a 3B or 7B float32 tree does not fit beside the served
@@ -29,7 +36,50 @@ import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+CONTROLS = ("int8w", "fp8kv")
 _Q_CHUNK = 512  # query rows per attention block: bounds the score matrix
+
+
+def tree_shapes(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """Leaf shapes of the served tree for an HF-style configuration."""
+    H = int(cfg["hidden_size"])
+    L = int(cfg["num_hidden_layers"])
+    nh = int(cfg["num_attention_heads"])
+    nkv = int(cfg["num_key_value_heads"])
+    d = int(cfg.get("head_dim") or H // nh)
+    I = int(cfg["intermediate_size"])
+    V = int(cfg["vocab_size"])
+    shapes = {
+        "embed": (V, H),
+        "final_norm": (H,),
+        "layers": {
+            "ln1": (L, H),
+            "ln2": (L, H),
+            "q_proj": (L, H, nh * d),
+            "k_proj": (L, H, nkv * d),
+            "v_proj": (L, H, nkv * d),
+            "o_proj": (L, nh * d, H),
+            "gate_proj": (L, H, I),
+            "up_proj": (L, H, I),
+            "down_proj": (L, I, H),
+        },
+    }
+    if cfg.get("attention_bias", cfg.get("model_type") == "qwen2"):
+        shapes["layers"].update(
+            q_bias=(L, nh * d), k_bias=(L, nkv * d), v_bias=(L, nkv * d)
+        )
+    if not cfg.get("tie_word_embeddings", False):
+        shapes["lm_head"] = (H, V)
+    return shapes
+
+
+def init_rule(name: str) -> str:
+    """How ``weights.py`` makes the leaf of that name."""
+    if name in ("ln1", "ln2", "final_norm"):
+        return "norm"
+    if name.endswith("_bias"):
+        return "bias"
+    return {"embed": "vocab_rows", "lm_head": "vocab_columns"}.get(name, "matrix")
 
 
 def _fake_int8(w, axis: int):

@@ -2,13 +2,15 @@
 """The builder's two one-off measurements, each in one process on the chip.
 Neither is part of a benchmark run.
 
-    python3 benchmark/calibrate.py --workload <cell> --seeds 12 --controls int8w,fp8kv
+    python3 benchmark/calibrate.py --workload <cell> --seeds 12 [--controls <a>,<b>]
         For each of the seeds: the numbers of ``correct.py`` for the
         served program, a run's worst of each, and on the first
-        ``--control-seeds`` of them for each control (the reference
-        computed in a lower precision, standing in the program's place).
-        The limits in ``limits.json`` are set from the largest of the
-        first and the smallest of the second.
+        ``--control-seeds`` of them for each control (the reference of
+        the configuration's architecture computed in a lower precision,
+        standing in the program's place; without ``--controls``, every one
+        in that architecture's ``CONTROLS``). The configuration's limits
+        (``limits/<config>.json``, or ``limits.json``) are set from the
+        largest of the first and the smallest of the second.
 
     python3 benchmark/calibrate.py --workload <open-loop cell> --sweep 6,8,10,12,14
         The knee: ascending rates, 20 s each, the backlog (requests sent
@@ -41,9 +43,11 @@ def say(line: str, **facts) -> None:
 
 
 async def correctness(args, cell, traffic, system) -> None:
-    from benchmark import correct
+    from benchmark import architectures, correct
 
-    controls = [c for c in args.controls.split(",") if c]
+    controls = [c for c in args.controls.split(",") if c] or list(
+        architectures.of(cell.config).CONTROLS
+    )
     names = ("logit_err", "served_regret", "repeat_diff")
 
     def worst(rows):
@@ -153,7 +157,8 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, default=12)
-    ap.add_argument("--controls", default="int8w,fp8kv")
+    ap.add_argument("--controls", default="", help="default: the CONTROLS of "
+                    "the configuration's architecture")
     ap.add_argument("--control-seeds", type=int, default=3,
                     help="the controls are read on the first this many seeds")
     ap.add_argument("--sweep", default="")

@@ -16,8 +16,9 @@ For each run, from ``--seed``:
    prefill of the prompts in the batch shapes the engine dispatched them
    in, then one decode step a token, through a scratch paged cache, fed
    the tokens that step 1 served: logits of each served position.
-4. The sample prompts are run through ``reference.forward_logits``
-   (float32, highest precision, no cache) over prompt + served tokens:
+4. The sample prompts are run through the plain reference of the cell's
+   configuration, ``forward_logits`` of ``architectures/<architecture>.py``
+   (float32, highest precision, no cache), over prompt + served tokens:
    logits of the same positions.
 
 Three numbers are compared with limits:
@@ -37,7 +38,9 @@ Three numbers are compared with limits:
   and it separates bf16 from int8 weights or an fp8 cache.
 - ``repeat_diff``: tokens that differ between the two idle answers.
 
-The limits are in ``limits.json`` with the readings they were set from.
+The limits are the configuration's own, ``limits/<config>.json``, where
+that file exists, else those of ``limits.json``; either file holds the
+readings its limits were set from.
 """
 
 from __future__ import annotations
@@ -57,12 +60,19 @@ FILL_PROMPT = 160  # their prompt length (one prefill bucket)
 FILL_TOKENS = 48  # their output: they outlast the samples' prefill and K tokens
 FILL_TIED = 16  # of which the first are judged against the direct logits
 
+HERE = Path(__file__).resolve().parent
 Seq = Tuple[List[int], List[int]]  # prompt ids, served tokens
 _JITS: Dict[int, tuple] = {}  # the direct path's two programs, traced once a model
 
 
-def load_limits() -> Dict[str, float]:
-    return json.loads((Path(__file__).parent / "limits.json").read_text())["limits"]
+def load_limits(config: str, directory: Path = HERE) -> Tuple[Dict[str, float], str]:
+    """The limits a configuration is held to, and the file they are from:
+    ``limits/<config>.json`` where it exists, else ``limits.json``."""
+    directory = Path(directory)
+    path = directory / "limits" / f"{config}.json"
+    if not path.is_file():
+        path = directory / "limits.json"
+    return json.loads(path.read_text())["limits"], str(path.relative_to(directory.parent))
 
 
 def spread(logits: np.ndarray) -> float:
@@ -220,8 +230,9 @@ async def check_cell(system, cfg: Dict[str, Any], lengths: Sequence[int],
     """The numbers of one run: a row per sample prompt and one for the
     fillers. ``kept`` holds the sequences and their direct logits, for
     ``calibrate.py`` to put the controls in the program's place."""
-    from . import reference
+    from . import architectures
 
+    reference = architectures.of(cfg)
     core = system.core
     prompts = [
         prompt_ids(seed, i, min(int(want), core.cfg.max_model_len - K_TOKENS - 1))
@@ -271,7 +282,9 @@ def control_rows(core, cfg: Dict[str, Any], kept: Dict[str, Any], control: str):
     (``logit_err``), and the tokens it would serve, its best at each
     position, judged by the program's direct logits (``served_regret``).
     Run by ``calibrate.py``, never by a benchmark run."""
-    from . import reference
+    from . import architectures
+
+    reference = architectures.of(cfg)
 
     def ctrl_logits(ids, toks):
         return np.asarray(reference.forward_logits(

@@ -4,7 +4,10 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything about a cell is data found by name from ``BENCHMARK.json``: its
-configuration (``benchmark/configs/<config>.json``), its traffic
+configuration (``benchmark/configs/<config>.json``), that configuration's
+architecture (``benchmark/architectures/<architecture>.py``: weight tree and
+plain reference) and limits (``benchmark/limits/<config>.json``, else
+``benchmark/limits.json``), its traffic
 (``benchmark/traffic/<traffic>.json``) and the per-layer metrics that list
 it (``benchmark/layer_metrics/<metric>.json`` naming a reader under
 ``benchmark/readers/``). See ``benchmark/README.md``.
@@ -12,7 +15,8 @@ it (``benchmark/layer_metrics/<metric>.json`` naming a reader under
 The last line of standard output is the result; earlier lines say where
 the set-up time went, what the traffic was, every percentile with its
 sample count, how late the generator ran, and each number of the
-correctness comparison beside its limit. Logs go to standard error.
+correctness comparison beside its limit (those are also the last lines of
+standard error). Logs go to standard error.
 Without a TPU holding the chips the cell asks for, the command exits
 non-zero and prints no result (``--rehearse-cpu`` with ``JAX_PLATFORMS=cpu``
 is the one switch; see ``rehearsal.json``).
@@ -59,7 +63,7 @@ def read_layer_metrics(cell, ctx) -> dict:
     return out
 
 
-async def run(args, cell, traffic, device) -> dict:
+async def run(args, cell, traffic, device) -> tuple:
     from benchmark import correct, loadgen, metrics, schedule, trace_reduce
     from benchmark.kernel_cost import peaks_for
     from benchmark.system import System
@@ -82,10 +86,11 @@ async def run(args, cell, traffic, device) -> dict:
     say("warmed", **warmed)
 
     checked = await correct.check_cell(system, cell.config, traffic["check_lengths"], args.seed)
-    check = correct.verdict(checked, correct.load_limits())
+    limits, limits_file = correct.load_limits(cell.config_name)
+    check = correct.verdict(checked, limits)
     del checked  # the logits it keeps for calibrate.py
     marks["reference"] = time.monotonic()
-    say("correct", **check)
+    say("correct", limits_file=limits_file, **check)
     system.timings.clear()
     first_dispatch = len(system.prefill_log)
 
@@ -180,7 +185,9 @@ async def run(args, cell, traffic, device) -> dict:
             for m in cell.end_to_end
         }
     result["device"] = device
-    return result
+    compared = {"correct": check["correct"], "limits_file": limits_file,
+                "compared": check["compared"]}
+    return result, compared
 
 
 def main() -> None:
@@ -202,7 +209,10 @@ def main() -> None:
     say("device", **device, workload=cell.name, seed=args.seed,
         seconds=args.seconds, trace=args.trace)
     loop = asyncio.new_event_loop()
-    result = loop.run_until_complete(run(args, cell, traffic, device))
+    result, compared = loop.run_until_complete(run(args, cell, traffic, device))
+    # After the loop has stopped, so that no warning of the worker's follows
+    # it: the record of a run that is not correct keeps standard error's end.
+    print(json.dumps(compared, default=float), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     # The worker's own shutdown waits for requests the run has abandoned
     # and the engine thread is a daemon; this process started no other

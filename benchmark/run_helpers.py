@@ -30,6 +30,7 @@ def load_cell(name: str) -> SimpleNamespace:
         name=name,
         chips=int(cell["chips"]),
         config=config,
+        config_name=config_entry["name"],
         traffic_file=traffic_file,
         end_to_end=[m for m in bench["end_to_end"] if listed(m)],
         per_layer=[m for m in bench["per_layer"] if listed(m)],
@@ -37,8 +38,11 @@ def load_cell(name: str) -> SimpleNamespace:
 
 
 def apply_rehearsal(cell: SimpleNamespace, traffic: dict) -> None:
-    """Shrink the cell to ``preset://tiny`` sizes (see rehearsal.json)."""
+    """Shrink the cell to ``preset://tiny`` sizes (see rehearsal.json): the
+    configuration, whatever its architecture, is replaced by the tiny
+    dense model, which names none, and is held to ``limits.json``."""
     r = json.loads((HERE / "rehearsal.json").read_text())
+    cell.config_name = "rehearsal"
     cell.config = dict(r["model"], program_model=r["program_model"], env={},
                        engine=dict(cell.config["engine"], **r["engine"]))
     div = r["length_divisor"]

@@ -18,12 +18,36 @@ changes what it does:
 from __future__ import annotations
 
 import asyncio
+import inspect
 import json
 import os
 import time
 from typing import Any, Callable, Dict, List, Optional
 
 QUEUE = "bench"
+# Keys of a configuration's ``engine`` that are the file's comments: any
+# that ends in ``_why``, and these (a new file uses ``_why``).
+ENGINE_COMMENTS = ("everything_else", "attention_path", "pool")
+
+
+def worker_options(engine_cfg: Dict[str, Any], signature: inspect.Signature) -> Dict[str, Any]:
+    """Every key of ``engine`` as an option of the worker: what the file
+    does not give is left to the worker's default, and a key that is
+    neither an option of ``signature`` nor a comment is an error."""
+    taken = {
+        name for name, p in signature.parameters.items() if p.kind is p.KEYWORD_ONLY
+    }
+    options = {
+        key: value for key, value in engine_cfg.items()
+        if not key.endswith("_why") and key not in ENGINE_COMMENTS
+    }
+    unknown = sorted(set(options) - taken)
+    if unknown:
+        raise SystemExit(
+            f"the configuration's engine gives {unknown}, which the worker does "
+            f"not take (it takes {sorted(taken)}; a comment's key ends in _why)"
+        )
+    return options
 
 
 class System:
@@ -52,10 +76,7 @@ class System:
         self.worker = build_tpu_worker(
             self.config["program_model"],
             QUEUE,
-            tensor_parallel=engine_cfg.get("tensor_parallel"),
-            max_num_seqs=engine_cfg.get("max_num_seqs"),
-            max_model_len=engine_cfg.get("max_model_len"),
-            dtype=engine_cfg.get("dtype", "bfloat16"),
+            **worker_options(engine_cfg, inspect.signature(build_tpu_worker)),
         )
         setup_logging(structured=False, level="WARNING")  # stdout is results
         self.wtask = asyncio.ensure_future(self.worker.run())
@@ -120,8 +141,9 @@ class System:
         is dropped first, so the two never lie side by side."""
         import jax
 
-        from . import weights
+        from . import architectures, weights
 
+        arch = architectures.of(self.config)
         core = self.core
         shardings = core._param_shardings
 
@@ -133,7 +155,7 @@ class System:
             core.params = None
             for leaf in jax.tree.leaves(old):
                 leaf.delete()
-            new = weights.make_weights(self.config, seed, shardings)
+            new = weights.make_weights(arch, self.config, seed, shardings)
             weights.check_same_layout(new, layout)
             core.params = new
             jax.block_until_ready(new)

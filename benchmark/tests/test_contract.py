@@ -76,3 +76,24 @@ def test_config_files_hold_the_source_widths():
 def test_run_seconds_fits_a_full_check_of_24_cells():
     rs = BENCH["run_seconds"]
     assert (2 + 14 * 24) * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configuration_has_an_architecture_limits_and_worker_options(config):
+    import inspect
+
+    from benchmark import architectures, correct, weights
+    from benchmark.system import worker_options
+    from llmq_tpu.cli.worker import build_tpu_worker
+
+    cfg = json.loads((ROOT / config["file"]).read_text())
+    arch = architectures.of(cfg)
+    assert all(hasattr(arch, m) for m in architectures.MEMBERS)
+    for group in arch.tree_shapes(cfg).values():
+        for name in group if isinstance(group, dict) else ():
+            assert arch.init_rule(name) in weights.RULES
+    limits, source = correct.load_limits(config["name"])
+    assert set(limits) == {"logit_err", "repeat_diff", "served_regret"}
+    assert source in (f"benchmark/limits/{config['name']}.json", "benchmark/limits.json")
+    options = worker_options(cfg["engine"], inspect.signature(build_tpu_worker))
+    assert options and all(not k.endswith("_why") for k in options)

@@ -2,8 +2,8 @@
 
 On the chip ``calibrate.py`` reads the served program and the controls at
 the cells' own sizes (PERF.md gives the readings). This is the same
-comparison at a size a test run can hold, with the reference standing
-for the program's direct logits: the reference computed with int8
+comparison at a size a test run can hold, with the ``qwen2`` reference
+standing for the program's direct logits: the reference computed with int8
 weights, or with an fp8 cache, in the program's place must come out as
 not correct under the limits of ``limits.json``; the reference itself
 must come out correct.
@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import correct, reference, weights
+from benchmark import correct, weights
+from benchmark.architectures import qwen2
 
 CFG = {
     "model_type": "qwen2", "hidden_size": 256, "intermediate_size": 704,
@@ -23,18 +24,19 @@ CFG = {
     "tie_word_embeddings": True,
 }
 SEEDS = (11, 2_147_483_659, 3_000_000_019)
+LIMITS, _ = correct.load_limits("qwen2.5-3b-bf16")  # limits.json: it has no file of its own
 
 
 def logits(seed, control):
-    shapes = weights.tree_shapes(CFG)
+    shapes = qwen2.tree_shapes(CFG)
     shard = jax.tree.map(
         lambda _: jax.sharding.SingleDeviceSharding(jax.devices()[0]), shapes,
         is_leaf=lambda x: isinstance(x, tuple),
     )
-    params = weights.make_weights(CFG, seed, shard)
+    params = weights.make_weights(qwen2, CFG, seed, shard)
     ids = correct.prompt_ids(seed, 0, 96)
     positions = list(range(88, 96))
-    return np.asarray(reference.forward_logits(params, CFG, ids, positions, control))
+    return np.asarray(qwen2.forward_logits(params, CFG, ids, positions, control))
 
 
 def numbers(program, ref):
@@ -50,14 +52,14 @@ def numbers(program, ref):
 def test_control_is_not_correct(seed, control):
     ref, ctrl = logits(seed, None), logits(seed, control)
     check = numbers(ctrl, ref)
-    assert not correct.verdict(check, correct.load_limits())["correct"], check
+    assert not correct.verdict(check, LIMITS)["correct"], check
 
 
 @pytest.mark.parametrize("seed", SEEDS)
 def test_reference_agrees_with_itself(seed):
     ref = logits(seed, None)
     check = numbers(ref, ref)
-    assert correct.verdict(check, correct.load_limits())["correct"]
+    assert correct.verdict(check, LIMITS)["correct"]
     assert check["rows"][0]["logit_err"] == 0.0 and check["rows"][0]["served_regret"] == 0.0
 
 
@@ -78,11 +80,11 @@ def test_weights_follow_the_seed():
 
 def test_a_dropped_bias_or_norm_weight_would_show():
     # make_weights gives biases and norm weights that are not 0 and 1
-    shapes = weights.tree_shapes(CFG)
+    shapes = qwen2.tree_shapes(CFG)
     shard = jax.tree.map(
         lambda _: jax.sharding.SingleDeviceSharding(jax.devices()[0]), shapes,
         is_leaf=lambda x: isinstance(x, tuple),
     )
-    p = weights.make_weights(CFG, 5, shard)
+    p = weights.make_weights(qwen2, CFG, 5, shard)
     assert float(jnp.abs(p["layers"]["q_bias"].astype(jnp.float32)).mean()) > 0.05
     assert float(jnp.abs(p["layers"]["ln1"].astype(jnp.float32) - 1).mean()) > 0.05
