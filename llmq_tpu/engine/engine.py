@@ -104,7 +104,12 @@ from llmq_tpu.engine.scheduler import (
 )
 from llmq_tpu.engine.tokenizer import Tokenizer
 from llmq_tpu.models.config import ModelConfig
-from llmq_tpu.models.transformer import Params, Transformer, make_kv_pages
+from llmq_tpu.models.transformer import (
+    Params,
+    Transformer,
+    build_model,
+    make_kv_pages,
+)
 from llmq_tpu.obs.metrics import (
     DEFAULT_BUCKETS,
     Gauge,
@@ -568,7 +573,9 @@ class EngineConfig:
                 ) from None
 
 
-def _prefill_buckets(cfg: EngineConfig, sp: int = 1) -> List[int]:
+def _prefill_buckets(
+    cfg: EngineConfig, sp: int = 1, quarter_steps: bool = True
+) -> List[int]:
     """Prompt buckets up to max_model_len: powers of two, plus quarter
     steps between octaves above 128. Pure doubling pads badly right
     where real prompts live — a 200-token prompt padded to 256 wastes
@@ -581,12 +588,17 @@ def _prefill_buckets(cfg: EngineConfig, sp: int = 1) -> List[int]:
     Every bucket is rounded up to a multiple of the sequence-parallel
     degree so ring attention (which shards the T axis over sp) applies
     to all of them — notably the top bucket, which is max_model_len
-    itself and need not be on the ladder."""
+    itself and need not be on the ladder.
+
+    ``quarter_steps=False`` keeps the powers of two alone: a model with a
+    layer pattern takes 7-17 s to compile a bucket's program for a v5e
+    and runs 26 of them before decode-long's window with quarter steps
+    (585 s of set-up, my chip run, PR 33), 8 without."""
     buckets = []
     b = cfg.min_prefill_bucket
     while b < cfg.max_model_len:
         buckets.append(b)
-        if b >= 128:
+        if b >= 128 and quarter_steps:
             for quarter in (b + b // 4, b + b // 2, b + 3 * b // 4):
                 if quarter < cfg.max_model_len:
                     buckets.append(quarter)
@@ -618,6 +630,15 @@ def kv_page_bytes_per_device(
     )
     pool = alloc.lower().compile().memory_analysis().output_size_in_bytes
     return 2 * pool // probe_pages
+
+
+def _layer_pattern_refusal(what: str) -> str:
+    return (
+        f"{what} is not supported for a model with a layer pattern "
+        "(per-sequence KDA state beside a latent cache): the state cannot "
+        "be shared by a prefix, cut at a chunk, rewound by a length or "
+        "moved between pools, and its experts are held whole on one device"
+    )
 
 
 # Pipeline entry: (dispatch index, kind "prefill"|"decode", device
@@ -654,6 +675,13 @@ class EngineCore:
         # hidden states).
         self.full_mesh = self.mesh
         self.pp = mesh_pp(self.mesh)
+        # A declared layer pattern (models/hybrid.py): a per-sequence
+        # state beside a paged latent cache. ``_state_rows`` sizes the
+        # state pool: a row a slot, and row 0 scratch.
+        self._hybrid = model_config.layer_pattern is not None
+        self._state_rows = self.cfg.max_num_seqs + 1 if self._hybrid else None
+        if self._hybrid:
+            self._refuse_for_layer_pattern(params)
         if self.pp > 1:
             if self.cfg.spec_tokens > 0:
                 raise ValueError(
@@ -722,7 +750,7 @@ class EngineCore:
             self._param_shardings = {"stages": stage_shardings}
         else:
             self._stage_models = None
-            self.model = Transformer(
+            self.model = build_model(
                 model_config, mesh=self.mesh, tp_overlap=self.tp_overlap
             )
             self._param_shardings = param_shardings(
@@ -765,6 +793,17 @@ class EngineCore:
             Format(Layout(tuple(range(5))), sh) if pin else sh
             for sh in self._kv_shardings
         ]
+        if self._hybrid:
+            # Latent pool and state pool differ in rank: one placement
+            # that fits both (tp = 1: whole on the device), and no layout
+            # pin: the latent pool's rows are whole lane tiles
+            # (``hybrid.latent_pool_width``), so the runtime's default
+            # layout is the row-major one the step computes in. (A pin
+            # at the jit boundary worked until a program came back from
+            # the compile cache and handed the pool on in the default
+            # layout: my chip run, PR 33, PERF.md section 6.)
+            self._kv_shardings = [NamedSharding(self.mesh, P())]
+            self._kv_formats = list(self._kv_shardings)
         self._kv_sharding = self._kv_shardings[-1]
         self._kv_format = self._kv_formats[-1]
         num_pages = self.cfg.num_pages or self._auto_num_pages()
@@ -812,9 +851,11 @@ class EngineCore:
                 self.cfg.page_size,
                 dtype=self.cfg.kv_dtype,
                 placement=self._kv_format,
+                state_rows=self._state_rows,
             )
-            self.kv_pool_bytes = (
-                2 * self.k_pages.size * self.k_pages.dtype.itemsize
+            self.kv_pool_bytes = sum(
+                x.size * x.dtype.itemsize
+                for x in jax.tree.leaves((self.k_pages, self.v_pages))
             )
             logger.info(
                 "KV cache: %d pages x %d tokens (%.2f GiB total), %d slots",
@@ -1059,6 +1100,19 @@ class EngineCore:
                 raise ValueError(
                     f"LLMQ_CANARY_EVERY={env_canary!r} is not a number"
                 ) from None
+        if self._hybrid:
+            # The options an environment variable can pin, once resolved.
+            for option, value, off in (
+                ("mixed_step", self.mixed_step, "off"),
+                ("preempt_mode", self.preempt_mode, "recompute"),
+                ("prefix_host_gb", self.prefix_host_gb, 0),
+            ):
+                if value != off:
+                    raise ValueError(_layer_pattern_refusal(f"{option}={value}"))
+            # Counters of the expert layers, summed over layers and decode
+            # steps; they ride the pending entry and the fetch of the tokens.
+            self.moe_assignments_held = 0
+            self.moe_experts_hit = 0
         if self.mixed_step == "on" and not self.cfg.prefill_chunk_size:
             raise ValueError(
                 "mixed_step=on requires prefill_chunk_size: the fused "
@@ -1074,7 +1128,8 @@ class EngineCore:
         # stages live on different hosts).
         self.pp_wire = os.environ.get("LLMQ_PP_WIRE", "0") == "1"
         self._buckets = _prefill_buckets(
-            self.cfg, sp=int(self.mesh.shape.get(SP_AXIS, 1))
+            self.cfg, sp=int(self.mesh.shape.get(SP_AXIS, 1)),
+            quarter_steps=not self._hybrid,
         )
         # Small-K interactive decode executables; _make_jits populates
         # this when interactive_decode_block is on (pp=1 only — the pp
@@ -1429,6 +1484,32 @@ class EngineCore:
                 self.canary_every,
             )
 
+    def _refuse_for_layer_pattern(self, params: Params) -> None:
+        """What a per-sequence state cannot do yet, refused at build by
+        name: it cannot be shared by a prefix, cut at a chunk, rewound by
+        a length or moved between pools, and the expert share is held
+        whole on one device."""
+        from llmq_tpu.models import quant as qm
+
+        cfg = self.cfg
+        tp = int(self.mesh.shape.get(TP_AXIS, 1))
+        quantised = any(
+            qm.is_quantized(leaf)
+            for leaf in jax.tree.leaves(params, is_leaf=qm.is_quantized)
+        )
+        for refused, what in (
+            (cfg.enable_prefix_caching, "enable_prefix_caching"),
+            (cfg.prefix_host_gb > 0, "prefix_host_gb"),
+            (cfg.spec_tokens > 0, f"spec_tokens={cfg.spec_tokens}"),
+            (bool(cfg.prefill_chunk_size), "prefill_chunk_size"),
+            (self.pp > 1, f"pp={self.pp}"),
+            (tp > 1, f"tp={tp}"),
+            (quantised, "quantised weights"),
+            (jnp.dtype(cfg.kv_dtype).itemsize < 2, f"kv_dtype={cfg.kv_dtype}"),
+        ):
+            if refused:
+                raise ValueError(_layer_pattern_refusal(what))
+
     def _dispatch_p99(self, kind: str) -> Optional[float]:
         """Watchdog deadline source: live p99 of one dispatch kind, or
         None (→ floor) before any dispatch of that kind landed. Reads a
@@ -1456,6 +1537,7 @@ class EngineCore:
         model = self.model
         S = self.cfg.max_num_seqs
         spec = self.cfg.spec_tokens > 0
+        hybrid = self._hybrid  # trace-time: other models' programs are as they were
         # On-device logit guard (default off → every closure below traces
         # the literal pre-existing program). When on, each step also
         # returns (stats f32[3], bad bool[rows]) folded from its logits;
@@ -1528,9 +1610,16 @@ class EngineCore:
         def decode_step(params, kp, vp, st, *, mode, h=None):
             (tokens, ctx, bt, active, keys, steps, temps, topks,
              topps, _limits, mins, stop_ids) = st
-            logits, kp, vp = model.decode(
-                params, tokens, ctx, kp, vp, bt, active, h=h
-            )
+            if hybrid:
+                # A slot's state row is its index + 1 (row 0 is scratch);
+                # the expert layers' counters leave beside the tokens.
+                logits, kp, vp, moe = model.decode(
+                    params, tokens, ctx, kp, vp, bt, active, 1, counters=True,
+                )
+            else:
+                logits, kp, vp = model.decode(
+                    params, tokens, ctx, kp, vp, bt, active, h=h
+                )
             # Guard reads the raw model logits: suppress_stops writes
             # NEG_INF sentinels that would false-trip the magnitude lane.
             g = guard_stats(logits, active) if guard else None
@@ -1540,6 +1629,8 @@ class EngineCore:
             )
             out = jnp.where(active, next_tokens, 0)
             new_st = advance_state(st, out, active)
+            if hybrid:
+                out = (out, moe)
             if guard:
                 return (out, g), kp, vp, new_st
             return out, kp, vp, new_st
@@ -1792,9 +1883,15 @@ class EngineCore:
                          p_limits, p_mins, p_stopids, *rest, mode, h=None):
             # rest = (p_history, st) under speculation, (st,) otherwise.
             p_history, st = rest if spec else (None, rest[0])
-            logits, kp, vp = model.prefill(
-                params, p_tokens, p_lengths, kp, vp, p_bt, h=h
-            )
+            if hybrid:
+                logits, kp, vp = model.prefill(
+                    params, p_tokens, p_lengths, kp, vp, p_bt,
+                    jnp.where(p_slots >= 0, p_slots + 1, 0),
+                )
+            else:
+                logits, kp, vp = model.prefill(
+                    params, p_tokens, p_lengths, kp, vp, p_bt, h=h
+                )
             g = guard_stats(logits, p_slots >= 0) if guard else None
             out, st = sample_and_scatter(
                 logits, p_slots >= 0, p_lengths, p_bt, p_slots, p_keys,
@@ -2029,6 +2126,8 @@ class EngineCore:
         # are untouched.
         g_on = self.logit_guard == "on"
         guard_sh = (repl, repl)
+        if self._hybrid:
+            out0 = (out0, repl)  # tokens, and the expert layers' counters
         if g_on:
             out0 = (out0, guard_sh)
         p_out = (repl, guard_sh) if g_on else repl
@@ -2062,6 +2161,8 @@ class EngineCore:
                 if self.cfg.spec_tokens > 0
                 else self._block1
             )
+            if self._hybrid:
+                s_out0 = (s_out0, repl)
             if g_on:
                 s_out0 = (s_out0, guard_sh)
             self._decode_jits_small = {
@@ -2619,6 +2720,18 @@ class EngineCore:
         budget = int(limit * self.cfg.hbm_utilization) - stats.get(
             "bytes_in_use", 0
         )
+        if self._hybrid:
+            # The state pool comes out of the same budget, before pages.
+            from llmq_tpu.models import hybrid
+
+            budget -= hybrid.state_pool_bytes(
+                self.model_config, self._state_rows, self.cfg.kv_dtype
+            )
+            page_bytes = hybrid.latent_page_bytes_per_device(
+                self.model_config, self.cfg.page_size, self.cfg.kv_dtype,
+                self._kv_format,
+            )
+            return int(min(max(2, budget // page_bytes), max_useful))
         page_bytes = kv_page_bytes_per_device(
             self.model_config,
             self.cfg.page_size,
@@ -2662,6 +2775,10 @@ class EngineCore:
         if priority not in ("interactive", "batch"):
             raise ValueError(
                 f"priority={priority!r} (want interactive|batch)"
+            )
+        if prefill_only and self._hybrid:
+            raise NotImplementedError(
+                _layer_pattern_refusal("prefill_only (the prefill role)")
             )
         if not self.priority_classes:
             priority = "batch"  # classes disabled: everything is FIFO batch
@@ -2989,6 +3106,9 @@ class EngineCore:
             if self.spans.on:
                 self.spans.end()
             return
+        moe = None
+        if self._hybrid and kind == "decode":
+            out, moe = out
         if isinstance(out, tuple):
             # Speculative verify block: ([K, S, Q] candidates, [K, S]
             # accept counts). Per row and iteration, the first count
@@ -3031,6 +3151,11 @@ class EngineCore:
             return
         with self._wd("decode_block" if kind == "decode" else "prefill"):
             tokens = np.asarray(out)  # transfer started at dispatch; ~ready
+        if moe is not None:
+            # Same dispatch, on its way to the host with the tokens.
+            held, hit = np.asarray(moe).reshape(-1, 2).sum(axis=0)  # llmq: ignore[unguarded-device-fetch]
+            self.moe_assignments_held += int(held)
+            self.moe_experts_hit += int(hit)
         if self.spans.on:
             self.spans.then("emit")
         # Normalise to a [K, rows] block: prefill outputs and K=1 decode
@@ -4069,6 +4194,7 @@ class EngineCore:
                 mode=self._mode, variant="",
                 rows=len(seqs), live_pages=self._live_pages(seqs),
                 k_steps=k_steps, pending=len(self._pending),
+                **({"state_rows": len(seqs)} if self._hybrid else {}),
             )
         with self._wd(kind):
             out, self.k_pages, self.v_pages, self._dev_state = (
@@ -4080,6 +4206,8 @@ class EngineCore:
         self.decode_steps += k_steps
         self.decode_dispatches += 1
         out, g = self._split_guard(out)
+        # A layer pattern's ``out`` is (tokens, the expert layers' counters):
+        # both ride the pending entry and start for the host together.
         self._push_pending(
             "decode",
             out,
@@ -4445,6 +4573,8 @@ class EngineCore:
         keep them — a request that finishes during the drain raises
         KeyError here but surfaces there). Greedy continuation after
         :meth:`insert_request` is bit-identical to never extracting."""
+        if self._hybrid:
+            raise NotImplementedError(_layer_pattern_refusal("extract_request"))
         out = finished if finished is not None else []
         self._drain(out)
         seq = self.scheduler.running.get(rid)
@@ -4464,6 +4594,8 @@ class EngineCore:
     ) -> List[RequestSnapshot]:
         """Extract every unfinished request (drain-with-handoff). See
         :meth:`extract_request`."""
+        if self._hybrid:
+            raise NotImplementedError(_layer_pattern_refusal("extract_all"))
         out = finished if finished is not None else []
         self._drain(out)
         snaps: List[RequestSnapshot] = []
@@ -4620,6 +4752,8 @@ class EngineCore:
         key chain is re-derived from (seed, rid) and verified against the
         snapshot bit-for-bit. A snapshot without KV re-prefills
         prompt+output instead — same math, same tokens."""
+        if self._hybrid:
+            raise NotImplementedError(_layer_pattern_refusal("insert_request"))
         sig, mine = dict(snap.model_sig), self._model_sig()
         if sig != mine:
             raise SnapshotCompatError(
@@ -4964,6 +5098,7 @@ class EngineCore:
                 self.cfg.page_size,
                 dtype=self.cfg.kv_dtype,
                 placement=self._kv_format,
+                state_rows=self._state_rows,
             )
 
     # --- metrics ----------------------------------------------------------
@@ -5046,6 +5181,13 @@ class EngineCore:
             itl_p50_ms=to_ms(self.itl_hist.percentile(0.50)),
             itl_p95_ms=to_ms(self.itl_hist.percentile(0.95)),
         )
+        if self._hybrid:
+            # Expert layers held by share: assignments that landed on the
+            # experts held here and held experts that got any, summed
+            # over layers and decode steps (their quotient is the tokens
+            # an expert that is hit sees a step).
+            s["moe_assignments_held"] = self.moe_assignments_held
+            s["moe_experts_hit"] = self.moe_experts_hit
         if self.cfg.spec_tokens > 0:
             # What speculation actually dispatches: the multi-query
             # verify resolves through its own plan, not the decode ladder.

@@ -61,6 +61,26 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     shared_expert_intermediate_size: Optional[int] = None  # qwen2_moe only
     norm_topk_prob: bool = False  # renormalize the top-k routing weights
+    # Declared layer pattern (bailing_hybrid): one (attention, mlp) pair a
+    # layer, attention "kda" | "mla", mlp "dense" | "moe". None: every layer
+    # is the family's one kind, run by ``models/transformer.py`` as before;
+    # a pattern is run by ``models/hybrid.py``.
+    layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
+    # KDA (delta-rule) layers: heads are num_heads x head_dim.
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0  # bounded ("safe") gate: g in [bound, 0]
+    # MLA (latent attention) layers.
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # noaux_tc sigmoid router: groups, a selection bias, a scaling factor.
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # (first, count) of the routed experts this chip holds; None: all of
+    # them. The router keeps its width (num_experts) either way.
+    experts_held: Optional[Tuple[int, int]] = None
     eos_token_ids: Tuple[int, ...] = ()
     bos_token_id: Optional[int] = None
     model_type: str = "llama"
@@ -90,6 +110,8 @@ class ModelConfig:
     def from_hf_config(cls, hf: Dict[str, Any]) -> "ModelConfig":
         """Map a HuggingFace config.json dict (llama/qwen2/gemma2/mistral)."""
         mt = hf.get("model_type", "llama")
+        if mt == "bailing_hybrid":
+            return cls._from_bailing_hybrid(hf)
         eos = _as_id_list(hf.get("eos_token_id"))
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -165,6 +187,96 @@ class ModelConfig:
                 query_pre_attn_scalar=hf.get("query_pre_attn_scalar"),
             )
         raise ValueError(f"Unsupported model_type: {mt!r}")
+
+    @classmethod
+    def _from_bailing_hybrid(cls, hf: Dict[str, Any]) -> "ModelConfig":
+        """Ling-3.0 (``bailing_hybrid``): KDA layers with every
+        ``layer_group_size``-th an MLA layer, ``first_k_dense_replace``
+        dense MLPs and routed experts after. Two keys of this repo's own
+        say what of the published model is here: ``kept_layers`` (published
+        layer indices, default all) and ``experts_held`` ([first, count],
+        default all). The multi-token-prediction module
+        (``num_nextn_predict_layers``) is a draft head only
+        self-speculation runs and is not built, as public loaders do."""
+        group = int(hf["layer_group_size"])
+        dense = int(hf["first_k_dense_replace"])
+        kept = hf.get("kept_layers")
+        if kept is None:
+            kept = range(int(hf["num_hidden_layers"]))
+        kept = tuple(int(i) for i in kept)
+        for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+            limits = hf.get(key) or []
+            clamped = [i for i in kept if i < len(limits) and limits[i]]
+            if clamped:
+                raise ValueError(
+                    f"bailing_hybrid: {key} is non-zero on kept layers "
+                    f"{clamped}: the clamped SwiGLU is not built"
+                )
+        # What the block is built for; any other value is another block.
+        wanted = {
+            "q_lora_rank": None, "rope_scaling": None, "use_kda_lora": False,
+            "use_bias": False, "use_qkv_bias": False, "use_nGPT": False,
+            "value_norm": False, "up_proj_norm": False,
+            "scale_router_input": False, "use_mla_nope": False,
+            "no_kda_lora": True, "kda_safe_gate": True, "linear_silu": True,
+            "rope_interleave": True, "use_qk_norm": True, "group_norm_size": 1,
+            "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+            "gated_attention_proj_granularity_type": "head_wise",
+            "hidden_act": "silu",
+        }
+        for key, want in wanted.items():
+            if hf.get(key, want) != want:
+                raise ValueError(
+                    f"bailing_hybrid: {key}={hf[key]!r} is not built "
+                    f"(only {want!r} is)"
+                )
+        held = hf.get("experts_held")
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            num_layers=len(kept),
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+            intermediate_size=hf["intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            max_position_embeddings=hf.get("max_position_embeddings", 131072),
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            eos_token_ids=tuple(_as_id_list(hf.get("eos_token_id"))),
+            bos_token_id=hf.get("bos_token_id"),
+            model_type="bailing_hybrid",
+            layer_pattern=tuple(
+                (
+                    "mla" if (i + 1) % group == 0 else "kda",
+                    "dense" if i < dense else "moe",
+                )
+                for i in kept
+            ),
+            short_conv_kernel_size=hf.get("short_conv_kernel_size", 4),
+            kda_lower_bound=float(hf.get("kda_lower_bound", -5.0)),
+            kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=hf["qk_nope_head_dim"],
+            qk_rope_head_dim=hf["qk_rope_head_dim"],
+            v_head_dim=hf["v_head_dim"],
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            moe_intermediate_size=hf["moe_intermediate_size"],
+            shared_expert_intermediate_size=(
+                hf["moe_shared_expert_intermediate_size"]
+                * int(hf.get("num_shared_experts", 1))
+            ),
+            norm_topk_prob=hf.get("norm_topk_prob", True),
+            n_group=hf.get("n_group", 1),
+            topk_group=hf.get("topk_group", 1),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            experts_held=None if held is None else (int(held[0]), int(held[1])),
+        )
+
+    @property
+    def experts_held_(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return self.experts_held or (0, self.num_experts or 0)
 
     @classmethod
     def from_pretrained(cls, model_path: str | Path) -> "ModelConfig":

@@ -1,0 +1,723 @@
+"""Models with a declared layer pattern (``ModelConfig.layer_pattern``):
+delta-rule (KDA) layers with a per-sequence state beside latent (MLA)
+layers over a paged latent cache, dense or routed-expert MLPs, the experts
+held by share (``bailing_hybrid``: Ling-3.0).
+
+Every other family is one uniform stack and stays on
+``models/transformer.py``; nothing here is on its path.
+
+Layout. Consecutive equal layers are one *group*, stacked on a leading
+layer axis under ``params["stack<i>"]`` and run as one ``lax.scan``. Two
+kinds of cache ride through the scans, in the places the uniform model
+has its K and V pools, so that the engine's step programs pass, donate
+and return them as they do those:
+
+- ``k_pages``: the latent pool ``[L_mla, P, page, kv_lora_rank + rope]``,
+  one row a token, addressed through the block table (no V pool);
+- ``v_pages``: the state pool, ``{"S": [L_kda, R, heads, d, d] float32,
+  "conv": [L_kda, R, K-1, 3 * heads * d]}``: one row a sequence, given by
+  ``state_rows`` (the engine: slot + 1). A caller that passes none gets the
+  row of the sequence's first page (``block_tables[:, 0]``). Row 0 is
+  scratch, as page 0 is: padded prefill rows and inactive decode rows
+  write there. A prefill overwrites its row whole, so a row needs no
+  clearing between sequences.
+
+The block (published; what the configuration does not settle is listed
+under ``assumed`` in ``benchmark/configs/ling-3.0-flash-ep4.json``):
+pre-norm residual layers; KDA with SiLU short convolutions, L2-normalised
+q and k, the bounded per-channel gate, a per-head output RMSNorm and a
+sigmoid output gate, no rotary; MLA without a query LoRA, expanded in
+prefill and absorbed in decode, with a head-wise sigmoid gate before
+``o_proj``; a ``noaux_tc`` sigmoid router over all experts (groups, a
+selection bias, ``routed_scaling_factor``) whose chosen experts are
+computed where they are held here (:func:`moe_held`), plus a shared
+expert.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from llmq_tpu.models import quant as qm
+from llmq_tpu.models.config import ModelConfig
+from llmq_tpu.models.transformer import Transformer, _mlp, apply_rope, rms_norm
+from llmq_tpu.ops import attention as attn_ops
+from llmq_tpu.ops import delta_rule
+
+Params = Dict[str, Any]
+F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerGroup:
+    """Consecutive layers of one kind: a stacked subtree and one scan."""
+
+    name: str  # key of its subtree in the params
+    attn: str  # "kda" | "mla"
+    mlp: str  # "dense" | "moe"
+    count: int
+    first: int  # index of its first layer in its attention kind's pool
+
+
+def layer_groups(config: ModelConfig) -> Tuple[LayerGroup, ...]:
+    groups = []
+    seen = {"kda": 0, "mla": 0}
+    for attn, mlp in config.layer_pattern:
+        if attn not in seen or mlp not in ("dense", "moe"):
+            raise ValueError(f"unknown layer kind ({attn!r}, {mlp!r})")
+        last = groups[-1] if groups else None
+        if last is not None and (last.attn, last.mlp) == (attn, mlp):
+            groups[-1] = dataclasses.replace(last, count=last.count + 1)
+        else:
+            groups.append(
+                LayerGroup(f"stack{len(groups)}", attn, mlp, 1, seen[attn])
+            )
+        seen[attn] += 1
+    return tuple(groups)
+
+
+def count_layers(config: ModelConfig, attn: str) -> int:
+    return sum(1 for a, _ in config.layer_pattern if a == attn)
+
+
+def latent_width(config: ModelConfig) -> int:
+    return config.kv_lora_rank + config.qk_rope_head_dim
+
+
+def latent_pool_width(config: ModelConfig) -> int:
+    """A pool row: the latent row in whole lane tiles of 128 (576 -> 640,
+    zeros beyond). A row-major pool takes that room on the chip anyway,
+    and for a width that is not whole tiles the TPU runtime's default
+    layout puts the tokens minor instead: every step then copied the
+    whole pool into row-major order and back (2.8 ms of a 28.7 ms decode
+    step at 2,305 pages, my chip run, PR 33)."""
+    return -(-latent_width(config) // 128) * 128
+
+
+def group_shapes(config: ModelConfig, group: LayerGroup) -> Dict[str, tuple]:
+    """Leaf shapes of one group's subtree (leading axis: its layers)."""
+    cfg = config
+    H, n, d, L = cfg.hidden_size, cfg.num_heads, cfg.head_dim_, group.count
+    D = n * d
+    shapes: Dict[str, tuple] = {"ln1": (L, H), "ln2": (L, H)}
+    if group.attn == "kda":
+        shapes.update(
+            kda_q_proj=(L, H, D), kda_k_proj=(L, H, D), kda_v_proj=(L, H, D),
+            kda_conv=(L, cfg.short_conv_kernel_size, 3 * D),
+            kda_f_proj=(L, H, D), kda_a_log=(L, n), kda_dt_bias=(L, D),
+            kda_b_proj=(L, H, n), kda_g_proj=(L, H, D), kda_o_norm=(L, d),
+            o_proj=(L, D, H),
+        )
+    else:
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        shapes.update(
+            mla_q_proj=(L, H, n * qk),
+            mla_kva_proj=(L, H, latent_width(cfg)),
+            mla_kv_norm=(L, cfg.kv_lora_rank),
+            mla_kvb_proj=(L, cfg.kv_lora_rank, n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            mla_g_proj=(L, H, n),
+            o_proj=(L, n * cfg.v_head_dim, H),
+        )
+    if group.mlp == "dense":
+        I = cfg.intermediate_size
+        shapes.update(gate_proj=(L, H, I), up_proj=(L, H, I), down_proj=(L, I, H))
+    else:
+        E, Im = cfg.num_experts, cfg.moe_intermediate_size
+        held, Is = cfg.experts_held_[1], cfg.shared_expert_intermediate_size
+        shapes.update(
+            router=(L, H, E), router_bias=(L, E),
+            expert_gate_proj=(L, held, H, Im), expert_up_proj=(L, held, H, Im),
+            expert_down_proj=(L, held, Im, H),
+            shared_gate_proj=(L, H, Is), shared_up_proj=(L, H, Is),
+            shared_down_proj=(L, Is, H),
+        )
+    return shapes
+
+
+def param_shapes(config: ModelConfig) -> Dict[str, Any]:
+    """Leaf shapes of the whole tree: top-level leaves, and one subtree a
+    group."""
+    H, V = config.hidden_size, config.vocab_size
+    shapes: Dict[str, Any] = {"embed": (V, H), "final_norm": (H,)}
+    if not config.tie_word_embeddings:
+        shapes["lm_head"] = (H, V)
+    for group in layer_groups(config):
+        shapes[group.name] = group_shapes(config, group)
+    return shapes
+
+
+_ONES = ("ln1", "ln2", "final_norm", "kda_o_norm", "mla_kv_norm")
+_ZEROS = ("router_bias", "kda_a_log", "kda_dt_bias")
+
+
+def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
+    """Random init (tests, ``preset://``): norms 1, biases 0, matrices
+    normal over sqrt(fan-in), the fan-in the axis before the last."""
+    shapes = param_shapes(config)
+    flat, treedef = jax.tree.flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple)
+    )
+    leaves = []
+    for k, (path, shape) in zip(jax.random.split(key, len(flat)), flat):
+        name = path[-1].key
+        if name in _ONES:
+            leaves.append(jnp.ones(shape, dtype))
+        elif name in _ZEROS:
+            leaves.append(jnp.zeros(shape, dtype))
+        else:
+            fan_in = shape[-1] if name == "embed" else shape[-2]
+            leaves.append(
+                (jax.random.normal(k, shape, F32) / math.sqrt(fan_in)).astype(dtype)
+            )
+    return jax.tree.unflatten(treedef, leaves)
+
+
+def make_state_pools(
+    config: ModelConfig,
+    num_pages: int,
+    page_size: int,
+    dtype=jnp.bfloat16,
+    *,
+    placement: Any = None,
+    state_rows: Optional[int] = None,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """(latent pool, state pool) in the places of the K and V pools. A
+    caller that gives no ``state_rows`` gets one state row a page, so
+    that a sequence's first page can name its row."""
+    n, d = config.num_heads, config.head_dim_
+    R = num_pages if state_rows is None else state_rows
+    L_kda = count_layers(config, "kda")
+    shapes = (
+        ((count_layers(config, "mla"), num_pages, page_size, latent_pool_width(config)), dtype),
+        ((L_kda, R, n, d, d), F32),
+        ((L_kda, R, config.short_conv_kernel_size - 1, 3 * n * d), dtype),
+    )
+
+    def alloc():
+        latent, S, conv = (jnp.zeros(shape, dt) for shape, dt in shapes)
+        return latent, {"S": S, "conv": conv}
+
+    if placement is None:
+        return alloc()
+    return jax.jit(alloc, out_shardings=placement)()
+
+
+def latent_page_bytes_per_device(
+    config: ModelConfig, page_size: int, dtype, placement
+) -> int:
+    """HBM one latent page (all MLA layers) takes as the compiler lays the
+    pool out: a row of 576 values is padded to whole lane tiles."""
+    probe = 8
+    shape = (count_layers(config, "mla"), probe, page_size, latent_pool_width(config))
+    alloc = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=placement)
+    return alloc.lower().compile().memory_analysis().output_size_in_bytes // probe
+
+
+def state_pool_bytes(config: ModelConfig, rows: int, dtype) -> int:
+    """Bytes of ``rows`` sequences' KDA state and convolution tails."""
+    n, d = config.num_heads, config.head_dim_
+    per_layer = n * d * d * 4 + (
+        (config.short_conv_kernel_size - 1) * 3 * n * d * jnp.dtype(dtype).itemsize
+    )
+    return rows * count_layers(config, "kda") * per_layer
+
+
+# ---------------------------------------------------------------------------
+# Experts held by share
+# ---------------------------------------------------------------------------
+
+
+#: Rows (tokens) up to which every held expert is computed on every row.
+#: Measured on a v5e at the published widths (PERF.md, PR 33): at 128 rows
+#: the dense form streams the experts' weights at two thirds of the HBM
+#: rate (2.7 ms a layer), the grouped form takes 5 ms a layer; beyond a
+#: few hundred rows the dense form's arithmetic (rows x experts) loses.
+DENSE_EXPERT_ROWS = 256
+
+#: Rows an expert layer takes at a time (see :func:`moe_held`).
+MOE_BLOCK_ROWS = 4096
+
+#: Tokens of a padded prefill batch above which a KDA layer takes its
+#: rows one at a time (see ``HybridTransformer._kda_prefill``).
+KDA_PREFILL_TOKENS = 8192
+
+
+def moe_held(
+    h: jnp.ndarray, lp: Params, config: ModelConfig
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed-expert MLP as this chip computes it: the router scores
+    all ``num_experts``, and of the ``num_experts_per_tok`` chosen a token
+    only those held here (``experts_held``) are computed, each weighted
+    by its share of ALL the chosen scores; plus the shared expert. What
+    experts elsewhere would add is left out: no code stands in for them.
+
+    Two forms of the same sum, chosen by the number of rows: up to
+    ``DENSE_EXPERT_ROWS`` every held expert on every row, weighted by a
+    ``[rows, held]`` matrix that is zero where an expert was not chosen
+    (a decode step: the weights stream once, nothing is sorted); above,
+    the assignments that land here sorted by expert and
+    ``lax.ragged_dot`` over the held experts (a prefill).
+
+    Returns the output and ``[assignments held, experts hit]`` (int32),
+    the counters a decode step reports. Above twice ``MOE_BLOCK_ROWS`` rows
+    the layer runs a block of rows at a time (``lax.map``), so that what
+    is in flight (``rows x 8`` assignments of float32 hidden rows) stays
+    one block's whatever the bucket: a 4 x 8,192 prefill would hold 2 x
+    2.5 GB otherwise (compiled for a v5e, PR 33); the counters are then
+    sums over blocks."""
+    *lead, H = h.shape
+    x = h.reshape(-1, H)
+    if x.shape[0] > 2 * MOE_BLOCK_ROWS and x.shape[0] % MOE_BLOCK_ROWS == 0:
+        out, counts = jax.lax.map(
+            lambda rows: _moe_rows(rows, lp, config),
+            x.reshape(-1, MOE_BLOCK_ROWS, H),
+        )
+        counts = counts.sum(axis=0)
+    else:
+        out, counts = _moe_rows(x, lp, config)
+    return out.reshape(*lead, H), counts
+
+
+def _moe_rows(x: jnp.ndarray, lp: Params, config: ModelConfig):
+    """:func:`moe_held` for rows ``[N, H]``."""
+    cfg = config
+    N, E, k = x.shape[0], cfg.num_experts, cfg.num_experts_per_tok
+    first, held = cfg.experts_held_
+    with jax.named_scope("llmq.moe.router"):
+        scores = jax.nn.sigmoid(
+            jnp.dot(x, lp["router"], preferred_element_type=F32)
+        )  # [N, E]
+        choice = scores + lp["router_bias"].astype(F32)  # selection only
+        G = cfg.n_group
+        grouped = choice.reshape(N, G, E // G)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)  # [N, G]
+        kept_groups = jax.lax.top_k(group_score, cfg.topk_group)[1]
+        keep = jnp.zeros((N, G), bool).at[
+            jnp.arange(N)[:, None], kept_groups
+        ].set(True)
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(N, E)
+        top_e = jax.lax.top_k(choice, k)[1]  # [N, k]
+        top_w = jnp.take_along_axis(scores, top_e, axis=1)
+        if cfg.norm_topk_prob:
+            top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w * cfg.routed_scaling_factor
+        local = top_e - first
+        here = (local >= 0) & (local < held)  # [N, k]
+    with jax.named_scope("llmq.moe.experts"):
+        if N <= DENSE_EXPERT_ROWS:
+            out, hit = _experts_dense(x, lp, local, here, top_w, held)
+        else:
+            out, hit = _experts_grouped(x, lp, local, here, top_w, held)
+    with jax.named_scope("llmq.moe.shared"):
+        act = jax.nn.silu(qm.matmul(x, lp["shared_gate_proj"])) * qm.matmul(
+            x, lp["shared_up_proj"]
+        )
+        out = out + qm.matmul(act, lp["shared_down_proj"]).astype(F32)
+    counts = jnp.stack([here.sum(dtype=jnp.int32), hit])
+    return out.astype(x.dtype), counts
+
+
+def _experts_dense(x, lp, local, here, top_w, held):
+    """Every held expert on every row; a row's weight for an expert it
+    did not choose is zero. ``[N, H]`` float32, and the experts hit."""
+    N = x.shape[0]
+    weight = jnp.zeros((N, held + 1), F32).at[
+        jnp.arange(N)[:, None], jnp.where(here, local, held)
+    ].add(jnp.where(here, top_w, 0.0))[:, :held]
+    gate = jnp.einsum("nh,ehi->eni", x, lp["expert_gate_proj"])
+    up = jnp.einsum("nh,ehi->eni", x, lp["expert_up_proj"])
+    act = (jax.nn.silu(gate) * up).astype(F32) * weight.T[:, :, None]
+    out = jnp.einsum(
+        "eni,eih->nh", act.astype(x.dtype), lp["expert_down_proj"],
+        preferred_element_type=F32,
+    )
+    return out, (weight > 0).any(axis=0).sum(dtype=jnp.int32)
+
+
+def _experts_grouped(x, lp, local, here, top_w, held):
+    """Only the assignments that land here, sorted by expert, one
+    ``lax.ragged_dot`` group an expert. (``ragged_dot`` takes its matrices
+    as a whole buffer, so inside a layer scan each layer's three are
+    copied out of their stack first: 3 x 250 MB a layer at the published
+    widths, a third of a 512-token prefill. Handing it the whole stack
+    with empty groups for the other layers avoids the copy and runs
+    alone, but a 4 x 1,024 prefill step built so never came back from the
+    chip: PERF.md, PR 33.) The lint's repartition pins do not apply: the
+    engine refuses tp > 1 for a layer pattern, so nothing here is split."""
+    N, k = local.shape
+    slot = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(slot)  # stable; what is held elsewhere sorts last  # llmq: ignore[unconstrained-repartition]
+    token_of = order // k
+    group_sizes = jnp.bincount(slot, length=held + 1)[:held].astype(jnp.int32)  # llmq: ignore[unconstrained-repartition]
+    xs = x[token_of]  # [N*k, H]
+    gate = jax.lax.ragged_dot(xs, lp["expert_gate_proj"], group_sizes)  # llmq: ignore[unconstrained-repartition]
+    up = jax.lax.ragged_dot(xs, lp["expert_up_proj"], group_sizes)  # llmq: ignore[unconstrained-repartition]
+    down = jax.lax.ragged_dot(  # llmq: ignore[unconstrained-repartition]
+        jax.nn.silu(gate) * up, lp["expert_down_proj"], group_sizes
+    )
+    here_sorted = here.reshape(-1)[order]
+    w_sorted = jnp.where(here_sorted, top_w.reshape(-1)[order], 0.0)
+    down = jnp.where(here_sorted[:, None], down.astype(F32), 0.0)
+    out = jax.ops.segment_sum(down * w_sorted[:, None], token_of, num_segments=N)  # llmq: ignore[unconstrained-repartition]
+    return out, (group_sizes > 0).sum(dtype=jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# The model
+# ---------------------------------------------------------------------------
+
+
+def _rope_interleaved(x, positions, inv_freq):
+    """``rope_interleave``: the pairs to rotate are (0, 1), (2, 3), ...:
+    bring them to the halves :func:`apply_rope` rotates."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, inv_freq)
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridTransformer(Transformer):
+    """``prefill`` and ``decode`` of a model with a layer pattern, with the
+    signatures of :class:`Transformer`'s plus ``state_rows``. The paths
+    that need a cache which can be cut or rewound by a length (chunked
+    prefill, verify, the mixed step) are not built for a per-sequence
+    state, and the engine refuses their options at build."""
+
+    def __post_init__(self):
+        if self.config.layer_pattern is None:
+            raise ValueError("HybridTransformer needs a layer_pattern")
+        if self.stage is not None:
+            raise ValueError("a layer pattern is not split into pipeline stages")
+
+    # --- attention kinds ----------------------------------------------------
+    def _kda_inputs(self, lp: Params, x: jnp.ndarray):
+        """Projections of the normed input shared by prefill and decode:
+        pre-convolution q|k|v, decay ``alpha``, write strength ``beta``
+        and the output gate, all but the first in float32."""
+        cfg = self.config
+        n, d = cfg.num_heads, cfg.head_dim_
+        *lead, _ = x.shape
+        u = jnp.concatenate(
+            [qm.matmul(x, lp[f"kda_{name}_proj"]) for name in "qkv"], axis=-1
+        )
+        f = qm.matmul(x, lp["kda_f_proj"]).astype(F32) + lp["kda_dt_bias"].astype(F32)
+        rate = jnp.exp(lp["kda_a_log"].astype(F32))[:, None]
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * f.reshape(*lead, n, d))
+        beta = jax.nn.sigmoid(qm.matmul(x, lp["kda_b_proj"]).astype(F32))
+        out_gate = jax.nn.sigmoid(
+            qm.matmul(x, lp["kda_g_proj"]).astype(F32)
+        ).reshape(*lead, n, d)
+        return u, jnp.exp(g), beta, out_gate
+
+    def _kda_qkv(self, conv_out: jnp.ndarray):
+        """SiLU of the convolution, split into heads; q and k
+        L2-normalised, q scaled by d^-1/2. Float32."""
+        cfg = self.config
+        n, d = cfg.num_heads, cfg.head_dim_
+        *lead, _ = conv_out.shape
+        q, k, v = (
+            part.reshape(*lead, n, d)
+            for part in jnp.split(jax.nn.silu(conv_out), 3, axis=-1)
+        )
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        return q * (d**-0.5), k, v
+
+    def _kda_out(self, lp: Params, o: jnp.ndarray, out_gate: jnp.ndarray, dtype):
+        cfg = self.config
+        *lead, n, d = o.shape
+        o = rms_norm(o, lp["kda_o_norm"], cfg.rms_norm_eps) * out_gate
+        with jax.named_scope("llmq.o_proj"):
+            return qm.matmul(o.astype(dtype).reshape(*lead, n * d), lp["o_proj"])
+
+    @jax.named_scope("llmq.attn.kda")
+    def _kda_prefill(self, lp, x, lengths, state, rows, li):
+        def run(x, lengths):
+            u, alpha, beta, out_gate = self._kda_inputs(lp, x)
+            with jax.named_scope("llmq.attn.kda.conv"):
+                conv_out, tail = delta_rule.causal_conv(u, lp["kda_conv"], lengths)
+            q, k, v = self._kda_qkv(conv_out)
+            valid = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
+            S, o = delta_rule.kda_scan(q, k, v, alpha, beta, valid)
+            return self._kda_out(lp, o, out_gate, x.dtype), S, tail
+
+        B, T, _ = x.shape
+        if B > 1 and B * T > KDA_PREFILL_TOKENS:
+            # A row at a time: the float32 projections in flight are one
+            # row's (a 4 x 8,192 bucket holds 4.5 GB of them otherwise:
+            # compiled for a v5e, PR 33). The scan's steps are as many.
+            y, S, tail = (
+                out[:, 0] for out in jax.lax.map(
+                    lambda row: run(row[0][None], row[1][None]), (x, lengths)
+                )
+            )
+        else:
+            y, S, tail = run(x, lengths)
+        state = {
+            "S": state["S"].at[li, rows].set(S),
+            "conv": state["conv"].at[li, rows].set(tail.astype(state["conv"].dtype)),
+        }
+        return y, state
+
+    @jax.named_scope("llmq.attn.kda")
+    def _kda_decode(self, lp, x, state, rows, li, active):
+        """``rows``: an array of state rows (gathered, updated, scattered;
+        an inactive slot's is 0), or an int, the first of one contiguous
+        run of rows, a slot each: then the run is read and written in
+        place, an inactive slot's row as it was (measured on a v5e, 128
+        rows, 6 layers: 7.7 ms against 20.8 through the gather)."""
+        u, alpha, beta, out_gate = self._kda_inputs(lp, x)
+        if isinstance(rows, int):
+            n = x.shape[0]
+
+            def read(pool):
+                return jax.lax.dynamic_slice(
+                    pool, (li, rows) + (0,) * (pool.ndim - 2), (1, n) + pool.shape[2:]
+                )[0]
+
+            def write(pool, new, old):
+                keep = active.reshape((n,) + (1,) * (new.ndim - 1))
+                return jax.lax.dynamic_update_slice(
+                    pool, jnp.where(keep, new, old)[None],
+                    (li, rows) + (0,) * (pool.ndim - 2),
+                )
+        else:
+            def read(pool):
+                return pool[li, rows]
+
+            def write(pool, new, old):
+                return pool.at[li, rows].set(new)
+
+        old_tail, old_S = read(state["conv"]), read(state["S"])
+        with jax.named_scope("llmq.attn.kda.conv"):
+            conv_out, tail = delta_rule.conv_step(old_tail, u, lp["kda_conv"])
+        q, k, v = self._kda_qkv(conv_out)
+        S, o = delta_rule.kda_step(old_S, q, k, v, alpha, beta)
+        state = {
+            "S": write(state["S"], S, old_S),
+            "conv": write(state["conv"], tail, old_tail),
+        }
+        return self._kda_out(lp, o, out_gate, x.dtype), state
+
+    def _mla_inputs(self, lp: Params, x: jnp.ndarray, positions: jnp.ndarray):
+        """q split into its content and rotary parts, and the latent row
+        ``[RMSNorm(c) ; RoPE(r)]`` that the cache holds. ``x`` is
+        ``[B, T, H]``, ``positions`` ``[B, T]``."""
+        cfg = self.config
+        n, rope = cfg.num_heads, cfg.qk_rope_head_dim
+        B, T, _ = x.shape
+        inv_freq = 1.0 / (
+            cfg.rope_theta ** (jnp.arange(0, rope, 2, dtype=F32) / rope)
+        )
+        q = qm.matmul(x, lp["mla_q_proj"]).reshape(B, T, n, -1)
+        q_c, q_r = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
+        q_r = _rope_interleaved(q_r, positions, inv_freq)
+        kva = qm.matmul(x, lp["mla_kva_proj"])
+        c = rms_norm(kva[..., : cfg.kv_lora_rank], lp["mla_kv_norm"], cfg.rms_norm_eps)
+        r = _rope_interleaved(
+            kva[..., cfg.kv_lora_rank :][:, :, None, :], positions, inv_freq
+        )[:, :, 0]
+        return q_c, q_r, jnp.concatenate([c, r], axis=-1)
+
+    def _mla_out(self, lp: Params, x: jnp.ndarray, o: jnp.ndarray):
+        """Head-wise sigmoid gate, then ``o_proj``. ``o``: [..., n, d_v]."""
+        gate = jax.nn.sigmoid(qm.matmul(x, lp["mla_g_proj"]).astype(F32))
+        o = (o.astype(F32) * gate[..., None]).astype(x.dtype)
+        *lead, n, dv = o.shape
+        with jax.named_scope("llmq.o_proj"):
+            return qm.matmul(o.reshape(*lead, n * dv), lp["o_proj"])
+
+    def _mla_scale(self) -> float:
+        cfg = self.config
+        return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+    @jax.named_scope("llmq.attn.mla_prefill")
+    def _mla_prefill(self, lp, x, positions, lengths, latent, block_tables, li):
+        """Expanded: keys and values raised from the latent, every head
+        its own."""
+        cfg = self.config
+        n = cfg.num_heads
+        B, T, _ = x.shape
+        q_c, q_r, row = self._mla_inputs(lp, x, positions)
+        with jax.named_scope("llmq.kv_write"):
+            latent = attn_ops.write_latent_pages(
+                latent, row, block_tables, positions, li
+            )
+        c, r = row[..., : cfg.kv_lora_rank], row[..., cfg.kv_lora_rank :]
+        kv = qm.matmul(c, lp["mla_kvb_proj"]).reshape(B, T, n, -1)
+        k_c, v = kv[..., : cfg.qk_nope_head_dim], kv[..., cfg.qk_nope_head_dim :]
+        k = jnp.concatenate(
+            [k_c, jnp.broadcast_to(r[:, :, None, :], (B, T, n, r.shape[-1]))],
+            axis=-1,
+        )
+        o = attn_ops.blocked_prefill_attention(
+            jnp.concatenate([q_c, q_r], axis=-1), k, v,
+            scale=self._mla_scale(), lengths=lengths,
+        )
+        return self._mla_out(lp, x, o), latent
+
+    @jax.named_scope("llmq.attn.mla_decode")
+    def _mla_decode(self, lp, x, positions, latent, block_tables, ctx_incl, li):
+        """Absorbed: the query goes to the latent (``W_uk^T q``), attention
+        runs over the cached rows themselves, and ``W_uv`` raises the
+        result. ``x``: [S, H]."""
+        cfg = self.config
+        n, rank = cfg.num_heads, cfg.kv_lora_rank
+        q_c, q_r, row = self._mla_inputs(lp, x[:, None, :], positions[:, None])
+        with jax.named_scope("llmq.kv_write"):
+            latent = attn_ops.write_latent_pages(
+                latent, row, block_tables, positions[:, None], li
+            )
+        w_kvb = lp["mla_kvb_proj"].reshape(rank, n, -1)
+        w_uk, w_uv = w_kvb[..., : cfg.qk_nope_head_dim], w_kvb[..., cfg.qk_nope_head_dim :]
+        q_lat = jnp.einsum("shd,chd->shc", q_c[:, 0], w_uk)
+        o_lat = attn_ops.latent_paged_decode_attention(
+            jnp.concatenate([q_lat, q_r[:, 0]], axis=-1),
+            latent, block_tables, ctx_incl,
+            scale=self._mla_scale(), rank=rank, layer=li,
+        )
+        o = jnp.einsum("shc,chd->shd", o_lat, w_uv)
+        return self._mla_out(lp, x, o), latent
+
+    # --- the layer scans ----------------------------------------------------
+    def _run_groups(self, params, h, latent, state, attend):
+        """Every group of equal layers as one scan. ``attend(group, lp, x,
+        latent, state, li)`` returns the attention output and the two
+        caches."""
+        cfg = self.config
+        moe = jnp.zeros((2,), jnp.int32)
+        for group in layer_groups(cfg):
+
+            def layer_fn(carry, xs, group=group):
+                h, latent, state, moe = carry
+                lp, li = xs
+                x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
+                a, latent, state = attend(group, lp, x, latent, state, li)
+                h = h + a
+                x = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+                if group.mlp == "dense":
+                    m = _mlp(x, lp, cfg.activation)
+                else:
+                    with jax.named_scope("llmq.moe"):
+                        m, counts = moe_held(x, lp, cfg)
+                    moe = moe + counts
+                return (h + m, latent, state, moe), None
+
+            carry = (h, latent, state, moe)
+            if group.count == 1:
+                # No scan of one: its slice of a stack of one layer is
+                # compiled as a copy of every weight (measured: 3 ms of a
+                # 29 ms decode step), the index 0 as a view.
+                only = jax.tree.map(lambda w: w[0], params[group.name])
+                carry, _ = layer_fn(carry, (only, jnp.int32(group.first)))
+            else:
+                carry, _ = jax.lax.scan(
+                    layer_fn,
+                    carry,
+                    (
+                        params[group.name],
+                        group.first + jnp.arange(group.count, dtype=jnp.int32),
+                    ),
+                )
+            h, latent, state, moe = carry
+        return h, latent, state, moe
+
+    def prefill(
+        self,
+        params: Params,
+        tokens: jnp.ndarray,  # [B, T] right-padded prompt bucket
+        lengths: jnp.ndarray,  # [B] true lengths (0: a padded row)
+        k_pages: jnp.ndarray,  # the latent pool
+        v_pages: Dict[str, jnp.ndarray],  # the state pool
+        block_tables: jnp.ndarray,  # [B, pages_per_seq]
+        state_rows: Optional[jnp.ndarray] = None,  # [B]
+        **unsupported,
+    ):
+        """Full-prompt forward: (last-token logits [B, V], latent pool,
+        state pool), the prompt's latent rows written to its pages and
+        its final state and convolution tails to its state row."""
+        _refuse(unsupported)
+        B, T = tokens.shape
+        pos_grid = jnp.arange(T, dtype=jnp.int32)[None, :]
+        positions = jnp.where(
+            pos_grid < lengths[:, None], jnp.broadcast_to(pos_grid, (B, T)), -1
+        )
+        rows = block_tables[:, 0] if state_rows is None else state_rows
+        rows = jnp.where(lengths > 0, rows, 0)
+
+        def attend(group, lp, x, latent, state, li):
+            if group.attn == "kda":
+                a, state = self._kda_prefill(lp, x, lengths, state, rows, li)
+            else:
+                a, latent = self._mla_prefill(
+                    lp, x, positions, lengths, latent, block_tables, li
+                )
+            return a, latent, state
+
+        h, k_pages, v_pages, _ = self._run_groups(
+            params, self._embed(params, tokens), k_pages, v_pages, attend
+        )
+        last = jnp.maximum(lengths - 1, 0)
+        last_h = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+        return self._logits(params, last_h), k_pages, v_pages
+
+    def decode(
+        self,
+        params: Params,
+        tokens: jnp.ndarray,  # [S]
+        context_lens: jnp.ndarray,  # [S] tokens already cached
+        k_pages: jnp.ndarray,
+        v_pages: Dict[str, jnp.ndarray],
+        block_tables: jnp.ndarray,  # [S, pages_per_seq]
+        active: jnp.ndarray,  # [S] bool
+        state_rows: Any = None,  # [S], or an int: rows first .. first + S - 1
+        *,
+        counters: bool = False,
+        **unsupported,
+    ):
+        """One decode step for every active slot: (logits [S, V], latent
+        pool, state pool). An inactive slot writes the scratch page and
+        the scratch state row. With ``counters`` a fourth value: [expert
+        assignments held here, held experts hit], summed over layers."""
+        _refuse(unsupported)
+        positions = jnp.where(active, context_lens, -1).astype(jnp.int32)
+        ctx_incl = jnp.where(active, context_lens + 1, 0)
+        rows = block_tables[:, 0] if state_rows is None else state_rows
+        if not isinstance(rows, int):
+            rows = jnp.where(active, rows, 0)
+
+        def attend(group, lp, x, latent, state, li):
+            if group.attn == "kda":
+                a, state = self._kda_decode(lp, x, state, rows, li, active)
+            else:
+                a, latent = self._mla_decode(
+                    lp, x, positions, latent, block_tables, ctx_incl, li
+                )
+            return a, latent, state
+
+        h, k_pages, v_pages, moe = self._run_groups(
+            params, self._embed(params, tokens), k_pages, v_pages, attend
+        )
+        logits = self._logits(params, h)
+        if counters:
+            return logits, k_pages, v_pages, moe
+        return logits, k_pages, v_pages
+
+    def prefill_chunk(self, *args, **kwargs):
+        raise NotImplementedError(
+            "chunked prefill is not built for a model with a per-sequence state"
+        )
+
+    mixed = verify = prefill_chunk
+
+
+def _refuse(unsupported: Dict[str, Any]) -> None:
+    given = {k: v for k, v in unsupported.items() if v is not None and v is not False}
+    if given:
+        raise NotImplementedError(
+            f"a model with a layer pattern takes no {sorted(given)}"
+        )

@@ -91,6 +91,41 @@ PRESETS = {
     ),
 }
 
+# Ling-3.0-flash (inclusionAI, ``bailing_hybrid``), the published config's
+# keys that shape the model: KDA layers with every sixth an MLA layer, two
+# dense MLPs, then 512 sigmoid-routed experts of which 8 a token, one
+# shared expert.
+_LING_3_FLASH = dict(
+    model_type="bailing_hybrid", vocab_size=157184, hidden_size=2560,
+    num_hidden_layers=42, num_attention_heads=32, num_key_value_heads=32,
+    head_dim=128, intermediate_size=6144, max_position_embeddings=262144,
+    rope_theta=6000000, rope_interleave=True, rms_norm_eps=1e-06,
+    tie_word_embeddings=False, layer_group_size=6, first_k_dense_replace=2,
+    short_conv_kernel_size=4, kda_lower_bound=-5, kda_safe_gate=True,
+    no_kda_lora=True, linear_silu=True, use_qk_norm=True, group_norm_size=1,
+    q_lora_rank=None, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128,
+    gated_attention_proj_granularity_type="head_wise",
+    num_experts=512, num_experts_per_tok=8, moe_intermediate_size=768,
+    num_shared_experts=1, moe_shared_expert_intermediate_size=768,
+    scoring_func="sigmoid", topk_method="noaux_tc", n_group=8, topk_group=4,
+    norm_topk_prob=True, routed_scaling_factor=2.5,
+    moe_router_enable_expert_bias=True,
+    expert_swiglu_limit_list=[0] * 35 + [4] * 7,
+    share_expert_swiglu_limit_list=[0] * 34 + [5] * 6 + [7] * 2,
+)
+# One chip's share of four that share each layer: published layer 0 (the
+# two leading dense layers count once) and layers 6-11 (one whole period:
+# five KDA, one MLA, all with experts); experts 0-127 of 512 (two whole
+# groups; the router keeps its 512 outputs and 8 a token); rows 0-39,295 of
+# the vocabulary. Every width is the published one.
+PRESETS["ling-3.0-flash-ep4"] = ModelConfig.from_hf_config(
+    dict(
+        _LING_3_FLASH, kept_layers=[0, 6, 7, 8, 9, 10, 11],
+        experts_held=[0, 128], vocab_size=157184 // 4,
+    )
+)
+
 
 def get_preset(name: str) -> ModelConfig:
     try:

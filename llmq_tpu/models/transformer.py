@@ -834,6 +834,16 @@ class Transformer:
         return self._logits(params, h), k_pages, v_pages
 
 
+def build_model(config: ModelConfig, **kwargs) -> Transformer:
+    """The model of a configuration: the uniform stack above, or, for a
+    declared layer pattern, ``models/hybrid.HybridTransformer``."""
+    if config.layer_pattern is None:
+        return Transformer(config, **kwargs)
+    from llmq_tpu.models import hybrid
+
+    return hybrid.HybridTransformer(config, **kwargs)
+
+
 # ---------------------------------------------------------------------------
 # Initialization
 # ---------------------------------------------------------------------------
@@ -860,6 +870,14 @@ def init_params(
     quantize would OOM on the bf16 tree alone. ``quantize="int4"`` puts
     the layer matmul weights on the packed int4 group rung instead
     (embed/lm_head stay int8, mirroring the checkpoint loader)."""
+    if config.layer_pattern is not None:
+        if quantize:
+            raise ValueError(
+                "quantised weights are not built for a model with a layer pattern"
+            )
+        from llmq_tpu.models import hybrid
+
+        return hybrid.init_params(config, key, dtype)
     cfg = config
     quant_mode = (
         "int4" if str(quantize).lower() == "int4"
@@ -961,14 +979,26 @@ def make_kv_pages(
     *,
     num_layers: Optional[int] = None,
     placement: Any = None,
+    state_rows: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Allocate the paged KV cache: [L, P, page, n_kv, d] ×2.
+
+    A model with a layer pattern gets, in the same two places, its latent
+    pool and its per-sequence state pool of ``state_rows`` rows
+    (``models/hybrid.make_state_pools``; none given: one row a page).
 
     ``num_layers`` overrides the leading depth for per-stage pools under
     pipeline parallelism (each stage caches only its own layers).
     ``placement`` (a sharding or layout ``Format``) creates the pools
     already placed: a tp-sharded pool is sized per device and, whole,
     would not fit the one device an unplaced ``zeros`` lands on."""
+    if config.layer_pattern is not None:
+        from llmq_tpu.models import hybrid
+
+        return hybrid.make_state_pools(
+            config, num_pages, page_size, dtype,
+            placement=placement, state_rows=state_rows,
+        )
     shape = (
         config.num_layers if num_layers is None else num_layers,
         num_pages,

@@ -353,3 +353,134 @@ def write_kv_pages(
         k_pages = k_pages.at[physical_page, offset].set(k_flat, mode="drop")
         v_pages = v_pages.at[physical_page, offset].set(v_flat, mode="drop")
     return k_pages, v_pages
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) attention: one pool of [c ; r] rows, no V pool
+# ---------------------------------------------------------------------------
+
+
+def write_latent_pages(
+    pages: jnp.ndarray,  # [L, P, page_size, Wp], Wp >= W
+    rows: jnp.ndarray,  # [B, T, W] one latent row a token
+    block_tables: jnp.ndarray,  # [B, pages_per_seq]
+    positions: jnp.ndarray,  # [B, T] absolute positions (−1 = skip)
+    layer: jnp.ndarray,
+) -> jnp.ndarray:
+    """Scatter latent rows into their pages; padded or inactive entries
+    (position −1) go to the scratch page 0, as in :func:`write_kv_pages`.
+    A pool wider than the rows (whole lane tiles) keeps zeros beyond."""
+    B, T, W = rows.shape
+    page_size, Wp = pages.shape[2], pages.shape[3]
+    pos = positions.reshape(B * T)
+    valid = pos >= 0
+    logical = jnp.where(valid, pos // page_size, 0)
+    offset = jnp.where(valid, pos % page_size, 0)
+    physical = block_tables[jnp.repeat(jnp.arange(B), T), logical]
+    physical = jnp.where(valid, physical, 0)
+    flat = jnp.pad(rows.reshape(B * T, W), ((0, 0), (0, Wp - W))).astype(pages.dtype)
+    return pages.at[layer, physical, offset].set(flat, mode="drop")
+
+
+#: Pages of a sequence that one pass of the latent decode attention folds
+#: into its running softmax (4 x 128 tokens).
+LATENT_DECODE_CHUNK_PAGES = 4
+
+
+def latent_paged_decode_attention(
+    q: jnp.ndarray,  # [S, n_heads, W] absorbed query [W_uk^T q^C ; q^R]
+    pages: jnp.ndarray,  # [L, P, page_size, Wp], Wp >= W
+    block_tables: jnp.ndarray,  # [S, pages_per_seq]
+    context_lens: jnp.ndarray,  # [S] INCLUDING the new token
+    *,
+    scale: float,
+    rank: int,  # the first ``rank`` values of a row are the latent c
+    layer: jnp.ndarray,
+) -> jnp.ndarray:
+    """Absorbed decode attention over the latent pool: every head scores
+    the same cached row, and the value is the row's latent part. Returns
+    ``[S, n_heads, rank]`` (``W_uv`` is applied by the caller).
+
+    A loop over chunks of ``LATENT_DECODE_CHUNK_PAGES`` pages with a
+    running softmax, as many passes as the longest live context needs: a
+    step gathers what is live, not ``pages_per_seq`` pages a row, so its
+    cost does not grow with ``max_model_len``."""
+    S, n, W = q.shape
+    page, Wp = pages.shape[2], pages.shape[3]
+    pps = block_tables.shape[1]
+    C = min(LATENT_DECODE_CHUNK_PAGES, pps)
+    passes = -(-pps // C)
+    bt = jnp.pad(block_tables, ((0, 0), (0, passes * C - pps)))  # page 0: scratch
+    mul = _compute_dtype(q.dtype, pages.dtype)
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, Wp - W))).astype(mul)
+    span = C * page
+    live = jnp.clip(-(-jnp.max(context_lens) // span), 1, passes)
+
+    def one_pass(j, carry):
+        m, l, acc = carry
+        cols = jax.lax.dynamic_slice_in_dim(bt, j * C, C, axis=1)
+        lat = pages[layer, cols].reshape(S, span, Wp).astype(mul)
+        scores = jnp.einsum(
+            "shw,skw->shk", qp, lat, preferred_element_type=jnp.float32
+        ) * scale
+        k_pos = j * span + jnp.arange(span)
+        mask = k_pos[None, :] < context_lens[:, None]
+        scores = jnp.where(mask[:, None, :], scores, NEG_INF)
+        m_new = jnp.maximum(m, scores.max(axis=-1))
+        p = jnp.exp(scores - m_new[..., None])
+        shrink = jnp.exp(m - m_new)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "shk,skc->shc", p.astype(mul), lat[:, :, :rank],
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l * shrink + p.sum(axis=-1), acc
+
+    init = (
+        jnp.full((S, n), NEG_INF, jnp.float32),
+        jnp.zeros((S, n), jnp.float32),
+        jnp.zeros((S, n, rank), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, live, one_pass, init)
+    return (acc / l[..., None]).astype(q.dtype)
+
+
+def blocked_prefill_attention(
+    q: jnp.ndarray,  # [B, T, n_heads, d_qk]
+    k: jnp.ndarray,  # [B, T, n_heads, d_qk]
+    v: jnp.ndarray,  # [B, T, n_heads, d_v]
+    *,
+    scale: float,
+    lengths: jnp.ndarray,  # [B]
+    block: int = 512,
+) -> jnp.ndarray:
+    """Causal self-attention over a right-padded prompt, a block of query
+    rows at a time (``lax.map``), so that the score matrix in flight is
+    ``[B, n, block, T]`` whatever the bucket, and the block halves until
+    that matrix is at most 2**27 float32 values (a 4 x 8,192 bucket:
+    128 rows). Head sizes of q/k and of v may differ (expanded MLA: 192
+    and 128)."""
+    B, T, n, _ = q.shape
+    while block > 32 and B * n * block * T > 2**27:
+        block //= 2
+    size = T if T <= block else next(
+        (b for b in (512, 256, 128, 64, 32) if b <= block and T % b == 0), T
+    )
+    k_pos = jnp.arange(T)
+    in_row = k_pos[None, :] < lengths[:, None]  # [B, T]
+
+    def one_block(args):
+        q_blk, q_pos = args  # [B, size, n, d], [size]
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q_blk, k, preferred_element_type=jnp.float32
+        ) * scale
+        mask = (k_pos[None, :] <= q_pos[:, None])[None] & in_row[:, None, :]
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+    if size == T:
+        return one_block((q, k_pos))
+    nb = T // size
+    q_blocks = jnp.moveaxis(q.reshape(B, nb, size, n, q.shape[-1]), 1, 0)
+    out = jax.lax.map(one_block, (q_blocks, k_pos.reshape(nb, size)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, n, v.shape[-1])

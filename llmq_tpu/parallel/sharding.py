@@ -48,6 +48,15 @@ def _tp_dim(size: int, tp: int) -> Optional[str]:
 
 def param_pspecs(config: ModelConfig, tp: int) -> Params:
     """PartitionSpec pytree matching the param layout."""
+    if config.layer_pattern is not None:
+        # Held by share, not split: every leaf whole on every device (the
+        # engine refuses tp > 1 for a layer pattern).
+        from llmq_tpu.models import hybrid
+
+        return jax.tree.map(
+            lambda shape: P(), hybrid.param_shapes(config),
+            is_leaf=lambda x: isinstance(x, tuple),
+        )
     d = config.head_dim_
     nh_d = config.num_heads * d
     nkv_d = config.num_kv_heads * d

@@ -389,6 +389,12 @@ class TPUWorker(BaseWorker):
 
             name = spec.split("://", 1)[1] or "tiny"
             model_config = get_preset(name)
+            if model_config.layer_pattern is not None and self.role != "unified":
+                raise ValueError(
+                    f"role={self.role} is not supported for a model with a "
+                    "layer pattern: its per-sequence state has no snapshot "
+                    "to hand from a prefill pool to a decode pool"
+                )
             self.logger.info("Preset model %s (random weights)", name)
             init = partial(
                 init_params, model_config, dtype=dtype, quantize=quantize

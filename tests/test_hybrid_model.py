@@ -1,0 +1,309 @@
+"""Models with a declared layer pattern (``models/hybrid.py``): KDA layers
+with a per-sequence state beside MLA layers over a paged latent cache,
+routed experts held by share. CPU, tiny widths, seeded weights.
+
+The comparison is with the benchmark's plain reference
+(``benchmark/architectures/bailing_hybrid.py``: float32, token-by-token
+recurrence, expanded MLA, nothing imported from the program): prefill and
+then decoding through both caches must give the logits of its full forward
+pass.
+"""
+
+import dataclasses
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import architectures, weights
+from llmq_tpu.models import hybrid
+from llmq_tpu.models.config import ModelConfig
+from llmq_tpu.models.presets import _LING_3_FLASH, get_preset
+from llmq_tpu.models.transformer import build_model, make_kv_pages
+from llmq_tpu.ops import delta_rule
+
+# The published config's keys at a tiny size: 12 layers in periods of 3
+# (KDA, KDA, MLA), two leading dense MLPs, 16 experts in 4 groups.
+HF = dict(
+    model_type="bailing_hybrid", vocab_size=304, hidden_size=64,
+    num_hidden_layers=12, num_attention_heads=4, num_key_value_heads=4,
+    head_dim=16, intermediate_size=128, rope_theta=10000.0, rms_norm_eps=1e-6,
+    layer_group_size=3, first_k_dense_replace=2, short_conv_kernel_size=4,
+    kda_lower_bound=-5, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, num_experts=16, num_experts_per_tok=4,
+    moe_intermediate_size=32, moe_shared_expert_intermediate_size=32,
+    num_shared_experts=1, n_group=4, topk_group=2, norm_topk_prob=True,
+    routed_scaling_factor=2.5, tie_word_embeddings=False,
+)
+KEPT = [0, 3, 4, 5]  # dense KDA, then one whole period
+
+
+def configs(first=4, held=8, kept=KEPT):
+    """(the program's ModelConfig, the benchmark file's keys) of one share."""
+    hf = dict(HF, kept_layers=kept, experts_held=[first, held])
+    file_cfg = dict(
+        hf, architecture="bailing_hybrid", num_experts=held,
+        num_experts_published=HF["num_experts"], num_hidden_layers=len(kept),
+    )
+    return ModelConfig.from_hf_config(hf), file_cfg
+
+
+def seeded(file_cfg, seed=7):
+    """Weights by the benchmark's rules: norms and biases off 1 and 0."""
+    arch = architectures.of(file_cfg)
+    return arch, weights.make_weights(arch, file_cfg, seed, None, dtype=jnp.float32)
+
+
+MC, FILE_CFG = configs()
+ARCH, PARAMS = seeded(FILE_CFG)
+MODEL = build_model(MC)
+PAGE, PAGES, PPS = 8, 24, 6
+
+
+def test_tree_is_the_one_the_benchmark_describes():
+    ours = jax.tree.map(
+        tuple, hybrid.param_shapes(MC), is_leaf=lambda x: isinstance(x, tuple)
+    )
+    assert ours == ARCH.tree_shapes(FILE_CFG)
+    assert [(g.attn, g.mlp, g.count) for g in hybrid.layer_groups(MC)] == [
+        ("kda", "dense", 1), ("kda", "moe", 2), ("mla", "moe", 1)
+    ]
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_prefill_then_decode_matches_the_reference(rows):
+    """Batches of 1 and 4 rows with padding: rows of different lengths in
+    one bucket, a padded row, then decode steps with an inactive slot,
+    against the reference's full forward pass."""
+    rng = np.random.default_rng(rows)
+    lengths = [19, 7, 0, 12][:rows]
+    steps = 6
+    seqs = [list(rng.integers(1, 300, size=n + steps)) for n in lengths]
+    k, v = make_kv_pages(MC, PAGES, PAGE, jnp.float32)
+    bt = np.zeros((rows, PPS), np.int32)
+    free = iter(range(1, PAGES))
+    for r, n in enumerate(lengths):
+        if n:
+            bt[r, : -(-(n + steps) // PAGE)] = [
+                next(free) for _ in range(-(-(n + steps) // PAGE))
+            ]
+    tokens = np.zeros((rows, 32), np.int32)
+    for r, n in enumerate(lengths):
+        tokens[r, :n] = seqs[r][:n]
+    logits, k, v = jax.jit(MODEL.prefill)(
+        PARAMS, tokens, np.asarray(lengths, np.int32), k, v, bt
+    )
+    got = [[np.asarray(logits[r])] for r in range(rows)]
+    decode = jax.jit(MODEL.decode)
+    active = np.asarray([n > 0 for n in lengths])
+    for j in range(steps - 1):
+        toks = np.asarray([s[n + j] if n else 0 for s, n in zip(seqs, lengths)], np.int32)
+        ctx = np.asarray([n + j for n in lengths], np.int32)
+        logits, k, v = decode(PARAMS, toks, ctx, k, v, bt, active)
+        for r in range(rows):
+            got[r].append(np.asarray(logits[r]))
+    for r, n in enumerate(lengths):
+        if not n:
+            continue
+        ref = np.asarray(ARCH.forward_logits(
+            PARAMS, FILE_CFG, seqs[r][: n + steps - 1], list(range(n - 1, n + steps - 1))
+        ))
+        np.testing.assert_allclose(np.stack(got[r]), ref, atol=2e-4, rtol=0)
+
+
+def test_long_prompt_takes_the_grouped_experts_and_blocked_attention():
+    """A bucket of 640 tokens: more rows than ``DENSE_EXPERT_ROWS`` (the
+    experts go through ``ragged_dot`` on the whole stack, inside the layer
+    scan) and more than one block of query rows; then decode over five
+    pages of 128."""
+    rng = np.random.default_rng(5)
+    n, steps = 600, 3
+    ids = list(rng.integers(1, 300, size=n + steps))
+    k, v = make_kv_pages(MC, 8, 128, jnp.float32)
+    bt = np.asarray([[1, 2, 3, 4, 5, 0]], np.int32)
+    tokens = np.zeros((1, 640), np.int32)
+    tokens[0, :n] = ids[:n]
+    logits, k, v = jax.jit(MODEL.prefill)(PARAMS, tokens, np.asarray([n], np.int32), k, v, bt)
+    got = [np.asarray(logits[0])]
+    for j in range(steps - 1):
+        logits, k, v = jax.jit(MODEL.decode)(
+            PARAMS, np.asarray([ids[n + j]], np.int32), np.asarray([n + j], np.int32),
+            k, v, bt, np.asarray([True]),
+        )
+        got.append(np.asarray(logits[0]))
+    ref = ARCH.forward_logits(PARAMS, FILE_CFG, ids[: n + steps - 1], list(range(n - 1, n + steps - 1)))
+    np.testing.assert_allclose(np.stack(got), np.asarray(ref), atol=2e-4, rtol=0)
+
+
+def test_state_rows_given_by_the_caller_are_the_rows_used():
+    """The engine passes slot + 1; the default is the first page."""
+    k, v = make_kv_pages(MC, PAGES, PAGE, jnp.float32, state_rows=3)
+    assert v["S"].shape[1] == 3 and k.shape[1] == PAGES
+    tokens = np.zeros((1, 16), np.int32)
+    tokens[0, :9] = np.arange(1, 10)
+    bt = np.zeros((1, PPS), np.int32)
+    bt[0, :2] = [5, 6]
+    _, _, v = jax.jit(MODEL.prefill)(
+        PARAMS, tokens, np.asarray([9], np.int32), k, v, bt, np.asarray([2], np.int32)
+    )
+    S = np.asarray(v["S"])
+    assert np.abs(S[:, 2]).max() > 0 and np.abs(S[:, :2]).max() == 0
+
+
+def test_scanned_kda_prefill_is_the_token_recurrence():
+    rng = np.random.default_rng(0)
+    B, T, n, d = 2, 12, 3, 8
+    q, k, v = (jnp.asarray(rng.normal(size=(B, T, n, d)), jnp.float32) for _ in range(3))
+    alpha = jnp.asarray(rng.uniform(0.1, 1.0, size=(B, T, n, d)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(size=(B, T, n)), jnp.float32)
+    lengths = np.asarray([12, 5])
+    valid = jnp.arange(T)[None, :] < lengths[:, None]
+    S, o = delta_rule.kda_scan(q, k, v, alpha, beta, valid)
+    for b in range(B):
+        state = np.zeros((n, d, d))
+        for t in range(lengths[b]):
+            decayed = np.asarray(alpha[b, t])[:, :, None] * state
+            delta = np.asarray(v[b, t]) - np.einsum("nkv,nk->nv", decayed, k[b, t])
+            state = decayed + np.asarray(beta[b, t])[:, None, None] * np.einsum(
+                "nk,nv->nkv", k[b, t], delta
+            )
+            np.testing.assert_allclose(
+                o[b, t], np.einsum("nkv,nk->nv", state, q[b, t]), atol=1e-4
+            )
+        np.testing.assert_allclose(S[b], state, atol=1e-4)  # padding: no-ops
+    # the convolution: a token at a time from the tails equals the bucket
+    u = jnp.asarray(rng.normal(size=(B, T, 6)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 6)), jnp.float32)
+    out, tail = delta_rule.causal_conv(u, w, jnp.asarray(lengths))
+    np.testing.assert_allclose(tail[1], u[1, 2:5])
+    step_tail = jnp.zeros((B, 3, 6))
+    for t in range(T):
+        got, step_tail = delta_rule.conv_step(step_tail, u[:, t], w)
+        np.testing.assert_allclose(got, out[:, t], atol=1e-5)
+
+
+def test_absorbed_mla_is_expanded_mla():
+    """Decode (query absorbed into the latent, attention over the cached
+    rows) gives what prefill (keys and values raised a head) gives."""
+    lp = {name: w[0] for name, w in PARAMS["stack2"].items()}
+    rng = np.random.default_rng(1)
+    T = 16
+    x = jnp.asarray(rng.normal(size=(1, T, MC.hidden_size)), jnp.float32)
+    latent, _ = make_kv_pages(MC, PAGES, PAGE, jnp.float32)
+    bt = np.asarray([[4, 9, 0, 0, 0, 0]], np.int32)
+    positions = jnp.arange(T, dtype=jnp.int32)[None]
+    lengths = jnp.asarray([T], jnp.int32)
+    expanded, latent = MODEL._mla_prefill(lp, x, positions, lengths, latent, bt, 0)
+    for t in (0, 7, T - 1):
+        absorbed, _ = MODEL._mla_decode(
+            lp, x[:, t], jnp.asarray([t], jnp.int32), latent, bt,
+            jnp.asarray([t + 1], jnp.int32), 0,
+        )
+        np.testing.assert_allclose(absorbed[0], expanded[0, t], atol=2e-5)
+
+
+@pytest.mark.parametrize("dense_rows", [256, 0], ids=["dense", "grouped"])
+def test_four_shares_add_up_to_the_uncut_layer(dense_rows, monkeypatch):
+    """Both forms of the held experts' sum (every expert on every row;
+    sorted assignments and ``ragged_dot``). The parts that the four shares of an expert layer give, with the
+    shared expert (which every chip computes alike) counted once, add up
+    to what the uncut reference gives for the whole layer."""
+    monkeypatch.setattr(hybrid, "DENSE_EXPERT_ROWS", dense_rows)
+    whole_mc, whole_cfg = configs(first=0, held=16)
+    _, whole = seeded(whole_cfg, seed=11)
+    lp = {name: w[1] for name, w in whole["stack1"].items()}
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(9, 64)), jnp.float32)
+    ref = architectures.of(whole_cfg)
+    z = tuple(sorted(ref._sizes(whole_cfg).items()))
+    route = (4, 2, 4, 2.5, True, 0)
+    shared, w, _ = ref._shared_and_route(x, lp, z=z, route_cfg=route, control=None)
+    uncut = shared + ref._expert_block(
+        x, w, lp["expert_gate_proj"], lp["expert_up_proj"], lp["expert_down_proj"],
+        control=None,
+    )
+    total, assignments = jnp.zeros_like(shared), 0
+    for first in (0, 4, 8, 12):
+        share = {
+            name: leaf[first : first + 4] if name.startswith("expert_") else leaf
+            for name, leaf in lp.items()
+        }
+        mc = dataclasses.replace(whole_mc, experts_held=(first, 4))
+        part, counts = hybrid.moe_held(x, share, mc)
+        total = total + (part - shared)
+        assignments += int(counts[0])
+    assert assignments == 9 * 4  # every assignment lands on exactly one share
+    np.testing.assert_allclose(total + shared, uncut, atol=2e-5)
+
+
+def test_from_hf_config_on_the_published_keys():
+    cut = get_preset("ling-3.0-flash-ep4")
+    assert cut.layer_pattern == (("kda", "dense"),) + (("kda", "moe"),) * 5 + (("mla", "moe"),)
+    assert (cut.hidden_size, cut.num_heads, cut.head_dim_, cut.intermediate_size) == (
+        2560, 32, 128, 6144)
+    assert (cut.kv_lora_rank, cut.qk_nope_head_dim, cut.qk_rope_head_dim, cut.v_head_dim) == (
+        512, 128, 64, 128)
+    assert (cut.num_experts, cut.experts_held, cut.num_experts_per_tok) == (512, (0, 128), 8)
+    assert (cut.moe_intermediate_size, cut.shared_expert_intermediate_size) == (768, 768)
+    assert (cut.n_group, cut.topk_group, cut.routed_scaling_factor) == (8, 4, 2.5)
+    assert cut.vocab_size == 157184 // 4 and not cut.tie_word_embeddings
+    n = sum(np.prod(s) for s in jax.tree.leaves(
+        hybrid.param_shapes(cut), is_leaf=lambda x: isinstance(x, tuple)))
+    assert round(n / 1e6) == 5232  # 10.46 GB in bf16
+    first_34 = ModelConfig.from_hf_config(dict(_LING_3_FLASH, kept_layers=range(34)))
+    assert [a for a, _ in first_34.layer_pattern].count("mla") == 5
+    assert [m for _, m in first_34.layer_pattern[:3]] == ["dense", "dense", "moe"]
+    with pytest.raises(ValueError, match="swiglu_limit_list"):  # layers 34-41 clamp
+        ModelConfig.from_hf_config(_LING_3_FLASH)
+    with pytest.raises(ValueError, match="q_lora_rank"):
+        ModelConfig.from_hf_config(dict(_LING_3_FLASH, kept_layers=[0], q_lora_rank=1536))
+
+
+def test_big_buckets_take_rows_and_blocks_one_at_a_time(monkeypatch):
+    """Above ``KDA_PREFILL_TOKENS`` a KDA layer takes its rows one at a
+    time and above ``MOE_BLOCK_ROWS`` an expert layer a block of rows at a
+    time (the 4 x 8,192 bucket's float32 in flight): same logits, same
+    state, same latent rows."""
+    rng = np.random.default_rng(3)
+    lengths = np.asarray([30, 9, 0, 17], np.int32)
+    tokens = np.zeros((4, 32), np.int32)
+    for r, n in enumerate(lengths):
+        tokens[r, :n] = rng.integers(1, 300, size=n)
+    bt = np.zeros((4, PPS), np.int32)
+    bt[0, :4], bt[1, :2], bt[3, :3] = [1, 2, 3, 4], [5, 6], [7, 8, 9]
+
+    def run():
+        k, v = make_kv_pages(MC, PAGES, PAGE, jnp.float32)
+        return jax.jit(MODEL.prefill)(PARAMS, tokens, lengths, k, v, bt)
+
+    plain = run()
+    monkeypatch.setattr(hybrid, "KDA_PREFILL_TOKENS", 16)
+    monkeypatch.setattr(hybrid, "MOE_BLOCK_ROWS", 16)
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(run())):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=0)
+
+
+def _reference_err(control, n=40, k=8):
+    from benchmark.correct import logit_err
+
+    ids = list(np.random.default_rng(9).integers(1, 300, size=n + k - 1))
+    positions = list(range(n - 1, n + k - 1))
+    ref = np.asarray(ARCH.forward_logits(PARAMS, FILE_CFG, ids, positions))
+    ctrl = np.asarray(ARCH.forward_logits(PARAMS, FILE_CFG, ids, positions, control))
+    return logit_err(ctrl, ref)
+
+
+@pytest.mark.parametrize("fault", ["kda_reset", "conv_tail", "moe_drop", "moe_drop_first"])
+def test_a_planted_fault_of_the_reference_is_far_from_it(fault):
+    """The controls that stand for a wrong cache manager or step program
+    (a state not carried, tails not carried, a layer's experts left out)
+    are in ``CONTROLS`` and move the logits by far more than rounding."""
+    assert fault in ARCH.CONTROLS
+    assert _reference_err(fault) > 0.05
+
+
+def test_the_diagnoses_round_and_force_the_float32_choice_of_experts():
+    rounded, routed = _reference_err("bf16act"), _reference_err("bf16act_routed")
+    assert 0 < routed < rounded < 0.2
+    assert not set(ARCH.DIAGNOSES) & set(ARCH.CONTROLS)

@@ -329,7 +329,69 @@ def _refusal_is_a_compile_failure_not_a_device_fault(topo, monkeypatch):
     assert classify_failure(refused.value) is None
 
 
+def _hybrid_step(which):
+    """The decode step and a prefill bucket of the Ling-3.0-flash cut
+    at its published widths (``preset://ling-3.0-flash-ep4``: 10.46 GB of
+    bf16 weights), over the pools the benchmark's cell runs with: 128
+    slots and their 129 state rows, 2,700 latent pages of 128 tokens, 64
+    page places a row (``max_model_len`` 8,192). What has to hold: the
+    chip's compiler accepts the scans over the layer groups, the grouped
+    expert matmuls and the state update, the pools are updated in place,
+    and weights, pools and temporaries fit the chip's 16.9 GB with room
+    for the benchmark's correctness scratch (0.6 GB); and a decode step
+    copies the latent pool nowhere and holds little besides the pools,
+    whatever ``max_model_len`` is. (The largest buckets, 4 x 4,096 and 4 x
+    8,192, which take rows and blocks one at a time in
+    ``models/hybrid.py``, compile too, ``_hybrid_step((4, 8192))``: 25 s
+    of every core each, and left out of ``CASES`` for the suite's sake.)"""
+    slots, pages, places = 128, 2700, 64
+
+    def case(topo, monkeypatch):
+        from llmq_tpu.models.transformer import build_model, make_kv_pages
+
+        cfg = get_preset("ling-3.0-flash-ep4")
+        model = build_model(cfg)
+        s = _Shapes(topo)
+        shaped = partial(jax.tree.map, lambda a: s(a.shape, a.dtype))
+        params = shaped(jax.eval_shape(
+            partial(init_params, cfg, dtype=jnp.bfloat16), jax.random.key(0)
+        ))
+        latent, state = shaped(jax.eval_shape(
+            lambda: make_kv_pages(cfg, pages, PAGE, jnp.bfloat16, state_rows=slots + 1)
+        ))
+        if which == "decode":
+            rows = slots
+            compiled = jax.jit(
+                partial(model.decode, counters=True, state_rows=1),
+                donate_argnums=(3, 4),
+            ).lower(
+                params, s((rows,), jnp.int32), s((rows,), jnp.int32), latent, state,
+                s((rows, places), jnp.int32), s((rows,), jnp.bool_),
+            ).compile()
+        else:
+            rows, bucket = which
+            compiled = jax.jit(model.prefill, donate_argnums=(3, 4)).lower(
+                params, s((rows, bucket), jnp.int32), s((rows,), jnp.int32), latent,
+                state, s((rows, places), jnp.int32), s((rows,), jnp.int32),
+            ).compile()
+        mem = compiled.memory_analysis()
+        pools = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves((latent, state)))
+        assert mem.alias_size_in_bytes >= pools  # both pools in place
+        if which == "decode":
+            assert mem.temp_size_in_bytes < 0.5e9
+            text = compiled.as_text()
+            pool = f"bf16[{latent.shape[0]},{pages},"
+            copies = [l for l in text.splitlines() if " copy(" in l and pool in l]
+            assert not copies, copies[:2]
+        if which != (4, 8192):  # there the compiler's own accounting decides
+            assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
+
+    return case
+
+
 CASES = {
+    "hybrid_decode_128_slots": _hybrid_step("decode"),
+    "hybrid_prefill_1x512": _hybrid_step((1, 512)),
     "decode_live": _decode(pk.paged_decode_attention_live),
     "decode_live_fp8_pool_handed_to_v1": _decode(
         pk.paged_decode_attention_live, jnp.float8_e5m2
