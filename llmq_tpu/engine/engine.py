@@ -14,10 +14,17 @@ TPU-native design differs from vLLM's CUDA core on purpose:
   state (current tokens, context lengths, block tables, sampling state)
   lives on the device and is *updated by the compiled step itself*; the
   host dispatches step ``k`` while asynchronously fetching the sampled
-  tokens of step ``k - runahead``. Steady-state decode therefore ships
-  **zero** host→device bytes and never blocks on a device→host sync —
-  critical when dispatch latency is high, and it removes host jitter
-  everywhere else. Correctness pieces:
+  tokens of step ``k - ahead``. Steady-state decode therefore ships
+  **zero** host→device bytes, and the device never waits for the host.
+  ``ahead`` is as many steps as cover the engine thread's own turn
+  (:func:`runahead_target`: the longest turn, fetch return to next
+  decode dispatch, that came twice in its last few hundred, over the
+  device's step period, plus one; both read off the loop's own clock),
+  at least 2 and never more than ``EngineConfig.runahead``: the device
+  queue is first in, first out, so every dispatch kept in flight is one
+  more step a new request's prefill waits behind. While every slot is
+  taken or requests wait, an arrival waits for a slot and not for that
+  queue, and ``ahead`` is the whole cap. Correctness pieces:
     * *Page lookahead*: KV pages are allocated at dispatch time for every
       position any in-flight step may write (`Scheduler.ensure_pages`),
       so the device block tables are never stale when a sequence crosses
@@ -54,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 import os
 import queue
 import re
@@ -335,7 +343,12 @@ class EngineConfig:
     # longer than this (latency floor for trickle arrivals; the clock
     # starts at the first deferred step, not at enqueue).
     admit_max_wait_s: float = 0.5
-    runahead: int = 8  # decode dispatches in flight ahead of result reads
+    # The most dispatches kept in flight ahead of result reads: a cap. With
+    # slots free and nothing waiting the engine holds as many as cover its
+    # own turn (runahead_target; 2 on a host whose turn is shorter than a
+    # step) and deepens by itself up to here; with every slot taken, and
+    # with pipeline stages, it runs at the cap. The OOM ladder halves it.
+    runahead: int = 8
     # Fused multi-step decode: one compiled XLA computation runs this
     # many decode iterations (a lax.scan over the single decode step —
     # attention, KV write, LM head, on-device sampling with the key
@@ -639,6 +652,37 @@ def _layer_pattern_refusal(what: str) -> str:
         "be shared by a prefix, cut at a chunk, rewound by a length or "
         "moved between pools, and its experts are held whole on one device"
     )
+
+
+# Turns a bucket of the record of long turns: the engine remembers the two
+# longest of this bucket and of the one before (3-7 s of 13 ms steps).
+_TURN_BUCKET = 256
+# Device-paced gaps between fetch returns whose median is the step period.
+_STEP_GAPS = 15
+
+
+def runahead_target(
+    turn_s: float, step_s: float, cap: int, *, pp: int = 1, full: bool = False
+) -> int:
+    """Dispatches to keep in flight before blocking on the oldest one's
+    tokens. When a fetch returns, the next entry is starting on the device
+    and ``n`` in flight give the host ``n`` step periods to issue its next
+    decode dispatch: as many steps as the host's turn ``turn_s`` takes of
+    device periods ``step_s``, rounded up, and one more as margin. So never
+    fewer than 2 (one step executing, one queued), and never more than
+    ``cap``, which also holds while no step period is known.
+
+    ``full``: every slot was taken or requests waited, lately. A request
+    that arrives then waits for a slot, not for the device queue, so a short
+    queue buys its first token nothing, while every stall of the host that
+    the record cannot foresee (this machine stands still for 100-170 ms one
+    to three times a minute, my chip runs, PR 34) idles the device under
+    all of the running requests: the cap. Pipeline stages read the depth as
+    their microbatch count (``m = decode_block × runahead``,
+    ``_build_pp_jits``): the cap as well."""
+    if pp > 1 or full or step_s <= 0.0:
+        return cap
+    return min(cap, max(2, math.ceil(turn_s / step_s) + 1))
 
 
 # Pipeline entry: (dispatch index, kind "prefill"|"decode", device
@@ -1170,6 +1214,24 @@ class EngineCore:
         # Run-ahead pipeline state.
         self._pending: Deque[_Pending] = deque()
         self._pending_decodes = 0  # decode entries within _pending
+        # How many of them to keep in flight (runahead_target), set anew at
+        # the top of every step() from the loop's own clock readings:
+        # ``_turn_from`` is when the last fetch returned with work still
+        # queued behind it (0.0: nothing to be late for); ``_turn_worst``
+        # the two longest fetch-return-to-decode-dispatch times of this
+        # bucket of turns, then of the bucket before; ``_full`` whether a
+        # turn of this bucket, of the one before, found every slot taken or
+        # requests waiting; ``_step_s`` the device's step period, the
+        # median of ``_step_gaps`` (the spacing of fetch returns that the
+        # device paced).
+        self._ahead = self.cfg.runahead
+        self._turn_from = 0.0
+        self._turn_worst = [0.0, 0.0, 0.0, 0.0]
+        self._full = [False, False]
+        self._turn_n = 0
+        self._step_s = 0.0
+        self._step_gaps: Deque[float] = deque(maxlen=_STEP_GAPS)
+        self._fetched = (0, 0.0, False)  # dispatch index, at, device-paced
         self._defer_since: Optional[float] = None  # admission-deferral start
         # The engine thread's span ring (obs/spans.py): off by default,
         # and every site tests ``spans.on`` in place before it writes.
@@ -2826,6 +2888,13 @@ class EngineCore:
         latency is unchanged.
         """
         finished: List[RequestOutput] = []
+        # The host's turn is the longest that came twice in the record: a
+        # stall that comes once (a profile starting, a compile, the machine
+        # standing still) is paid when it comes, whatever is queued after.
+        self._ahead = runahead_target(
+            sorted(self._turn_worst)[2], self._step_s, self.cfg.runahead,
+            pp=self.pp, full=any(self._full),
+        )
         if self._deadlines_enabled:
             self._expire_deadlines(finished)
         if self._cancel_rids:
@@ -2960,7 +3029,7 @@ class EngineCore:
         # just before the wave) return to the allocator BETWEEN chunks —
         # otherwise a tight pool cuts the wave short on OutOfPages that
         # next step's releases would have covered.
-        while len(self._pending) > self.cfg.runahead:
+        while len(self._pending) > self._ahead:
             self._process_oldest(finished)
         self._flush_deferred()
         free = sum(s is None for s in self.scheduler.slots)
@@ -3068,6 +3137,7 @@ class EngineCore:
         idx, kind, out, snapshot, g = self._pending.popleft()
         if kind in ("decode", "mixed"):
             self._pending_decodes -= 1
+        t_wait = time.monotonic()
         if self.spans.on:
             # ``fetch``: the wait for this dispatch's tokens, caused by
             # the dispatch; once they are on the host it becomes ``emit``:
@@ -3089,6 +3159,7 @@ class EngineCore:
             block, starts = out
             with self._wd("mixed"):
                 tokens = np.asarray(block)
+            self._note_fetch(idx, kind, t_wait)
             if self.spans.on:
                 self.spans.then("emit")
             for k in range(tokens.shape[0]):
@@ -3120,6 +3191,7 @@ class EngineCore:
             with self._wd("verify"):
                 emit = np.asarray(out[0])
                 counts = np.asarray(out[1])
+            self._note_fetch(idx, kind, t_wait)
             if self.spans.on:
                 self.spans.then("emit")
             for k in range(emit.shape[0]):
@@ -3156,6 +3228,7 @@ class EngineCore:
             held, hit = np.asarray(moe).reshape(-1, 2).sum(axis=0)  # llmq: ignore[unguarded-device-fetch]
             self.moe_assignments_held += int(held)
             self.moe_experts_hit += int(hit)
+        self._note_fetch(idx, kind, t_wait)
         if self.spans.on:
             self.spans.then("emit")
         # Normalise to a [K, rows] block: prefill outputs and K=1 decode
@@ -3182,6 +3255,32 @@ class EngineCore:
         self._processed_idx = idx
         if self.spans.on:
             self.spans.end()
+
+    def _note_fetch(self, idx: int, kind: str, t_wait: float) -> None:
+        """A dispatch's tokens are on the host: the two clock readings
+        behind ``runahead_target``. The host's turn starts here if work is
+        still queued on the device (otherwise the device is idle whatever
+        the host does next, and the turn is not read), or as much earlier
+        as this return came later than one step period after the last: the
+        thread waits for the interpreter lock inside the fetch, and while
+        another thread holds it (a collection, a long handler of the event
+        loop) the device runs on with nobody to feed it. The device's step
+        period is the median spacing of two successive decode dispatches'
+        fetch returns where the device set the pace of both (the thread
+        spent most of each interval blocked in the fetch, not in its own
+        work): a median, because a return that the interpreter lock held
+        up reads long and the one after it short."""
+        now = time.monotonic()
+        last_idx, last_at, last_paced = self._fetched
+        paced = kind != "prefill" and 2.0 * (now - t_wait) > now - last_at
+        late = 0.0
+        if paced and last_paced and idx == last_idx + 1:
+            gaps = self._step_gaps
+            gaps.append(now - last_at)
+            self._step_s = sorted(gaps)[len(gaps) // 2]
+            late = max(0.0, now - last_at - self._step_s)
+        self._fetched = (idx, now, paced)
+        self._turn_from = now - late if self._pending else 0.0
 
     def _eval_guard(
         self,
@@ -3935,7 +4034,7 @@ class EngineCore:
                 )
                 if self.spans.on:
                     self.spans.end_dispatch(self._dispatch_idx)
-                while len(self._pending) > self.cfg.runahead:
+                while len(self._pending) > self._ahead:
                     self._process_oldest(finished)
                 cur = pos
 
@@ -4194,6 +4293,7 @@ class EngineCore:
                 mode=self._mode, variant="",
                 rows=len(seqs), live_pages=self._live_pages(seqs),
                 k_steps=k_steps, pending=len(self._pending),
+                ahead=self._ahead,
                 **({"state_rows": len(seqs)} if self._hybrid else {}),
             )
         with self._wd(kind):
@@ -4202,7 +4302,25 @@ class EngineCore:
                     self.params, self.k_pages, self.v_pages, self._dev_state
                 )
             )
-            self._record_dispatch(kind, time.monotonic() - t0)
+            now = time.monotonic()
+            self._record_dispatch(kind, now - t0)
+        if self._turn_from:
+            # The host's turn ends with the first decode dispatch after a
+            # fetch. The record keeps long turns and no mean: a starved
+            # device is paid by every running request, a queued prefill by
+            # one.
+            turn, worst = now - self._turn_from, self._turn_worst
+            self._turn_from = 0.0
+            if turn > worst[1]:
+                worst[:2] = (turn, worst[0]) if turn > worst[0] else (worst[0], turn)
+            sched = self.scheduler
+            if sched.has_waiting or len(sched.running) >= len(sched.slots):
+                self._full[0] = True
+            self._turn_n += 1
+            if self._turn_n >= _TURN_BUCKET:
+                self._turn_n = 0
+                worst[:] = 0.0, 0.0, worst[0], worst[1]
+                self._full[:] = False, self._full[0]
         self.decode_steps += k_steps
         self.decode_dispatches += 1
         out, g = self._split_guard(out)
@@ -4220,7 +4338,7 @@ class EngineCore:
         )
         if self.spans.on:
             self.spans.end_dispatch(self._dispatch_idx)
-        while len(self._pending) > self.cfg.runahead:
+        while len(self._pending) > self._ahead:
             self._process_oldest(finished)
 
     def _self_preempt_deferred(self, seq: Sequence) -> None:
@@ -4682,7 +4800,7 @@ class EngineCore:
     def degrade_for_oom(self) -> Optional[str]:
         """One rung of the HBM-OOM degradation ladder per call, in
         order: (1) demote refcount-0 prefix device pages to the host
-        cold tier, (2) halve the run-ahead pipeline depth (fewer
+        cold tier, (2) cap the run-ahead pipeline at half its depth (fewer
         in-flight result buffers resident in HBM), (3) preempt one
         victim with swap-to-host. Returns the rung taken, or None when
         the ladder is dry — the caller then falls through to fault
@@ -4705,7 +4823,10 @@ class EngineCore:
                         return "demote_prefix"
             elif rung == 1:
                 if self.cfg.runahead > 1:
-                    self.cfg.runahead = max(1, self.cfg.runahead // 2)
+                    # Half of what is in flight, which may be under the cap.
+                    self.cfg.runahead = max(
+                        1, min(self.cfg.runahead, self._ahead) // 2
+                    )
                     self._oom_ladder_log.append("shrink_runahead")
                     logger.warning(
                         "hbm_oom ladder: run-ahead shrunk to %d",
@@ -5121,6 +5242,8 @@ class EngineCore:
             # iterations, so dispatches <= ceil(decode_steps / K).
             decode_dispatches=self.decode_dispatches,
             decode_block=self.cfg.decode_block,
+            # Dispatches the engine keeps in flight now (runahead_target).
+            runahead_target=self._ahead,
             # Speculation health: accepted/proposed drafts. A dispatch
             # emits 1 + (accepted this step) tokens, so tok/s scales
             # with acceptance_rate at fixed step time (PERF_NOTES math).
