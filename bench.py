@@ -49,7 +49,7 @@ dispatches at the winning point and keeps the mode only on a measured
 win — pin engine-wide with LLMQ_MIXED_STEP instead).
 
 When the remaining LLMQ_BENCH_DEADLINE budget cannot fit the whole plan
-(quant attempt + kernel A/B + the multi-candidate ladder), phases are
+(quant attempt + the multi-candidate ladder), phases are
 trimmed in speculation order — see trim_plan() — down to, at minimum,
 one bf16 headline at the proven 192-slot config.
 """
@@ -195,69 +195,6 @@ def peak_flops_per_chip(devices) -> float:
     )
 
 
-def pick_decode_kernel() -> str:
-    """Quick on-hardware A/B of the paged-decode kernels (v1 BlockSpec
-    pipeline vs v2 chunked manual-DMA), run in a SUBPROCESS under a
-    deadline. Two reasons for the subprocess: a kernel hang must cost at
-    most the A/B budget, never the headline run, and a chip belongs to
-    one process — the probe must run (and exit) before this process
-    initialises the backend. The child derives its own preset/shape from
-    the same env knobs main() uses. An explicit LLMQ_DECODE_KERNEL always
-    wins; a failed or timed-out A/B is reported and the run uses the default.
-    """
-    import subprocess
-
-    explicit = os.environ.get("LLMQ_DECODE_KERNEL")
-    if explicit:
-        return explicit
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--kernel-ab-probe"],
-            timeout=float(os.environ.get("LLMQ_BENCH_AB_TIMEOUT", 420)),
-            capture_output=True,
-            text=True,
-        )
-        sys.stderr.write(proc.stderr[-600:])
-        choice = proc.stdout.strip().splitlines()[-1] if proc.stdout else ""
-        if proc.returncode == 0 and choice in ("live", "v1", "v2", "v3"):
-            return choice
-        print(f"bench: kernel A/B rc={proc.returncode}; using live", file=sys.stderr)
-    except subprocess.TimeoutExpired:
-        print("bench: kernel A/B timed out; using live", file=sys.stderr)
-    except Exception as exc:  # noqa: BLE001
-        print(f"bench: kernel A/B failed ({exc!r}); using live", file=sys.stderr)
-    return "live"
-
-
-def _kernel_ab_probe_main() -> None:
-    """Entry for `bench.py --kernel-ab-probe` (child process). Derives
-    the preset the same way main() will (same env knobs, same HBM), so
-    the A/B measures the shapes the headline run uses."""
-    from llmq_tpu.engine.kernel_autotune import run_ab
-    from llmq_tpu.models.presets import get_preset
-
-    _jax, devices = init_devices()
-    limit = (devices[0].memory_stats() or {}).get("bytes_limit")
-    preset = os.environ.get("LLMQ_BENCH_PRESET") or pick_preset(
-        limit, devices[0].platform
-    )
-    config = get_preset(preset)
-    kv_env = (os.environ.get("LLMQ_BENCH_KV_DTYPE") or "").lower()
-    choice, _measured = run_ab(
-        num_heads=config.num_heads,
-        num_kv_heads=config.num_kv_heads,
-        head_dim=config.head_dim_,
-        num_layers=config.num_layers,
-        max_seqs=int(os.environ.get("LLMQ_BENCH_SEQS", 192)),
-        page_size=128,
-        # The A/B must rank kernels at the production pool dtype (fp8
-        # pools move half the bytes of bf16).
-        kv_dtype="float8_e5m2" if kv_env in ("fp8", "fp8_e5m2",
-                                             "float8_e5m2") else "bfloat16",
-    )
-    print(choice)
-
-
 # Set when the quantized attempt produced a valid-but-not-clearly-winning
 # number: the bf16 ladder runs too, and the better line is emitted. A
 # module global (not a main() local) on purpose: the failure emitters —
@@ -287,7 +224,6 @@ def trim_plan(
     remaining_s: Optional[float],
     *,
     quant_s: float,
-    ab_s: float,
     ladder_extra_s: float,
     spec_s: float,
     tp_overlap_s: float,
@@ -305,7 +241,6 @@ def trim_plan(
 
     - ``int4_ladder``: the int4+fp8 subprocess attempt (its timeout),
     - ``quant``: the int8+fp8 subprocess attempt (cost: its timeout),
-    - ``kernel_ab``: the decode-kernel A/B subprocess (its timeout),
     - ``full_ladder``: every bf16 slot/decode-block candidate beyond the
       proven config (``ladder_extra_s`` extra build+measure cost),
     - ``spec_ladder``: the speculative-decoding rung at the winning
@@ -349,9 +284,9 @@ def trim_plan(
     most failure modes), then the spec rung (workload-dependent
     acceptance — the most likely rung to measure a loss), then the
     mixed-step rung (steady-state decode on synchronized bench arrivals
-    understates it), then the extra ladder rungs, then the kernel A/B;
-    each phase runs only if everything still planned fits the remaining
-    budget. No deadline (None) runs everything.
+    understates it), then the extra ladder rungs; each phase runs only if
+    everything still planned fits the remaining budget. No deadline
+    (None) runs everything.
     """
     # (name, cost) in DROP order: most speculative first.
     phases = (
@@ -365,7 +300,6 @@ def trim_plan(
         ("spec_ladder", spec_s),
         ("mixed_step", mixed_s),
         ("full_ladder", ladder_extra_s),
-        ("kernel_ab", ab_s),
     )
     plan = {name: True for name, _ in phases}
     if remaining_s is None:
@@ -465,48 +399,14 @@ def _fp8_kernel_canary() -> None:
     bt = jnp.arange(1, 1 + S * PPS, dtype=jnp.int32).reshape(S, PPS)
     cl = jnp.asarray([1, 40, 128, 129, 200, 255, 300, 332], jnp.int32)
     li = jnp.asarray(1, jnp.int32)
-    kern, fused = dispatch.decode_kernel_plan(H, NKV)
-    if fused:
-        # v3 writes the step's fp8 K/V rows in-kernel — a DISTINCT code
-        # path from plain decode; validate exactly what the engine runs.
-        kn = (jax.random.normal(jax.random.key(8), (S, NKV, D),
-                                jnp.float32) * 0.3).astype(jnp.bfloat16)
-        vn = (jax.random.normal(jax.random.key(9), (S, NKV, D),
-                                jnp.float32) * 0.3).astype(jnp.bfloat16)
-        # Reference FIRST: the fused kernel aliases (donates) the pool
-        # buffers, so kp/vp are unusable after it runs.
-        positions = (cl - 1)[:, None]
-        kp_r, vp_r = xla_ops.write_kv_pages(
-            kp, vp, kn[:, None], vn[:, None], bt, positions, layer=li
-        )
-        ref = xla_ops.paged_decode_attention(
-            q, kp_r, vp_r, bt, cl, scale=D**-0.5, layer=li
-        )
-        jax.block_until_ready(ref)
-        out_p, kp_p, vp_p = dispatch.decode_attention_fused_write(
-            q, kp, vp, kn, vn, bt, cl, scale=D**-0.5, layer=li
-        )
-        for name, got, want in (("K", kp_p, kp_r), ("V", vp_p, vp_r)):
-            pool_err = np.max(
-                np.abs(
-                    np.asarray(got[li, 1:], np.float32)
-                    - np.asarray(want[li, 1:], np.float32)
-                )
-            )
-            if pool_err > 0:
-                raise RuntimeError(
-                    f"fp8 v3 canary: fused {name} write diverged "
-                    f"(|diff| {pool_err})"
-                )
-        err = np.max(np.abs(np.asarray(out_p, np.float32) - np.asarray(ref, np.float32)))
-    else:
-        out_p = dispatch.decode_attention(
-            q, kp, vp, bt, cl, scale=D**-0.5, backend="pallas", layer=li
-        )
-        ref = xla_ops.paged_decode_attention(
-            q, kp, vp, bt, cl, scale=D**-0.5, layer=li
-        )
-        err = np.max(np.abs(np.asarray(out_p, np.float32) - np.asarray(ref, np.float32)))
+    kern = dispatch.decode_kernel_plan(H, NKV, kp.dtype)
+    out_p = dispatch.decode_attention(
+        q, kp, vp, bt, cl, scale=D**-0.5, backend="pallas", layer=li
+    )
+    ref = xla_ops.paged_decode_attention(
+        q, kp, vp, bt, cl, scale=D**-0.5, layer=li
+    )
+    err = np.max(np.abs(np.asarray(out_p, np.float32) - np.asarray(ref, np.float32)))
     if not np.isfinite(err) or err > 0.05:
         raise RuntimeError(
             f"fp8 decode-kernel canary failed ({kern}): |pallas - xla| = {err}"
@@ -519,17 +419,15 @@ def _fp8_kernel_canary() -> None:
 
 def main() -> None:
     # Children FIRST, while no backend is initialised in this process: a
-    # chip belongs to one process, so each probing child must own it
+    # chip belongs to one process, so each quantized attempt must own it
     # briefly and exit before the parent claims it.
-    ab_choice = None
     # Budget-aware trimming: on a short remaining deadline the
     # speculative phases are dropped (quant attempt first, then extra
-    # ladder rungs, then the kernel A/B) so the run always lands a real
-    # bf16 measurement instead of a watchdog 0.0.
+    # ladder rungs) so the run always lands a real bf16 measurement
+    # instead of a watchdog 0.0.
     plan = trim_plan(
         _remaining_budget(),
         quant_s=float(os.environ.get("LLMQ_BENCH_QUANT_TIMEOUT", 1500)),
-        ab_s=float(os.environ.get("LLMQ_BENCH_AB_TIMEOUT", 420)),
         # Extra rungs beyond the proven config: one more slot count and
         # the decode-block ladder, ~4 min of builds+measures each.
         ladder_extra_s=720.0,
@@ -573,17 +471,11 @@ def main() -> None:
         and not os.environ.get("LLMQ_BENCH_KV_DTYPE")
         and not os.environ.get("LLMQ_BENCH_PRESET")
     )
-    ab_eligible = plan["kernel_ab"] and not os.environ.get(
-        "LLMQ_DECODE_KERNEL"
-    )
-    if os.environ.get("JAX_PLATFORMS", "") != "cpu" and (
-        quant_eligible or ab_eligible
-    ):
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
         # Quantized-config attempts first (each owns the chip start to
-        # finish, including its own kernel A/B at the fp8 pool dtype).
-        # int8 always; the int4 ladder rung when the budget kept it (it
-        # is the first phase trimmed) and not opted out. Skipped when
-        # the operator pinned any of the knobs they would override —
+        # finish). int8 always; the int4 ladder rung when the budget kept
+        # it (it is the first phase trimmed) and not opted out. Skipped
+        # when the operator pinned any of the knobs they would override —
         # explicit settings mean explicit intent.
         if quant_eligible:
             attempts = [_try_quantized_headline("int8")]
@@ -611,11 +503,6 @@ def main() -> None:
                 )
                 global _QUANT_FALLBACK
                 _QUANT_FALLBACK = quant
-        if ab_eligible:
-            ab_choice = pick_decode_kernel()
-            # Export immediately: everything downstream — the fp8
-            # canary included — must trace with the measured winner.
-            os.environ["LLMQ_DECODE_KERNEL"] = ab_choice
 
     jax, devices = init_devices()
 
@@ -1595,7 +1482,7 @@ def main() -> None:
             if kv_env not in ("", "auto")
             else {}
         ),
-        "decode_kernel": ab_choice or os.environ.get("LLMQ_DECODE_KERNEL") or "live",
+        "decode_kernel": win_stats["decode_kernel"],
     }
     if (
         _QUANT_FALLBACK is not None
@@ -1606,9 +1493,7 @@ def main() -> None:
     _emit(payload)
 
 
-if __name__ == "__main__" and "--kernel-ab-probe" in sys.argv:
-    _kernel_ab_probe_main()
-elif __name__ == "__main__":
+if __name__ == "__main__":
     # Whole-run watchdog: a device call can block in C for ever (first jit
     # compile / dispatch). If the run exceeds the deadline, the failure
     # JSON still gets emitted before exiting.

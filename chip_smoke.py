@@ -256,15 +256,13 @@ def phase_kernels(mode: Mode) -> dict:
     )
     out["paged_prefill"] = max_err(got, want, np.asarray(q_pos) >= 0)
 
-    # The decode kernel the worker will run (its probing child cannot run
-    # under this script — the script holds the chip — so that is the
-    # LLMQ_DECODE_KERNEL / default choice), through the engine's dispatch.
-    kernel, fused = dispatch.decode_kernel_plan(H, NKV, backend="pallas")
-    check(not fused, "the smoke covers the unfused decode kernels (live, v1, v2)")
+    # The decode schedule the worker will run on a pool of this shape,
+    # through the engine's dispatch.
     pages_per_seq = 8192 // PAGE + 1  # the worker's default max_model_len
     live = 8  # pages a slot may touch here: contexts up to 8 pages
     P = 1 + S * live
     kp, vp = rnd((L, P, PAGE, NKV, D)), rnd((L, P, PAGE, NKV, D))
+    kernel = dispatch.decode_kernel_plan(H, NKV, kp.dtype, backend="pallas")
     bt = np.zeros((S, pages_per_seq), np.int32)
     bt[:, :live] = np.arange(1, P).reshape(S, live)
     ctx = jax.random.randint(next(keys), (S,), 1, live * PAGE, jnp.int32)
@@ -557,7 +555,7 @@ async def _serve(mode: Mode, meter, n_batch: int, n_http_prompts: int) -> dict:
         # qwen2.5-7b at tp=4 leaves one kv head a shard: XLA attention by
         # the shape rule of ops/dispatch._tp_heads_ok. Everything else
         # the smoke serves runs the decode kernel.
-        kernels = ("xla",) if mode.chips == 4 else ("live", "v1", "v2", "v3")
+        kernels = ("xla",) if mode.chips == 4 else ("live", "v1")
         check(
             stats["decode_kernel"] in kernels,
             f"decode kernel {stats['decode_kernel']!r}, not one of {kernels}",
@@ -611,15 +609,6 @@ async def _serve(mode: Mode, meter, n_batch: int, n_http_prompts: int) -> dict:
         "faults": faults,
         "attn_backend": stats["attn_backend"],
         "decode_kernel": stats["decode_kernel"],
-        # The worker's probing child needs the chip, which this script
-        # holds from its first phase: the worker refuses to start it (and
-        # says so at ERROR level), so no seconds are ever reported here.
-        "decode_kernel_probe": (
-            f"{stats['decode_kernel_probe_s']} s"
-            if "decode_kernel_probe_s" in stats
-            else "not run: no chip" if mode.rehearse_cpu
-            else "not run: this process holds the chip"
-        ),
         "page_size": stats["page_size"],
         "num_pages": stats["num_pages"],
         "slots": stats["slots"],

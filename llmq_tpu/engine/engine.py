@@ -49,8 +49,7 @@ TPU-native design differs from vLLM's CUDA core on purpose:
   the model step and the sampler fuse into one executable.
 - **Memory dtypes.** Weight-only int8 (``models/quant.py``) and an fp8
   (float8_e5m2) KV cache (``kv_dtype="fp8"``) are first-class: pools
-  and params stay narrow in HBM, kernels convert on-chip, and the
-  decode-kernel autotune calibrates at the production pool dtype.
+  and params stay narrow in HBM and kernels convert on-chip.
 
 An ``AsyncEngine`` wrapper runs the step loop on a dedicated thread and
 bridges to asyncio futures, mirroring the AsyncLLMEngine surface the
@@ -826,9 +825,10 @@ class EngineCore:
         # rules of ops/dispatch._tp_heads_ok) runs no custom call, and
         # the pin would only force the compiler's own compact layout
         # through a padded copy: its pool stays in the default layout.
-        kernel, _ = _dispatch.decode_kernel_plan(
+        kernel = _dispatch.decode_kernel_plan(
             model_config.num_heads,
             model_config.num_kv_heads,
+            self.cfg.kv_dtype,
             mesh=self.mesh,
             backend=self.model.attn_backend,
         )
@@ -5228,10 +5228,12 @@ class EngineCore:
         s = self.scheduler.stats()
         from llmq_tpu.ops import dispatch as _dispatch
 
-        kern, _fused = _dispatch.decode_kernel_plan(
+        kern = _dispatch.decode_kernel_plan(
             self.model_config.num_heads,
             self.model_config.num_kv_heads,
+            self.cfg.kv_dtype,
             mesh=self.mesh,
+            backend=self.model.attn_backend,
         )
         s.update(
             prompt_tokens=self.total_prompt_tokens,
@@ -5295,8 +5297,9 @@ class EngineCore:
             page_size=self.cfg.page_size,
             num_pages=self.scheduler.config.num_pages,
             kv_pool_bytes=self.kv_pool_bytes,
-            # Resolved at build time (env pin / config / autotune) — may
-            # differ from cfg.tp_overlap ("auto", or forced off on tp=1).
+            # Resolved at build time (LLMQ_TP_OVERLAP / config / its probe)
+            # — may differ from cfg.tp_overlap ("auto", or forced off on
+            # tp=1).
             tp_overlap=self.tp_overlap,
             # Latency percentiles (ms; None until the histogram has data).
             ttft_p50_ms=to_ms(self.ttft_hist.percentile(0.50)),
@@ -5313,18 +5316,18 @@ class EngineCore:
             s["moe_experts_hit"] = self.moe_experts_hit
         if self.cfg.spec_tokens > 0:
             # What speculation actually dispatches: the multi-query
-            # verify resolves through its own plan, not the decode ladder.
+            # verify resolves through its own plan, not the decode one.
             s["verify_kernel"] = _dispatch.verify_kernel_plan(
                 self.model_config.num_heads,
                 self.model_config.num_kv_heads,
                 mesh=self.mesh,
-            )[0]
+            )
         if self.mixed_step == "on":
             s["mixed_kernel"] = _dispatch.mixed_kernel_plan(
                 self.model_config.num_heads,
                 self.model_config.num_kv_heads,
                 mesh=self.mesh,
-            )[0]
+            )
         if self.prefix_store is not None:
             s.update(self.prefix_store.stats())
         # Pipeline parallelism (superset-only: pp=1 engines publish

@@ -1,27 +1,21 @@
-"""On-hardware A/B selection of the paged-decode attention kernel.
+"""On-hardware A/B probes for ``tp_overlap=auto`` and the int4 matmul.
 
-Four candidates exist (``ops/pallas_attention.py``): live (the default:
-a schedule that visits only live pages), v1 (BlockSpec page pipeline over
-a fixed grid), v2 (chunked manual-DMA over a fixed grid) and v3 (v2 plus
-the step's KV write fused into the kernel). Which one wins depends on the
-chip generation, page size and pool residency — so the choice is made by
-*measuring* on the deployment hardware, not hardcoded. Both ``bench.py``
-and the TPU worker (``workers/tpu_worker.py``) call this module so
-production workers get the same self-calibration the benchmark does —
-throughput must not depend on an operator knowing ``LLMQ_DECODE_KERNEL``.
+Which of two programs wins (GSPMD's all-reduces or the ppermute rings;
+the XLA dequantize-then-matmul or the dequant-in-VMEM kernel) depends on
+the chip generation and the shapes — so ``auto`` is resolved by
+*measuring* on the deployment hardware, not hardcoded.
 
-The probe always runs in a SUBPROCESS (``python -m
-llmq_tpu.engine.kernel_autotune``): a chip belongs to one process at a
-time, so the probing child must own it briefly and exit *before* the
-calling process initialises its JAX backend. A caller that already holds
-the chip gets no child (it could only fail or hang): the drivers say so
-at ERROR level and the engine starts on the default kernel.
+A probe always runs in a SUBPROCESS (``python -m
+llmq_tpu.engine.kernel_autotune <mode> ...``): a chip belongs to one
+process at a time, so the probing child must own it briefly and exit
+*before* the calling process initialises its JAX backend. A caller that
+already holds the chip gets no child (it could only fail or hang): the
+drivers say so at ERROR level and the engine starts on the default.
 
-An explicit ``LLMQ_DECODE_KERNEL`` env var always wins. A probe that
-fails — the child crashed, a candidate kernel did not compile, the
+A probe that fails — the child crashed, a candidate did not compile, the
 budget ran out — is reported at ERROR level with the child's last
 output, never as a quiet win; the engine then starts on the default
-(live / off / xla) and ``stats()["decode_kernel"]`` shows what runs.
+(off / xla).
 """
 
 from __future__ import annotations
@@ -32,176 +26,6 @@ import subprocess
 import sys
 import time
 from typing import Optional
-
-
-# The unset ``LLMQ_DECODE_KERNEL`` first: what a failed probe answers.
-DECODE_KERNELS = ("live", "v1", "v2", "v3")
-
-
-def run_ab(
-    *,
-    num_heads: int,
-    num_kv_heads: int,
-    head_dim: int,
-    num_layers: int,
-    max_seqs: int,
-    page_size: int,
-    kv_dtype: str = "bfloat16",
-) -> tuple:
-    """In-process kernel A/B (the child-process body).
-
-    The pool must NOT fit in VMEM (~128 MB) or every kernel looks
-    infinitely fast (round-3 finding); ~300 MB per side with per-layer
-    distinct pages defeats caching while leaving the caller's HBM alone.
-    Returns ``("live", False)`` on a CPU run (nothing to measure). On a TPU
-    any failure raises — a candidate that does not compile is a failure
-    of the probe (the child exits non-zero), not a lost A/B.
-    """
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from llmq_tpu.ops.attention import write_kv_pages
-    from llmq_tpu.ops.pallas_attention import (
-        paged_decode_attention_live,
-        paged_decode_attention_pallas,
-        paged_decode_attention_pallas_v2,
-        paged_decode_attention_pallas_v3,
-    )
-
-    if jax.devices()[0].platform != "tpu":
-        return "live", False  # Pallas candidates only differ on real TPUs
-
-    H, NKV, D = num_heads, num_kv_heads, head_dim
-    L = num_layers
-    S = max_seqs
-    PAGE = page_size
-    PPS = 4
-    # The v1-vs-v2/v3 trade is KV-bandwidth-bound, so the probe pool
-    # must use the PRODUCTION pool dtype: an fp8 cache moves half the
-    # bytes of bf16 and can rank the kernels differently.
-    kvd = jnp.dtype(kv_dtype)
-    per_page = PAGE * NKV * D * kvd.itemsize
-    ctx = min(PPS * PAGE - 2, int(PAGE * 2.6))
-    # Pool sizing. Two constraints pull apart: the pool must NOT fit
-    # in VMEM (~128 MB) or every kernel looks infinitely fast, and
-    # the page each sequence WRITES must be distinct across sequences
-    # (all three candidates write the step's KV row; a collision on
-    # the written page makes the XLA scatter — one winner — and the
-    # fused v3 kernel — own row each — legitimately disagree,
-    # spuriously tripping the numerics guard). READ pages may collide
-    # freely: TPU DMAs stream from HBM either way, so timing is
-    # unaffected. Prefer fully-distinct pages when a probe-sized HBM
-    # budget allows; otherwise distinct written pages only (GQA
-    # models with few KV heads have small pages — 300 MB is only
-    # ~127 pages at qwen2.5-3b shapes, far under S*PPS).
-    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
-    budget = int(0.4 * limit) if limit else 6 * 2**30
-    per_pool_page = 2 * L * per_page  # K and V sides, all layers
-    p_full = S * PPS + 1
-    p_budget = max(PPS * 4, min(budget // max(1, per_pool_page), 4096))
-    # VMEM-defeating floor (~300 MiB pool): below it every kernel
-    # times as cache-resident and the ranking is meaningless
-    # (round-3 finding).
-    p_floor = 300 * 2**20 // max(1, per_pool_page)
-    wcol = (ctx - 1) // PAGE  # the page column the step writes into
-    rng = np.random.default_rng(0)
-    if p_budget >= p_full:
-        P = max(p_full, p_floor)
-        perm = rng.permutation(np.arange(1, P))[: S * PPS]
-        bt = jnp.asarray(perm.reshape(S, PPS).astype(np.int32))
-    elif p_budget >= S + 1:
-        P = max(p_budget, p_floor, S + 1)
-        pages = rng.integers(1, P, size=(S, PPS))
-        pages[:, wcol] = rng.permutation(np.arange(1, P))[:S]
-        bt = jnp.asarray(pages.astype(np.int32))
-    else:
-        print(
-            f"kernel-autotune: pool budget {budget >> 20} MiB < "
-            f"{S + 1} pages x {per_pool_page >> 10} KiB; skipping A/B",
-            file=sys.stderr,
-        )
-        return "live", False
-    def rnd(seed, shape, dtype=jnp.bfloat16):
-        return jax.random.normal(jax.random.key(seed), shape, jnp.float32).astype(dtype)
-
-    q = rnd(0, (S, H, D))
-    kp = rnd(1, (L, P, PAGE, NKV, D), kvd)
-    vp = rnd(2, (L, P, PAGE, NKV, D), kvd)
-    kn = rnd(3, (S, NKV, D))
-    vn = rnd(4, (S, NKV, D))
-    cl = jnp.full((S,), ctx, jnp.int32)
-    positions = (cl - 1)[:, None]
-    w = jnp.asarray([1 << 30], jnp.int32)
-    scale = D**-0.5
-
-    # v1/v2 pay the separate XLA KV scatter the engine runs before
-    # them; v3 writes in-kernel. Time each candidate as the engine
-    # would actually run it, so the ranking is apples-to-apples.
-    # Donation matters: without it XLA must preserve the caller's
-    # pool, which forces a full-pool copy around v3's in-place alias
-    # and penalizes it artificially.
-    @functools.partial(
-        jax.jit, static_argnames=("which",), donate_argnums=(0, 1)
-    )
-    def step(kp, vp, li, *, which):
-        if which == "v3":
-            out, kp, vp = paged_decode_attention_pallas_v3(
-                q, kp, vp, kn, vn, bt, cl, w, li, scale=scale
-            )
-            return out, kp, vp
-        kp, vp = write_kv_pages(
-            kp, vp, kn[:, None], vn[:, None], bt, positions, layer=li
-        )
-        kern = {
-            "live": paged_decode_attention_live,
-            "v1": paged_decode_attention_pallas,
-            "v2": paged_decode_attention_pallas_v2,
-        }[which]
-        return kern(q, kp, vp, bt, cl, w, li, scale=scale), kp, vp
-
-    def timeit(which, n=2):
-        nonlocal kp, vp
-        for li in range(L):
-            out, kp, vp = step(kp, vp, jnp.int32(li), which=which)
-        jax.block_until_ready(out)
-        t0 = time.monotonic()
-        for _ in range(n):
-            for li in range(L):
-                out, kp, vp = step(kp, vp, jnp.int32(li), which=which)
-            jax.block_until_ready(out)
-        return (time.monotonic() - t0) / (n * L)
-
-    times = {which: timeit(which) for which in DECODE_KERNELS}
-    # Numerics guard: per-candidate agreement with v1. Each guard call
-    # rewrites the same (kn, vn) row at the same position, so the pool
-    # state is identical for all three.
-    outs = {}
-    for which in DECODE_KERNELS:
-        o, kp, vp = step(kp, vp, jnp.int32(0), which=which)
-        outs[which] = o.astype(jnp.float32)
-    diffs = {
-        a: float(jnp.max(jnp.abs(outs[a] - outs["v1"])))
-        for a in DECODE_KERNELS
-    }
-    # The default holds unless another beats it by 8 % (and v1 holds
-    # against a default that disagrees with it).
-    choice = "live" if diffs["live"] < 0.05 else "v1"
-    for cand in DECODE_KERNELS[1:]:
-        if times[cand] < 0.92 * times[choice] and diffs[cand] < 0.05:
-            choice = cand
-    for arr in (q, kp, vp, kn, vn, *outs.values()):
-        arr.delete()
-    shown = " ".join(f"{k}={v*1e3:.3f}ms" for k, v in times.items())
-    dshown = " ".join(f"{k}|diff|={v:.2e}" for k, v in diffs.items())
-    print(
-        f"kernel-autotune: decode A/B {shown} per layer ({dshown}) "
-        f"-> {choice}",
-        file=sys.stderr,
-    )
-    return choice, True
 
 
 def run_tp_overlap_ab(
@@ -472,13 +296,14 @@ def autotune_tp_overlap(
 ) -> Optional[str]:
     """Subprocess A/B driver for ``tp_overlap=auto``.
 
-    Same contract as :func:`autotune_decode_kernel`: returns the winning
-    mode ("on"/"off"), or ``None`` when the probe does not apply (a CPU
-    run, ``LLMQ_KERNEL_AUTOTUNE=0``) or cannot run (this process holds
-    the chip — reported loudly); a failed or timed-out probe is reported
-    loudly and returns "off" (the literal-GSPMD default). Deliberately does NOT short-circuit on ``LLMQ_TP_OVERLAP``
-    — env precedence belongs to ``ops/dispatch.resolve_tp_overlap``,
-    whose ``auto`` branch only reaches here when no pin is set. Call it
+    Returns the winning mode ("on"/"off"), or ``None`` when the probe
+    does not apply (a CPU run, ``LLMQ_KERNEL_AUTOTUNE=0``) or cannot run
+    (this process holds the chip — reported loudly); a failed or
+    timed-out probe is reported loudly and returns "off" (the
+    literal-GSPMD default). Deliberately does NOT short-circuit on
+    ``LLMQ_TP_OVERLAP`` — env precedence belongs to
+    ``ops/dispatch.resolve_tp_overlap``, whose ``auto`` branch only
+    reaches here when no pin is set. Call it
     BEFORE the parent initialises its backend (the worker pattern).
     """
     if os.environ.get("LLMQ_KERNEL_AUTOTUNE", "1").lower() in ("0", "false"):
@@ -496,53 +321,6 @@ def autotune_tp_overlap(
         ("on", "off"),
         "off",
         "tp_overlap",
-        timeout_s,
-        logger,
-    )
-
-
-def autotune_decode_kernel(
-    *,
-    num_heads: int,
-    num_kv_heads: int,
-    head_dim: int,
-    num_layers: int,
-    max_seqs: int = 192,
-    page_size: int = 128,
-    kv_dtype: str = "bfloat16",
-    timeout_s: Optional[float] = None,
-    logger=None,
-) -> Optional[str]:
-    """Subprocess A/B driver for callers that have NOT yet initialised a
-    JAX backend (one process per chip — see module docstring).
-
-    Returns the winning kernel name, or ``None`` when the probe does not
-    apply (explicit ``LLMQ_DECODE_KERNEL`` set, a CPU run, or
-    ``LLMQ_KERNEL_AUTOTUNE=0``) or cannot run (this process holds the
-    chip — reported loudly). A failed or timed-out probe is reported
-    loudly and returns the default, ``"live"``. The caller is expected to
-    export the choice via ``LLMQ_DECODE_KERNEL`` before building its
-    engine.
-    """
-    if os.environ.get("LLMQ_DECODE_KERNEL"):
-        return None
-    if os.environ.get("LLMQ_KERNEL_AUTOTUNE", "1").lower() in ("0", "false"):
-        return None
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        return None  # CPU runs take the XLA attention path anyway
-    return _run_probe_child(
-        [
-            str(num_heads),
-            str(num_kv_heads),
-            str(head_dim),
-            str(num_layers),
-            str(max_seqs),
-            str(page_size),
-            str(kv_dtype),
-        ],
-        DECODE_KERNELS,
-        DECODE_KERNELS[0],
-        "decode kernel",
         timeout_s,
         logger,
     )
@@ -572,35 +350,21 @@ def cache_path_from_env():
     return Path(env) if env else DEFAULT_CACHE_DIR / "autotune.json"
 
 
-def _cache_key(shapes: tuple, identity: str, kv_dtype: str) -> str:
-    h, kv, d, layers, seqs, page = shapes
-    return (
-        f"decode:h{h}:kv{kv}:d{d}:l{layers}:s{seqs}:p{page}"
-        f":{kv_dtype}:{identity}"
-    )
-
-
 def _tp_overlap_cache_key(
     hidden: int, inter: int, seqs: int, tp: int, dtype: str, identity: str
 ) -> str:
     return f"tpovl:h{hidden}:i{inter}:s{seqs}:tp{tp}:{dtype}:{identity}"
 
 
-def resolve_choice(
-    shapes: tuple, identity: str, measure, kv_dtype: str = "bfloat16",
-    *, key: Optional[str] = None, valid: tuple = DECODE_KERNELS
-) -> str:
+def resolve_choice(measure, *, key: str, valid: tuple) -> str:
     """Cache-or-measure for the probing child. ``measure()`` must return
     ``(choice, measured)`` — only MEASURED results are ever stored (the
     A/B's internal failure fallbacks must not pin a stale default).
-
-    ``key``/``valid`` generalize the cache beyond the decode-kernel probe
-    (the tp-overlap A/B passes its own key and ``("on", "off")``);
-    defaults keep the original decode-kernel behaviour."""
+    ``key`` names the probe, its shapes and the measuring chip + jax
+    version; a cached entry outside ``valid`` is measured again."""
     import json
 
     path = cache_path_from_env()
-    key = key if key is not None else _cache_key(shapes, identity, kv_dtype)
     if path is not None and path.exists():
         try:
             entry = json.loads(path.read_text()).get(key)
@@ -660,10 +424,7 @@ def _main() -> None:
 
         print(
             resolve_choice(
-                (),
-                identity,
                 measure_overlap,
-                dtype,
                 key=_tp_overlap_cache_key(
                     hidden, inter, seqs, tp, dtype, identity
                 ),
@@ -691,8 +452,6 @@ def _main() -> None:
 
         print(
             resolve_choice(
-                (),
-                identity,
                 measure_int4,
                 key=_int4_matmul_cache_key(
                     hidden, inter, seqs, group, identity
@@ -702,24 +461,7 @@ def _main() -> None:
         )
         return
 
-    shapes = tuple(int(a) for a in sys.argv[1:7])
-    kv_dtype = sys.argv[7] if len(sys.argv) > 7 else "bfloat16"
-    h, kv, d, layers, seqs, page = shapes
-    dev = jax.devices()[0]
-    identity = f"{dev.device_kind or dev.platform}/jax{jax.__version__}"
-
-    def measure():
-        return run_ab(
-            num_heads=h,
-            num_kv_heads=kv,
-            head_dim=d,
-            num_layers=layers,
-            max_seqs=seqs,
-            page_size=page,
-            kv_dtype=kv_dtype,
-        )
-
-    print(resolve_choice(shapes, identity, measure, kv_dtype))
+    sys.exit(f"kernel-autotune: want tp-overlap|int4-matmul: {sys.argv[1:]}")
 
 
 if __name__ == "__main__":

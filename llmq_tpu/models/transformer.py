@@ -694,7 +694,7 @@ class Transformer:
         per-row (``gather_idx``) to avoid an S·C logit grid. Returns
         (logits [S, V], k_pages, v_pages)."""
         cfg = self.config
-        kernel, _ = attn_dispatch.mixed_kernel_plan(
+        kernel = attn_dispatch.mixed_kernel_plan(
             cfg.num_heads, cfg.num_kv_heads, self.mesh, self.attn_backend
         )
         h, k_pages, v_pages = self._paged_chunk_trunk(
@@ -761,12 +761,11 @@ class Transformer:
         Scan-compatible by construction: a pure function of its array
         arguments (the engine's fused decode blocks run it K times
         inside one ``lax.scan`` with (k_pages, v_pages, state) as the
-        carry), and the only Python-level branching — the trace-time
-        kernel plan below — is a function of shapes and env alone, so
-        every scan iteration inlines the identical kernel choice.
-        Inactive slots write no KV in either plan: the XLA scatter
-        routes their positions to -1 (dropped) and the fused-write
-        kernel guards on ctx_incl == 0."""
+        carry), and the only Python-level branching — the kernel plan
+        ``decode_attention`` resolves at trace time — is a function of
+        shapes and the backend alone, so every scan iteration inlines
+        the identical kernel choice. Inactive slots write no KV: the
+        scatter routes their positions to -1 (dropped)."""
         cfg = self.config
         S = tokens.shape[0]
         inv_freq = compute_rope_inv_freq(cfg)
@@ -776,14 +775,6 @@ class Transformer:
         windows = self._window_for_layers()
         one_plus = cfg.model_type.startswith("gemma")
         ctx_incl = jnp.where(active, context_lens + 1, 0)
-        # Trace-time kernel plan: with the v3 (fused-write) kernel the XLA
-        # KV scatter is skipped — the kernel patches + persists the new
-        # row itself. ctx_incl already zeroes inactive slots, so the
-        # kernel's ctx>0 guard skips their writes (the scatter's -1
-        # position routing handled this for the XLA path).
-        _, fused_write = attn_dispatch.decode_kernel_plan(
-            cfg.num_heads, cfg.num_kv_heads, self.mesh, self.attn_backend
-        )
 
         def layer_fn(carry, xs):
             h, kps, vps = carry
@@ -792,35 +783,24 @@ class Transformer:
             q, k, v = self._qkv(lp, x[:, None, :], positions[:, None], inv_freq, li)
             # q/k/v: [S, 1, heads, d]. The KV stack is written and read
             # in place via the layer index — see prefill's layer_fn.
-            if fused_write:
-                attn_out, kps, vps = attn_dispatch.decode_attention_fused_write(
-                    q[:, 0], kps, vps, k[:, 0], v[:, 0],
-                    block_tables, ctx_incl,
-                    scale=cfg.attn_scale,
-                    sliding_window=window,
-                    softcap=cfg.attn_softcap,
-                    mesh=self.mesh,
+            with jax.named_scope("llmq.kv_write"):
+                kps, vps = attn_ops.write_kv_pages(
+                    kps, vps, k, v, block_tables, positions[:, None],
                     layer=li,
                 )
-            else:
-                with jax.named_scope("llmq.kv_write"):
-                    kps, vps = attn_ops.write_kv_pages(
-                        kps, vps, k, v, block_tables, positions[:, None],
-                        layer=li,
-                    )
-                attn_out = attn_dispatch.decode_attention(
-                    q[:, 0],
-                    kps,
-                    vps,
-                    block_tables,
-                    ctx_incl,
-                    scale=cfg.attn_scale,
-                    sliding_window=window,
-                    softcap=cfg.attn_softcap,
-                    mesh=self.mesh,
-                    backend=self.attn_backend,
-                    layer=li,
-                )
+            attn_out = attn_dispatch.decode_attention(
+                q[:, 0],
+                kps,
+                vps,
+                block_tables,
+                ctx_incl,
+                scale=cfg.attn_scale,
+                sliding_window=window,
+                softcap=cfg.attn_softcap,
+                mesh=self.mesh,
+                backend=self.attn_backend,
+                layer=li,
+            )
             h = self._finish_layer(lp, h, attn_out, li)
             return (h, kps, vps), None
 

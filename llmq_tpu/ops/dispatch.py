@@ -243,103 +243,63 @@ def chunked_prefill_attention(
         )
 
 
-# LLMQ_DECODE_KERNEL -> the unfused kernel behind the name. Unset means
-# "live": the schedule that visits only live pages. v1 and v2 walk a fixed
-# grid over every page place; v3 is v2 plus the fused KV write and exists
-# only on the decode_attention_fused_write path (a caller who scattered KV
-# separately gets its base, v2).
-_DECODE_KERNELS = {
-    "live": pk.paged_decode_attention_live,
-    "v1": pk.paged_decode_attention_pallas,
-    "v2": pk.paged_decode_attention_pallas_v2,
-    "v3": pk.paged_decode_attention_pallas_v2,
-}
-
-
-def _decode_kernel_name() -> str:
-    # Empty string = unset (the `VAR= cmd` shell idiom must mean default).
-    kern = (os.environ.get("LLMQ_DECODE_KERNEL") or "live").lower()
-    if kern not in _DECODE_KERNELS:
-        raise ValueError(
-            f"LLMQ_DECODE_KERNEL={kern!r} (want {'|'.join(_DECODE_KERNELS)})"
-        )
-    return kern
-
-
 def decode_kernel_plan(
-    n_heads: int, n_kv: int, mesh: Optional[Mesh] = None,
+    n_heads: int, n_kv: int, kv_dtype, mesh: Optional[Mesh] = None,
     backend: str = "auto",
-) -> tuple:
-    """(kernel_name, fused_write) the current env resolves to for these
-    shapes. ``fused_write`` (the v3 kernel) means the decode kernel writes
-    the step's new K/V row itself — the model must then SKIP its XLA
-    scatter and call :func:`decode_attention_fused_write` instead.
+) -> str:
+    """The decode-attention schedule a pool of this shape runs, and the
+    only place that chooses it: ``"xla"`` (the backend is not pallas, or
+    ``_tp_heads_ok`` refuses the head counts), ``"v1"`` (pallas, and a
+    shard's pool is padded on the chip, which only v1's BlockSpec pipeline
+    can read), else ``"live"``.
 
-    Deliberately a pure function of (shapes, mesh, env): it is consulted
-    at trace time from inside jitted step functions — including from
-    every iteration of the fused decode-block ``lax.scan`` — so it must
-    resolve identically on every call within one process or the scan
-    body would diverge between iterations."""
+    A pure function of its arguments and the backend: it is consulted at
+    trace time from inside jitted step functions, including from every
+    iteration of the fused decode-block ``lax.scan``, so it must resolve
+    identically on every call within one process or the scan body would
+    diverge between iterations."""
     backend = resolve_backend() if backend == "auto" else backend
-    kern = _decode_kernel_name()
     tp = _tp_degree(mesh)
-    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
-    if backend != "pallas" or not tp_ok:
-        return "xla", False
-    return kern, kern == "v3"
+    if backend != "pallas" or not _tp_heads_ok(n_heads, n_kv, tp):
+        return "xla"
+    return "v1" if pk.pool_rows_padded(n_kv // tp, kv_dtype) else "live"
 
 
 def verify_kernel_plan(
     n_heads: int, n_kv: int, mesh: Optional[Mesh] = None,
     backend: str = "auto",
-) -> tuple:
-    """(kernel_name, fused_write) the speculative verify step resolves to
-    for these shapes. Verify is multi-query decode — Q = spec_tokens+1
-    query positions per row against the paged cache — which is exactly
-    the chunked-prefill shape, so the plan mirrors
+) -> str:
+    """The kernel the speculative verify step resolves to for these
+    shapes. Verify is multi-query decode — Q = spec_tokens+1 query
+    positions per row against the paged cache — which is exactly the
+    chunked-prefill shape, so the plan mirrors
     :func:`chunked_prefill_attention`'s resolution (pallas paged-prefill
     kernel on TPU, XLA reference elsewhere) rather than the single-query
-    decode ladder. ``fused_write`` is always False: with Q > 1 a
-    candidate must attend its predecessors' fresh K/V, so the write has
-    to land (``write_kv_pages``) before the attention reads — the v3
-    single-row fused write cannot apply.
+    decode schedules.
 
     Same contract as :func:`decode_kernel_plan`: a pure function of
-    (shapes, mesh, env), consulted at trace time from every iteration of
-    the fused verify ``lax.scan``."""
+    (shapes, mesh, backend), consulted at trace time from every iteration
+    of the fused verify ``lax.scan``."""
     backend = resolve_backend() if backend == "auto" else backend
     tp = _tp_degree(mesh)
-    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
-    if backend != "pallas" or not tp_ok:
-        return "xla", False
-    return "chunked_prefill", False
+    if backend != "pallas" or not _tp_heads_ok(n_heads, n_kv, tp):
+        return "xla"
+    return "chunked_prefill"
 
 
 def mixed_kernel_plan(
     n_heads: int, n_kv: int, mesh: Optional[Mesh] = None,
     backend: str = "auto",
-) -> tuple:
-    """(kernel_name, fused_write) for the fused mixed prefill+decode
-    step: one [S, C] query grid where every active decode row carries a
-    single position (a one-element leading run at its context length)
-    and the piggybacked prefill row carries its budgeted chunk segment
-    (a leading contiguous run at the chunk offset) — BOTH forms satisfy
-    the leading-contiguous-run contract of
-    :func:`chunked_prefill_attention`, so the mixed step scores through
-    the same paged path speculative ``verify`` already uses, and the
-    plan mirrors :func:`verify_kernel_plan`. ``fused_write`` is always
-    False: the prefill segment writes C rows of K/V that its own later
-    positions must attend (``write_kv_pages`` lands before the read).
-
-    Same contract as :func:`decode_kernel_plan`: a pure function of
-    (shapes, mesh, env), consulted at trace time from every iteration of
-    the fused mixed-block ``lax.scan``."""
-    backend = resolve_backend() if backend == "auto" else backend
-    tp = _tp_degree(mesh)
-    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
-    if backend != "pallas" or not tp_ok:
-        return "xla", False
-    return "chunked_prefill", False
+) -> str:
+    """The kernel of the fused mixed prefill+decode step: one [S, C]
+    query grid where every active decode row carries a single position (a
+    one-element leading run at its context length) and the piggybacked
+    prefill row carries its budgeted chunk segment (a leading contiguous
+    run at the chunk offset) — BOTH forms satisfy the
+    leading-contiguous-run contract of :func:`chunked_prefill_attention`,
+    so the mixed step scores through the same paged path speculative
+    ``verify`` already uses, and the plan is :func:`verify_kernel_plan`'s."""
+    return verify_kernel_plan(n_heads, n_kv, mesh, backend)
 
 
 def resolve_tp_overlap(
@@ -359,8 +319,8 @@ def resolve_tp_overlap(
     Unlike the kernel plans above, this is resolved ONCE at engine build
     time and carried as a static field on the ``Transformer`` — so the
     ``auto`` branch is free to run a subprocess A/B (it never executes at
-    trace time). Precedence mirrors ``decode_kernel``: the
-    ``LLMQ_TP_OVERLAP`` env pin wins over the config value, and any mesh
+    trace time). The ``LLMQ_TP_OVERLAP`` env pin wins over the config
+    value, and any mesh
     without a tp axis degenerates to ``off`` (there is no all-reduce to
     hide).
     """
@@ -392,66 +352,6 @@ def resolve_tp_overlap(
     return choice if choice in ("on", "off") else "off"
 
 
-def decode_attention_fused_write(
-    q: jnp.ndarray,  # [S, n_heads, d]
-    k_pages: jnp.ndarray,  # [L, P, page, n_kv, d] (or unstacked)
-    v_pages: jnp.ndarray,
-    k_new: jnp.ndarray,  # [S, n_kv, d] — this step's fresh K/V rows
-    v_new: jnp.ndarray,
-    block_tables: jnp.ndarray,
-    context_lens: jnp.ndarray,  # [S] INCLUDING the new token
-    *,
-    scale: float,
-    sliding_window=None,
-    softcap: Optional[float] = None,
-    mesh: Optional[Mesh] = None,
-    layer: Optional[jnp.ndarray] = None,
-) -> tuple:
-    """v3 decode path: attention + in-kernel KV write in one pallas call
-    (see paged_decode_attention_pallas_v3). Only valid when
-    :func:`decode_kernel_plan` returned ``fused_write=True`` — the caller
-    must not have scattered the new rows. Returns (out, k_pages, v_pages).
-    """
-    stacked = k_pages.ndim == 5
-    window = _window_scalar(sliding_window)
-    li = (
-        jnp.asarray(layer, jnp.int32).reshape(1)
-        if layer is not None
-        else jnp.zeros((1,), jnp.int32)
-    )
-
-    def call(q, kp, vp, kn, vn, bt, cl, window, li):
-        return pk.paged_decode_attention_pallas_v3(
-            q, kp, vp, kn, vn, bt, cl, window, li,
-            scale=scale, softcap=softcap, interpret=_interpret(),
-        )
-
-    tp = _tp_degree(mesh)
-    if tp > 1:
-        assert mesh is not None
-        kv_spec = (
-            P(None, None, None, TP_AXIS, None)
-            if stacked
-            else P(None, None, TP_AXIS, None)
-        )
-        row_spec = P(None, TP_AXIS, None)
-        call = _shard_over_heads(
-            call,
-            mesh=mesh,
-            in_specs=(
-                P(None, TP_AXIS, None),
-                kv_spec, kv_spec, row_spec, row_spec,
-                P(), P(), P(), P(),
-            ),
-            out_specs=(P(None, TP_AXIS, None), kv_spec, kv_spec),
-        )
-    with jax.named_scope("llmq.attn.paged_decode"):
-        return call(
-            q, k_pages, v_pages, k_new, v_new, block_tables, context_lens,
-            window, li,
-        )
-
-
 def decode_attention(
     q: jnp.ndarray,  # [S, n_heads, d]
     k_pages: jnp.ndarray,  # [Pg, page_size, n_kv, d] or [L, Pg, ...]
@@ -466,12 +366,11 @@ def decode_attention(
     backend: str = "auto",
     layer: Optional[jnp.ndarray] = None,  # required when pages are stacked
 ) -> jnp.ndarray:
-    backend = resolve_backend() if backend == "auto" else backend
     stacked = k_pages.ndim == 5
-    n_heads, n_kv = q.shape[1], k_pages.shape[-2]
-    tp = _tp_degree(mesh)
-    tp_ok = _tp_heads_ok(n_heads, n_kv, tp)
-    if backend != "pallas" or not tp_ok:
+    plan = decode_kernel_plan(
+        q.shape[1], k_pages.shape[-2], k_pages.dtype, mesh, backend
+    )
+    if plan == "xla":
         with jax.named_scope("llmq.attn.xla"):
             return xla_ops.paged_decode_attention(
                 q, k_pages, v_pages, block_tables, context_lens,
@@ -485,7 +384,11 @@ def decode_attention(
         else jnp.zeros((1,), jnp.int32)
     )
 
-    kern = _DECODE_KERNELS[_decode_kernel_name()]
+    kern = (
+        pk.paged_decode_attention_pallas
+        if plan == "v1"
+        else pk.paged_decode_attention_live
+    )
 
     def call(q, kp, vp, bt, cl, window, li):
         return kern(
@@ -493,6 +396,7 @@ def decode_attention(
             scale=scale, softcap=softcap, interpret=_interpret(),
         )
 
+    tp = _tp_degree(mesh)
     if tp > 1:
         assert mesh is not None
         kv_spec = (

@@ -248,441 +248,10 @@ def paged_decode_attention_pallas(
 
 
 # ---------------------------------------------------------------------------
-# Paged decode v2: chunked manual-DMA pipeline
-# ---------------------------------------------------------------------------
-#
-# Why a second kernel: v1 rides Mosaic's automatic BlockSpec pipeline,
-# which (a) prefetches one 64 KB page ahead — a single in-flight page DMA
-# never hides HBM latency at these block sizes — and (b) runs its fixed
-# DMA schedule for pages past a sequence's context (`pl.when` skips the
-# FLOPs, not the copy). v2 processes a *chunk* of `pages_per_chunk` pages
-# per grid step with hand-issued async copies: the whole next chunk is in
-# flight while the current one computes, only live pages are fetched
-# (per-page predicates), fully-dead chunks and empty slots cost one
-# near-empty grid step, and the per-group dot grows from [G, page] to
-# [G, chunk*page] — fewer, larger MXU ops and ~4x less scalar bookkeeping
-# per byte moved. A first manual-DMA attempt that kept the per-page grid
-# and tracked a live-block schedule in SMEM was *5x slower* than v1: at
-# 768 tiny grid steps the while-loop page scans and div/rem bookkeeping
-# dominated the 64 KB copies. Chunking is what makes manual DMA win.
-
-
-def _paged_decode_kernel_v2(
-    # scalar prefetch
-    li_ref,  # [1] int32 — layer index into the stacked page pool
-    bt_ref,  # [S, pages_per_seq] int32
-    cl_ref,  # [S] int32 — context length INCLUDING the new token
-    w_ref,  # [1] int32 — sliding window (huge = disabled)
-    # refs (layout depends on fused_write — see unpacking below)
-    *refs,
-    scale: float,
-    page_size: int,
-    pages_per_seq: int,
-    pages_per_chunk: int,
-    n_kv: int,
-    num_seqs: int,
-    softcap: Optional[float],
-    fused_write: bool = False,
-):
-    if fused_write:
-        # v3: the kernel also WRITES the step's new K/V row (normally an
-        # XLA scatter before the attention call, ~1.4 ms/step at 3B/192):
-        # the row is patched into the VMEM chunk before compute and
-        # persisted to the (input-output aliased) HBM pool.
-        (q_ref, kn_ref, vn_ref, k_hbm_ref, v_hbm_ref,
-         o_ref, ko_ref, vo_ref,
-         m_ref, l_ref, acc_ref, k_bufs, v_bufs, k_sems, v_sems,
-         kw_sem, vw_sem) = refs
-    else:
-        (q_ref, k_hbm_ref, v_hbm_ref, o_ref,
-         m_ref, l_ref, acc_ref, k_bufs, v_bufs, k_sems, v_sems) = refs
-    C = pages_per_chunk
-    NC = pages_per_seq // C  # launcher pads the block table to a multiple
-    s = pl.program_id(0)
-    c = pl.program_id(1)
-    li = li_ref[0]
-    window = w_ref[0]
-    group = q_ref.shape[1] // n_kv
-    t = s * NC + c  # flattened grid step; buffer parity = t % 2
-
-    def chunk_bounds(seq, chunk):
-        """(first, last+1) live page indices within the chunk (may be
-        empty; a page is live iff it overlaps the attended span
-        [ctx - window, ctx), which is contiguous per sequence)."""
-        ctx = cl_ref[seq]
-        lo = jnp.maximum(chunk * C, (ctx - window) // page_size)
-        hi = jnp.minimum((chunk + 1) * C, (ctx + page_size - 1) // page_size)
-        return lo, hi
-
-    def issue_chunk(seq, chunk, parity):
-        """Start K/V copies for the chunk's live pages (pair-merged when
-        the block table maps them adjacently in the pool)."""
-        lo, hi = chunk_bounds(seq, chunk)
-        for i in range(C):
-            p = chunk * C + i
-
-            @pl.when(jnp.logical_and(p >= lo, p < hi))
-            def _go(p=p, i=i):
-                pid = bt_ref[seq, p]
-                pltpu.make_async_copy(
-                    k_hbm_ref.at[li, pid], k_bufs.at[parity, i],
-                    k_sems.at[parity, i],
-                ).start()
-                pltpu.make_async_copy(
-                    v_hbm_ref.at[li, pid], v_bufs.at[parity, i],
-                    v_sems.at[parity, i],
-                ).start()
-
-    def wait_chunk(seq, chunk, parity):
-        lo, hi = chunk_bounds(seq, chunk)
-        for i in range(C):
-            p = chunk * C + i
-
-            @pl.when(jnp.logical_and(p >= lo, p < hi))
-            def _wait(i=i):
-                pltpu.make_async_copy(
-                    k_hbm_ref.at[li, 0], k_bufs.at[parity, i],
-                    k_sems.at[parity, i],
-                ).wait()
-                pltpu.make_async_copy(
-                    v_hbm_ref.at[li, 0], v_bufs.at[parity, i],
-                    v_sems.at[parity, i],
-                ).wait()
-
-    @pl.when(t == 0)
-    def _prime():
-        # Zero both buffer halves once: regions no DMA ever targets (dead
-        # pages inside a live chunk) must hold finite values — stale real
-        # floats are fine, but *uninitialized* VMEM can be NaN, and
-        # `probs(=0) @ NaN` poisons the PV dot despite the score mask.
-        k_bufs[...] = jnp.zeros_like(k_bufs)
-        v_bufs[...] = jnp.zeros_like(v_bufs)
-        issue_chunk(0, 0, 0)
-
-    # Prefetch the successor grid step's chunk into the other buffer.
-    last = num_seqs * NC - 1
-
-    @pl.when(t < last)
-    def _ahead():
-        nxt = t + 1
-        issue_chunk(nxt // NC, jax.lax.rem(nxt, NC), jax.lax.rem(nxt, 2))
-
-    ctx = cl_ref[s]
-    lo, hi = chunk_bounds(s, c)
-    any_live = lo < hi
-
-    @pl.when(any_live)
-    def _compute():
-        parity = jax.lax.rem(t, 2)
-        wait_chunk(s, c, parity)
-
-        if fused_write:
-            # The chunk holding the NEW token's position (ctx−1) is always
-            # the last live chunk: patch the freshly-computed K/V row into
-            # the VMEM copy (the prefetch read the pool before this write)
-            # and persist it to HBM for subsequent steps/layers.
-            p_new = ctx - 1
-            c_new = (p_new // page_size) // C
-
-            @pl.when(c == c_new)
-            def _write_new():
-                i_new = jax.lax.rem(p_new // page_size, C)
-                o_new = jax.lax.rem(p_new, page_size)
-                k_bufs[parity, i_new, o_new] = kn_ref[0]
-                v_bufs[parity, i_new, o_new] = vn_ref[0]
-                pid_new = bt_ref[s, p_new // page_size]
-                ck = pltpu.make_async_copy(
-                    kn_ref.at[0], ko_ref.at[li, pid_new, o_new], kw_sem
-                )
-                cv = pltpu.make_async_copy(
-                    vn_ref.at[0], vo_ref.at[li, pid_new, o_new], vw_sem
-                )
-                ck.start()
-                cv.start()
-                ck.wait()
-                cv.wait()
-
-        # First live chunk of this sequence: reset the accumulators.
-        prev_dead = jnp.logical_or(c == 0, chunk_bounds(s, c - 1)[0]
-                                   >= chunk_bounds(s, c - 1)[1])
-
-        @pl.when(prev_dead)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        start = c * C * page_size
-        q = q_ref[0].astype(jnp.float32)  # [H, d]
-        # [C, page, n_kv, d] -> [C*page, n_kv, d]; dead pages in the
-        # buffer hold stale-but-finite floats and are masked below.
-        k = k_bufs[parity].reshape(C * page_size, n_kv, -1).astype(
-            jnp.float32
-        )
-        v = v_bufs[parity].reshape(C * page_size, n_kv, -1).astype(
-            jnp.float32
-        )
-        for g in range(n_kv):
-            rows = slice(g * group, (g + 1) * group)
-            scores = (
-                jax.lax.dot_general(
-                    q[rows], k[:, g, :], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                * scale
-            )  # [group, C*page]
-            scores = _apply_softcap(scores, softcap)
-            kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 1
-            )
-            mask = jnp.logical_and(kpos < ctx, kpos >= ctx - window)
-            scores = jnp.where(mask, scores, NEG_INF)
-
-            m_prev = m_ref[rows, :1]
-            l_prev = l_ref[rows, :1]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(scores, axis=1, keepdims=True)
-            )
-            alpha = jnp.exp(m_prev - m_new)
-            probs = jnp.exp(scores - m_new)
-            l_ref[rows, :] = jnp.broadcast_to(
-                alpha * l_prev + jnp.sum(probs, axis=1, keepdims=True),
-                (group, l_ref.shape[1]),
-            )
-            m_ref[rows, :] = jnp.broadcast_to(
-                m_new, (group, m_ref.shape[1])
-            )
-            acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
-                probs, v[:, g, :], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
-        # Last live chunk: normalize and emit. (For ctx > 0 the final
-        # context page is always live, so every active sequence emits.)
-        nxt_dead = jnp.logical_or(
-            c == NC - 1,
-            chunk_bounds(s, c + 1)[0] >= chunk_bounds(s, c + 1)[1],
-        )
-
-        @pl.when(nxt_dead)
-        def _finish():
-            l = l_ref[:, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-    # Inactive slot (ctx == 0): defined zero output, never NaN — garbage
-    # rows are discarded by the caller but must not poison the batch.
-    @pl.when(jnp.logical_and(c == NC - 1, ctx == 0))
-    def _inactive():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("scale", "softcap", "pages_per_chunk", "interpret"),
-)
-def paged_decode_attention_pallas_v2(
-    q: jnp.ndarray,  # [S, n_heads, d]
-    k_pages: jnp.ndarray,  # [P, page_size, n_kv, d] or [L, P, page, n_kv, d]
-    v_pages: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [S, pages_per_seq] int32
-    context_lens: jnp.ndarray,  # [S] int32, INCLUDING the new token
-    sliding_window: jnp.ndarray,  # [] or [1] int32 (huge = disabled)
-    layer: Optional[jnp.ndarray] = None,  # traced layer index when stacked
-    *,
-    scale: float,
-    softcap: Optional[float] = None,
-    pages_per_chunk: int = 4,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Chunked manual-DMA paged decode attention (see notes above).
-
-    Same contract as :func:`paged_decode_attention_pallas`. The page pool
-    stays in HBM (``memory_space=ANY``); each grid step computes one
-    ``pages_per_chunk``-page chunk while the next chunk's live pages are
-    already in flight into the other half of a double buffer.
-    """
-    S, n_heads, d = q.shape
-    if k_pages.ndim == 4:  # single-layer callers: view as a 1-layer stack
-        k_pages = k_pages[None]
-        v_pages = v_pages[None]
-        layer = jnp.zeros((), jnp.int32)
-    assert layer is not None, "stacked pages need a layer index"
-    _, _, page_size, n_kv, _ = k_pages.shape
-    pages_per_seq = block_tables.shape[1]
-    C = max(1, min(pages_per_chunk, pages_per_seq))
-    if pages_per_seq % C:  # pad with never-live page slots
-        pad = C - pages_per_seq % C
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
-        pages_per_seq += pad
-
-    kernel = functools.partial(
-        _paged_decode_kernel_v2,
-        scale=scale,
-        page_size=page_size,
-        pages_per_seq=pages_per_seq,
-        pages_per_chunk=C,
-        n_kv=n_kv,
-        num_seqs=S,
-        softcap=softcap,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(S, pages_per_seq // C),
-        in_specs=[
-            pl.BlockSpec((1, n_heads, d), lambda s, c, *_: (s, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, n_heads, d), lambda s, c, *_: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_heads, _LANES), jnp.float32),
-            pltpu.VMEM((n_heads, _LANES), jnp.float32),
-            pltpu.VMEM((n_heads, d), jnp.float32),
-            pltpu.VMEM((2, C, page_size, n_kv, d), k_pages.dtype),
-            pltpu.VMEM((2, C, page_size, n_kv, d), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, C)),
-            pltpu.SemaphoreType.DMA((2, C)),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((S, n_heads, d), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        block_tables.astype(jnp.int32),
-        context_lens.astype(jnp.int32),
-        jnp.asarray(sliding_window, jnp.int32).reshape(1),
-        q,
-        k_pages,
-        v_pages,
-    )
-    return out
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("scale", "softcap", "pages_per_chunk", "interpret"),
-    donate_argnums=(1, 2),
-)
-def paged_decode_attention_pallas_v3(
-    q: jnp.ndarray,  # [S, n_heads, d]
-    k_pages: jnp.ndarray,  # [L, P, page, n_kv, d] (or unstacked)
-    v_pages: jnp.ndarray,
-    k_new: jnp.ndarray,  # [S, n_kv, d] — the step's fresh K row per slot
-    v_new: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [S, pages_per_seq] int32
-    context_lens: jnp.ndarray,  # [S] int32, INCLUDING the new token
-    sliding_window: jnp.ndarray,  # [] or [1] int32 (huge = disabled)
-    layer: Optional[jnp.ndarray] = None,
-    *,
-    scale: float,
-    softcap: Optional[float] = None,
-    pages_per_chunk: int = 4,
-    interpret: bool = False,
-):
-    """v2 + fused KV write: the kernel itself stores the new token's K/V
-    (VMEM patch for this step's own attention + HBM persist via the
-    input-output-aliased pool), replacing the separate XLA scatter that
-    cost ~1.4 ms/step at 3B/192 slots (round-4 trace). The caller must
-    NOT pre-write the row. Returns (out, k_pages, v_pages)."""
-    S, n_heads, d = q.shape
-    unstacked = k_pages.ndim == 4
-    if unstacked:  # single-layer callers: view as a 1-layer stack
-        k_pages = k_pages[None]
-        v_pages = v_pages[None]
-        layer = jnp.zeros((), jnp.int32)
-    assert layer is not None, "stacked pages need a layer index"
-    _, _, page_size, n_kv, _ = k_pages.shape
-    k_new = k_new.astype(k_pages.dtype)  # VMEM patch + DMA need pool dtype
-    v_new = v_new.astype(v_pages.dtype)
-    pages_per_seq = block_tables.shape[1]
-    C = max(1, min(pages_per_chunk, pages_per_seq))
-    if pages_per_seq % C:  # pad with never-live page slots
-        pad = C - pages_per_seq % C
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
-        pages_per_seq += pad
-
-    kernel = functools.partial(
-        _paged_decode_kernel_v2,
-        scale=scale,
-        page_size=page_size,
-        pages_per_seq=pages_per_seq,
-        pages_per_chunk=C,
-        n_kv=n_kv,
-        num_seqs=S,
-        softcap=softcap,
-        fused_write=True,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(S, pages_per_seq // C),
-        in_specs=[
-            pl.BlockSpec((1, n_heads, d), lambda s, c, *_: (s, 0, 0)),
-            pl.BlockSpec((1, n_kv, d), lambda s, c, *_: (s, 0, 0)),
-            pl.BlockSpec((1, n_kv, d), lambda s, c, *_: (s, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_heads, d), lambda s, c, *_: (s, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n_heads, _LANES), jnp.float32),
-            pltpu.VMEM((n_heads, _LANES), jnp.float32),
-            pltpu.VMEM((n_heads, d), jnp.float32),
-            pltpu.VMEM((2, C, page_size, n_kv, d), k_pages.dtype),
-            pltpu.VMEM((2, C, page_size, n_kv, d), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, C)),
-            pltpu.SemaphoreType.DMA((2, C)),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    out, kp, vp = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((S, n_heads, d), q.dtype),
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ),
-        grid_spec=grid_spec,
-        # Alias indices count ALL inputs incl. the 4 scalar-prefetch
-        # operands: li=0, bt=1, cl=2, w=3, q=4, k_new=5, v_new=6,
-        # k_pages=7, v_pages=8 → pool outputs 1/2.
-        input_output_aliases={7: 1, 8: 2},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        block_tables.astype(jnp.int32),
-        context_lens.astype(jnp.int32),
-        jnp.asarray(sliding_window, jnp.int32).reshape(1),
-        q,
-        k_new,
-        v_new,
-        k_pages,
-        v_pages,
-    )
-    if unstacked:  # hand back the caller's original pool rank
-        kp = kp[0]
-        vp = vp[0]
-    return out, kp, vp
-
-
-# ---------------------------------------------------------------------------
 # Paged decode, live pages only: the default decode kernel
 # ---------------------------------------------------------------------------
 #
-# v1 and v2 walk a grid that spans every page PLACE of every slot
+# v1 walks a grid that spans every page PLACE of every slot
 # (``S x pages_per_seq`` steps a layer; 128 x 64 at the worker's default
 # ``max_model_len``), and ``pl.when`` only skips a dead step's arithmetic.
 # On the chip a dead step still costs ~0.09 us, which at short caches is
@@ -714,8 +283,8 @@ def paged_decode_attention_pallas_v3(
 #   spend anyway, and saves the sublane gather ``k[:, g, :]`` that v1 pays
 #   a page. A pool whose heads do not fill a word (one bf16 head, two fp8
 #   heads) is padded on the chip, Mosaic refuses to slice a page of it for
-#   a hand-issued copy, and it stays on v1's BlockSpec pipeline (the
-#   launcher hands it over).
+#   a hand-issued copy, and it stays on v1's BlockSpec pipeline
+#   (``pool_rows_padded``; ``ops/dispatch.decode_kernel_plan`` decides).
 #
 # The arithmetic is v1's: K, V and the queries upcast to float32, float32
 # running max, sum and accumulator. On the chip the float32 dots cost
@@ -724,6 +293,12 @@ def paged_decode_attention_pallas_v3(
 _DECODE_STEP_BYTES = 512 * 1024  # K bytes one softmax update folds in
 _DECODE_CHUNK_BYTES = 1024 * 1024  # K bytes in flight (x2 for V, x2 buffers)
 _NOT_A_POSITION = 1 << 29  # a column of another kv head: past any context
+
+
+def pool_rows_padded(n_kv: int, kv_dtype) -> bool:
+    """Whether the chip pads a pool's ``[page, n_kv, d]`` pages: the kv
+    heads of one token do not fill whole 32-bit words."""
+    return (n_kv * jnp.dtype(kv_dtype).itemsize) % 4 != 0
 
 
 def _decode_schedule(page_bytes: int) -> tuple:
@@ -934,15 +509,16 @@ def paged_decode_attention_live(
 ) -> jnp.ndarray:
     """Paged decode attention whose work follows the live cache (see the
     notes above). Same contract as :func:`paged_decode_attention_pallas`,
-    which also serves the pools this schedule cannot copy by hand;
-    nothing in the schedule depends on ``block_tables.shape[1]``."""
+    except that a pool padded on the chip is refused: this schedule cannot
+    copy its pages by hand. Nothing in the schedule depends on
+    ``block_tables.shape[1]``."""
     n_kv, d = k_pages.shape[-2:]
-    itemsize = jnp.dtype(k_pages.dtype).itemsize
-    if (n_kv * itemsize) % 4:  # pages padded on the chip: see the notes
-        return paged_decode_attention_pallas(
-            q, k_pages, v_pages, block_tables, context_lens, sliding_window,
-            layer, scale=scale, softcap=softcap, interpret=interpret,
+    if pool_rows_padded(n_kv, k_pages.dtype):
+        raise ValueError(
+            f"a {k_pages.dtype} pool of {n_kv} kv head(s) is padded on the "
+            "chip: paged_decode_attention_pallas reads it"
         )
+    itemsize = jnp.dtype(k_pages.dtype).itemsize
     S, n_heads, _ = q.shape
     if k_pages.ndim == 4:  # single-layer callers: view as a 1-layer stack
         k_pages = k_pages[None]

@@ -125,16 +125,11 @@ class TPUWorker(BaseWorker):
         self._tp_overlap = tp_overlap
         self._mixed_step = mixed_step
         # Test/sim seam: a callable(worker) -> engine replaces the whole
-        # JAX engine build (and skips the kernel autotune passes), so the
+        # JAX engine build (and skips the tp-overlap probe), so the
         # full worker control plane runs with a stub engine and no
         # accelerator. None (the default) builds the real AsyncEngine.
         self._engine_factory = engine_factory
         self.engine = None
-        # Seconds the decode-kernel probing child cost this start (it
-        # runs before the engine exists); None when no child ran (env
-        # pin, CPU run, or this process already held the chip). Rides
-        # _engine_stats beside the decode_kernel it chose.
-        self._kernel_probe_s: Optional[float] = None
         self._usage: dict = {}
         # Terminal finish_reason held between generate() and
         # _build_result, which pops it onto the result as an extra so the
@@ -233,13 +228,12 @@ class TPUWorker(BaseWorker):
     async def _initialize_processor(self) -> None:
         # Engine construction compiles XLA programs and possibly loads a
         # multi-GB checkpoint: run off the event loop so broker heartbeats
-        # and signals stay live. The kernel A/B runs FIRST, while no JAX
-        # backend is initialised in this process: a chip belongs to one
-        # process, so the probing child can only have it before we do
-        # (kernel_autotune refuses, loudly, once we hold it).
+        # and signals stay live. A ``tp_overlap=auto`` probe runs FIRST,
+        # while no JAX backend is initialised in this process: a chip
+        # belongs to one process, so the probing child can only have it
+        # before we do (kernel_autotune refuses, loudly, once we hold it).
         loop = asyncio.get_running_loop()
         if self._engine_factory is None:
-            await loop.run_in_executor(None, self._autotune_kernel)
             await loop.run_in_executor(None, self._autotune_tp_overlap)
         self.engine = await loop.run_in_executor(None, self._build_engine)
         # The fault callbacks fire on the engine thread; breaker
@@ -273,36 +267,6 @@ class TPUWorker(BaseWorker):
         except Exception:  # noqa: BLE001 — _build_engine reports properly
             return None
 
-    def _autotune_kernel(self) -> None:
-        """Self-calibrate the paged-decode kernel (v1/v2/v3) by measuring
-        on this host's chip — same A/B ``bench.py`` runs, so production
-        throughput doesn't depend on an operator knowing the
-        ``LLMQ_DECODE_KERNEL`` env var. No-op when that var is already
-        set, when pinned to CPU, or under ``LLMQ_KERNEL_AUTOTUNE=0``."""
-        from llmq_tpu.engine.kernel_autotune import autotune_decode_kernel
-
-        cfg = self._model_config_host()
-        if cfg is None:
-            return
-        t0 = time.monotonic()
-        choice = autotune_decode_kernel(
-            num_heads=cfg.num_heads,
-            num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim_,
-            num_layers=cfg.num_layers,
-            max_seqs=self._max_num_seqs or self.config.max_num_seqs or 192,
-            page_size=self._page_size or 128,
-            # The A/B must rank the kernels on the production pool
-            # dtype (fp8 pools move half the bytes, f32 pools double
-            # them), resolved with _build_engine's exact precedence:
-            # explicit kv_dtype flag/env, else the compute dtype.
-            kv_dtype=self._resolve_pool_dtype(),
-            logger=self.logger,
-        )
-        if choice is not None:
-            os.environ["LLMQ_DECODE_KERNEL"] = choice
-            self._kernel_probe_s = round(time.monotonic() - t0, 1)
-
     def _autotune_tp_overlap(self) -> None:
         """Resolve ``tp_overlap=auto`` by A/B-ing the ppermute rings
         against GSPMD on this host's chips — run HERE, before any JAX
@@ -329,25 +293,6 @@ class TPUWorker(BaseWorker):
         )
         if choice is not None:
             os.environ["LLMQ_TP_OVERLAP"] = choice
-
-    def _resolve_pool_dtype(self) -> str:
-        """The KV pool dtype _build_engine will actually use, as a
-        canonical dtype name — per-worker flag > LLMQ_KV_DTYPE env >
-        the compute dtype (int8 weight quantization computes in bf16,
-        so its pool is bf16 too)."""
-        kv = self._kv_dtype or self.config.kv_dtype
-        names = {
-            "fp8": "float8_e5m2",
-            "fp8_e5m2": "float8_e5m2",
-            "float8_e5m2": "float8_e5m2",
-            "bf16": "bfloat16",
-            "bfloat16": "bfloat16",
-            "f32": "float32",
-            "float32": "float32",
-        }
-        if kv not in (None, "", "auto"):
-            return names.get(str(kv).lower(), "bfloat16")
-        return "float32" if self._dtype == "float32" else "bfloat16"
 
     def _build_core(self):
         """Construct a fresh EngineCore (mesh, params, compiled programs)
@@ -1494,8 +1439,6 @@ class TPUWorker(BaseWorker):
         if self.engine is None:
             return None
         stats = self.engine.stats()
-        if self._kernel_probe_s is not None:
-            stats["decode_kernel_probe_s"] = self._kernel_probe_s
         # Superset-only: rebuild accounting appears once a fault happened.
         if self.engine.engine_rebuilds:
             stats["engine_rebuilds"] = self.engine.engine_rebuilds

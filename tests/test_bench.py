@@ -198,21 +198,21 @@ class TestTrimPlan:
     rung (also diagnostic — it never replaces the headline), then the
     int4 attempt, then the tp-overlap rung, then quant, then the
     spec-decode rung, then the mixed-step rung, then the extra ladder
-    rungs, then the A/B."""
+    rungs."""
 
-    KW = dict(quant_s=1500.0, ab_s=420.0, ladder_extra_s=720.0,
+    KW = dict(quant_s=1500.0, ladder_extra_s=720.0,
               spec_s=360.0, tp_overlap_s=240.0, proven_s=300.0,
               int4_s=1500.0, mixed_s=300.0, prefix_s=240.0,
               disagg_s=420.0, pp_s=300.0, serve_s=240.0)
-    ALL = {"quant": True, "kernel_ab": True, "full_ladder": True,
+    ALL = {"quant": True, "full_ladder": True,
            "spec_ladder": True, "tp_overlap": True, "int4_ladder": True,
            "mixed_step": True, "prefix_rung": True, "disagg_rung": True,
            "pp_rung": True, "serve_rung": True}
     # Remaining-seconds sweep covering every drop boundary (phase sums
     # + the 300 s proven floor): see the per-test comments.
-    SWEEP = (350.0, 720.0, 800.0, 1440.0, 1500.0, 1740.0, 1900.0,
-             2100.0, 2500.0, 3600.0, 3700.0, 3840.0, 4000.0, 5340.0,
-             5400.0, 5580.0, 5820.0, 6000.0, 6300.0, 6540.0, 6600.0)
+    SWEEP = (300.0, 350.0, 1000.0, 1020.0, 1080.0, 1320.0, 1480.0,
+             1680.0, 2080.0, 3180.0, 3280.0, 3420.0, 3580.0, 4920.0,
+             4980.0, 5160.0, 5400.0, 5580.0, 5880.0, 6120.0, 6180.0)
 
     def test_no_deadline_runs_everything(self):
         assert bench.trim_plan(None, **self.KW) == self.ALL
@@ -220,63 +220,63 @@ class TestTrimPlan:
     def test_roomy_budget_runs_everything(self):
         # 300 (proven) + 240 (serve) + 300 (pp) + 420 (disagg)
         # + 240 (prefix) + 1500 (int4) + 240 + 1500 + 360 + 300 + 720
-        # + 420 = 6540 fits.
-        assert bench.trim_plan(6540.0, **self.KW) == self.ALL
+        # = 6120 fits.
+        assert bench.trim_plan(6120.0, **self.KW) == self.ALL
 
     def test_serve_rung_dropped_first(self):
-        # Everything but the serve rung fits (6000 after the floor),
+        # Everything but the serve rung fits (5580 after the floor),
         # + 240 does not.
-        plan = bench.trim_plan(6300.0, **self.KW)
+        plan = bench.trim_plan(5880.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False}
 
     def test_pp_rung_dropped_second(self):
         # After shedding the serve rung, everything but the pp rung
-        # fits (5700 after the floor), + 300 does not.
-        plan = bench.trim_plan(6000.0, **self.KW)
+        # fits (5280 after the floor), + 300 does not.
+        plan = bench.trim_plan(5580.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False}
 
     def test_disagg_rung_dropped_third(self):
         # After shedding the serve + pp rungs, everything but the
-        # disagg rung fits (5280 after the floor), + 420 does not.
-        plan = bench.trim_plan(5820.0, **self.KW)
+        # disagg rung fits (4860 after the floor), + 420 does not.
+        plan = bench.trim_plan(5400.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False, "disagg_rung": False}
 
     def test_prefix_rung_dropped_fourth(self):
         # After shedding the serve + pp + disagg rungs, everything but
-        # the prefix rung fits (5040 after the floor), + 240 does not.
-        plan = bench.trim_plan(5400.0, **self.KW)
+        # the prefix rung fits (4620 after the floor), + 240 does not.
+        plan = bench.trim_plan(4980.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False,
                         "disagg_rung": False, "prefix_rung": False}
 
     def test_int4_dropped_fifth(self):
-        # Everything through the ladder fits (3540 after the floor),
+        # Everything through the ladder fits (3120 after the floor),
         # + 1500 (int4) does not.
-        plan = bench.trim_plan(4000.0, **self.KW)
+        plan = bench.trim_plan(3580.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False, "disagg_rung": False,
                         "prefix_rung": False, "int4_ladder": False}
 
     def test_tp_overlap_dropped_sixth(self):
-        plan = bench.trim_plan(3700.0, **self.KW)
+        plan = bench.trim_plan(3280.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False, "disagg_rung": False,
                         "prefix_rung": False, "int4_ladder": False,
                         "tp_overlap": False}
 
     def test_quant_dropped_seventh(self):
-        # 300 (proven) + 420 + 720 + 360 + 300 fits, + 1500 does not.
-        plan = bench.trim_plan(2500.0, **self.KW)
+        # 300 (proven) + 720 + 360 + 300 fits, + 1500 does not.
+        plan = bench.trim_plan(2080.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False, "disagg_rung": False,
                         "prefix_rung": False, "int4_ladder": False,
                         "tp_overlap": False, "quant": False}
 
     def test_spec_rung_dropped_eighth(self):
-        # 300 + 420 + 720 + 300 fits, + 360 (spec rung) does not.
-        plan = bench.trim_plan(1900.0, **self.KW)
+        # 300 + 720 + 300 fits, + 360 (spec rung) does not.
+        plan = bench.trim_plan(1480.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False, "disagg_rung": False,
                         "prefix_rung": False, "int4_ladder": False,
@@ -284,8 +284,8 @@ class TestTrimPlan:
                         "spec_ladder": False}
 
     def test_mixed_rung_dropped_ninth(self):
-        # 300 + 420 + 720 fits, + 300 (mixed rung) does not.
-        plan = bench.trim_plan(1500.0, **self.KW)
+        # 300 + 720 fits, + 300 (mixed rung) does not.
+        plan = bench.trim_plan(1080.0, **self.KW)
         assert plan == {**self.ALL, "serve_rung": False,
                         "pp_rung": False, "disagg_rung": False,
                         "prefix_rung": False, "int4_ladder": False,
@@ -293,9 +293,9 @@ class TestTrimPlan:
                         "spec_ladder": False, "mixed_step": False}
 
     def test_ladder_dropped_tenth(self):
-        # 300 + 420 fits, + 720 does not.
-        plan = bench.trim_plan(800.0, **self.KW)
-        assert plan == {k: False for k in self.ALL} | {"kernel_ab": True}
+        # The last phase to go: 300 + 720 does not fit.
+        plan = bench.trim_plan(1000.0, **self.KW)
+        assert plan == {k: False for k in self.ALL}
 
     def test_everything_but_proven_dropped(self):
         plan = bench.trim_plan(350.0, **self.KW)
@@ -304,21 +304,20 @@ class TestTrimPlan:
     def test_proven_floor_reserved_before_phases(self):
         # Exactly the full phase sum of budget but NO room for the
         # proven floor on top -> the floor wins, the serve rung goes.
-        plan = bench.trim_plan(6240.0, **self.KW)
+        plan = bench.trim_plan(5820.0, **self.KW)
         assert plan["serve_rung"] is False
 
     def test_boundaries_inclusive(self):
-        assert bench.trim_plan(6540.0, **self.KW)["serve_rung"] is True
-        assert bench.trim_plan(6300.0, **self.KW)["pp_rung"] is True
-        assert bench.trim_plan(6000.0, **self.KW)["disagg_rung"] is True
-        assert bench.trim_plan(5580.0, **self.KW)["prefix_rung"] is True
-        assert bench.trim_plan(5340.0, **self.KW)["int4_ladder"] is True
-        assert bench.trim_plan(3840.0, **self.KW)["tp_overlap"] is True
-        assert bench.trim_plan(3600.0, **self.KW)["quant"] is True
-        assert bench.trim_plan(2100.0, **self.KW)["spec_ladder"] is True
-        assert bench.trim_plan(1740.0, **self.KW)["mixed_step"] is True
-        assert bench.trim_plan(1440.0, **self.KW)["full_ladder"] is True
-        assert bench.trim_plan(720.0, **self.KW)["kernel_ab"] is True
+        assert bench.trim_plan(6120.0, **self.KW)["serve_rung"] is True
+        assert bench.trim_plan(5880.0, **self.KW)["pp_rung"] is True
+        assert bench.trim_plan(5580.0, **self.KW)["disagg_rung"] is True
+        assert bench.trim_plan(5160.0, **self.KW)["prefix_rung"] is True
+        assert bench.trim_plan(4920.0, **self.KW)["int4_ladder"] is True
+        assert bench.trim_plan(3420.0, **self.KW)["tp_overlap"] is True
+        assert bench.trim_plan(3180.0, **self.KW)["quant"] is True
+        assert bench.trim_plan(1680.0, **self.KW)["spec_ladder"] is True
+        assert bench.trim_plan(1320.0, **self.KW)["mixed_step"] is True
+        assert bench.trim_plan(1020.0, **self.KW)["full_ladder"] is True
 
     def test_drop_order_invariants(self):
         # A more speculative phase never survives a less speculative
@@ -326,7 +325,7 @@ class TestTrimPlan:
         order = ("serve_rung", "pp_rung", "disagg_rung", "prefix_rung",
                  "int4_ladder",
                  "tp_overlap", "quant", "spec_ladder", "mixed_step",
-                 "full_ladder", "kernel_ab")
+                 "full_ladder")
         for remaining in self.SWEEP:
             plan = bench.trim_plan(remaining, **self.KW)
             for earlier, later in zip(order, order[1:]):
@@ -338,9 +337,9 @@ class TestTrimPlan:
         # Callers that never pass int4_s/mixed_s/prefix_s/disagg_s/
         # pp_s/serve_s get them at zero cost: the keys exist but never
         # consume budget.
-        kw = dict(quant_s=1500.0, ab_s=420.0, ladder_extra_s=720.0,
+        kw = dict(quant_s=1500.0, ladder_extra_s=720.0,
                   spec_s=360.0, tp_overlap_s=240.0, proven_s=300.0)
-        plan = bench.trim_plan(3540.0, **kw)
+        plan = bench.trim_plan(3120.0, **kw)
         assert plan["tp_overlap"] is True and plan["int4_ladder"] is True
         assert plan["prefix_rung"] is True
         assert plan["disagg_rung"] is True

@@ -1,21 +1,24 @@
 """Autotune driver logic (``engine/kernel_autotune.py``): gating, the
-subprocess contract, and the child-side per-chip cache. The measured A/B
-itself is hardware-only; here children/measurers are mocked."""
+subprocess contract, and the child-side per-chip cache, through the
+tp-overlap probe. The measured A/B itself is hardware-only; here
+children/measurers are mocked."""
 
 import json
 import subprocess
 import types
 
-import pytest
-
 from llmq_tpu.engine import kernel_autotune as ka
 
-SHAPES = dict(num_heads=8, num_kv_heads=2, head_dim=64, num_layers=4)
-SHAPE_TUPLE = (8, 2, 64, 4, 192, 128)
-_DETAIL = "kernel-autotune: decode A/B v1=1ms v2=0.5ms v3=0.6ms per layer -> v2"
+SHAPES = dict(hidden_size=64, intermediate_size=128)
+VALID = ("on", "off")
+_DETAIL = "kernel-autotune: tp-overlap A/B gspmd=9.0us ring=7.0us per layer -> on"
 
 
-def _fake_run(choice="v2", rc=0, detail=_DETAIL):
+def _key(identity):
+    return ka._tp_overlap_cache_key(64, 128, 192, 4, "bfloat16", identity)
+
+
+def _fake_run(choice="on", rc=0, detail=_DETAIL):
     def run(argv, timeout, capture_output, text):
         return types.SimpleNamespace(
             returncode=rc, stdout=choice + "\n", stderr=detail + "\n"
@@ -24,27 +27,19 @@ def _fake_run(choice="v2", rc=0, detail=_DETAIL):
     return run
 
 
-def test_respects_explicit_env(monkeypatch):
-    monkeypatch.setenv("LLMQ_DECODE_KERNEL", "v3")
-    assert ka.autotune_decode_kernel(**SHAPES) is None
-
-
 def test_skips_on_cpu_pin(monkeypatch):
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert ka.autotune_decode_kernel(**SHAPES) is None
+    assert ka.autotune_tp_overlap(**SHAPES) is None
 
 
 def test_disabled_by_flag(monkeypatch):
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
     monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     monkeypatch.setenv("LLMQ_KERNEL_AUTOTUNE", "0")
-    assert ka.autotune_decode_kernel(**SHAPES) is None
+    assert ka.autotune_tp_overlap(**SHAPES) is None
 
 
 def _probe_applies(monkeypatch, *, holds_chip=False):
     """Pretend a TPU host whose calling process has (not) touched JAX."""
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
     monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     monkeypatch.delenv("LLMQ_KERNEL_AUTOTUNE", raising=False)
     monkeypatch.setattr(
@@ -54,20 +49,20 @@ def _probe_applies(monkeypatch, *, holds_chip=False):
 
 def test_probe_choice_from_child(monkeypatch):
     _probe_applies(monkeypatch)
-    monkeypatch.setattr(subprocess, "run", _fake_run("v2"))
-    assert ka.autotune_decode_kernel(**SHAPES) == "v2"
+    monkeypatch.setattr(subprocess, "run", _fake_run("on"))
+    assert ka.autotune_tp_overlap(**SHAPES) == "on"
 
 
 def test_child_failure_is_loud_and_starts_on_default(monkeypatch, capsys):
     """A failed probe is an ERROR with the child's output, never a quiet
-    win, and the answer is the default kernel: non-zero exit (a kernel
+    win, and the answer is the default mode: non-zero exit (a candidate
     did not compile), junk answer, timeout."""
     _probe_applies(monkeypatch)
     monkeypatch.setattr(
         subprocess, "run",
         _fake_run("junk", rc=3, detail="MosaicError: kernel refused"),
     )
-    assert ka.autotune_decode_kernel(**SHAPES) == "live"
+    assert ka.autotune_tp_overlap(**SHAPES) == "off"
     err = capsys.readouterr().err
     assert "probe FAILED" in err and "exit 3" in err
     assert "MosaicError: kernel refused" in err
@@ -76,7 +71,7 @@ def test_child_failure_is_loud_and_starts_on_default(monkeypatch, capsys):
         raise subprocess.TimeoutExpired(cmd="x", timeout=1)
 
     monkeypatch.setattr(subprocess, "run", boom)
-    assert ka.autotune_decode_kernel(**SHAPES) == "live"
+    assert ka.autotune_tp_overlap(**SHAPES) == "off"
     assert "probe FAILED: no answer" in capsys.readouterr().err
 
 
@@ -89,11 +84,8 @@ def test_no_child_from_a_process_that_holds_the_chip(monkeypatch, capsys):
         raise AssertionError("spawned a child that needs the chip")
 
     monkeypatch.setattr(subprocess, "run", never)
-    assert ka.autotune_decode_kernel(**SHAPES) is None
-    assert ka.autotune_tp_overlap(
-        hidden_size=64, intermediate_size=128
-    ) is None
-    assert capsys.readouterr().err.count("probe NOT RUN") == 2
+    assert ka.autotune_tp_overlap(**SHAPES) is None
+    assert capsys.readouterr().err.count("probe NOT RUN") == 1
 
 
 def test_probe_blocked_tracks_backend_init():
@@ -126,34 +118,34 @@ class TestChildCache:
 
         def measure():
             calls.append(1)
-            return "v2", True
+            return "on", True
 
-        got = ka.resolve_choice(SHAPE_TUPLE, "TPU_v5e/jax0.9", measure)
-        assert got == "v2" and len(calls) == 1
+        def resolve(identity):
+            return ka.resolve_choice(measure, key=_key(identity), valid=VALID)
+
+        assert resolve("TPU_v5e/jax0.9") == "on" and len(calls) == 1
         (key,) = json.loads(cache.read_text()).keys()
-        assert key.startswith("decode:h8:kv2:d64:l4:s192:p128")
+        assert key.startswith("tpovl:h64:i128:s192:tp4:bfloat16")
         assert key.endswith("TPU_v5e/jax0.9")
 
         # Same shapes + same identity: served from cache, no re-measure.
-        got = ka.resolve_choice(SHAPE_TUPLE, "TPU_v5e/jax0.9", measure)
-        assert got == "v2" and len(calls) == 1
+        assert resolve("TPU_v5e/jax0.9") == "on" and len(calls) == 1
 
         # Same shapes, DIFFERENT chip: cache miss, measured again.
-        got = ka.resolve_choice(SHAPE_TUPLE, "TPU_v4/jax0.9", measure)
-        assert got == "v2" and len(calls) == 2
+        assert resolve("TPU_v4/jax0.9") == "on" and len(calls) == 2
         assert len(json.loads(cache.read_text())) == 2
 
         # Toolchain upgrade: also a miss.
-        ka.resolve_choice(SHAPE_TUPLE, "TPU_v5e/jax0.10", measure)
+        resolve("TPU_v5e/jax0.10")
         assert len(calls) == 3
 
     def test_unmeasured_fallback_not_cached(self, monkeypatch, tmp_path):
         cache = tmp_path / "autotune.json"
         monkeypatch.setenv("LLMQ_AUTOTUNE_CACHE", str(cache))
         got = ka.resolve_choice(
-            SHAPE_TUPLE, "TPU_v5e/jax0.9", lambda: ("v1", False)
+            lambda: ("off", False), key=_key("TPU_v5e/jax0.9"), valid=VALID
         )
-        assert got == "v1"
+        assert got == "off"
         assert not cache.exists()
 
     def test_disabled_cache_always_measures(self, monkeypatch):
@@ -162,10 +154,11 @@ class TestChildCache:
 
         def measure():
             calls.append(1)
-            return "v3", True
+            return "on", True
 
-        assert ka.resolve_choice(SHAPE_TUPLE, "x/y", measure) == "v3"
-        assert ka.resolve_choice(SHAPE_TUPLE, "x/y", measure) == "v3"
+        for _ in range(2):
+            got = ka.resolve_choice(measure, key=_key("x/y"), valid=VALID)
+            assert got == "on"
         assert len(calls) == 2
 
     def test_corrupt_cache_re_measures(self, monkeypatch, tmp_path):
@@ -173,18 +166,7 @@ class TestChildCache:
         cache.write_text("{not json")
         monkeypatch.setenv("LLMQ_AUTOTUNE_CACHE", str(cache))
         got = ka.resolve_choice(
-            SHAPE_TUPLE, "TPU_v5e/jax0.9", lambda: ("v2", True)
+            lambda: ("on", True), key=_key("TPU_v5e/jax0.9"), valid=VALID
         )
-        assert got == "v2"
+        assert got == "on"
         assert json.loads(cache.read_text())  # rewritten valid
-
-
-def test_run_ab_off_tpu_is_unmeasured():
-    """On the CPU backend run_ab must report measured=False so the child
-    never caches the unmeasured default."""
-    pytest.importorskip("jax")
-    choice, measured = ka.run_ab(
-        num_heads=4, num_kv_heads=2, head_dim=8, num_layers=1,
-        max_seqs=2, page_size=8,
-    )
-    assert choice == "live" and measured is False

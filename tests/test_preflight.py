@@ -9,8 +9,8 @@ and executes each one on CPU with tiny shape overrides — proving the
 whole ladder is runnable end to end before chip time is spent.
 
 Fast tier (always on): the parser finds the expected steps, every
-referenced script/module exists, and the cheap commands (probes, the
-kernel-autotune A/B, one bench) actually run. The heavyweight commands
+referenced script/module exists, and the cheap commands (probes, one
+bench) actually run. The heavyweight commands
 (every bench variant, the profilers, the queue-drain harness) are
 ``slow``-marked and run in CI's full pass.
 """
@@ -167,13 +167,13 @@ def test_ladders_parse():
     """The runbook yields its full command ladder (a parser that
     silently matches nothing would make every other test vacuous). The
     count covers the steps that came over from the deleted chip poller:
-    the two kernel A/Bs, the driver-style and pinned benches, the fp8-KV
-    and Pallas-int8 variants, and the queue-drain harness."""
+    the driver-style and pinned benches, the fp8-KV and Pallas-int8
+    variants, and the queue-drain harness."""
     names = [name for name, _, _ in all_steps()]
-    assert sum(n.startswith("hardware_session") for n in names) >= 32
+    assert sum(n.startswith("hardware_session") for n in names) >= 29
     joined = " ".join(names)
-    assert "kernel_v123" in joined and "queue_drain_tpu" in joined
-    assert "ab_s224" in joined and "bench_driver_style" in joined
+    assert "int4_kernel" in joined and "queue_drain_tpu" in joined
+    assert "int8_fusion" in joined and "bench_driver_style" in joined
     assert "metrics_probe" in joined
     assert "fleet_chaos_probe" in joined
     assert "engine_fault_probe" in joined
@@ -203,15 +203,16 @@ def test_referenced_files_exist():
 
 
 def test_probes_and_autotune_run():
-    """The cheap ladder steps execute on CPU: the device probe and both
-    kernel-autotune A/B invocations (which short-circuit to v1 on CPU)."""
+    """The cheap ladder steps execute on CPU: the device probe, and any
+    kernel-autotune child the runbook scripts (a tp-overlap or
+    int4-matmul leg would land here; today it scripts none)."""
     ran = 0
     for name, env, argv in unique_tiny_steps():
         if _is_probe(name) or "llmq_tpu.engine.kernel_autotune" in argv:
             proc = _run(env, argv, timeout=240)
             _assert_ran(name, proc, allow_fail=_is_probe(name))
             ran += 1
-    assert ran >= 3
+    assert ran >= 1
 
 
 def test_bench_tiny_decode_block_runs():

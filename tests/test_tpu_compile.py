@@ -112,7 +112,6 @@ def _decode_default_128_slots_64_places(topo, monkeypatch):
     benchmark's trace readers look for, and the pool reaches it as it
     lies (the launcher's 2-D view of a page is a bitcast, not a copy)."""
     monkeypatch.setattr(dispatch, "_interpret", lambda: False)
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
     s = _Shapes(topo)
     pool = s((Q3B.num_layers, 1915, PAGE, NKV, D), jnp.bfloat16)
     kv = Format(Layout(tuple(range(5))), pool.sharding)
@@ -136,15 +135,25 @@ def _decode_default_128_slots_64_places(topo, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
-def _decode_v3(topo, monkeypatch):
+def _decode_fp8_pool_2_kv_heads_takes_v1(topo, monkeypatch):
+    """qwen2.5-3b's pool in fp8: two 1-byte heads a token do not fill a
+    word, the chip pads the pages, and the dispatch compiles v1 for it."""
+    monkeypatch.setattr(dispatch, "_interpret", lambda: False)
     s = _Shapes(topo)
-    row = s((SLOTS, NKV, D), jnp.bfloat16)
-    _compile(
-        partial(pk.paged_decode_attention_pallas_v3, scale=SCALE),
-        s((SLOTS, H, D), jnp.bfloat16), s.pool(), s.pool(), row, row,
+    pool = s.pool(dtype=jnp.float8_e5m2)
+
+    def step(q, kp, vp, bt, cl, layer):
+        return dispatch.decode_attention(
+            q, kp, vp, bt, cl, scale=SCALE, backend="pallas", layer=layer
+        )
+
+    text = _compile(
+        step, s((SLOTS, H, D), jnp.bfloat16), pool, pool,
         s((SLOTS, PAGES_PER_SEQ), jnp.int32), s((SLOTS,), jnp.int32),
-        s((1,), jnp.int32), s((1,), jnp.int32),
-    )
+        s((), jnp.int32),
+    ).as_text()
+    assert "%paged_decode_attention_pallas" in text
+    assert "paged_decode_attention_live" not in text
 
 
 def _flash_prefill(T):
@@ -244,17 +253,14 @@ def _one_kv_head_a_shard_takes_the_xla_path(topo, monkeypatch):
     pool copy (the page-bytes case below), so the dispatch gives way —
     and says so, once."""
     mesh = make_mesh(tensor_parallel=4, devices=topo.devices)
-    for plan in (
-        dispatch.decode_kernel_plan,
-        dispatch.verify_kernel_plan,
-        dispatch.mixed_kernel_plan,
-    ):
-        assert plan(Q7B.num_heads, Q7B.num_kv_heads, mesh, "pallas") == (
-            "xla", False,
-        )
+    for plan in (dispatch.verify_kernel_plan, dispatch.mixed_kernel_plan):
+        assert plan(Q7B.num_heads, Q7B.num_kv_heads, mesh, "pallas") == "xla"
     assert dispatch.decode_kernel_plan(
-        L8B.num_heads, L8B.num_kv_heads, mesh, "pallas"
-    ) == ("live", False)
+        Q7B.num_heads, Q7B.num_kv_heads, jnp.bfloat16, mesh, "pallas"
+    ) == "xla"
+    assert dispatch.decode_kernel_plan(
+        L8B.num_heads, L8B.num_kv_heads, jnp.bfloat16, mesh, "pallas"
+    ) == "live"
 
 
 def _pool_page_bytes_come_from_the_compiler(topo, monkeypatch):
@@ -393,13 +399,11 @@ CASES = {
     "hybrid_decode_128_slots": _hybrid_step("decode"),
     "hybrid_prefill_1x512": _hybrid_step((1, 512)),
     "decode_live": _decode(pk.paged_decode_attention_live),
-    "decode_live_fp8_pool_handed_to_v1": _decode(
-        pk.paged_decode_attention_live, jnp.float8_e5m2
+    "decode_fp8_pool_2_kv_heads_takes_v1": (
+        _decode_fp8_pool_2_kv_heads_takes_v1
     ),
     "decode_default_128_slots_64_places": _decode_default_128_slots_64_places,
     "decode_v1": _decode(pk.paged_decode_attention_pallas),
-    "decode_v2": _decode(pk.paged_decode_attention_pallas_v2),
-    "decode_v3_fused_write": _decode_v3,
     "decode_v1_fp8_pool": _decode(
         pk.paged_decode_attention_pallas, jnp.float8_e5m2
     ),

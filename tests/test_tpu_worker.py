@@ -136,45 +136,33 @@ def test_worker_id_encodes_topology():
     assert "-tp2-dp1" in worker.worker_id
 
 
-def test_worker_exports_autotuned_kernel(monkeypatch):
-    """_autotune_kernel resolves the model architecture host-side and
-    exports the measured winner via LLMQ_DECODE_KERNEL; a None verdict
-    (explicit env / CPU pin / disabled) leaves the env alone."""
-    import os
+async def test_worker_starts_without_a_probing_child(monkeypatch):
+    """No engine factory and ``tp_overlap`` not ``auto``: start-up is the
+    engine build alone. Even where a probe would apply (a TPU host, the
+    chip not yet held) the worker starts no child process."""
+    import subprocess
+
+    import jax
 
     import llmq_tpu.engine.kernel_autotune as ka
 
-    worker = make_worker("memory://at-test", max_num_seqs=8)
-    seen = {}
+    def never(*a, **k):
+        raise AssertionError("the worker started a child process")
 
-    def fake_autotune(**kw):
-        seen.update(kw)
-        return "v3"
-
-    monkeypatch.setattr(ka, "autotune_decode_kernel", fake_autotune)
-    # setenv-then-delenv records the ORIGINAL (absent) state with
-    # monkeypatch, so the worker's direct os.environ write below is
-    # rolled back at teardown even if an assert fails mid-test.
-    monkeypatch.setenv("LLMQ_DECODE_KERNEL", "sentinel")
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL")
-    worker._autotune_kernel()
-    assert os.environ.get("LLMQ_DECODE_KERNEL") == "v3"
-    # Shapes came from the preset's host-side config, engine knobs from
-    # the worker's.
-    assert seen["num_layers"] >= 1 and seen["num_heads"] >= 1
-    assert seen["max_seqs"] == 8
-    assert seen["page_size"] == 8  # explicit --page-size wins
-    # Without an explicit page size the probe uses the worker's TPU
-    # default of 128-token pages.
-    bare = make_worker("memory://at-test2", page_size=None)
-    monkeypatch.setattr(ka, "autotune_decode_kernel", fake_autotune)
-    bare._autotune_kernel()
-    assert seen["page_size"] == 128
-
-    monkeypatch.delenv("LLMQ_DECODE_KERNEL", raising=False)
-    monkeypatch.setattr(ka, "autotune_decode_kernel", lambda **kw: None)
-    worker._autotune_kernel()
-    assert "LLMQ_DECODE_KERNEL" not in os.environ
+    jax.devices()  # JAX has read JAX_PLATFORMS=cpu; the probes' gate has not
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.delenv("LLMQ_KERNEL_AUTOTUNE", raising=False)
+    monkeypatch.setattr(ka, "_probe_blocked", lambda: None)
+    monkeypatch.setattr(subprocess, "run", never)
+    monkeypatch.setattr(subprocess, "Popen", never)
+    worker = make_worker("memory://no-probe")
+    await worker._initialize_processor()
+    try:
+        stats = worker._engine_stats()
+        assert stats["decode_kernel"] == "xla"  # a CPU run
+        assert "decode_kernel_probe_s" not in stats
+    finally:
+        worker.engine.shutdown()
 
 
 async def test_tpu_worker_result_carries_engine_trace(mem_url):

@@ -45,7 +45,6 @@ WINDOW = jnp.asarray([1 << 30], jnp.int32)
 
 KERNELS = {
     "v1": pk.paged_decode_attention_pallas,
-    "v2": pk.paged_decode_attention_pallas_v2,
     "live": pk.paged_decode_attention_live,
 }
 

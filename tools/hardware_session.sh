@@ -5,15 +5,16 @@
 # one process per chip, so run nothing else that needs it meanwhile.
 # Results land under PERF_RESULTS/:
 #
-#   1. kernel A/Bs and micro-benches: v1 vs v2 vs v3 incl. the XLA
-#      KV-write cost, at the ladder's two slot counts
-#   2. int8 matmul fusion check (decides whether int8 helps DECODE)
-#   3. the per-plane probes (snapshot, prefix, disagg, faults, ...)
-#   4. headline bench, bf16 (kernel A/B + 224->192 slot ladder built in),
+#   1. int8 matmul fusion check (decides whether int8 helps DECODE)
+#   2. the per-plane probes (snapshot, prefix, disagg, faults, ...)
+#   3. headline bench, bf16 (224->192 slot ladder built in),
 #      driver-style and pinned variants
-#   5. int8 / fp8-KV / int4 benches at 3B, int8 9B on ONE 16 GB chip
-#   6. param auto-layout, speculative decoding, mixed-step A/Bs
-#   7. the queue-drain harness (broker -> worker -> results) at 3B
+#   4. int8 / fp8-KV / int4 benches at 3B, int8 9B on ONE 16 GB chip
+#   5. param auto-layout, speculative decoding, mixed-step A/Bs
+#   6. the queue-drain harness (broker -> worker -> results) at 3B
+#
+# The decode-attention schedules are timed through the chip tool
+# (tools/decode_kernel_bench.py), not here.
 #
 # The quickest proof that the system starts on the chip at all is
 # `python chip_smoke.py` at the repo root; run that first.
@@ -37,11 +38,6 @@ run() {  # run <timeout-s> <name> <cmd...>
 run 60  probe         python -c "import jax; d=jax.devices(); print(len(d), d[0].platform, d[0].device_kind)"
 grep -q tpu "$OUT/probe.log" || { echo "no TPU on this host; aborting"; exit 1; }
 
-# The decode-kernel A/B (the worker's own probing child) at the ladder's
-# two slot counts — decides the production default.
-run 900 ab_s224 python -m llmq_tpu.engine.kernel_autotune 16 2 128 36 224 128
-run 600 ab_s192 python -m llmq_tpu.engine.kernel_autotune 16 2 128 36 192 128
-run 900 kernel_v123   python tools/profile_kernel_v2.py
 run 300 int8_fusion   python tools/profile_int8_matmul.py
 # ICI microbench: decides whether the tp-overlap ring matmuls pay on
 # this slice (single-chip sessions exit immediately with a note).
@@ -140,6 +136,5 @@ echo "=== summary"
 grep -h '"metric"' "$OUT"/bench_*.log 2>/dev/null
 echo "Next: compare bench_autolayout vs bench_bf16; if auto-layout holds,"
 echo "compare bench_spec3 vs bench_bf16 and record the acceptance rate;"
-echo "default LLMQ_PARAM_AUTO_LAYOUT=1 on TPU in engine.py; flip the"
-echo "LLMQ_DECODE_KERNEL fallback in ops/dispatch.py to kernel_v123's"
-echo "winner; record the best line in PERF_NOTES."
+echo "default LLMQ_PARAM_AUTO_LAYOUT=1 on TPU in engine.py; record the"
+echo "best line in PERF_NOTES."
