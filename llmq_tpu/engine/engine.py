@@ -58,6 +58,7 @@ reference consumed.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import logging
 import math
@@ -1175,6 +1176,12 @@ class EngineCore:
             self.cfg, sp=int(self.mesh.shape.get(SP_AXIS, 1)),
             quarter_steps=not self._hybrid,
         )
+        # What the rows of an admission wave share (Scheduler.next_wave):
+        # the bucket, i.e. the program, of a whole-prompt prefill. Chunked,
+        # mixed and prefix-cached prefill have no buckets.
+        self._admit_bucket = (
+            None if self.cfg.prefill_chunk_size else self._wave_bucket
+        )
         # Small-K interactive decode executables; _make_jits populates
         # this when interactive_decode_block is on (pp=1 only — the pp
         # drivers keep the single big-K pipeline).
@@ -1274,6 +1281,9 @@ class EngineCore:
         # same step's finished list.
         self._prefill_snapshots: Dict[str, RequestSnapshot] = {}
         self.prefill_tokens = 0  # prompt positions actually computed
+        # Positions the prefill programs were shaped for (rows x width,
+        # padding included): over prefill_tokens it is the padding factor.
+        self.prefill_grid_tokens = 0
         self.prefix_demotes = 0  # pages parked in the host tier on evict
         self.prefix_promotes = 0  # pages restored from the host tier
         self.prefix_chunks_exported = 0  # pages serialized for peers
@@ -3048,17 +3058,19 @@ class EngineCore:
                 self._self_preempt_deferred(victim)
                 self.priority_preemptions += 1
                 free = 1
-        want = (
-            min(
-                self.cfg.max_prefill_batch,
-                len(self.scheduler.waiting),
-                len(self.scheduler.slots),  # a chunk can't exceed the slots
-            )
-            if self.scheduler.has_waiting
-            else 0
-        )
-        # Batch admission: wait for enough free slots to fill a prefill
-        # chunk rather than prefilling singletons as slots trickle free —
+        # The wave that would go: the head and, where more wait than a
+        # wave admits, the waiters that share its prefill program
+        # (Scheduler.next_wave). A chunk can't exceed the slots; with no
+        # slot free nothing goes, and a full worker's every turn is spared
+        # the look through its queue.
+        wave: List[int] = []
+        if free:
+            wave = self.scheduler.next_wave(
+                self.cfg.max_prefill_batch, self._admit_bucket
+            )[: len(self.scheduler.slots)]
+        want = len(wave)
+        # Batch admission: wait for enough free slots to fill that wave
+        # rather than prefilling singletons as slots trickle free —
         # a B=1 chunk costs nearly a full weight pass for 1/B the tokens.
         # Never defer when nothing is running (no progress to wait for),
         # and never keep deferring past admit_max_wait_s. The clock starts
@@ -3088,7 +3100,9 @@ class EngineCore:
                 self._span_admit_hold(free, expired=overdue)
             self.spans.begin("admit", free=free)
         self._defer_since = None
-        admitted = self.scheduler.admit(max_new=self.cfg.max_prefill_batch)
+        admitted = self.scheduler.admit(
+            self.cfg.max_prefill_batch, self._admit_bucket
+        )
         # Host-tier promotion runs BEFORE anything else touches the wave:
         # admit() already registered the promoted pages' hashes (so later
         # admits may share them), which is only sound if their KV lands
@@ -3114,7 +3128,8 @@ class EngineCore:
             self._prefill_batch(todo, finished)
         if self.spans.on:
             self.spans.end(
-                rows=len(admitted), waiting=len(self.scheduler.waiting)
+                rows=len(admitted), waiting=len(self.scheduler.waiting),
+                reach=max(wave[: len(admitted)], default=-1),
             )
         return bool(admitted)
 
@@ -3732,9 +3747,7 @@ class EngineCore:
             return
         by_bucket: Dict[int, List[Sequence]] = {}
         for seq in seqs:
-            n = seq.num_tokens
-            bucket = next(b for b in self._buckets if b >= n)
-            by_bucket.setdefault(bucket, []).append(seq)
+            by_bucket.setdefault(self._bucket_of(seq), []).append(seq)
         # Decode interleaving across a multi-chunk wave happens at the
         # step() level (one decode per _try_admit round); per-chunk
         # interleaving inside one call only matters for the chunked path,
@@ -3743,6 +3756,15 @@ class EngineCore:
             for i in range(0, len(group), self.cfg.max_prefill_batch):
                 self._prefill_chunk(group[i : i + self.cfg.max_prefill_batch],
                                     bucket)
+
+    def _bucket_of(self, seq: Sequence) -> int:
+        """The prefill bucket that holds ``seq``'s prompt and output."""
+        return self._buckets[bisect.bisect_left(self._buckets, seq.num_tokens)]
+
+    def _wave_bucket(self, seq: Sequence) -> Optional[int]:
+        """``_bucket_of``, or None for a sequence that brings its KV with
+        it and is not prefilled."""
+        return None if seq.restore is not None else self._bucket_of(seq)
 
     def _prefill_chunked(
         self, seqs: List[Sequence], finished: List[RequestOutput]
@@ -3825,6 +3847,7 @@ class EngineCore:
                         mode=chunk_mode, variant=f"{B}x{C}", rows=len(rows),
                         rids=[seq.rid for seq in rows],
                         pending=len(self._pending),
+                        tokens=int((positions >= 0).sum()), grid=B * C,
                     )
                 t0 = time.monotonic()
                 for seq in rows:
@@ -3838,6 +3861,7 @@ class EngineCore:
                         )
                     )
                     self._record_dispatch("prefill", time.monotonic() - t0)
+                self.prefill_grid_tokens += B * C
                 out, g = self._split_guard(out)
                 if snapshot:  # rows whose prompt finished in this chunk
                     for _, seq in snapshot:
@@ -3993,6 +4017,7 @@ class EngineCore:
                         program=getattr(self._mixedfill_jits[mode], "name", ""),
                         mode=mode, variant=f"{K}x{C}", rows=1,
                         rids=[seq.rid], pending=len(self._pending),
+                        tokens=sum(t for _, t in segs), grid=K * C,
                     )
                 t0 = time.monotonic()
                 if seq.t_prefill_start == 0.0:
@@ -4008,6 +4033,7 @@ class EngineCore:
                 self.mixed_steps += 1
                 self.mixed_prefill_tokens += sum(t for _, t in segs)
                 self.prefill_tokens += sum(t for _, t in segs)
+                self.prefill_grid_tokens += K * C
                 self.decode_steps += K
                 self.decode_dispatches += 1
                 if final_k is not None:
@@ -4102,6 +4128,7 @@ class EngineCore:
                 program=getattr(self._prefill_jits[chunk_mode], "name", ""),
                 mode=chunk_mode, variant=f"{B}x{bucket}", rows=len(chunk),
                 rids=[seq.rid for seq in chunk], pending=len(self._pending),
+                tokens=int(lengths.sum()), grid=B * bucket,
             )
         t0 = time.monotonic()
         for seq in chunk:
@@ -4118,6 +4145,7 @@ class EngineCore:
         for seq in chunk:
             seq.prefilled = True
             self.prefill_tokens += seq.num_tokens
+        self.prefill_grid_tokens += B * bucket
         self.prefills += len(chunk)
         out, g = self._split_guard(out)
         self._push_pending("prefill", out, list(enumerate(chunk)), g)
@@ -5277,6 +5305,7 @@ class EngineCore:
             # templated batch with working reuse shows prefill_tokens
             # well below prompt_tokens.
             prefill_tokens=self.prefill_tokens,
+            prefill_grid_tokens=self.prefill_grid_tokens,
             prefix_demotes=self.prefix_demotes,
             prefix_promotes=self.prefix_promotes,
             prefix_chunks_exported=self.prefix_chunks_exported,

@@ -15,18 +15,26 @@ Invariants (property-tested in tests/test_scheduler.py):
   - slots hold at most one sequence; finished/preempted sequences release
     their references immediately (cache-registered pages park in an
     evictable LRU pool instead of the free list),
-  - admission is FIFO (priority-aware schedulers admit interactive
-    waiters first, FIFO within each class); preemption evicts the
-    *youngest* running sequence (its re-prefill wastes the least work;
-    priority-aware schedulers prefer batch victims).
+  - admission is head-first: every wave takes the waiting queue's head
+    (the oldest waiter; priority-aware schedulers put the oldest
+    interactive waiter there, and admit FIFO within each class). With
+    more waiting than a wave admits and than there are free slots, the
+    rest of the wave is the oldest waiters of the head's prefill bucket
+    among the first ``ADMIT_WINDOW`` (``next_wave``), so that one program
+    prefills them; the sequence at place p is admitted within p + 1
+    waves, whatever overtakes it.
+    Preemption evicts the *youngest* running sequence (its re-prefill
+    wastes the least work; priority-aware schedulers prefer batch
+    victims).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, Hashable, List, Optional, Tuple
 
 from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.obs.metrics import Histogram
@@ -35,6 +43,13 @@ from llmq_tpu.utils.hashing import token_prefix_chain
 
 class OutOfPages(Exception):
     """No free KV pages; caller should preempt or defer."""
+
+
+# How far into the waiting queue a wave looks for sequences of its head's
+# prefill bucket. On the benchmark's own queues (tests/test_admission_replay.py)
+# 32 fills 3.5-3.7 of a program's 4 rows and 64 a little more; a worker keeps
+# half its slots' count waiting (64 behind 128 slots).
+ADMIT_WINDOW = 64
 
 
 def _ms(seconds: Optional[float]) -> Optional[float]:
@@ -462,20 +477,75 @@ class Scheduler:
                     return i
         return 0
 
-    def admit(self, max_new: Optional[int] = None) -> List[Sequence]:
+    def next_wave(
+        self,
+        max_new: Optional[int] = None,
+        bucket_of: Optional[Callable[[Sequence], Hashable]] = None,
+    ) -> List[int]:
+        """Places in the waiting queue of the sequences the next ``admit``
+        would take, in the order it takes them (slots and pages allowing).
+
+        ``bucket_of`` names the program that would prefill a sequence (the
+        engine's prefill bucket; None for a sequence, or instead of the
+        function, where there is none to share). While no more wait than
+        ``max_new`` or than there are free slots (nobody has to wait for a
+        slot, so there is no order to choose: a server with room, whose
+        requests are there for their latency), without a ``bucket_of``, and
+        while a priority-aware scheduler has an interactive waiter, the
+        wave is FIFO (interactive waiters first). Otherwise it is the head
+        plus the oldest waiters among the first ``ADMIT_WINDOW`` whose
+        bucket is the head's, in queue order. The head is in every wave,
+        so nothing starves: the sequence at place p is admitted within
+        p + 1 waves."""
+        n = len(self.waiting)
+        if max_new is not None:
+            n = min(n, max_new)
+        if n == 0:
+            return []
+        first = self.waiting[self._next_admit_index()]
+        if self.config.priority_aware and first.priority == "interactive":
+            wave = list(itertools.islice(
+                (i for i, seq in enumerate(self.waiting)
+                 if seq.priority == "interactive"), n
+            ))
+            rest = (i for i in range(len(self.waiting)) if i not in wave)
+            return wave + list(itertools.islice(rest, n - len(wave)))
+        # From here the head is the queue's first.
+        key = None
+        if bucket_of is not None and len(self.waiting) > max(
+            n, self.slots.count(None)
+        ):
+            key = bucket_of(first)
+        if key is None:
+            return list(range(n))
+        wave = [0]
+        for i in range(1, min(ADMIT_WINDOW, len(self.waiting))):
+            if len(wave) == n:
+                break
+            if bucket_of(self.waiting[i]) == key:
+                wave.append(i)
+        return wave
+
+    def admit(
+        self,
+        max_new: Optional[int] = None,
+        bucket_of: Optional[Callable[[Sequence], Hashable]] = None,
+    ) -> List[Sequence]:
         """Move waiting sequences into free slots while pages allow.
 
         Returns the newly admitted sequences (their ``slot`` and ``pages``
         set); each needs a prefill pass before joining decode. Admission
-        is FIFO; a priority-aware scheduler admits interactive waiters
-        first (see ``_next_admit_index``).
+        takes ``next_wave``: the head first (the oldest waiter; the oldest
+        interactive one under a priority-aware scheduler), then FIFO or,
+        with more than ``max_new`` waiting, the head's bucket mates. It
+        stops at the first sequence the pool has no pages for.
         """
         admitted: List[Sequence] = []
         free_slots = [i for i, s in enumerate(self.slots) if s is None]
-        while self.waiting and free_slots:
-            if max_new is not None and len(admitted) >= max_new:
+        taken: List[int] = []
+        for idx in self.next_wave(max_new, bucket_of):
+            if not free_slots:
                 break
-            idx = self._next_admit_index()
             seq = self.waiting[idx]
             matched: List[int] = []
             host: List[Any] = []
@@ -522,7 +592,7 @@ class Scheduler:
             seq.cacheable_pages = n_reused
             self.prefix_hits += n_reused
             self.prefix_misses += len(hashes) - n_reused
-            del self.waiting[idx]
+            taken.append(idx)
             seq.slot = free_slots.pop(0)
             seq.admitted_at = self._tick
             self._tick += 1
@@ -536,6 +606,8 @@ class Scheduler:
             self.slots[seq.slot] = seq
             self.running[seq.rid] = seq
             admitted.append(seq)
+        for idx in sorted(taken, reverse=True):
+            del self.waiting[idx]
         return admitted
 
     # --- decode-step bookkeeping -----------------------------------------
