@@ -130,7 +130,7 @@ from llmq_tpu.obs.trace import emit_trace_event
 from llmq_tpu.ops import dispatch as _dispatch
 from llmq_tpu.utils.host_mem import get_governor
 from llmq_tpu.utils.platform import on_tpu
-from llmq_tpu.ops.attention import mixed_query_grid
+from llmq_tpu.ops.attention import latent_decode_pages_visited, mixed_query_grid
 from llmq_tpu.parallel import pipeline as pp_mod
 from llmq_tpu.parallel.mesh import (
     DP_AXIS,
@@ -645,12 +645,22 @@ def kv_page_bytes_per_device(
     return 2 * pool // probe_pages
 
 
-def _layer_pattern_refusal(what: str) -> str:
+def _layer_pattern_refusal(what: str, stateful: bool) -> str:
+    """Why an option is refused for a layer pattern: the reason that holds
+    for this one (``stateful``: it has KDA layers)."""
+    if stateful:
+        return (
+            f"{what} is not supported for a model with a layer pattern "
+            "(per-sequence KDA state beside a latent cache): the state cannot "
+            "be shared by a prefix, cut at a chunk, rewound by a length or "
+            "moved between pools, and its experts are held whole on one device"
+        )
     return (
-        f"{what} is not supported for a model with a layer pattern "
-        "(per-sequence KDA state beside a latent cache): the state cannot "
-        "be shared by a prefix, cut at a chunk, rewound by a length or "
-        "moved between pools, and its experts are held whole on one device"
+        f"{what} is not supported for a model with a layer pattern (a latent "
+        "cache alone, no per-sequence state): chunked prefill, verify, the "
+        "mixed step and moving a latent pool are not built for a layer "
+        "pattern (HybridTransformer has whole-prompt prefill and decode), "
+        "and its experts are held whole on one device"
     )
 
 
@@ -724,6 +734,11 @@ class EngineCore:
         # state pool: a row a slot, and row 0 scratch.
         self._hybrid = model_config.layer_pattern is not None
         self._state_rows = self.cfg.max_num_seqs + 1 if self._hybrid else None
+        # Which reason a refusal gives: a state that cannot be cut, or
+        # paths that are not built for a layer pattern.
+        self._stateful = self._hybrid and any(
+            attn == "kda" for attn, _ in model_config.layer_pattern
+        )
         if self._hybrid:
             self._refuse_for_layer_pattern(params)
         if self.pp > 1:
@@ -1153,7 +1168,9 @@ class EngineCore:
                 ("prefix_host_gb", self.prefix_host_gb, 0),
             ):
                 if value != off:
-                    raise ValueError(_layer_pattern_refusal(f"{option}={value}"))
+                    raise ValueError(
+                        _layer_pattern_refusal(f"{option}={value}", self._stateful)
+                    )
             # Counters of the expert layers, summed over layers and decode
             # steps; they ride the pending entry and the fetch of the tokens.
             self.moe_assignments_held = 0
@@ -1557,10 +1574,11 @@ class EngineCore:
             )
 
     def _refuse_for_layer_pattern(self, params: Params) -> None:
-        """What a per-sequence state cannot do yet, refused at build by
-        name: it cannot be shared by a prefix, cut at a chunk, rewound by
-        a length or moved between pools, and the expert share is held
-        whole on one device."""
+        """What a layer pattern cannot do yet, refused at build by name: a
+        per-sequence state cannot be shared by a prefix, cut at a chunk,
+        rewound by a length or moved between pools; for a latent cache
+        alone those paths are not built; either way the expert share is
+        held whole on one device."""
         from llmq_tpu.models import quant as qm
 
         cfg = self.cfg
@@ -1580,7 +1598,7 @@ class EngineCore:
             (jnp.dtype(cfg.kv_dtype).itemsize < 2, f"kv_dtype={cfg.kv_dtype}"),
         ):
             if refused:
-                raise ValueError(_layer_pattern_refusal(what))
+                raise ValueError(_layer_pattern_refusal(what, self._stateful))
 
     def _dispatch_p99(self, kind: str) -> Optional[float]:
         """Watchdog deadline source: live p99 of one dispatch kind, or
@@ -2850,7 +2868,9 @@ class EngineCore:
             )
         if prefill_only and self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("prefill_only (the prefill role)")
+                _layer_pattern_refusal(
+                    "prefill_only (the prefill role)", self._stateful
+                )
             )
         if not self.priority_classes:
             priority = "batch"  # classes disabled: everything is FIFO batch
@@ -2944,6 +2964,17 @@ class EngineCore:
             -(-s.num_tokens // page)
             - (max(s.num_tokens - window, 0) // page if window else 0)
             for s in seqs
+        )
+
+    def _latent_pages_visited(self, seqs: List[Sequence]) -> int:
+        """Latent pages a decode step gathers a layer (beside
+        ``_live_pages``, what is live): every row of the step, the
+        longest row's passes."""
+        return latent_decode_pages_visited(
+            self.cfg.max_num_seqs,
+            max((s.num_tokens for s in seqs), default=0),
+            self._pages_per_seq,
+            self.cfg.page_size,
         )
 
     def _expire_deadlines(self, finished: List[RequestOutput]) -> None:
@@ -4322,7 +4353,11 @@ class EngineCore:
                 rows=len(seqs), live_pages=self._live_pages(seqs),
                 k_steps=k_steps, pending=len(self._pending),
                 ahead=self._ahead,
-                **({"state_rows": len(seqs)} if self._hybrid else {}),
+                **({"state_rows": len(seqs)} if self._stateful else {}),
+                **(
+                    {"latent_pages_visited": self._latent_pages_visited(seqs)}
+                    if self._hybrid else {}
+                ),
             )
         with self._wd(kind):
             out, self.k_pages, self.v_pages, self._dev_state = (
@@ -4720,7 +4755,9 @@ class EngineCore:
         KeyError here but surfaces there). Greedy continuation after
         :meth:`insert_request` is bit-identical to never extracting."""
         if self._hybrid:
-            raise NotImplementedError(_layer_pattern_refusal("extract_request"))
+            raise NotImplementedError(
+                _layer_pattern_refusal("extract_request", self._stateful)
+            )
         out = finished if finished is not None else []
         self._drain(out)
         seq = self.scheduler.running.get(rid)
@@ -4741,7 +4778,9 @@ class EngineCore:
         """Extract every unfinished request (drain-with-handoff). See
         :meth:`extract_request`."""
         if self._hybrid:
-            raise NotImplementedError(_layer_pattern_refusal("extract_all"))
+            raise NotImplementedError(
+                _layer_pattern_refusal("extract_all", self._stateful)
+            )
         out = finished if finished is not None else []
         self._drain(out)
         snaps: List[RequestSnapshot] = []
@@ -4902,7 +4941,9 @@ class EngineCore:
         snapshot bit-for-bit. A snapshot without KV re-prefills
         prompt+output instead — same math, same tokens."""
         if self._hybrid:
-            raise NotImplementedError(_layer_pattern_refusal("insert_request"))
+            raise NotImplementedError(
+                _layer_pattern_refusal("insert_request", self._stateful)
+            )
         sig, mine = dict(snap.model_sig), self._model_sig()
         if sig != mine:
             raise SnapshotCompatError(
@@ -5256,7 +5297,10 @@ class EngineCore:
         s = self.scheduler.stats()
         from llmq_tpu.ops import dispatch as _dispatch
 
-        kern = _dispatch.decode_kernel_plan(
+        # A layer pattern's decode attention is the XLA loop over its latent
+        # pool (``ops/attention.latent_paged_decode_attention``), whatever
+        # the plan would say of a K/V pool with its head counts.
+        kern = "xla" if self._hybrid else _dispatch.decode_kernel_plan(
             self.model_config.num_heads,
             self.model_config.num_kv_heads,
             self.cfg.kv_dtype,

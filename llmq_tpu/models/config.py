@@ -50,7 +50,7 @@ class ModelConfig:
     scale_embeddings: bool = False  # Gemma: embed * sqrt(hidden)
     logit_softcap: Optional[float] = None  # Gemma-2 final softcap
     attn_softcap: Optional[float] = None  # Gemma-2 attention softcap
-    post_norms: bool = False  # Gemma-2 post-attn/post-mlp norms
+    post_norms: bool = False  # Gemma-2 post-attn/post-mlp norms; pangu's sandwich_norm
     qk_norm: bool = False  # Qwen3/Gemma-3 per-head q/k RMSNorm
     sliding_window: Optional[int] = None
     sliding_window_pattern: int = 1  # every Nth layer is global (Gemma-2: 2)
@@ -61,22 +61,29 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     shared_expert_intermediate_size: Optional[int] = None  # qwen2_moe only
     norm_topk_prob: bool = False  # renormalize the top-k routing weights
-    # Declared layer pattern (bailing_hybrid): one (attention, mlp) pair a
-    # layer, attention "kda" | "mla", mlp "dense" | "moe". None: every layer
-    # is the family's one kind, run by ``models/transformer.py`` as before;
-    # a pattern is run by ``models/hybrid.py``.
+    # Declared layer pattern (bailing_hybrid, pangu_ultra_moe): one
+    # (attention, mlp) pair a layer, attention "kda" | "mla", mlp "dense" |
+    # "moe". None: every layer is the family's one kind, run by
+    # ``models/transformer.py`` as before; a pattern is run by
+    # ``models/hybrid.py``.
     layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
     # KDA (delta-rule) layers: heads are num_heads x head_dim.
     short_conv_kernel_size: int = 4
     kda_lower_bound: float = -5.0  # bounded ("safe") gate: g in [bound, 0]
-    # MLA (latent attention) layers.
+    # MLA (latent attention) layers. ``q_lora_rank``: the query goes through
+    # a normed latent of that width; None: one full-rank projection.
+    # ``mla_head_gate``: a head-wise sigmoid gate before ``o_proj``.
     kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    mla_head_gate: bool = False
     qk_nope_head_dim: Optional[int] = None
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
-    # noaux_tc sigmoid router: groups, a selection bias, a scaling factor.
+    # Sigmoid router of a layer pattern: groups (1: none), a selection
+    # bias (``router_bias``), a scaling factor.
     n_group: int = 1
     topk_group: int = 1
+    router_bias: bool = False
     routed_scaling_factor: float = 1.0
     # (first, count) of the routed experts this chip holds; None: all of
     # them. The router keeps its width (num_experts) either way.
@@ -112,6 +119,8 @@ class ModelConfig:
         mt = hf.get("model_type", "llama")
         if mt == "bailing_hybrid":
             return cls._from_bailing_hybrid(hf)
+        if mt == "pangu_ultra_moe":
+            return cls._from_pangu_ultra_moe(hf)
         eos = _as_id_list(hf.get("eos_token_id"))
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -256,6 +265,7 @@ class ModelConfig:
             short_conv_kernel_size=hf.get("short_conv_kernel_size", 4),
             kda_lower_bound=float(hf.get("kda_lower_bound", -5.0)),
             kv_lora_rank=hf["kv_lora_rank"],
+            mla_head_gate=True,
             qk_nope_head_dim=hf["qk_nope_head_dim"],
             qk_rope_head_dim=hf["qk_rope_head_dim"],
             v_head_dim=hf["v_head_dim"],
@@ -269,6 +279,67 @@ class ModelConfig:
             norm_topk_prob=hf.get("norm_topk_prob", True),
             n_group=hf.get("n_group", 1),
             topk_group=hf.get("topk_group", 1),
+            router_bias=True,
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            experts_held=None if held is None else (int(held[0]), int(held[1])),
+        )
+
+    @classmethod
+    def _from_pangu_ultra_moe(cls, hf: Dict[str, Any]) -> "ModelConfig":
+        """openPangu-Ultra-MoE (``pangu_ultra_moe``): latent attention on
+        every layer, the query through a normed latent (``q_lora_rank``),
+        ``first_k_dense_replace`` dense MLPs and routed experts after
+        (one group, no selection bias, sigmoid scores) with
+        ``n_shared_experts`` shared ones run as one of their summed width;
+        ``sandwich_norm``: an RMSNorm on each sub-layer's output before
+        the residual add. ``experts_held`` ([first, count], default all)
+        is this repo's own key, as in ``bailing_hybrid``. The
+        multi-token-prediction module (``num_nextn_predict_layers``) is
+        not built, as public loaders leave it out."""
+        wanted = {
+            "attention_bias": False, "hidden_act": "silu", "rope_scaling": None,
+            "n_group": 1, "topk_group": 1, "scoring_func": "sigmoid",
+            "moe_router_enable_expert_bias": False, "rope_interleave": True,
+            "num_key_value_heads": hf["num_attention_heads"],
+        }
+        for key, want in wanted.items():
+            if hf.get(key, want) != want:
+                raise ValueError(
+                    f"pangu_ultra_moe: {key}={hf[key]!r} is not built "
+                    f"(only {want!r} is)"
+                )
+        layers, dense = int(hf["num_hidden_layers"]), int(hf["first_k_dense_replace"])
+        held = hf.get("experts_held")
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            num_layers=layers,
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_attention_heads"],
+            intermediate_size=hf["intermediate_size"],
+            max_position_embeddings=hf.get("max_position_embeddings", 131072),
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            post_norms=bool(hf.get("sandwich_norm", False)),
+            eos_token_ids=tuple(_as_id_list(hf.get("eos_token_id"))),
+            bos_token_id=hf.get("bos_token_id"),
+            model_type="pangu_ultra_moe",
+            layer_pattern=tuple(
+                ("mla", "dense" if i < dense else "moe") for i in range(layers)
+            ),
+            kv_lora_rank=hf["kv_lora_rank"],
+            q_lora_rank=hf.get("q_lora_rank"),
+            qk_nope_head_dim=hf["qk_nope_head_dim"],
+            qk_rope_head_dim=hf["qk_rope_head_dim"],
+            v_head_dim=hf["v_head_dim"],
+            num_experts=hf["n_routed_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            moe_intermediate_size=hf["moe_intermediate_size"],
+            shared_expert_intermediate_size=(
+                hf["moe_intermediate_size"] * int(hf.get("n_shared_experts", 1))
+            ),
+            norm_topk_prob=hf.get("norm_topk_prob", True),
             routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
             experts_held=None if held is None else (int(held[0]), int(held[1])),
         )

@@ -1,7 +1,10 @@
-"""Models with a declared layer pattern (``ModelConfig.layer_pattern``):
-delta-rule (KDA) layers with a per-sequence state beside latent (MLA)
-layers over a paged latent cache, dense or routed-expert MLPs, the experts
-held by share (``bailing_hybrid``: Ling-3.0).
+"""Models with a declared layer pattern (``ModelConfig.layer_pattern``),
+two families: delta-rule (KDA) layers with a per-sequence state beside
+latent (MLA) layers over a paged latent cache (``bailing_hybrid``:
+Ling-3.0), and latent layers alone, every one with a query LoRA and
+sandwich norms, no state layer at all (``pangu_ultra_moe``:
+openPangu-Ultra-MoE). Dense or routed-expert MLPs, the experts held by
+share.
 
 Every other family is one uniform stack and stays on
 ``models/transformer.py``; nothing here is on its path.
@@ -20,7 +23,8 @@ and return them as they do those:
   row of the sequence's first page (``block_tables[:, 0]``). Row 0 is
   scratch, as page 0 is: padded prefill rows and inactive decode rows
   write there. A prefill overwrites its row whole, so a row needs no
-  clearing between sequences.
+  clearing between sequences. A pattern with no KDA layer has ``L_kda``
+  0: both leaves are empty, cost no HBM, and ride along untouched.
 
 The block (published; what the configuration does not settle is listed
 under ``assumed`` in ``benchmark/configs/ling-3.0-flash-ep4.json``):
@@ -32,6 +36,16 @@ prefill and absorbed in decode, with a head-wise sigmoid gate before
 selection bias, ``routed_scaling_factor``) whose chosen experts are
 computed where they are held here (:func:`moe_held`), plus a shared
 expert.
+
+``pangu_ultra_moe`` (``benchmark/configs/openpangu-ultra-moe-718b-ep16.json``
+lists what is assumed) differs by static branches on the configuration,
+so that the other family's programs lower as they did: the query is
+``W_qb RMSNorm(W_qa x)`` (``q_lora_rank``; scope ``llmq.attn.mla.q_lora``
+inside the attention scopes), there is no head-wise gate
+(``mla_head_gate``), each sub-layer's output is normed before the
+residual add (``post_norms``: ``h + N2(MLA(N1 h))``, ``x + N4(F(N3 x))``;
+scope ``llmq.norm.sandwich``), and the router is one group with no
+selection bias (``n_group`` 1, ``router_bias``).
 """
 
 from __future__ import annotations
@@ -115,22 +129,34 @@ def group_shapes(config: ModelConfig, group: LayerGroup) -> Dict[str, tuple]:
         )
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        if cfg.q_lora_rank:
+            shapes.update(
+                mla_qa_proj=(L, H, cfg.q_lora_rank),
+                mla_q_norm=(L, cfg.q_lora_rank),
+                mla_qb_proj=(L, cfg.q_lora_rank, n * qk),
+            )
+        else:
+            shapes.update(mla_q_proj=(L, H, n * qk))
         shapes.update(
-            mla_q_proj=(L, H, n * qk),
             mla_kva_proj=(L, H, latent_width(cfg)),
             mla_kv_norm=(L, cfg.kv_lora_rank),
             mla_kvb_proj=(L, cfg.kv_lora_rank, n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-            mla_g_proj=(L, H, n),
             o_proj=(L, n * cfg.v_head_dim, H),
         )
+        if cfg.mla_head_gate:
+            shapes.update(mla_g_proj=(L, H, n))
+    if cfg.post_norms:
+        shapes.update(post_attn_norm=(L, H), post_mlp_norm=(L, H))
     if group.mlp == "dense":
         I = cfg.intermediate_size
         shapes.update(gate_proj=(L, H, I), up_proj=(L, H, I), down_proj=(L, I, H))
     else:
         E, Im = cfg.num_experts, cfg.moe_intermediate_size
         held, Is = cfg.experts_held_[1], cfg.shared_expert_intermediate_size
+        if cfg.router_bias:
+            shapes.update(router_bias=(L, E))
         shapes.update(
-            router=(L, H, E), router_bias=(L, E),
+            router=(L, H, E),
             expert_gate_proj=(L, held, H, Im), expert_up_proj=(L, held, H, Im),
             expert_down_proj=(L, held, Im, H),
             shared_gate_proj=(L, H, Is), shared_up_proj=(L, H, Is),
@@ -151,7 +177,10 @@ def param_shapes(config: ModelConfig) -> Dict[str, Any]:
     return shapes
 
 
-_ONES = ("ln1", "ln2", "final_norm", "kda_o_norm", "mla_kv_norm")
+_ONES = (
+    "ln1", "ln2", "final_norm", "kda_o_norm", "mla_kv_norm", "mla_q_norm",
+    "post_attn_norm", "post_mlp_norm",
+)
 _ZEROS = ("router_bias", "kda_a_log", "kda_dt_bias")
 
 
@@ -239,12 +268,18 @@ def state_pool_bytes(config: ModelConfig, rows: int, dtype) -> int:
 #: few hundred rows the dense form's arithmetic (rows x experts) loses.
 DENSE_EXPERT_ROWS = 256
 
-#: Rows an expert layer takes at a time (see :func:`moe_held`).
+#: Rows an expert layer takes at a time (see :func:`moe_held`), at the
+#: hidden size it was measured at; a wider model takes fewer.
 MOE_BLOCK_ROWS = 4096
+_MOE_BLOCK_HIDDEN = 2560
 
 #: Tokens of a padded prefill batch above which a KDA layer takes its
 #: rows one at a time (see ``HybridTransformer._kda_prefill``).
 KDA_PREFILL_TOKENS = 8192
+
+#: Tokens x heads of a padded prefill batch above which an MLA layer
+#: expands its rows one at a time (see ``HybridTransformer._mla_prefill``).
+MLA_PREFILL_HEAD_TOKENS = 2**20
 
 
 def moe_held(
@@ -269,13 +304,17 @@ def moe_held(
     is in flight (``rows x 8`` assignments of float32 hidden rows) stays
     one block's whatever the bucket: a 4 x 8,192 prefill would hold 2 x
     2.5 GB otherwise (compiled for a v5e, PR 33); the counters are then
-    sums over blocks."""
+    sums over blocks. The block halves while its rows are wider in all
+    than ``MOE_BLOCK_ROWS`` rows of 2,560 (7,680 wide: 1,024 rows; a 4 x
+    2,048 prefill held 4.2 GB otherwise, compiled for a v5e, PR 39)."""
     *lead, H = h.shape
     x = h.reshape(-1, H)
-    if x.shape[0] > 2 * MOE_BLOCK_ROWS and x.shape[0] % MOE_BLOCK_ROWS == 0:
+    block = MOE_BLOCK_ROWS
+    while block > 256 and block * H > MOE_BLOCK_ROWS * _MOE_BLOCK_HIDDEN:
+        block //= 2
+    if x.shape[0] > 2 * block and x.shape[0] % block == 0:
         out, counts = jax.lax.map(
-            lambda rows: _moe_rows(rows, lp, config),
-            x.reshape(-1, MOE_BLOCK_ROWS, H),
+            lambda rows: _moe_rows(rows, lp, config), x.reshape(-1, block, H)
         )
         counts = counts.sum(axis=0)
     else:
@@ -292,15 +331,18 @@ def _moe_rows(x: jnp.ndarray, lp: Params, config: ModelConfig):
         scores = jax.nn.sigmoid(
             jnp.dot(x, lp["router"], preferred_element_type=F32)
         )  # [N, E]
-        choice = scores + lp["router_bias"].astype(F32)  # selection only
+        choice = scores
+        if cfg.router_bias:
+            choice = scores + lp["router_bias"].astype(F32)  # selection only
         G = cfg.n_group
-        grouped = choice.reshape(N, G, E // G)
-        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)  # [N, G]
-        kept_groups = jax.lax.top_k(group_score, cfg.topk_group)[1]
-        keep = jnp.zeros((N, G), bool).at[
-            jnp.arange(N)[:, None], kept_groups
-        ].set(True)
-        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(N, E)
+        if G > 1:
+            grouped = choice.reshape(N, G, E // G)
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)  # [N, G]
+            kept_groups = jax.lax.top_k(group_score, cfg.topk_group)[1]
+            keep = jnp.zeros((N, G), bool).at[
+                jnp.arange(N)[:, None], kept_groups
+            ].set(True)
+            choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(N, E)
         top_e = jax.lax.top_k(choice, k)[1]  # [N, k]
         top_w = jnp.take_along_axis(scores, top_e, axis=1)
         if cfg.norm_topk_prob:
@@ -384,8 +426,9 @@ class HybridTransformer(Transformer):
     """``prefill`` and ``decode`` of a model with a layer pattern, with the
     signatures of :class:`Transformer`'s plus ``state_rows``. The paths
     that need a cache which can be cut or rewound by a length (chunked
-    prefill, verify, the mixed step) are not built for a per-sequence
-    state, and the engine refuses their options at build."""
+    prefill, verify, the mixed step) are not built for a layer pattern,
+    and the engine refuses their options at build: a per-sequence state
+    cannot be cut so; a latent cache alone could be, and is not yet."""
 
     def __post_init__(self):
         if self.config.layer_pattern is None:
@@ -513,7 +556,15 @@ class HybridTransformer(Transformer):
         inv_freq = 1.0 / (
             cfg.rope_theta ** (jnp.arange(0, rope, 2, dtype=F32) / rope)
         )
-        q = qm.matmul(x, lp["mla_q_proj"]).reshape(B, T, n, -1)
+        if cfg.q_lora_rank:
+            with jax.named_scope("llmq.attn.mla.q_lora"):
+                c_q = rms_norm(
+                    qm.matmul(x, lp["mla_qa_proj"]), lp["mla_q_norm"], cfg.rms_norm_eps
+                )
+                q = qm.matmul(c_q, lp["mla_qb_proj"])
+        else:
+            q = qm.matmul(x, lp["mla_q_proj"])
+        q = q.reshape(B, T, n, -1)
         q_c, q_r = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
         q_r = _rope_interleaved(q_r, positions, inv_freq)
         kva = qm.matmul(x, lp["mla_kva_proj"])
@@ -524,9 +575,11 @@ class HybridTransformer(Transformer):
         return q_c, q_r, jnp.concatenate([c, r], axis=-1)
 
     def _mla_out(self, lp: Params, x: jnp.ndarray, o: jnp.ndarray):
-        """Head-wise sigmoid gate, then ``o_proj``. ``o``: [..., n, d_v]."""
-        gate = jax.nn.sigmoid(qm.matmul(x, lp["mla_g_proj"]).astype(F32))
-        o = (o.astype(F32) * gate[..., None]).astype(x.dtype)
+        """Head-wise sigmoid gate where the family has one, then
+        ``o_proj``. ``o``: [..., n, d_v]."""
+        if self.config.mla_head_gate:
+            gate = jax.nn.sigmoid(qm.matmul(x, lp["mla_g_proj"]).astype(F32))
+            o = (o.astype(F32) * gate[..., None]).astype(x.dtype)
         *lead, n, dv = o.shape
         with jax.named_scope("llmq.o_proj"):
             return qm.matmul(o.reshape(*lead, n * dv), lp["o_proj"])
@@ -538,15 +591,38 @@ class HybridTransformer(Transformer):
     @jax.named_scope("llmq.attn.mla_prefill")
     def _mla_prefill(self, lp, x, positions, lengths, latent, block_tables, li):
         """Expanded: keys and values raised from the latent, every head
-        its own."""
+        its own. Above ``MLA_PREFILL_HEAD_TOKENS`` a row of the batch at a
+        time: the expanded q, k and v in flight are one row's (a 4 x 4,096
+        bucket at 128 heads holds 3.2 GB of them otherwise: compiled for a
+        v5e, PR 39)."""
+
+        def write(latent, row):
+            with jax.named_scope("llmq.kv_write"):
+                return attn_ops.write_latent_pages(
+                    latent, row, block_tables, positions, li
+                )
+
+        B, T, _ = x.shape
+        if B > 1 and B * T * self.config.num_heads > MLA_PREFILL_HEAD_TOKENS:
+            def one_row(args):
+                x, positions, lengths = (a[None] for a in args)
+                q_c, q_r, row = self._mla_inputs(lp, x, positions)
+                return self._mla_expanded(lp, x, q_c, q_r, row, lengths), row
+
+            y, row = (
+                out[:, 0] for out in jax.lax.map(one_row, (x, positions, lengths))
+            )
+            return y, write(latent, row)
+        q_c, q_r, row = self._mla_inputs(lp, x, positions)
+        latent = write(latent, row)
+        return self._mla_expanded(lp, x, q_c, q_r, row, lengths), latent
+
+    def _mla_expanded(self, lp, x, q_c, q_r, row, lengths):
+        """Attention of a prefill over keys and values raised from the
+        latent rows of the batch itself, and the output projection."""
         cfg = self.config
         n = cfg.num_heads
         B, T, _ = x.shape
-        q_c, q_r, row = self._mla_inputs(lp, x, positions)
-        with jax.named_scope("llmq.kv_write"):
-            latent = attn_ops.write_latent_pages(
-                latent, row, block_tables, positions, li
-            )
         c, r = row[..., : cfg.kv_lora_rank], row[..., cfg.kv_lora_rank :]
         kv = qm.matmul(c, lp["mla_kvb_proj"]).reshape(B, T, n, -1)
         k_c, v = kv[..., : cfg.qk_nope_head_dim], kv[..., cfg.qk_nope_head_dim :]
@@ -558,7 +634,7 @@ class HybridTransformer(Transformer):
             jnp.concatenate([q_c, q_r], axis=-1), k, v,
             scale=self._mla_scale(), lengths=lengths,
         )
-        return self._mla_out(lp, x, o), latent
+        return self._mla_out(lp, x, o)
 
     @jax.named_scope("llmq.attn.mla_decode")
     def _mla_decode(self, lp, x, positions, latent, block_tables, ctx_incl, li):
@@ -597,6 +673,9 @@ class HybridTransformer(Transformer):
                 lp, li = xs
                 x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
                 a, latent, state = attend(group, lp, x, latent, state, li)
+                if cfg.post_norms:
+                    with jax.named_scope("llmq.norm.sandwich"):
+                        a = rms_norm(a, lp["post_attn_norm"], cfg.rms_norm_eps)
                 h = h + a
                 x = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
                 if group.mlp == "dense":
@@ -605,6 +684,9 @@ class HybridTransformer(Transformer):
                     with jax.named_scope("llmq.moe"):
                         m, counts = moe_held(x, lp, cfg)
                     moe = moe + counts
+                if cfg.post_norms:
+                    with jax.named_scope("llmq.norm.sandwich"):
+                        m = rms_norm(m, lp["post_mlp_norm"], cfg.rms_norm_eps)
                 return (h + m, latent, state, moe), None
 
             carry = (h, latent, state, moe)
@@ -709,7 +791,8 @@ class HybridTransformer(Transformer):
 
     def prefill_chunk(self, *args, **kwargs):
         raise NotImplementedError(
-            "chunked prefill is not built for a model with a per-sequence state"
+            "chunked prefill, verify and the mixed step are not built for a "
+            "layer pattern"
         )
 
     mixed = verify = prefill_chunk

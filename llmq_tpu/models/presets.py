@@ -126,6 +126,45 @@ PRESETS["ling-3.0-flash-ep4"] = ModelConfig.from_hf_config(
     )
 )
 
+# openPangu-Ultra-MoE-718B (``pangu_ultra_moe``), the published config:
+# latent attention with a query LoRA on all 61 layers, sandwich norms,
+# three dense MLPs, then 256 sigmoid-routed experts (one group, no
+# selection bias) of which 8 a token, one shared expert.
+_OPENPANGU_ULTRA_MOE = dict(
+    model_type="pangu_ultra_moe", vocab_size=153600, hidden_size=7680,
+    num_hidden_layers=61, num_attention_heads=128, num_key_value_heads=128,
+    intermediate_size=18432, max_position_embeddings=131072,
+    rope_theta=25600000, rms_norm_eps=1e-05, tie_word_embeddings=False,
+    attention_bias=False, hidden_act="silu", sandwich_norm=True,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, first_k_dense_replace=3,
+    n_routed_experts=256, n_shared_experts=1, num_experts_per_tok=8,
+    moe_intermediate_size=2048, norm_topk_prob=True,
+    routed_scaling_factor=2.5, num_nextn_predict_layers=1,
+)
+# One chip's share of 16 that share each layer: one of the three leading
+# dense layers and four expert layers (a pipeline stage's worth); experts
+# 0-15 of 256 (the router keeps its 256 outputs and 8 a token); rows
+# 0-19,199 of the vocabulary (split over 8). Every width is the published
+# one.
+PRESETS["openpangu-ultra-moe-718b-ep16"] = ModelConfig.from_hf_config(
+    dict(
+        _OPENPANGU_ULTRA_MOE, num_hidden_layers=5, first_k_dense_replace=1,
+        experts_held=[0, 16], vocab_size=153600 // 8,
+    )
+)
+# The same block at widths a CPU test serves (byte tokenizer: ids < 304).
+PRESETS["openpangu-ultra-moe-tiny"] = ModelConfig.from_hf_config(
+    dict(
+        _OPENPANGU_ULTRA_MOE, vocab_size=304, hidden_size=64,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        intermediate_size=128, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        first_k_dense_replace=1, n_routed_experts=16, num_experts_per_tok=4,
+        moe_intermediate_size=32, experts_held=[4, 8],
+    )
+)
+
 
 def get_preset(name: str) -> ModelConfig:
     try:

@@ -408,13 +408,12 @@ def latent_paged_decode_attention(
     S, n, W = q.shape
     page, Wp = pages.shape[2], pages.shape[3]
     pps = block_tables.shape[1]
-    C = min(LATENT_DECODE_CHUNK_PAGES, pps)
-    passes = -(-pps // C)
+    C, passes = _latent_decode_chunks(pps)
     bt = jnp.pad(block_tables, ((0, 0), (0, passes * C - pps)))  # page 0: scratch
     mul = _compute_dtype(q.dtype, pages.dtype)
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, Wp - W))).astype(mul)
     span = C * page
-    live = jnp.clip(-(-jnp.max(context_lens) // span), 1, passes)
+    live = latent_decode_passes(jnp.max(context_lens), pps, page)
 
     def one_pass(j, carry):
         m, l, acc = carry
@@ -442,6 +441,36 @@ def latent_paged_decode_attention(
     )
     _, l, acc = jax.lax.fori_loop(0, live, one_pass, init)
     return (acc / l[..., None]).astype(q.dtype)
+
+
+def _latent_decode_chunks(pages_per_seq: int):
+    """Pages a pass, and the passes that cover a whole row of the block
+    table."""
+    C = min(LATENT_DECODE_CHUNK_PAGES, pages_per_seq)
+    return C, -(-pages_per_seq // C)
+
+
+def latent_decode_passes(longest, pages_per_seq: int, page_size: int):
+    """The passes a step of :func:`latent_paged_decode_attention` takes:
+    the longest context's (new token included), at least one. Written
+    once: ``longest`` is the traced maximum of the step's ``context_lens``
+    inside the program, or a Python int where the engine's dispatch span
+    counts the same passes on the host."""
+    C, passes = _latent_decode_chunks(pages_per_seq)
+    need = -(-longest // (C * page_size))
+    if isinstance(longest, int):
+        return min(max(need, 1), passes)
+    return jnp.clip(need, 1, passes)
+
+
+def latent_decode_pages_visited(
+    rows: int, longest: int, pages_per_seq: int, page_size: int
+) -> int:
+    """Pages :func:`latent_paged_decode_attention` gathers a layer for a
+    step of ``rows`` rows: every row takes the longest row's passes, a
+    chunk of pages a pass."""
+    C, _ = _latent_decode_chunks(pages_per_seq)
+    return rows * latent_decode_passes(int(longest), pages_per_seq, page_size) * C
 
 
 def blocked_prefill_attention(

@@ -337,8 +337,9 @@ class TPUWorker(BaseWorker):
             if model_config.layer_pattern is not None and self.role != "unified":
                 raise ValueError(
                     f"role={self.role} is not supported for a model with a "
-                    "layer pattern: its per-sequence state has no snapshot "
-                    "to hand from a prefill pool to a decode pool"
+                    "layer pattern: it has no snapshot to hand from a "
+                    "prefill pool to a decode pool (a per-sequence state "
+                    "cannot be moved; moving a latent pool is not built)"
                 )
             self.logger.info("Preset model %s (random weights)", name)
             init = partial(

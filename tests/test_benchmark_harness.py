@@ -57,16 +57,58 @@ def test_cell_finds_traffic_and_metric_files(cell):
         assert callable(reader.read)
 
 
-def test_the_hybrid_preset_is_the_tree_its_configuration_describes():
-    """The served tree of ``preset://ling-3.0-flash-ep4`` and the tree the
-    benchmark makes from the configuration's file have one layout."""
+@pytest.mark.parametrize("name", ["ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16"])
+def test_the_hybrid_preset_is_the_tree_its_configuration_describes(name):
+    """The served tree of ``preset://<name>`` and the tree the benchmark
+    makes from the configuration's file have one layout."""
     from llmq_tpu.models import hybrid
     from llmq_tpu.models.presets import get_preset
 
-    cfg = json.loads((ROOT / "benchmark/configs/ling-3.0-flash-ep4.json").read_text())
-    assert cfg["program_model"] == "preset://ling-3.0-flash-ep4"
+    cfg = json.loads((ROOT / f"benchmark/configs/{name}.json").read_text())
+    assert cfg["program_model"] == f"preset://{name}"
     ours = jax.tree.map(
-        tuple, hybrid.param_shapes(get_preset("ling-3.0-flash-ep4")),
+        tuple, hybrid.param_shapes(get_preset(name)),
         is_leaf=lambda x: isinstance(x, tuple),
     )
     assert ours == architectures.of(cfg).tree_shapes(cfg)
+
+
+def test_the_openpangu_file_keeps_every_published_key_it_does_not_reduce():
+    """Every key of the published config (the worker's preset carries it)
+    stands in the benchmark's file at its published value, but for those
+    under ``reduced``, which stand beside their published value; no
+    reduced key is a width."""
+    from llmq_tpu.models.presets import _OPENPANGU_ULTRA_MOE as published
+
+    cfg = json.loads(
+        (ROOT / "benchmark/configs/openpangu-ultra-moe-718b-ep16.json").read_text()
+    )
+    reduced = set(cfg["reduced"])
+    assert reduced == {"num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
+                       "vocab_size", "num_nextn_predict_layers"}
+    for key, value in published.items():
+        if key in reduced:
+            assert cfg[f"{key}_published"] == value and cfg[key] != value, key
+        else:
+            assert cfg[key] == value, key
+    assert cfg["experts_held"] == [0, cfg["n_routed_experts"]]
+    assert set(cfg["assumed"]) >= {"router", "rope", "softmax_scale", "norm_placement",
+                                   "shared_expert"}
+
+
+def test_latent_decode_cost_by_hand_and_a_reader_with_nothing_to_read():
+    """``kernel_cost_mla``: one live token and one row of one layer at the
+    published widths (the benchmark's own copy of this proof is
+    ``benchmark/tests/test_kernel_cost_mla.py``); and the reader leaves
+    the metric out where the program gave no spans."""
+    from types import SimpleNamespace
+
+    from benchmark import kernel_cost_mla
+    from benchmark.readers import decode_mla_roofline
+
+    kw = dict(live_tokens=1, rows=1, layers=1, hidden=7680, heads=128, kv_rank=512,
+              nope=128, rope=64, v_dim=128, q_lora=True)
+    assert kernel_cost_mla.mla_decode_bytes(**kw) == 1_152 + 42_401_792 + 48_128
+    assert kernel_cost_mla.mla_decode_flops(**kw) == 278_528 + 42_401_792
+    ctx = SimpleNamespace(_span_join=False, peaks={}, live_kv={"tokens": 1, "sequences": 1})
+    assert decode_mla_roofline.read(ctx, program="jit_decode_step", scope="x") is None

@@ -1,6 +1,8 @@
 """The engine serving a model with a layer pattern (``models/hybrid.py``):
 slots and their state rows reused while run-ahead steps are in flight,
-what it refuses at build, and its counters. CPU, tiny widths."""
+what it refuses at build, and its counters; and a pattern with no state
+layer at all (``pangu_ultra_moe``: latent attention alone), whose state
+pool is empty. CPU, tiny widths."""
 
 import jax
 import jax.numpy as jnp
@@ -11,8 +13,11 @@ from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.engine.snapshot import RequestSnapshot
 from llmq_tpu.engine.tokenizer import ByteTokenizer
 from llmq_tpu.models import quant as qm
+from llmq_tpu.models import hybrid
 from llmq_tpu.models.config import ModelConfig
+from llmq_tpu.models.presets import get_preset
 from llmq_tpu.models.transformer import init_params
+from llmq_tpu.ops.attention import latent_decode_pages_visited
 from llmq_tpu.parallel import make_mesh
 
 CFG = ModelConfig(
@@ -26,18 +31,26 @@ CFG = ModelConfig(
     topk_group=2, routed_scaling_factor=2.5, experts_held=(4, 8),
 )
 PARAMS = init_params(CFG, jax.random.key(0), dtype=jnp.float32)
+# A pattern with NO state layer: latent attention alone (a query LoRA,
+# sandwich norms, one group of experts), as the worker's preset builds it.
+STATELESS = get_preset("openpangu-ultra-moe-tiny")
+STATELESS_PARAMS = init_params(STATELESS, jax.random.key(0), dtype=jnp.float32)
 
 
-def make_core(params=PARAMS, mesh=None, **engine) -> EngineCore:
+def make_core(params=PARAMS, mesh=None, cfg=CFG, **engine) -> EngineCore:
     options = dict(
         max_num_seqs=4, max_model_len=96, page_size=8, num_pages=60,
         kv_dtype=jnp.float32, min_prefill_bucket=16,
     )
     options.update(engine)
     return EngineCore(
-        CFG, params, ByteTokenizer(), mesh=mesh or make_mesh(tensor_parallel=1),
+        cfg, params, ByteTokenizer(), mesh=mesh or make_mesh(tensor_parallel=1),
         engine_config=EngineConfig(**options),
     )
+
+
+def make_stateless(**engine) -> EngineCore:
+    return make_core(params=STATELESS_PARAMS, cfg=STATELESS, **engine)
 
 
 def greedy(n=8):
@@ -145,6 +158,7 @@ def test_counters_ride_the_token_fetch_and_the_span():
     )
     dispatches = [s for s in core.spans.dump()["spans"] if s["name"] == "decode_dispatch"]
     assert dispatches and all(1 <= s["state_rows"] <= 3 for s in dispatches)
+    assert all(s["latent_pages_visited"] >= s["live_pages"] for s in dispatches)
 
 
 REFUSED_AT_BUILD = {  # option -> the name the refusal gives
@@ -163,6 +177,98 @@ def test_refused_at_build_by_name(option, named):
     with pytest.raises(ValueError, match="layer pattern") as refused:
         make_core(**option)
     assert str(refused.value).startswith(named)
+    assert "per-sequence KDA state" in str(refused.value)
+
+
+@pytest.mark.parametrize("option, named", REFUSED_AT_BUILD.values(), ids=REFUSED_AT_BUILD.keys())
+def test_a_pattern_without_state_is_refused_for_the_reason_that_holds(option, named):
+    """No state layer: the option is refused because the path is not built
+    for a layer pattern, not for a state the model does not have."""
+    with pytest.raises(ValueError, match="not built for a layer pattern") as refused:
+        make_stateless(**option)
+    assert str(refused.value).startswith(named)
+    assert "KDA" not in str(refused.value)
+
+
+def test_a_pattern_without_state_layers_has_an_empty_state_pool():
+    """Zero KDA layers: both leaves of the state pool are empty, the pool
+    sizing takes nothing out of the budget for them, and the engine's
+    cache is the latent pool alone."""
+    assert hybrid.count_layers(STATELESS, "kda") == 0
+    assert hybrid.state_pool_bytes(STATELESS, 129, jnp.bfloat16) == 0
+    core = make_stateless()
+    assert core._state_rows == 5 and not core._stateful
+    assert {k: v.shape[0] for k, v in core.v_pages.items()} == {"S": 0, "conv": 0}
+    assert all(v.size == 0 for v in core.v_pages.values())
+    latent = core.k_pages
+    assert latent.shape == (3, 60, 8, 128)  # 3 MLA layers; rows of 40 kept in 128
+    assert core.kv_pool_bytes == latent.size * latent.dtype.itemsize
+
+
+def test_a_pattern_without_state_layers_is_served_as_a_fresh_engine_serves_it():
+    """8 requests through 4 slots, slots reused while run-ahead steps are
+    in flight, then a pool too small for three (recompute preemption):
+    every request gets the tokens an engine with nothing else in it
+    serves."""
+    idle = make_stateless()
+
+    def alone_stateless(prompt, n):
+        idle.add_request("x", prompt=prompt, params=greedy(n))
+        return drain(idle)["x"].token_ids
+
+    core = make_stateless()
+    for rid, prompt, n in REQUESTS:
+        core.add_request(rid, prompt=prompt, params=greedy(n))
+    outs = drain(core)
+    assert core.stats()["prefills"] == len(REQUESTS)
+    for rid, prompt, n in REQUESTS:
+        assert outs[rid].token_ids == alone_stateless(prompt, n), rid
+    prompts = [(f"r{i}", f"pr {i} " * 3, 14) for i in range(3)]
+    small = make_stateless(num_pages=9, page_size=4, max_model_len=48)
+    for rid, prompt, n in prompts:
+        small.add_request(rid, prompt=prompt, params=greedy(n))
+    outs = drain(small)
+    assert small.stats()["preemptions"] >= 1
+    for rid, prompt, n in prompts:
+        assert outs[rid].token_ids == alone_stateless(prompt, n), rid
+
+
+def test_a_pattern_without_state_layers_reports_what_the_latent_loop_gathers(monkeypatch):
+    """``decode_dispatch`` carries ``latent_pages_visited`` (every row of
+    the step x the longest row's passes x the pages a pass) beside
+    ``live_pages``, no ``state_rows``; the expert counters ride the token
+    fetch; ``stats()["decode_kernel"]`` says what it says for any latent
+    pool: the XLA loop, whatever schedule a K/V pool of its head counts
+    would get on the chip."""
+    core = make_stateless(max_model_len=96, page_size=8)  # 12 page places: 3 passes of 4
+    core.spans.set(True)
+    for rid, prompt, n in REQUESTS[:3]:
+        core.add_request(rid, prompt=prompt, params=greedy(n))
+    drain(core)
+    monkeypatch.setattr(
+        "llmq_tpu.ops.dispatch.decode_kernel_plan", lambda *a, **k: "live"
+    )
+    stats = core.stats()
+    assert stats["decode_kernel"] == make_core().stats()["decode_kernel"] == "xla"
+    steps = stats["decode_steps"]
+    # 2 expert layers, 4 slots a step, 4 experts a token of which 8 of 16 held
+    assert 0 < stats["moe_assignments_held"] <= steps * 2 * 4 * 4
+    assert 0 < stats["moe_experts_hit"] <= min(stats["moe_assignments_held"], steps * 2 * 8)
+    dispatches = [s for s in core.spans.dump()["spans"] if s["name"] == "decode_dispatch"]
+    assert dispatches and all("state_rows" not in s for s in dispatches)
+    for s in dispatches:
+        # 4 slots x passes x 4 pages a pass; a pass covers 32 tokens
+        assert s["latent_pages_visited"] in (16, 32, 48)
+        assert s["latent_pages_visited"] >= s["live_pages"]
+    # the host's view of the longest row lags the steps in flight
+    longest = max(len(p) + n for _, p, n in REQUESTS[:3])
+    assert max(s["latent_pages_visited"] for s in dispatches) <= 16 * -(-longest // 32)
+    # rows x the longest row's passes x pages a pass, capped at the block table
+    visited = latent_decode_pages_visited
+    assert visited(128, 3584, 32, 128) == 128 * 7 * 4 == 3584
+    assert visited(128, 1, 32, 128) == visited(128, 0, 32, 128) == 512
+    assert visited(4, 33, 12, 8) == 4 * 2 * 4 and visited(4, 10**6, 12, 8) == 4 * 3 * 4
+    assert visited(2, 5, 3, 8) == 2 * 1 * 3  # fewer page places than a pass takes
 
 
 @pytest.mark.parametrize(
@@ -202,13 +308,17 @@ def test_refuses_snapshots_and_the_prefill_role():
     with pytest.raises(NotImplementedError, match="prefill role"):
         core.add_request("p", prompt="x", params=greedy(2), prefill_only=True)
     assert drain(core)["r"].completion_tokens == 20
+    stateless = make_stateless()
+    with pytest.raises(NotImplementedError, match="extract_all.*not built for a layer pattern"):
+        stateless.extract_all()
 
 
-def test_worker_refuses_the_disaggregated_roles(monkeypatch):
+@pytest.mark.parametrize("preset", ["ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16"])
+def test_worker_refuses_the_disaggregated_roles(monkeypatch, preset):
     from llmq_tpu.workers.tpu_worker import TPUWorker
 
     monkeypatch.setenv("LLMQ_WORKER_ROLE", "prefill")
     monkeypatch.setenv("LLMQ_BROKER_URL", "memory://hybrid-role")
-    worker = TPUWorker("q", model="preset://ling-3.0-flash-ep4")
+    worker = TPUWorker("q", model=f"preset://{preset}")
     with pytest.raises(ValueError, match="role=prefill is not supported"):
         worker._build_core()

@@ -1,12 +1,14 @@
 """Models with a declared layer pattern (``models/hybrid.py``): KDA layers
-with a per-sequence state beside MLA layers over a paged latent cache,
-routed experts held by share. CPU, tiny widths, seeded weights.
+with a per-sequence state beside MLA layers over a paged latent cache
+(``bailing_hybrid``), or MLA layers alone with a query LoRA and sandwich
+norms (``pangu_ultra_moe``), routed experts held by share. CPU, tiny
+widths, seeded weights.
 
-The comparison is with the benchmark's plain reference
-(``benchmark/architectures/bailing_hybrid.py``: float32, token-by-token
-recurrence, expanded MLA, nothing imported from the program): prefill and
-then decoding through both caches must give the logits of its full forward
-pass.
+The comparison is with the benchmark's plain references
+(``benchmark/architectures/bailing_hybrid.py``, ``pangu_ultra_moe.py``:
+float32, token-by-token recurrence, expanded MLA, nothing imported from
+the program): prefill and then decoding through the caches must give the
+logits of the reference's full forward pass.
 """
 
 import dataclasses
@@ -20,7 +22,7 @@ import pytest
 from benchmark import architectures, weights
 from llmq_tpu.models import hybrid
 from llmq_tpu.models.config import ModelConfig
-from llmq_tpu.models.presets import _LING_3_FLASH, get_preset
+from llmq_tpu.models.presets import _LING_3_FLASH, _OPENPANGU_ULTRA_MOE, get_preset
 from llmq_tpu.models.transformer import build_model, make_kv_pages
 from llmq_tpu.ops import delta_rule
 
@@ -61,22 +63,83 @@ ARCH, PARAMS = seeded(FILE_CFG)
 MODEL = build_model(MC)
 PAGE, PAGES, PPS = 8, 24, 6
 
+# openPangu-Ultra-MoE's published keys at a tiny size: latent attention on
+# every layer, one leading dense MLP, 16 experts in one group, no bias.
+PANGU_HF = dict(
+    model_type="pangu_ultra_moe", vocab_size=304, hidden_size=64,
+    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+    intermediate_size=128, rope_theta=10000.0, rms_norm_eps=1e-5,
+    q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, first_k_dense_replace=1, n_routed_experts=16,
+    n_shared_experts=1, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, routed_scaling_factor=2.5, sandwich_norm=True,
+    tie_word_embeddings=False,
+)
 
-def test_tree_is_the_one_the_benchmark_describes():
-    ours = jax.tree.map(
-        tuple, hybrid.param_shapes(MC), is_leaf=lambda x: isinstance(x, tuple)
+
+def pangu_configs(first=4, held=8, **changed):
+    """(the program's ModelConfig, the benchmark file's keys) of one share."""
+    hf = dict(PANGU_HF, experts_held=[first, held], **changed)
+    file_cfg = dict(
+        hf, architecture="pangu_ultra_moe", n_routed_experts=held,
+        n_routed_experts_published=PANGU_HF["n_routed_experts"],
     )
-    assert ours == ARCH.tree_shapes(FILE_CFG)
-    assert [(g.attn, g.mlp, g.count) for g in hybrid.layer_groups(MC)] == [
-        ("kda", "dense", 1), ("kda", "moe", 2), ("mla", "moe", 1)
-    ]
+    return ModelConfig.from_hf_config(hf), file_cfg
 
 
-@pytest.mark.parametrize("rows", [1, 4])
-def test_prefill_then_decode_matches_the_reference(rows):
+class Family:
+    """A configuration with its reference, seeded weights and model."""
+
+    def __init__(self, mc, file_cfg, groups):
+        self.mc, self.file_cfg, self.groups = mc, file_cfg, groups
+        self.arch, self.params = seeded(file_cfg)
+        self.model = build_model(mc)
+
+
+_MADE = {}
+_FAMILIES = {
+    "ling": lambda: (MC, FILE_CFG, [("kda", "dense", 1), ("kda", "moe", 2), ("mla", "moe", 1)]),
+    "pangu": lambda: (*pangu_configs(), [("mla", "dense", 1), ("mla", "moe", 3)]),
+    "pangu_no_query_lora": lambda: (
+        *pangu_configs(q_lora_rank=None), [("mla", "dense", 1), ("mla", "moe", 3)]),
+    "pangu_no_sandwich_norms": lambda: (
+        *pangu_configs(sandwich_norm=False), [("mla", "dense", 1), ("mla", "moe", 3)]),
+    "pangu_lead_layer_alone": lambda: (
+        *pangu_configs(num_hidden_layers=1), [("mla", "dense", 1)]),
+    "pangu_expert_layers_alone": lambda: (
+        *pangu_configs(num_hidden_layers=2, first_k_dense_replace=0), [("mla", "moe", 2)]),
+}
+
+
+def family(name) -> Family:
+    if name not in _MADE:
+        _MADE[name] = Family(*_FAMILIES[name]())
+    return _MADE[name]
+
+
+@pytest.mark.parametrize("name", _FAMILIES)
+def test_tree_is_the_one_the_benchmark_describes(name):
+    f = family(name)
+    ours = jax.tree.map(
+        tuple, hybrid.param_shapes(f.mc), is_leaf=lambda x: isinstance(x, tuple)
+    )
+    assert ours == f.arch.tree_shapes(f.file_cfg)
+    assert [(g.attn, g.mlp, g.count) for g in hybrid.layer_groups(f.mc)] == f.groups
+
+
+@pytest.mark.parametrize("name, rows", [
+    ("ling", 1), ("ling", 4), ("pangu", 1), ("pangu", 4), ("pangu_no_query_lora", 4),
+    ("pangu_no_sandwich_norms", 4), ("pangu_lead_layer_alone", 4),
+    ("pangu_expert_layers_alone", 4),
+])
+def test_prefill_then_decode_matches_the_reference(name, rows):
     """Batches of 1 and 4 rows with padding: rows of different lengths in
     one bucket, a padded row, then decode steps with an inactive slot,
-    against the reference's full forward pass."""
+    against the reference's full forward pass. For the lead layer and the
+    expert layers of ``pangu_ultra_moe``, with and without the query LoRA
+    and the sandwich norms."""
+    f = family(name)
+    MC, MODEL, PARAMS, ARCH, FILE_CFG = f.mc, f.model, f.params, f.arch, f.file_cfg
     rng = np.random.default_rng(rows)
     lengths = [19, 7, 0, 12][:rows]
     steps = 6
@@ -184,10 +247,14 @@ def test_scanned_kda_prefill_is_the_token_recurrence():
         np.testing.assert_allclose(got, out[:, t], atol=1e-5)
 
 
-def test_absorbed_mla_is_expanded_mla():
+@pytest.mark.parametrize("name, stack", [("ling", "stack2"), ("pangu", "stack1")])
+def test_absorbed_mla_is_expanded_mla(name, stack):
     """Decode (query absorbed into the latent, attention over the cached
-    rows) gives what prefill (keys and values raised a head) gives."""
-    lp = {name: w[0] for name, w in PARAMS["stack2"].items()}
+    rows) gives what prefill (keys and values raised a head) gives, with
+    a head-wise gate and with a query LoRA."""
+    f = family(name)
+    MC, MODEL = f.mc, f.model
+    lp = {name: w[0] for name, w in f.params[stack].items()}
     rng = np.random.default_rng(1)
     T = 16
     x = jnp.asarray(rng.normal(size=(1, T, MC.hidden_size)), jnp.float32)
@@ -237,6 +304,77 @@ def test_four_shares_add_up_to_the_uncut_layer(dense_rows, monkeypatch):
     np.testing.assert_allclose(total + shared, uncut, atol=2e-5)
 
 
+@pytest.mark.parametrize("dense_rows", [256, 0], ids=["dense", "grouped"])
+def test_sixteen_shares_of_one_group_add_up_to_the_uncut_layer(dense_rows, monkeypatch):
+    """The same for ``pangu_ultra_moe``'s router (one group, no selection
+    bias): sixteen shares of one expert each, the shared expert counted
+    once, against the uncut reference's layer."""
+    monkeypatch.setattr(hybrid, "DENSE_EXPERT_ROWS", dense_rows)
+    whole_mc, whole_cfg = pangu_configs(first=0, held=16)
+    ref, whole = seeded(whole_cfg, seed=11)
+    lp = {name: w[1] for name, w in whole["stack1"].items()}
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(9, 64)), jnp.float32)
+    z = tuple(sorted(ref._sizes(whole_cfg).items()))
+    shared, w, _ = ref._shared_and_route(
+        x, lp, z=z, route_cfg=(4, 2.5, True, 0), control=None
+    )
+    uncut = shared + ref._expert_block(
+        x, w, lp["expert_gate_proj"], lp["expert_up_proj"], lp["expert_down_proj"],
+        control=None,
+    )
+    total, assignments, hit = jnp.zeros_like(shared), 0, 0
+    for first in range(16):
+        share = {
+            name: leaf[first : first + 1] if name.startswith("expert_") else leaf
+            for name, leaf in lp.items()
+        }
+        mc = dataclasses.replace(whole_mc, experts_held=(first, 1))
+        part, counts = hybrid.moe_held(x, share, mc)
+        total = total + (part - shared)
+        assignments += int(counts[0])
+        hit += int(counts[1])
+    assert assignments == 9 * 4 and 4 <= hit <= 16
+    np.testing.assert_allclose(total + shared, uncut, atol=2e-5)
+
+
+def test_pangu_from_hf_config_on_the_published_keys():
+    """The cut the benchmark serves, and the published counts from the
+    shapes: attention 196.6 M, an expert 47.2 M, the dense lead layer
+    621.3 M, the cut 4,919 M (9.84 GB in bf16)."""
+    cut = get_preset("openpangu-ultra-moe-718b-ep16")
+    assert cut.layer_pattern == (("mla", "dense"),) + (("mla", "moe"),) * 4
+    assert (cut.hidden_size, cut.num_heads, cut.intermediate_size) == (7680, 128, 18432)
+    assert (cut.q_lora_rank, cut.kv_lora_rank, cut.qk_nope_head_dim,
+            cut.qk_rope_head_dim, cut.v_head_dim) == (1536, 512, 128, 64, 128)
+    assert (cut.num_experts, cut.experts_held, cut.num_experts_per_tok) == (256, (0, 16), 8)
+    assert (cut.moe_intermediate_size, cut.shared_expert_intermediate_size) == (2048, 2048)
+    assert (cut.n_group, cut.router_bias, cut.routed_scaling_factor) == (1, False, 2.5)
+    assert cut.post_norms and not cut.mla_head_gate and cut.rms_norm_eps == 1e-5
+    assert cut.vocab_size == 153600 // 8 and cut.rope_theta == 25.6e6
+    assert hybrid.count_layers(cut, "kda") == 0
+
+    def millions(shapes, keep=lambda name: True):
+        flat = jax.tree.flatten_with_path(shapes, is_leaf=lambda x: isinstance(x, tuple))[0]
+        return sum(int(np.prod(s)) for path, s in flat if keep(path[-1].key)) / 1e6
+
+    shapes = hybrid.param_shapes(cut)
+    lead = {k: v[1:] for k, v in shapes["stack0"].items()}
+    layer = {k: v[1:] for k, v in shapes["stack1"].items()}
+    attention = ("mla_qa_proj", "mla_qb_proj", "mla_kva_proj", "mla_kvb_proj", "o_proj")
+    assert round(millions(layer, lambda n: n in attention), 1) == 196.6
+    assert round(millions(layer, lambda n: n.startswith("expert_")) / 16, 1) == 47.2
+    assert round(millions(layer, lambda n: n.startswith("shared_")), 1) == 47.2
+    assert round(millions(lead), 1) == 621.3
+    assert round(millions(shapes)) == 4919
+    whole = ModelConfig.from_hf_config(_OPENPANGU_ULTRA_MOE)
+    assert len(whole.layer_pattern) == 61 and whole.experts_held_ == (0, 256)
+    assert [m for _, m in whole.layer_pattern[:4]] == ["dense"] * 3 + ["moe"]
+    for key, value in (("n_group", 8), ("scoring_func", "softmax"),
+                       ("rope_scaling", {"type": "yarn"}), ("attention_bias", True)):
+        with pytest.raises(ValueError, match=key):  # refused by name
+            ModelConfig.from_hf_config(dict(_OPENPANGU_ULTRA_MOE, **{key: value}))
+
+
 def test_from_hf_config_on_the_published_keys():
     cut = get_preset("ling-3.0-flash-ep4")
     assert cut.layer_pattern == (("kda", "dense"),) + (("kda", "moe"),) * 5 + (("mla", "moe"),)
@@ -284,26 +422,90 @@ def test_big_buckets_take_rows_and_blocks_one_at_a_time(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=0)
 
 
-def _reference_err(control, n=40, k=8):
+def test_a_big_bucket_at_many_heads_expands_its_rows_one_at_a_time(monkeypatch):
+    """Above ``MLA_PREFILL_HEAD_TOKENS`` (tokens x heads) an MLA layer
+    raises keys and values for one row of the batch at a time (128 heads:
+    the 4 x 4,096 bucket), and an expert layer's block of rows halves
+    with the hidden size: same logits, same latent rows."""
+    f = family("pangu")
+    rng = np.random.default_rng(3)
+    lengths = np.asarray([30, 9, 0, 17], np.int32)
+    tokens = np.zeros((4, 32), np.int32)
+    for r, n in enumerate(lengths):
+        tokens[r, :n] = rng.integers(1, 300, size=n)
+    bt = np.zeros((4, PPS), np.int32)
+    bt[0, :4], bt[1, :2], bt[3, :3] = [1, 2, 3, 4], [5, 6], [7, 8, 9]
+
+    def run():
+        k, v = make_kv_pages(f.mc, PAGES, PAGE, jnp.float32)
+        return jax.jit(f.model.prefill)(f.params, tokens, lengths, k, v, bt)
+
+    plain = run()
+    monkeypatch.setattr(hybrid, "MLA_PREFILL_HEAD_TOKENS", 16)
+    monkeypatch.setattr(hybrid, "MOE_BLOCK_ROWS", 16)
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(run())):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=0)
+
+
+def _reference_err(control, n=40, k=8, name="ling", measure=None):
     from benchmark.correct import logit_err
 
+    f = family(name)
     ids = list(np.random.default_rng(9).integers(1, 300, size=n + k - 1))
     positions = list(range(n - 1, n + k - 1))
-    ref = np.asarray(ARCH.forward_logits(PARAMS, FILE_CFG, ids, positions))
-    ctrl = np.asarray(ARCH.forward_logits(PARAMS, FILE_CFG, ids, positions, control))
-    return logit_err(ctrl, ref)
+    ref = np.asarray(f.arch.forward_logits(f.params, f.file_cfg, ids, positions))
+    ctrl = np.asarray(f.arch.forward_logits(f.params, f.file_cfg, ids, positions, control))
+    return (measure or logit_err)(ctrl, ref)
 
 
-@pytest.mark.parametrize("fault", ["kda_reset", "conv_tail", "moe_drop", "moe_drop_first"])
-def test_a_planted_fault_of_the_reference_is_far_from_it(fault):
+PANGU_FAULTS = [
+    "q_norm_off", "post_attn_norm_off", "post_mlp_norm_off", "scale_off",
+    "topk_norm_off", "moe_drop", "moe_drop_first",
+]
+
+
+@pytest.mark.parametrize("name, fault", [
+    ("ling", "kda_reset"), ("ling", "conv_tail"), ("ling", "moe_drop"), ("ling", "moe_drop_first"),
+    *(("pangu", fault) for fault in PANGU_FAULTS),
+])
+def test_a_planted_fault_of_the_reference_is_far_from_it(name, fault):
     """The controls that stand for a wrong cache manager or step program
-    (a state not carried, tails not carried, a layer's experts left out)
-    are in ``CONTROLS`` and move the logits by far more than rounding."""
-    assert fault in ARCH.CONTROLS
-    assert _reference_err(fault) > 0.05
+    (a state not carried, tails not carried, a layer's experts left out;
+    the query norm, an output norm, the routed scale or the top-8
+    normalisation left out) are in ``CONTROLS`` and move the logits by far
+    more than rounding: a program that left one out would be hundreds of
+    times outside the 2e-4 that
+    ``test_prefill_then_decode_matches_the_reference`` holds it to."""
+    assert fault in family(name).arch.CONTROLS
+    assert _reference_err(fault, name=name) > 0.05
+    worst = _reference_err(fault, name=name, measure=lambda a, b: np.abs(a - b).max())
+    assert worst > 100 * 2e-4
 
 
-def test_the_diagnoses_round_and_force_the_float32_choice_of_experts():
-    rounded, routed = _reference_err("bf16act"), _reference_err("bf16act_routed")
+@pytest.mark.parametrize("name", ["ling", "pangu"])
+def test_the_diagnoses_round_and_force_the_float32_choice_of_experts(name):
+    rounded = _reference_err("bf16act", name=name)
+    routed = _reference_err("bf16act_routed", name=name)
     assert 0 < routed < rounded < 0.2
-    assert not set(ARCH.DIAGNOSES) & set(ARCH.CONTROLS)
+    arch = family(name).arch
+    assert not set(arch.DIAGNOSES) & set(arch.CONTROLS)
+
+
+def test_the_reference_takes_its_heads_a_group_at_a_time(monkeypatch):
+    """``pangu_ultra_moe.py`` expands keys and values for a group of heads
+    at a time (128 heads x 3,080 positions do not fit otherwise): two
+    groups of two heads give what one group of four gives, int8 control
+    included (its scales are per output channel of the whole matrix)."""
+    f = family("pangu")
+    ids = list(np.random.default_rng(4).integers(1, 300, size=30))
+    positions = list(range(20, 30))
+
+    def logits(control):
+        jax.clear_caches()
+        return np.asarray(f.arch.forward_logits(f.params, f.file_cfg, ids, positions, control))
+
+    whole = {c: logits(c) for c in (None, "int8w")}
+    monkeypatch.setattr(f.arch, "_HEAD_GROUP", 2)
+    for control, want in whole.items():
+        np.testing.assert_allclose(logits(control), want, atol=2e-5, rtol=0)
+    jax.clear_caches()

@@ -335,7 +335,7 @@ def _refusal_is_a_compile_failure_not_a_device_fault(topo, monkeypatch):
     assert classify_failure(refused.value) is None
 
 
-def _hybrid_step(which):
+def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64):
     """The decode step and a prefill bucket of the Ling-3.0-flash cut
     at its published widths (``preset://ling-3.0-flash-ep4``: 10.46 GB of
     bf16 weights), over the pools the benchmark's cell runs with: 128
@@ -349,13 +349,21 @@ def _hybrid_step(which):
     whatever ``max_model_len`` is. (The largest buckets, 4 x 4,096 and 4 x
     8,192, which take rows and blocks one at a time in
     ``models/hybrid.py``, compile too, ``_hybrid_step((4, 8192))``: 25 s
-    of every core each, and left out of ``CASES`` for the suite's sake.)"""
-    slots, pages, places = 128, 2700, 64
+    of every core each, and left out of ``CASES`` for the suite's sake.)
+
+    The same for the openPangu-Ultra-MoE cut
+    (``preset://openpangu-ultra-moe-718b-ep16``: 9.84 GB of weights, latent
+    attention at 128 heads on all five layers, NO state layer: the state
+    pool's leaves are empty), over its cell's pools: 3,200 latent pages, 32
+    page places a row (``max_model_len`` 4,096). Its largest bucket, 4 x
+    4,096, which expands a row at a time, compiles in 14 s to 3.2 GB of
+    temporaries and is left out of ``CASES`` likewise."""
+    slots = 128
 
     def case(topo, monkeypatch):
         from llmq_tpu.models.transformer import build_model, make_kv_pages
 
-        cfg = get_preset("ling-3.0-flash-ep4")
+        cfg = get_preset(preset)
         model = build_model(cfg)
         s = _Shapes(topo)
         shaped = partial(jax.tree.map, lambda a: s(a.shape, a.dtype))
@@ -398,6 +406,12 @@ def _hybrid_step(which):
 CASES = {
     "hybrid_decode_128_slots": _hybrid_step("decode"),
     "hybrid_prefill_1x512": _hybrid_step((1, 512)),
+    "latent_only_decode_128_slots_128_heads": _hybrid_step(
+        "decode", "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
+    ),
+    "latent_only_prefill_1x2048": _hybrid_step(
+        (1, 2048), "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
+    ),
     "decode_live": _decode(pk.paged_decode_attention_live),
     "decode_fp8_pool_2_kv_heads_takes_v1": (
         _decode_fp8_pool_2_kv_heads_takes_v1
