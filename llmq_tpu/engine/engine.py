@@ -2967,14 +2967,31 @@ class EngineCore:
         )
 
     def _latent_pages_visited(self, seqs: List[Sequence]) -> int:
-        """Latent pages a decode step gathers a layer (beside
-        ``_live_pages``, what is live): every row of the step, the
-        longest row's passes."""
+        """Latent pages a decode step reads out of the pool a layer
+        (beside ``_live_pages``, what is live), by the schedule the step
+        runs."""
         return latent_decode_pages_visited(
+            self._decode_kernel_plan(),
+            [s.num_tokens for s in seqs],
             self.cfg.max_num_seqs,
-            max((s.num_tokens for s in seqs), default=0),
             self._pages_per_seq,
             self.cfg.page_size,
+        )
+
+    def _decode_kernel_plan(self) -> str:
+        """The decode-attention schedule of this engine's pool, as
+        ``ops/dispatch`` names it: a layer pattern's is its latent pool's
+        (state layers have no attention kernel), any other model's its K/V
+        pool's."""
+        mc = self.model_config
+        if self._hybrid:
+            return _dispatch.latent_decode_kernel_plan(
+                mc.kv_lora_rank, *self.k_pages.shape[2:], self.k_pages.dtype,
+                mesh=self.mesh, backend=self.model.attn_backend,
+            )
+        return _dispatch.decode_kernel_plan(
+            mc.num_heads, mc.num_kv_heads, self.cfg.kv_dtype,
+            mesh=self.mesh, backend=self.model.attn_backend,
         )
 
     def _expire_deadlines(self, finished: List[RequestOutput]) -> None:
@@ -5295,18 +5312,7 @@ class EngineCore:
     def stats(self) -> Dict[str, Any]:
         elapsed = max(1e-9, time.monotonic() - self._started_at)
         s = self.scheduler.stats()
-        from llmq_tpu.ops import dispatch as _dispatch
-
-        # A layer pattern's decode attention is the XLA loop over its latent
-        # pool (``ops/attention.latent_paged_decode_attention``), whatever
-        # the plan would say of a K/V pool with its head counts.
-        kern = "xla" if self._hybrid else _dispatch.decode_kernel_plan(
-            self.model_config.num_heads,
-            self.model_config.num_kv_heads,
-            self.cfg.kv_dtype,
-            mesh=self.mesh,
-            backend=self.model.attn_backend,
-        )
+        kern = self._decode_kernel_plan()
         s.update(
             prompt_tokens=self.total_prompt_tokens,
             generated_tokens=self.total_generated_tokens,

@@ -61,7 +61,7 @@ from llmq_tpu.models import quant as qm
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.transformer import Transformer, _mlp, apply_rope, rms_norm
 from llmq_tpu.ops import attention as attn_ops
-from llmq_tpu.ops import delta_rule
+from llmq_tpu.ops import delta_rule, dispatch
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -651,10 +651,11 @@ class HybridTransformer(Transformer):
         w_kvb = lp["mla_kvb_proj"].reshape(rank, n, -1)
         w_uk, w_uv = w_kvb[..., : cfg.qk_nope_head_dim], w_kvb[..., cfg.qk_nope_head_dim :]
         q_lat = jnp.einsum("shd,chd->shc", q_c[:, 0], w_uk)
-        o_lat = attn_ops.latent_paged_decode_attention(
+        o_lat = dispatch.latent_decode_attention(
             jnp.concatenate([q_lat, q_r[:, 0]], axis=-1),
             latent, block_tables, ctx_incl,
             scale=self._mla_scale(), rank=rank, layer=li,
+            mesh=self.mesh, backend=self.attn_backend,
         )
         o = jnp.einsum("shc,chd->shd", o_lat, w_uv)
         return self._mla_out(lp, x, o), latent

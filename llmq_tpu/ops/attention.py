@@ -464,13 +464,23 @@ def latent_decode_passes(longest, pages_per_seq: int, page_size: int):
 
 
 def latent_decode_pages_visited(
-    rows: int, longest: int, pages_per_seq: int, page_size: int
+    plan: str, contexts, slots: int, pages_per_seq: int, page_size: int
 ) -> int:
-    """Pages :func:`latent_paged_decode_attention` gathers a layer for a
-    step of ``rows`` rows: every row takes the longest row's passes, a
-    chunk of pages a pass."""
+    """Latent pages a decode step reads out of the pool a layer under the
+    schedule ``plan`` (``ops/dispatch.latent_decode_kernel_plan``'s name),
+    for rows of ``contexts`` tokens (new token included) in a step of
+    ``slots`` slots. ``"xla"`` (:func:`latent_paged_decode_attention`):
+    every slot takes the longest row's passes, a chunk of pages a pass. A
+    kernel that follows the live cache
+    (``pallas_attention.latent_paged_decode_attention_live``) copies each
+    row's own pages, one a copy, and nothing for an empty slot (its
+    arithmetic folds several page places an update and masks the dead
+    ones: no pool bytes)."""
+    if plan != "xla":
+        return sum(-(-int(n) // page_size) for n in contexts)
     C, _ = _latent_decode_chunks(pages_per_seq)
-    return rows * latent_decode_passes(int(longest), pages_per_seq, page_size) * C
+    longest = max((int(n) for n in contexts), default=0)
+    return slots * latent_decode_passes(longest, pages_per_seq, page_size) * C
 
 
 def blocked_prefill_attention(

@@ -265,6 +265,69 @@ def decode_kernel_plan(
     return "v1" if pk.pool_rows_padded(n_kv // tp, kv_dtype) else "live"
 
 
+def latent_decode_kernel_plan(
+    rank: int, page_size: int, width: int, pool_dtype,
+    mesh: Optional[Mesh] = None, backend: str = "auto",
+) -> str:
+    """The decode-attention schedule a LATENT pool of ``[page_size,
+    width]`` pages (the first ``rank`` values of a row its latent part)
+    runs, and the only place that chooses it: ``"latent_live"``
+    (``pallas_attention.latent_paged_decode_attention_live``: each row's
+    own live pages, copied once and used for scores and values) where the
+    backend is pallas, one device holds the pool whole and the chip does
+    not pad its pages; else ``"xla"``
+    (``ops/attention.latent_paged_decode_attention``: the CPU, a mesh of
+    several devices, a pool of one-byte values, rows or a rank that are
+    not whole lane tiles). One kernel for every head count, ling's 32 as
+    openpangu's 128: the pages a chunk copies and an update folds in come
+    from the shapes (``pallas_attention._latent_decode_schedule``), and on the
+    chip it was the faster at both (PERF.md section 6, PR 40), so the head
+    count is no argument of the plan.
+
+    The contract of :func:`decode_kernel_plan`: a pure function of its
+    arguments and the backend, consulted at trace time."""
+    backend = resolve_backend() if backend == "auto" else backend
+    if (
+        backend != "pallas"
+        or (mesh is not None and mesh.size > 1)
+        or jnp.dtype(pool_dtype).itemsize < 2
+        or pk.latent_pool_padded(page_size, width, rank, pool_dtype)
+    ):
+        return "xla"
+    return "latent_live"
+
+
+def latent_decode_attention(
+    q: jnp.ndarray,  # [S, n_heads, W] absorbed query
+    pages: jnp.ndarray,  # [L, P, page_size, Wp] the latent pool
+    block_tables: jnp.ndarray,  # [S, pages_per_seq]
+    context_lens: jnp.ndarray,  # [S] INCLUDING the new token
+    *,
+    scale: float,
+    rank: int,
+    layer: jnp.ndarray,
+    mesh: Optional[Mesh] = None,
+    backend: str = "auto",
+) -> jnp.ndarray:
+    """Absorbed decode attention over the latent pool by the schedule
+    :func:`latent_decode_kernel_plan` names. No ``jax.named_scope`` of its
+    own: the caller's (``llmq.attn.mla_decode``) has to stay the
+    innermost scope of the kernel, or the benchmark's ``decode_mla_ms``
+    would lose the kernel's time."""
+    plan = latent_decode_kernel_plan(
+        rank, *pages.shape[2:], pages.dtype, mesh, backend
+    )
+    if plan == "xla":
+        return xla_ops.latent_paged_decode_attention(
+            q, pages, block_tables, context_lens,
+            scale=scale, rank=rank, layer=layer,
+        )
+    return pk.latent_paged_decode_attention_live(
+        q, pages, block_tables, context_lens, layer,
+        scale=scale, rank=rank, interpret=_interpret(),
+    )
+
+
 def verify_kernel_plan(
     n_heads: int, n_kv: int, mesh: Optional[Mesh] = None,
     backend: str = "auto",

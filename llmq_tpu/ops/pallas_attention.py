@@ -309,6 +309,111 @@ def _decode_schedule(page_bytes: int) -> tuple:
     return G * max(1, min(2, _DECODE_CHUNK_BYTES // (G * page_bytes))), G
 
 
+def _walk_live_pages(
+    s,  # this grid step's sequence
+    S,  # the grid's
+    bt_ref,  # [S, pages_per_seq] int32
+    cl_ref,  # [S] int32 — context length INCLUDING the new token; 0: empty
+    slot_ref,  # SMEM [1] int32: parity of the chunks consumed so far
+    *,
+    span,  # seq -> (first, last + 1) page places the sequence attends to
+    chunk: int,  # C: page places a chunk copies into one of the two buffers
+    group: int,  # G: page places one softmax update folds in; C = G or 2 G
+    page_copies,  # (page id, buffer, place in it) -> the copies of one page
+    clear,  # zero the buffers: a dead place beside a live one is finite
+    prepare,  # context -> fold(first page place, buffer, place in it)
+):
+    """The schedule both live decode kernels run a grid step ``s`` of
+    ``(S,)`` by, written once: THIS row's live page places in chunks of
+    ``C``, every copy of a chunk started before the first wait, the next
+    chunk (or the next live row's first) in flight into the other buffer
+    meanwhile, ``G`` places an update, places past the row's last neither
+    copied nor waited for. A kernel brings what differs: which places are
+    live, one page's copies, its buffers, and the arithmetic of an update
+    (``prepare`` is given the row's context once the first row's copies
+    are in flight, sets the running softmax up and returns it). Nothing
+    here asks which kernel called."""
+    C, G = chunk, group
+
+    def next_live(t):
+        """The first sequence at or after ``t`` with a context, or S."""
+        return jax.lax.while_loop(
+            lambda t: jnp.logical_and(
+                t < S, cl_ref[jnp.minimum(t, S - 1)] == 0
+            ),
+            lambda t: t + 1,
+            t,
+        )
+
+    def issue_chunk(seq, first_page, last_page, slot):
+        """Start the copies of up to C pages from ``first_page``: all of
+        them before anything waits."""
+
+        def start(i, _):
+            for copy in page_copies(bt_ref[seq, first_page + i], slot, i):
+                copy.start()
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(C, last_page - first_page), start, 0)
+
+    def issue_first_chunk(seq, slot):
+        @pl.when(seq < S)
+        def _go():
+            first, last = span(seq)
+            issue_chunk(seq, first, last, slot)
+
+    @pl.when(s == 0)
+    def _prime():
+        if G > 1:
+            clear()
+        slot_ref[0] = 0
+        issue_first_chunk(next_live(0), 0)
+
+    ctx = cl_ref[s]
+    first, last = span(s)
+    n_chunks = (last - first + C - 1) // C
+    slot0 = slot_ref[0]
+    # The sequence whose first chunk rides behind this one's last. An
+    # empty slot starts nothing: the live step before it already did.
+    successor = next_live(jnp.where(n_chunks > 0, s + 1, S))
+    fold = prepare(ctx)
+
+    def attend_chunk(j, _):
+        slot = jax.lax.rem(slot0 + j, 2)
+        base = first + j * C
+        n_here = jnp.minimum(C, last - base)
+
+        # Keep the other buffer busy: this sequence's next chunk, or after
+        # its last the successor's first.
+        @pl.when(j + 1 < n_chunks)
+        def _ahead():
+            issue_chunk(s, base + C, last, 1 - slot)
+
+        @pl.when(j + 1 == n_chunks)
+        def _successor():
+            issue_first_chunk(successor, 1 - slot)
+
+        def attend(g, _):
+            for i in range(G):
+
+                @pl.when(g * G + i < n_here)
+                def _wait(i=i):
+                    for copy in page_copies(0, slot, g * G + i):
+                        copy.wait()
+
+            fold(base + g * G, slot, g * G)
+            return 0
+
+        if C == G:  # a chunk is one update
+            attend(0, 0)
+        else:
+            jax.lax.fori_loop(0, (n_here + G - 1) // G, attend, 0)
+        return 0
+
+    jax.lax.fori_loop(0, n_chunks, attend_chunk, 0)
+    slot_ref[0] = jax.lax.rem(slot0 + n_chunks, 2)
+
+
 def _paged_decode_live_kernel(
     # scalar prefetch
     li_ref,  # [1] int32 — layer index into the stacked page pool
@@ -353,16 +458,6 @@ def _paged_decode_live_kernel(
         first = jnp.maximum(ctx - window, 0) // page_size
         return first, (ctx + page_size - 1) // page_size
 
-    def next_live(t):
-        """The first sequence at or after ``t`` with a context, or S."""
-        return jax.lax.while_loop(
-            lambda t: jnp.logical_and(
-                t < S, cl_ref[jnp.minimum(t, S - 1)] == 0
-            ),
-            lambda t: t + 1,
-            t,
-        )
-
     def page_copies(pid, slot, i):
         dst = pl.ds(pl.multiple_of(i * rows, rows), rows)
         return (
@@ -374,116 +469,64 @@ def _paged_decode_live_kernel(
             ),
         )
 
-    def issue_chunk(seq, first_page, last_page, slot):
-        """Start the copies of up to C pages from ``first_page``: all of
-        them before anything waits."""
+    def clear():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
 
-        def start(i, _):
-            for copy in page_copies(bt_ref[seq, first_page + i], slot, i):
-                copy.start()
-            return 0
+    def prepare(ctx):
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        jax.lax.fori_loop(0, jnp.minimum(C, last_page - first_page), start, 0)
+        q = q_ref[0].astype(jnp.float32)  # [H, d]
+        # Column -> token offset from the group's first page, or "not a
+        # position" where the column's kv head is not the row's.
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, G * rows), 1)
+        row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, G * rows), 0) // (
+            H // n_kv
+        )
+        col_pos = jnp.where(col % n_kv == row_kv, col // n_kv, _NOT_A_POSITION)
 
-    def issue_first_chunk(seq, slot):
-        @pl.when(seq < S)
-        def _go():
-            first, last = live_span(seq)
-            issue_chunk(seq, first, last, slot)
+        def attend_group(page, slot, i):
+            """Fold the G page places from ``page`` (in buffer ``slot``
+            from ``i``) into the running softmax of all heads."""
+            at = pl.ds(pl.multiple_of(i * rows, rows), G * rows)
+            k = k_buf[slot, at].astype(jnp.float32)  # [G * page * n_kv, d]
+            v = v_buf[slot, at].astype(jnp.float32)
+            scores = (
+                jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                * scale
+            )
+            scores = _apply_softcap(scores, softcap)
+            kpos = page * page_size + col_pos
+            mask = jnp.logical_and(kpos < ctx, kpos >= ctx - window)
+            scores = jnp.where(mask, scores, NEG_INF)
 
-    @pl.when(s == 0)
-    def _prime():
-        if G > 1:  # a dead page beside a live one must hold finite values
-            k_buf[...] = jnp.zeros_like(k_buf)
-            v_buf[...] = jnp.zeros_like(v_buf)
-        slot_ref[0] = 0
-        issue_first_chunk(next_live(0), 0)
-
-    ctx = cl_ref[s]
-    first, last = live_span(s)
-    n_chunks = (last - first + C - 1) // C
-    slot0 = slot_ref[0]
-    # The sequence whose first chunk rides behind this one's last. An
-    # empty slot starts nothing: the live step before it already did.
-    successor = next_live(jnp.where(n_chunks > 0, s + 1, S))
-
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)  # [H, d]
-    # Column -> token offset from the group's first page, or "not a
-    # position" where the column's kv head is not the row's.
-    col = jax.lax.broadcasted_iota(jnp.int32, (H, G * rows), 1)
-    row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, G * rows), 0) // (
-        H // n_kv
-    )
-    col_pos = jnp.where(col % n_kv == row_kv, col // n_kv, _NOT_A_POSITION)
-
-    def attend_group(page, slot, i):
-        """Fold the G page places from ``page`` (in buffer ``slot`` from
-        ``i``) into the running softmax of all heads."""
-        at = pl.ds(pl.multiple_of(i * rows, rows), G * rows)
-        k = k_buf[slot, at].astype(jnp.float32)  # [G * page * n_kv, d]
-        v = v_buf[slot, at].astype(jnp.float32)
-        scores = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+            m_prev = m_ref[:, :1]
+            l_prev = l_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            probs = jnp.exp(scores - m_new)
+            l_ref[...] = jnp.broadcast_to(
+                alpha * l_prev + jnp.sum(probs, axis=1, keepdims=True),
+                l_ref.shape,
+            )
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                probs, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            * scale
-        )
-        scores = _apply_softcap(scores, softcap)
-        kpos = page * page_size + col_pos
-        mask = jnp.logical_and(kpos < ctx, kpos >= ctx - window)
-        scores = jnp.where(mask, scores, NEG_INF)
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(probs, axis=1, keepdims=True),
-            l_ref.shape,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            probs, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        return attend_group
 
-    def attend_chunk(j, _):
-        slot = jax.lax.rem(slot0 + j, 2)
-        base = first + j * C
-        n_here = jnp.minimum(C, last - base)
-
-        # Keep the other buffer busy: this sequence's next chunk, or after
-        # its last the successor's first.
-        @pl.when(j + 1 < n_chunks)
-        def _ahead():
-            issue_chunk(s, base + C, last, 1 - slot)
-
-        @pl.when(j + 1 == n_chunks)
-        def _successor():
-            issue_first_chunk(successor, 1 - slot)
-
-        def attend(g, _):
-            for i in range(G):
-
-                @pl.when(g * G + i < n_here)
-                def _wait(i=i):
-                    for copy in page_copies(0, slot, g * G + i):
-                        copy.wait()
-
-            attend_group(base + g * G, slot, g * G)
-            return 0
-
-        jax.lax.fori_loop(0, (n_here + G - 1) // G, attend, 0)
-        return 0
-
-    jax.lax.fori_loop(0, n_chunks, attend_chunk, 0)
-    slot_ref[0] = jax.lax.rem(slot0 + n_chunks, 2)
+    _walk_live_pages(
+        s, S, bt_ref, cl_ref, slot_ref,
+        span=live_span, chunk=C, group=G,
+        page_copies=page_copies, clear=clear, prepare=prepare,
+    )
 
     l = l_ref[:, :1]
     l = jnp.where(l == 0.0, 1.0, l)  # inactive slot: zeros, never NaN
@@ -574,6 +617,241 @@ def paged_decode_attention_live(
         q,
         k_pages.reshape(L, P, rows, d),
         v_pages.reshape(L, P, rows, d),
+    )
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) paged decode, live pages only
+# ---------------------------------------------------------------------------
+#
+# Absorbed decode attention over the latent pool, by the schedule of the
+# live kernel above (``_walk_live_pages``, written once for both: grid
+# ``(S,)``, a loop over THAT row's live pages, every copy of a chunk
+# started before the first wait, the next chunk or the next live row's
+# first in flight meanwhile). What this kernel brings to it differs in
+# four ways.
+#
+# * There is one pool and a page of it is read ONCE and used twice: every
+#   head scores the same cached row ``[c ; r]`` and the value is the row's
+#   latent part, a lane-aligned slice of the same buffer. All heads share
+#   the row, so the MXU sees ``M = n_heads`` with no repeat and no mask of
+#   another head's columns.
+# * The operands go into the MXU in the dtype the XLA loop multiplies in
+#   (``_mul_dtype``: bf16 for a bf16 pool), with float32 accumulation and
+#   a float32 softmax. The K/V kernel upcasts to float32 because at 16
+#   heads it is bound by memory; at 128 heads absorbed decode is 242 FLOP a
+#   cached byte against the chip's ridge of 240, and float32 dots would
+#   make the MXU the wall.
+# * Pages an update folds in follow the score tile ``[n_heads, G * page]``
+#   float32 as well as the page's bytes: 8 pages at 128 heads, 16 at 32.
+#   Measured on v5e (PERF.md section 6 "PR 40"; ``tools/decode_kernel_bench.py
+#   --case latent``): at 128 heads the copies alone take 0.24 us a live page
+#   and the arithmetic 0.37 (4 pages an update: 0.41, 16: 0.38, more of
+#   them masked); at 32 heads the copies are the time.
+# * A chunk IS one update's pages: two updates a chunk (16 x 8, 8 x 4) read
+#   within 2 % of one (8 x 8, 4 x 4) on the chip, so there is one number.
+#
+# The copies are the row's own pages, one a page; the arithmetic folds G
+# page places an update and masks the places past the row's last like
+# positions past its context (the buffers are zeroed once, so what a dead
+# place holds is finite).
+
+_LATENT_SCORE_BYTES = 512 * 1024  # float32 score tile of one softmax update
+_LATENT_STEP_BYTES = 2560 * 1024  # pool bytes one softmax update folds in
+
+
+def latent_pool_padded(page_size: int, width: int, rank: int, dtype) -> bool:
+    """Whether the chip pads a latent pool's ``[page, width]`` pages or
+    the ``[page, rank]`` value part the kernel slices out of them: rows or
+    a rank that are not whole lane tiles, or a page that is not whole
+    packed sublane tiles (8 rows of 32 bits)."""
+    return bool(
+        width % _LANES
+        or rank % _LANES
+        or page_size % (8 * 4 // jnp.dtype(dtype).itemsize)
+    )
+
+
+def _latent_decode_schedule(page_bytes: int, n_heads: int, page_size: int) -> int:
+    """Pages a chunk copies and one softmax update folds in, from the
+    bytes of one page and the head count alone."""
+    return max(
+        1,
+        min(
+            16,
+            _LATENT_STEP_BYTES // page_bytes,
+            _LATENT_SCORE_BYTES // (n_heads * page_size * 4),
+        ),
+    )
+
+
+def _latent_fold(q, lat_ref, kpos, ctx, m_ref, l_ref, acc_ref, *, scale, rank):
+    """Fold the chunk of page places in ``lat_ref`` into the running
+    softmax of all heads: scores against the whole rows, values from
+    their first ``rank`` lanes."""
+    mul = q.dtype
+    scores = (
+        jax.lax.dot_general(
+            q, lat_ref[...].astype(mul), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )  # [H, G * page]
+    scores = jnp.where(kpos < ctx, scores, NEG_INF)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    probs = jnp.exp(scores - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        alpha * l_ref[:, :1] + jnp.sum(probs, axis=1, keepdims=True),
+        l_ref.shape,
+    )
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        probs.astype(mul), lat_ref[:, pl.ds(0, rank)].astype(mul),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _latent_decode_live_kernel(
+    # scalar prefetch
+    li_ref,  # [1] int32 — layer index into the stacked pool
+    bt_ref,  # [S, pages_per_seq] int32
+    cl_ref,  # [S] int32 — context length INCLUDING the new token
+    # inputs
+    q_ref,  # [1, n_heads, Wp] absorbed query, zero beyond its width
+    lat_hbm,  # [L, P, page, Wp], left in HBM
+    # output
+    o_ref,  # [1, n_heads, rank]
+    # scratch
+    m_ref,  # [n_heads, LANES] f32, lane-replicated running max
+    l_ref,  # [n_heads, LANES] f32, lane-replicated running denom
+    acc_ref,  # [n_heads, rank] f32
+    buf,  # [2, G * page, Wp] pool dtype: two chunks of pages
+    sem,  # DMA [2, G]
+    slot_ref,  # SMEM [1] int32: parity of the chunks consumed so far
+    *,
+    scale: float,
+    rank: int,
+    page_size: int,
+    group: int,
+):
+    G = group
+    s = pl.program_id(0)
+    S = pl.num_programs(0)
+    li = li_ref[0]
+    H = q_ref.shape[1]
+
+    def live_span(seq):
+        return 0, (cl_ref[seq] + page_size - 1) // page_size
+
+    def page_copies(pid, slot, i):
+        return (
+            pltpu.make_async_copy(
+                lat_hbm.at[li, pid],
+                buf.at[slot, pl.ds(pl.multiple_of(i * page_size, page_size), page_size)],
+                sem.at[slot, i],
+            ),
+        )
+
+    def clear():
+        buf[...] = jnp.zeros_like(buf)
+
+    def prepare(ctx):
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        q = q_ref[0]  # [H, Wp], already in the dtype the dots multiply in
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, G * page_size), 1)
+
+        def fold(page, slot, i):  # a chunk is one update: i is 0
+            _latent_fold(
+                q, buf.at[slot], page * page_size + col, ctx,
+                m_ref, l_ref, acc_ref, scale=scale, rank=rank,
+            )
+
+        return fold
+
+    _walk_live_pages(
+        s, S, bt_ref, cl_ref, slot_ref,
+        span=live_span, chunk=G, group=G,
+        page_copies=page_copies, clear=clear, prepare=prepare,
+    )
+
+    l = l_ref[:, :1]
+    l = jnp.where(l == 0.0, 1.0, l)  # inactive slot: zeros, never NaN
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "rank", "interpret"),
+)
+def latent_paged_decode_attention_live(
+    q: jnp.ndarray,  # [S, n_heads, W] absorbed query [W_uk^T q^C ; q^R]
+    pages: jnp.ndarray,  # [L, P, page_size, Wp], Wp >= W
+    block_tables: jnp.ndarray,  # [S, pages_per_seq] int32
+    context_lens: jnp.ndarray,  # [S] int32, INCLUDING the new token
+    layer: jnp.ndarray,  # traced layer index into the stacked pool
+    *,
+    scale: float,
+    rank: int,  # the first ``rank`` values of a row are the latent c
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Absorbed latent decode attention whose work follows the live cache
+    (see the notes above). The contract of
+    ``ops/attention.latent_paged_decode_attention``, and its precision;
+    returns ``[S, n_heads, rank]``. A pool padded on the chip is refused.
+    Nothing in the schedule depends on ``block_tables.shape[1]`` or on the
+    longest row."""
+    S, n_heads, W = q.shape
+    L, P, page_size, Wp = pages.shape
+    if latent_pool_padded(page_size, Wp, rank, pages.dtype):
+        raise ValueError(
+            f"a {pages.dtype} latent pool of [{page_size}, {Wp}] pages (rank "
+            f"{rank}) is padded on the chip: "
+            "ops/attention.latent_paged_decode_attention reads it"
+        )
+    mul = _mul_dtype(q.dtype, pages.dtype)
+    G = _latent_decode_schedule(
+        page_size * Wp * jnp.dtype(pages.dtype).itemsize, n_heads, page_size
+    )
+    kernel = functools.partial(
+        _latent_decode_live_kernel,
+        scale=scale, rank=rank, page_size=page_size, group=G,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, n_heads, Wp), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, n_heads, rank), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((n_heads, _LANES), jnp.float32),
+            pltpu.VMEM((n_heads, _LANES), jnp.float32),
+            pltpu.VMEM((n_heads, rank), jnp.float32),
+            pltpu.VMEM((2, G * page_size, Wp), pages.dtype),
+            pltpu.SemaphoreType.DMA((2, G)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((S, n_heads, rank), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32),
+        context_lens.astype(jnp.int32),
+        jnp.pad(q, ((0, 0), (0, 0), (0, Wp - W))).astype(mul),
+        pages,
     )
 
 

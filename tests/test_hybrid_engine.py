@@ -264,11 +264,68 @@ def test_a_pattern_without_state_layers_reports_what_the_latent_loop_gathers(mon
     longest = max(len(p) + n for _, p, n in REQUESTS[:3])
     assert max(s["latent_pages_visited"] for s in dispatches) <= 16 * -(-longest // 32)
     # rows x the longest row's passes x pages a pass, capped at the block table
-    visited = latent_decode_pages_visited
+    def visited(rows, longest, places, page):
+        return latent_decode_pages_visited("xla", [longest], rows, places, page)
+
     assert visited(128, 3584, 32, 128) == 128 * 7 * 4 == 3584
     assert visited(128, 1, 32, 128) == visited(128, 0, 32, 128) == 512
     assert visited(4, 33, 12, 8) == 4 * 2 * 4 and visited(4, 10**6, 12, 8) == 4 * 3 * 4
     assert visited(2, 5, 3, 8) == 2 * 1 * 3  # fewer page places than a pass takes
+
+
+@pytest.mark.parametrize(
+    "contexts,places,page,own",
+    [
+        ([3584] * 128, 32, 128, 128 * 28),  # every row at the longest: 3,584 as the loop
+        ([2560, 3584, 3000, 0, 1], 32, 128, 20 + 28 + 24 + 0 + 1),
+        ([129], 64, 128, 2),
+        ([], 32, 128, 0),
+        ([33, 8, 9], 12, 8, 5 + 1 + 2),
+    ],
+)
+def test_the_kernels_plan_visits_each_rows_own_pages(contexts, places, page, own):
+    """``latent_decode_pages_visited`` for the kernel's plan: the sum of
+    each row's own pages (the kernel copies a page a copy: its granule is
+    one page), whatever the longest row and the block table's width; at
+    least what is live, at most what the XLA loop gathers for the slots."""
+    slots = max(len(contexts), 1)
+    kernel = latent_decode_pages_visited("latent_live", contexts, slots, places, page)
+    assert kernel == own == sum(-(-n // page) for n in contexts)
+    assert kernel <= latent_decode_pages_visited("xla", contexts, slots, places, page)
+
+
+def test_a_latent_pool_the_kernel_reads_is_served_by_it(monkeypatch):
+    """Where the plan names the kernel (pallas, interpreted here; a rank
+    and rows of whole lane tiles, float32 pages of 8 rows), the decode
+    step runs it: the tokens are the XLA loop's, ``stats()`` names the
+    plan's schedule, and ``latent_pages_visited`` is what is live."""
+    import dataclasses
+
+    from llmq_tpu.ops import dispatch
+
+    cfg = dataclasses.replace(STATELESS, kv_lora_rank=128)
+    params = init_params(cfg, jax.random.key(1), dtype=jnp.float32)
+    served = {}
+    for backend, plan in (("xla", "xla"), ("pallas", "latent_live")):
+        monkeypatch.setenv("LLMQ_ATTN_BACKEND", backend)
+        core = make_core(params=params, cfg=cfg)
+        assert dispatch.latent_decode_kernel_plan(
+            128, *core.k_pages.shape[2:], core.k_pages.dtype
+        ) == plan
+        core.spans.set(True)
+        for rid, prompt, n in REQUESTS[:3]:
+            core.add_request(rid, prompt=prompt, params=greedy(n))
+        served[backend] = {rid: out.token_ids for rid, out in drain(core).items()}
+        assert core.stats()["decode_kernel"] == plan
+        dispatches = [s for s in core.spans.dump()["spans"] if s["name"] == "decode_dispatch"]
+        assert dispatches
+        for s in dispatches:
+            if plan == "xla":
+                assert s["latent_pages_visited"] in (16, 32, 48) and s["live_pages"] < 16
+            else:
+                assert s["latent_pages_visited"] == s["live_pages"] > 0
+    assert served["pallas"] == served["xla"]
+    assert all(len(ids) == n for ids, (_, _, n) in zip(served["xla"].values(), REQUESTS))
 
 
 @pytest.mark.parametrize(

@@ -360,14 +360,9 @@ def _pallas_grids(fn, *args, **kwargs):
     return grids
 
 
-def test_the_default_decode_schedule_follows_the_live_set(monkeypatch):
-    """No grid axis spans the page places, and the copies a call starts
-    are the live pages' (K and V), whatever ``pages_per_seq`` is: a later
-    PR cannot bring the fixed grid back unnoticed."""
-    from llmq_tpu.ops import dispatch
-
-    assert dispatch.decode_kernel_plan(8, 2, jnp.float32, None, "pallas") == "live"
-
+def _count_copy_starts(monkeypatch):
+    """Every ``make_async_copy(...).start()`` of a kernel traced from here
+    on appends to the returned list when it runs."""
     started = []
     real_copy = pk.pltpu.make_async_copy
 
@@ -385,6 +380,18 @@ def test_the_default_decode_schedule_follows_the_live_set(monkeypatch):
     monkeypatch.setattr(
         pk.pltpu, "make_async_copy", lambda *a: Counted(real_copy(*a))
     )
+    return started
+
+
+def test_the_default_decode_schedule_follows_the_live_set(monkeypatch):
+    """No grid axis spans the page places, and the copies a call starts
+    are the live pages' (K and V), whatever ``pages_per_seq`` is: a later
+    PR cannot bring the fixed grid back unnoticed."""
+    from llmq_tpu.ops import dispatch
+
+    assert dispatch.decode_kernel_plan(8, 2, jnp.float32, None, "pallas") == "live"
+
+    started = _count_copy_starts(monkeypatch)
     ctx = [20, 0, 300, 64, 1]
     live_pages = sum(-(-c // 8) for c in ctx)
     window = jnp.asarray([_WINDOW_DISABLED], jnp.int32)
@@ -802,3 +809,213 @@ class TestMixedQueryGrid:
                 np.testing.assert_array_equal(
                     row[:n], np.arange(row[0], row[0] + n)
                 )
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) paged decode: the kernel against the XLA loop
+# ---------------------------------------------------------------------------
+
+LATENT_PAGE = 16  # a chunk is 16 pages at these widths: 256 tokens
+# (heads, rank, W, Wp): openpangu's 128 heads over rows of 576 kept in 640,
+# and ling's 32.
+LATENT_SHAPES = {"heads128": (128, 512, 576, 640), "heads32": (32, 512, 576, 640)}
+# An empty slot, one token, exactly one page, one token past a chunk, a
+# ragged row, and the block table's last place.
+LATENT_PLACES = 18
+LATENT_CTX = [0, 1, LATENT_PAGE, 16 * LATENT_PAGE + 1, 93, LATENT_PLACES * LATENT_PAGE]
+
+
+def _latent_setup(shape, dtype, *, ctx=LATENT_CTX, layers=3, garbage=None):
+    """A stacked latent pool whose rows hold zeros beyond ``W``, queries,
+    and a block table that gives every live place a scattered page of its
+    own; a dead place holds page 0, or ``garbage``."""
+    n_heads, rank, W, Wp = LATENT_SHAPES[shape]
+    rng = np.random.default_rng(7)
+    need = [-(-c // LATENT_PAGE) for c in ctx]
+    P = 1 + sum(need) + 3
+    pages = np.zeros((layers, P, LATENT_PAGE, Wp), np.float32)
+    pages[..., :W] = rng.normal(size=(layers, P, LATENT_PAGE, W)) * 0.3
+    q = rng.normal(size=(len(ctx), n_heads, W)) * 0.3
+    bt = np.full((len(ctx), LATENT_PLACES), 0 if garbage is None else garbage, np.int32)
+    order, at = rng.permutation(np.arange(1, P)), 0
+    for s, n in enumerate(need):
+        bt[s, :n] = order[at : at + n]
+        at += n
+    return (
+        jnp.asarray(q, dtype), jnp.asarray(pages, dtype), jnp.asarray(bt),
+        jnp.asarray(ctx, jnp.int32), rank,
+    )
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2), (jnp.float32, 2e-5)], ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", LATENT_SHAPES)
+def test_latent_decode_live_matches_the_xla_loop(shape, dtype, tol):
+    """Ragged contexts over a layer > 0 of a stacked pool: the kernel is
+    the XLA loop's result in its precision (bf16 operands, float32
+    softmax), an empty slot gives zeros and nothing is NaN."""
+    q, pages, bt, cl, rank = _latent_setup(shape, dtype)
+    layer = jnp.asarray(2, jnp.int32)
+    ref = ref_ops.latent_paged_decode_attention(
+        q, pages, bt, cl, scale=0.07, rank=rank, layer=layer
+    )
+    out = pk.latent_paged_decode_attention_live(
+        q, pages, bt, cl, layer, scale=0.07, rank=rank, interpret=True
+    )
+    assert out.shape == ref.shape == (len(LATENT_CTX), q.shape[1], rank)
+    assert out.dtype == q.dtype
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    live = np.asarray(LATENT_CTX) > 0
+    assert np.isfinite(out).all()
+    assert not out[~live].any()
+    np.testing.assert_allclose(out[live], ref[live], rtol=0, atol=tol)
+    # another layer's rows are other rows
+    other = pk.latent_paged_decode_attention_live(
+        q, pages, bt, cl, jnp.asarray(0, jnp.int32), scale=0.07, rank=rank, interpret=True
+    )
+    assert np.abs(np.asarray(other, np.float32)[live] - out[live]).max() > 10 * tol
+
+
+@pytest.mark.parametrize("shape", LATENT_SHAPES)
+def test_latent_decode_live_never_reads_a_dead_page_place(shape):
+    """Dead places of the block table may hold anything, a page id far
+    outside the pool included: the output is bit for bit what it is with
+    the scratch page there."""
+    outs = []
+    for garbage in (None, 10**6):
+        q, pages, bt, cl, rank = _latent_setup(shape, jnp.bfloat16, garbage=garbage)
+        outs.append(np.asarray(
+            pk.latent_paged_decode_attention_live(
+                q, pages, bt, cl, jnp.asarray(1, jnp.int32),
+                scale=0.07, rank=rank, interpret=True,
+            ),
+            np.float32,
+        ))
+    assert np.array_equal(*outs)
+
+
+def test_latent_decode_live_under_the_layer_scan():
+    """The model's layer scan: the whole stacked pool, the layer a traced
+    index of one jitted program."""
+    q, pages, bt, cl, rank = _latent_setup("heads32", jnp.float32, ctx=[40, 0, 17, 270])
+
+    def attend(fn, **kw):
+        def layer(_, li):
+            return None, fn(q, pages, bt, cl, scale=0.07, rank=rank, layer=li, **kw)
+
+        return jax.jit(lambda: jax.lax.scan(layer, None, jnp.arange(pages.shape[0]))[1])()
+
+    def kernel(q, pages, bt, cl, *, layer, **kw):
+        return pk.latent_paged_decode_attention_live(q, pages, bt, cl, layer, **kw)
+
+    out = attend(kernel, interpret=True)
+    ref = attend(ref_ops.latent_paged_decode_attention)
+    live = np.asarray([40, 0, 17, 270]) > 0
+    np.testing.assert_allclose(
+        np.asarray(out)[:, live], np.asarray(ref)[:, live], rtol=0, atol=2e-5
+    )
+
+
+def test_latent_decode_live_refuses_a_pool_padded_on_the_chip():
+    q, pages, bt, cl, rank = _latent_setup("heads32", jnp.bfloat16)
+    with pytest.raises(ValueError, match="padded on the chip"):
+        pk.latent_paged_decode_attention_live(
+            q, pages[..., :576], bt, cl, jnp.asarray(0, jnp.int32),
+            scale=0.07, rank=rank, interpret=True,
+        )
+
+
+@pytest.mark.parametrize(
+    "page,width,pool,tp,backend,plan",
+    [
+        (128, 640, jnp.bfloat16, 1, "pallas", "latent_live"),
+        (128, 640, jnp.float32, 1, "pallas", "latent_live"),
+        (16, 640, jnp.bfloat16, 1, "pallas", "latent_live"),
+        (128, 576, jnp.bfloat16, 1, "pallas", "xla"),  # rows not whole lane tiles
+        (8, 640, jnp.bfloat16, 1, "pallas", "xla"),  # a page of half a packed tile
+        (128, 640, jnp.float8_e5m2, 1, "pallas", "xla"),  # one-byte values
+        (128, 640, jnp.bfloat16, 2, "pallas", "xla"),  # a mesh of several devices
+        (128, 640, jnp.bfloat16, 1, "xla", "xla"),
+    ],
+    ids=[
+        "bf16", "f32", "bf16_small_pages", "rows_of_576", "pages_of_8_bf16",
+        "fp8", "tp2", "backend_xla",
+    ],
+)
+def test_latent_decode_kernel_plan_names_what_runs(page, width, pool, tp, backend, plan):
+    """The latent plan is a function of the pool's shape, the mesh and the
+    backend, and what it names is what ``latent_decode_attention`` puts in
+    the jaxpr: one grid step a slot, or no kernel."""
+    from llmq_tpu.ops import dispatch
+    from llmq_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(tensor_parallel=tp, devices=jax.devices()[:tp])
+    assert dispatch.latent_decode_kernel_plan(
+        512, page, width, pool, mesh, backend
+    ) == plan
+    assert dispatch.latent_decode_kernel_plan(
+        512, page, width, pool, None, backend
+    ) == (plan if tp == 1 else "latent_live")
+    # a rank that is not whole lane tiles (the tiny test models') stays XLA
+    assert dispatch.latent_decode_kernel_plan(
+        32, page, width, pool, mesh, backend
+    ) == "xla"
+    S, places = 3, 4
+    grids = _pallas_grids(
+        dispatch.latent_decode_attention,
+        jnp.zeros((S, 4, min(width, 576)), jnp.bfloat16),
+        jnp.zeros((2, 9, page, width), pool),
+        jnp.zeros((S, places), jnp.int32), jnp.ones((S,), jnp.int32),
+        scale=0.07, rank=512, layer=jnp.asarray(1, jnp.int32), mesh=mesh, backend=backend,
+    )
+    assert grids == ([(S,)] if plan == "latent_live" else [])
+
+
+def test_the_latent_schedule_follows_the_live_set(monkeypatch):
+    """The copies a call starts are each row's own pages, one a page,
+    whatever ``pages_per_seq`` and the longest row are, and that is the
+    count ``latent_decode_pages_visited`` gives for the kernel's plan:
+    at least what is live, at most what the XLA loop gathers."""
+    started = _count_copy_starts(monkeypatch)
+    ctx = [20, 0, 270, 64, 1]
+    q, pages, bt, cl, rank = _latent_setup("heads32", jnp.float32, ctx=ctx)
+    live_pages = sum(-(-c // LATENT_PAGE) for c in ctx)
+    visited = ref_ops.latent_decode_pages_visited
+    for places in (LATENT_PLACES, 40):
+        table = jnp.pad(bt, ((0, 0), (0, places - LATENT_PLACES)))
+        # A scale no other test uses: this kernel is traced afresh, with
+        # the counting copies.
+        call = dict(scale=0.05 + places / 1000, rank=rank, interpret=True)
+        layer = jnp.asarray(1, jnp.int32)
+        assert _pallas_grids(
+            pk.latent_paged_decode_attention_live, q, pages, table, cl, layer, **call
+        ) == [(len(ctx),)]
+        started.clear()
+        jax.block_until_ready(
+            pk.latent_paged_decode_attention_live(q, pages, table, cl, layer, **call)
+        )
+        jax.effects_barrier()
+        assert len(started) == live_pages
+        assert (
+            live_pages
+            == visited("latent_live", ctx, len(ctx), places, LATENT_PAGE)
+            <= visited("xla", ctx, len(ctx), places, LATENT_PAGE)
+        )
+
+
+@pytest.mark.parametrize(
+    "n_heads,page_size,itemsize,pages",
+    [
+        (128, 128, 2, 8),  # openpangu: a score tile of [128, 1,024] float32
+        (32, 128, 2, 16),  # ling: 2.5 MiB of pages an update
+        (128, 128, 4, 8),
+        (8, 128, 4, 8),  # a float32 pool: the bytes an update folds in
+        (128, 16, 2, 16),  # the small pages of these tests
+    ],
+)
+def test_latent_schedule_comes_from_the_page_bytes_and_the_heads(
+    n_heads, page_size, itemsize, pages
+):
+    G = pk._latent_decode_schedule(page_size * 640 * itemsize, n_heads, page_size)
+    assert G == pages
+    # two chunks of pages, the score tile and the accumulator fit VMEM's default limit
+    assert 2 * G * page_size * 640 * itemsize + n_heads * (G * page_size + 512) * 4 < 12 * 2**20
