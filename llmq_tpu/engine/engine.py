@@ -645,22 +645,36 @@ def kv_page_bytes_per_device(
     return 2 * pool // probe_pages
 
 
-def _layer_pattern_refusal(what: str, stateful: bool) -> str:
+# What a layer pattern is and why it refuses an option, by the kind of its
+# state layers (False: it has none).
+_PATTERN_REFUSALS = {
+    "kda": (
+        "per-sequence KDA state beside a latent cache",
+        "the state cannot be shared by a prefix, cut at a chunk, rewound by a "
+        "length or moved between pools",
+    ),
+    "conv": (
+        "gated short-convolution layers beside a K/V paged cache",
+        "a per-sequence convolution tail cannot be shared by a prefix, cut at "
+        "a chunk or rewound, moving it between pools is not built",
+    ),
+    False: (
+        "a latent cache alone, no per-sequence state",
+        "chunked prefill, verify, the mixed step and moving a latent pool are "
+        "not built for a layer pattern (HybridTransformer has whole-prompt "
+        "prefill and decode)",
+    ),
+}
+
+
+def _layer_pattern_refusal(what: str, stateful: "str | bool") -> str:
     """Why an option is refused for a layer pattern: the reason that holds
-    for this one (``stateful``: it has KDA layers)."""
-    if stateful:
-        return (
-            f"{what} is not supported for a model with a layer pattern "
-            "(per-sequence KDA state beside a latent cache): the state cannot "
-            "be shared by a prefix, cut at a chunk, rewound by a length or "
-            "moved between pools, and its experts are held whole on one device"
-        )
+    for this one (``stateful``: the kind of its state layers, "kda" or
+    "conv", or False where it has none)."""
+    pattern, reason = _PATTERN_REFUSALS[stateful]
     return (
-        f"{what} is not supported for a model with a layer pattern (a latent "
-        "cache alone, no per-sequence state): chunked prefill, verify, the "
-        "mixed step and moving a latent pool are not built for a layer "
-        "pattern (HybridTransformer has whole-prompt prefill and decode), "
-        "and its experts are held whole on one device"
+        f"{what} is not supported for a model with a layer pattern "
+        f"({pattern}): {reason}, and its experts are held whole on one device"
     )
 
 
@@ -730,16 +744,21 @@ class EngineCore:
         self.full_mesh = self.mesh
         self.pp = mesh_pp(self.mesh)
         # A declared layer pattern (models/hybrid.py): a per-sequence
-        # state beside a paged latent cache. ``_state_rows`` sizes the
-        # state pool: a row a slot, and row 0 scratch.
+        # state beside a paged latent or K/V cache. ``_state_rows`` sizes
+        # the state pool: a row a slot, and row 0 scratch.
         self._hybrid = model_config.layer_pattern is not None
         self._state_rows = self.cfg.max_num_seqs + 1 if self._hybrid else None
-        # Which reason a refusal gives: a state that cannot be cut, or
-        # paths that are not built for a layer pattern.
-        self._stateful = self._hybrid and any(
-            attn == "kda" for attn, _ in model_config.layer_pattern
-        )
+        # The kind of the pattern's state layers ("kda", "conv") or False:
+        # which reason a refusal gives, a state or a tail that cannot be
+        # cut, or paths that are not built for a layer pattern.
+        self._stateful = False
         if self._hybrid:
+            from llmq_tpu.models.hybrid import STATE_KINDS
+
+            self._stateful = next(
+                (attn for attn, _ in model_config.layer_pattern if attn in STATE_KINDS),
+                False,
+            )
             self._refuse_for_layer_pattern(params)
         if self.pp > 1:
             if self.cfg.spec_tokens > 0:
@@ -2980,13 +2999,15 @@ class EngineCore:
 
     def _decode_kernel_plan(self) -> str:
         """The decode-attention schedule of this engine's pool, as
-        ``ops/dispatch`` names it: a layer pattern's is its latent pool's
-        (state layers have no attention kernel), any other model's its K/V
-        pool's."""
+        ``ops/dispatch`` names it: a layer pattern's is its paged pool's
+        (latent rows, or a token's V and K side by side; state layers have
+        no attention kernel), any other model's its K/V pool's."""
         mc = self.model_config
         if self._hybrid:
+            from llmq_tpu.models import hybrid
+
             return _dispatch.latent_decode_kernel_plan(
-                mc.kv_lora_rank, *self.k_pages.shape[2:], self.k_pages.dtype,
+                hybrid.paged_rank(mc), *self.k_pages.shape[2:], self.k_pages.dtype,
                 mesh=self.mesh, backend=self.model.attn_backend,
             )
         return _dispatch.decode_kernel_plan(

@@ -61,13 +61,14 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     shared_expert_intermediate_size: Optional[int] = None  # qwen2_moe only
     norm_topk_prob: bool = False  # renormalize the top-k routing weights
-    # Declared layer pattern (bailing_hybrid, pangu_ultra_moe): one
-    # (attention, mlp) pair a layer, attention "kda" | "mla", mlp "dense" |
-    # "moe". None: every layer is the family's one kind, run by
-    # ``models/transformer.py`` as before; a pattern is run by
+    # Declared layer pattern (bailing_hybrid, pangu_ultra_moe, lfm2_moe):
+    # one (attention, mlp) pair a layer, attention "kda" | "mla" | "conv" |
+    # "gqa", mlp "dense" | "moe". None: every layer is the family's one
+    # kind, run by ``models/transformer.py`` as before; a pattern is run by
     # ``models/hybrid.py``.
     layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
-    # KDA (delta-rule) layers: heads are num_heads x head_dim.
+    # KDA (delta-rule) layers: heads are num_heads x head_dim. The taps of
+    # their short convolution, and of a "conv" layer's (``conv_L_cache``).
     short_conv_kernel_size: int = 4
     kda_lower_bound: float = -5.0  # bounded ("safe") gate: g in [bound, 0]
     # MLA (latent attention) layers. ``q_lora_rank``: the query goes through
@@ -85,6 +86,8 @@ class ModelConfig:
     topk_group: int = 1
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # Added to the sum of the chosen scores before it divides them.
+    router_norm_eps: float = 1e-20
     # (first, count) of the routed experts this chip holds; None: all of
     # them. The router keeps its width (num_experts) either way.
     experts_held: Optional[Tuple[int, int]] = None
@@ -121,6 +124,8 @@ class ModelConfig:
             return cls._from_bailing_hybrid(hf)
         if mt == "pangu_ultra_moe":
             return cls._from_pangu_ultra_moe(hf)
+        if mt == "lfm2_moe":
+            return cls._from_lfm2_moe(hf)
         eos = _as_id_list(hf.get("eos_token_id"))
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -342,6 +347,75 @@ class ModelConfig:
             norm_topk_prob=hf.get("norm_topk_prob", True),
             routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
             experts_held=None if held is None else (int(held[0]), int(held[1])),
+        )
+
+    @classmethod
+    def _from_lfm2_moe(cls, hf: Dict[str, Any]) -> "ModelConfig":
+        """LFM2 with routed experts (``lfm2_moe``): ``layer_types`` names
+        each layer's operator, a gated short convolution (``conv``, a tail
+        of ``conv_L_cache - 1`` inputs a sequence, no attention) or
+        grouped-query softmax attention (``full_attention``) with per-head
+        RMSNorms of q and k before half-split rotary; the first
+        ``num_dense_layers`` MLPs are dense, the rest sigmoid-routed
+        experts chosen by score plus ``expert_bias`` and weighted by the
+        score alone, no shared expert. ``kept_layers`` (published layer
+        indices, default all) is this repo's own key: layer ``j`` of those
+        kept has the operator of its published index and a dense MLP
+        where ``j < num_dense_layers``."""
+        rope = hf.get("rope_parameters") or {}
+        wanted = {"conv_bias": False, "hidden_act": "silu", "rope_scaling": None}
+        for key, want in wanted.items():
+            if hf.get(key, want) != want:
+                raise ValueError(
+                    f"lfm2_moe: {key}={hf[key]!r} is not built (only {want!r} is)"
+                )
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(
+                f"lfm2_moe: rope_type={rope['rope_type']!r} is not built "
+                "(only 'default' is)"
+            )
+        kinds = {"conv": "conv", "full_attention": "gqa"}
+        kept = hf.get("kept_layers")
+        if kept is None:
+            kept = range(int(hf["num_hidden_layers"]))
+        kept = tuple(int(i) for i in kept)
+        if len(kept) != int(hf["num_hidden_layers"]):
+            raise ValueError(
+                f"lfm2_moe: kept_layers names {len(kept)} layers, "
+                f"num_hidden_layers {hf['num_hidden_layers']}"
+            )
+        dense = int(hf.get("num_dense_layers", 0))
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            num_layers=len(kept),
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+            intermediate_size=hf["intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            max_position_embeddings=hf.get("max_position_embeddings", 128000),
+            rope_theta=float(rope.get("rope_theta", hf.get("rope_theta", 1e6))),
+            rms_norm_eps=hf.get("norm_eps", 1e-5),
+            tie_word_embeddings=hf.get(
+                "tie_embedding", hf.get("tie_word_embeddings", True)
+            ),
+            qk_norm=True,
+            eos_token_ids=tuple(_as_id_list(hf.get("eos_token_id"))),
+            bos_token_id=hf.get("bos_token_id"),
+            model_type="lfm2_moe",
+            layer_pattern=tuple(
+                (kinds[hf["layer_types"][i]], "dense" if j < dense else "moe")
+                for j, i in enumerate(kept)
+            ),
+            short_conv_kernel_size=int(hf.get("conv_L_cache", 3)),
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            moe_intermediate_size=hf["moe_intermediate_size"],
+            shared_expert_intermediate_size=None,
+            norm_topk_prob=hf.get("norm_topk_prob", True),
+            router_bias=bool(hf.get("use_expert_bias", True)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            router_norm_eps=1e-6,
         )
 
     @property

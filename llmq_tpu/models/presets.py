@@ -165,6 +165,43 @@ PRESETS["openpangu-ultra-moe-tiny"] = ModelConfig.from_hf_config(
     )
 )
 
+# LFM2-24B-A2B (LiquidAI, ``lfm2_moe``), the published config: gated
+# short-convolution layers with every fourth (from layer 2) a GQA layer of
+# 32 / 8 heads of 64, two dense MLPs, then 64 sigmoid-routed experts
+# (a selection bias, no group, no shared expert) of which 4 a token.
+_LFM2_24B_A2B = dict(
+    model_type="lfm2_moe", vocab_size=65536, hidden_size=2048,
+    num_hidden_layers=40, num_attention_heads=32, num_key_value_heads=8,
+    intermediate_size=11776, max_position_embeddings=128000,
+    rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
+    norm_eps=1e-05, conv_L_cache=3, conv_bias=False,
+    layer_types=["conv" if i < 2 or i % 4 != 2 else "full_attention" for i in range(40)],
+    num_dense_layers=2, num_experts=64, num_experts_per_tok=4,
+    moe_intermediate_size=1536, norm_topk_prob=True, routed_scaling_factor=1,
+    use_expert_bias=True,
+)
+# One pipeline stage of five, every layer whole on its chip and every
+# expert held: published layer 0 (a conv layer with the dense MLP; the two
+# leading dense layers count once) and layers 2-9 (two whole periods:
+# attention, three conv, all with experts). Every width is the published
+# one; the embedding is tied and whole.
+PRESETS["lfm2-24b-a2b-pp5"] = ModelConfig.from_hf_config(
+    dict(
+        _LFM2_24B_A2B, num_hidden_layers=9, num_dense_layers=1,
+        kept_layers=[0, 2, 3, 4, 5, 6, 7, 8, 9],
+    )
+)
+# The same structure at widths a CPU test serves (byte tokenizer: ids <
+# 304): a dense conv layer, both periods, 4 of 8 experts a token.
+PRESETS["lfm2-moe-tiny"] = ModelConfig.from_hf_config(
+    dict(
+        _LFM2_24B_A2B, vocab_size=304, hidden_size=64, num_hidden_layers=9,
+        num_dense_layers=1, kept_layers=[0, 2, 3, 4, 5, 6, 7, 8, 9],
+        num_attention_heads=4, num_key_value_heads=2, intermediate_size=128,
+        num_experts=8, moe_intermediate_size=32,
+    )
+)
+
 
 def get_preset(name: str) -> ModelConfig:
     try:

@@ -57,7 +57,9 @@ def test_cell_finds_traffic_and_metric_files(cell):
         assert callable(reader.read)
 
 
-@pytest.mark.parametrize("name", ["ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16"])
+@pytest.mark.parametrize(
+    "name", ["ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16", "lfm2-24b-a2b-pp5"]
+)
 def test_the_hybrid_preset_is_the_tree_its_configuration_describes(name):
     """The served tree of ``preset://<name>`` and the tree the benchmark
     makes from the configuration's file have one layout."""
@@ -112,3 +114,78 @@ def test_latent_decode_cost_by_hand_and_a_reader_with_nothing_to_read():
     assert kernel_cost_mla.mla_decode_flops(**kw) == 278_528 + 42_401_792
     ctx = SimpleNamespace(_span_join=False, peaks={}, live_kv={"tokens": 1, "sequences": 1})
     assert decode_mla_roofline.read(ctx, program="jit_decode_step", scope="x") is None
+
+
+def test_the_lfm2_file_keeps_every_published_key_it_does_not_reduce():
+    """Every key of the published config (the worker's preset carries it)
+    stands in the benchmark's file at its published value, ``layer_types``
+    whole, but for the two under ``reduced``, which stand beside their
+    published value; no reduced key is a width; the file states what it
+    assumed, its deployment and the arithmetic of its 10.36 GB, and the
+    file's own mapping gives the preset's pattern."""
+    from llmq_tpu.models.config import ModelConfig
+    from llmq_tpu.models.presets import _LFM2_24B_A2B as published
+    from llmq_tpu.models.presets import get_preset
+
+    cfg = json.loads((ROOT / "benchmark/configs/lfm2-24b-a2b-pp5.json").read_text())
+    reduced = set(cfg["reduced"])
+    assert reduced == {"num_hidden_layers", "num_dense_layers"}
+    for key, value in published.items():
+        if key in reduced:
+            assert cfg[f"{key}_published"] == value and cfg[key] != value, key
+        else:
+            assert cfg[key] == value, key
+    assert len(cfg["layer_types"]) == 40 and len(cfg["kept_layers"]) == cfg["num_hidden_layers"]
+    assert [cfg["layer_types"][i] for i in cfg["kept_layers"]] == (
+        ["conv"] + ["full_attention", "conv", "conv", "conv"] * 2
+    )
+    assert set(cfg["assumed"]) >= {"tie_embedding", "head_dim", "router", "qk_norm_order", "rope"}
+    assert "5,178 M parameters, 10.36 GB" in cfg["deployment"]
+    assert "pipeline stage of five" in cfg["deployment"]
+    preset = get_preset("lfm2-24b-a2b-pp5")
+    assert ModelConfig.from_hf_config(cfg).layer_pattern == preset.layer_pattern
+    traffic = json.loads((ROOT / "benchmark/traffic/decode-agent.json").read_text())
+    assert {k: traffic[k] for k in (
+        "generator", "clients", "requests_per_client", "stagger_seconds", "warm_seconds",
+        "trace_offset_s", "trace_seconds", "check_lengths",
+    )} == {
+        "generator": "closed_loop", "clients": 128, "requests_per_client": 8,
+        "stagger_seconds": 48, "warm_seconds": 60, "trace_offset_s": 5, "trace_seconds": 4,
+        "check_lengths": [3072, 1024],
+    }
+    assert (traffic["prompt_tokens"]["min"], traffic["prompt_tokens"]["max"]) == (2048, 4096)
+    assert traffic["output_tokens"]["value"] == 2048 and "rate_rps" not in traffic
+
+
+def test_the_lfm2_cell_lists_its_own_attention_metrics_and_not_the_uniform_models():
+    """``decode_attn_ms`` / ``decode_attn_roofline`` multiply by
+    ``num_hidden_layers`` where 2 layers of 9 attend: the cell is in
+    neither list, and reads its attention by ``decode_gqa_*`` instead."""
+    cell = "lfm2-24b-a2b-pp5.decode-agent"
+    lists = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
+    assert cell not in lists["decode_attn_ms"] and cell not in lists["decode_attn_roofline"]
+    for name in ("decode_gqa_ms", "decode_gqa_roofline", "decode_shortconv_ms"):
+        assert lists[name] == [cell], name
+    for name in ("decode_step_dev_ms", "decode_moe_ms", "decode_moe_roofline",
+                 "moe_tokens_per_expert_mean", "decode_live_pages_mean", "tpot_p95_ms.closed"):
+        assert cell in lists[name], name
+
+
+def test_gqa_decode_cost_by_hand_and_a_reader_with_nothing_to_read():
+    """``kernel_cost_gqa``: one live token and one row of one attention
+    layer at the published widths (the benchmark's own copy of this proof
+    is ``benchmark/tests/test_kernel_cost_gqa.py``); the attention layers
+    are counted from ``layer_types``; and the reader leaves the metric out
+    where the program gave no spans."""
+    from types import SimpleNamespace
+
+    from benchmark import kernel_cost_gqa
+    from benchmark.readers import decode_gqa_roofline
+
+    kw = dict(live_tokens=1, rows=1, layers=1, hidden=2048, heads=32, kv_heads=8, head_dim=64)
+    assert kernel_cost_gqa.gqa_decode_bytes(**kw) == 2_048 + 12_582_912 + 8_192
+    assert kernel_cost_gqa.gqa_decode_flops(**kw) == 8_192 + 12_582_912
+    cfg = json.loads((ROOT / "benchmark/configs/lfm2-24b-a2b-pp5.json").read_text())
+    assert kernel_cost_gqa.attention_layers(cfg) == 2
+    ctx = SimpleNamespace(_span_join=False, peaks={}, live_kv={"tokens": 1, "sequences": 1})
+    assert decode_gqa_roofline.read(ctx, program="jit_decode_step", scope="x") is None

@@ -4,8 +4,14 @@ what it refuses at build, and its counters; and a pattern with no state
 layer at all (``pangu_ultra_moe``: latent attention alone), whose state
 pool is empty. CPU, tiny widths."""
 
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from llmq_tpu.engine.engine import EngineConfig, EngineCore
@@ -16,7 +22,7 @@ from llmq_tpu.models import quant as qm
 from llmq_tpu.models import hybrid
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.presets import get_preset
-from llmq_tpu.models.transformer import init_params
+from llmq_tpu.models.transformer import build_model, init_params, make_kv_pages
 from llmq_tpu.ops.attention import latent_decode_pages_visited
 from llmq_tpu.parallel import make_mesh
 
@@ -379,3 +385,154 @@ def test_worker_refuses_the_disaggregated_roles(monkeypatch, preset):
     worker = TPUWorker("q", model=f"preset://{preset}")
     with pytest.raises(ValueError, match="role=prefill is not supported"):
         worker._build_core()
+
+
+# --- gated short-convolution layers beside GQA layers over a K/V paged pool
+# (``lfm2_moe``): a tail-only state, the pool's row a token's V then K.
+LFM2 = get_preset("lfm2-moe-tiny")
+LFM2_PARAMS = init_params(LFM2, jax.random.key(2), dtype=jnp.float32)
+LFM2_PARAMS["stack1"]["router_bias"] = 0.3 * jax.random.normal(
+    jax.random.key(3), LFM2_PARAMS["stack1"]["router_bias"].shape
+)
+
+
+def make_lfm2(**engine) -> EngineCore:
+    return make_core(params=LFM2_PARAMS, cfg=LFM2, **engine)
+
+
+def direct_greedy(prompt, n, page=8, places=12):
+    """Greedy tokens of the model itself: one prefill, then a decode step
+    a token, through a scratch pool of its own."""
+    model = build_model(LFM2)
+    ids = ByteTokenizer().encode(prompt)
+    bucket = max(16, 1 << (len(ids) - 1).bit_length())
+    tokens = np.zeros((1, bucket), np.int32)
+    tokens[0, : len(ids)] = ids
+    bt = np.arange(1, places + 1, dtype=np.int32)[None]
+    k, v = make_kv_pages(LFM2, places + 1, page, jnp.float32)
+    logits, k, v = jax.jit(model.prefill)(
+        LFM2_PARAMS, tokens, np.asarray([len(ids)], np.int32), k, v, bt
+    )
+    out = [int(np.argmax(logits[0]))]
+    decode = jax.jit(model.decode)
+    for j in range(n - 1):
+        logits, k, v = decode(
+            LFM2_PARAMS, np.asarray(out[-1:], np.int32),
+            np.asarray([len(ids) + j], np.int32), k, v, bt, np.asarray([True]),
+        )
+        out.append(int(np.argmax(logits[0])))
+    return out
+
+
+def test_a_conv_gqa_pattern_is_served_as_the_model_itself_decodes():
+    """8 requests through 4 slots (every tail row overwritten by its next
+    prefill while run-ahead steps are in flight), then a pool too small
+    for three (recompute preemption): every request gets the tokens of
+    the direct model's own greedy loop."""
+    core = make_lfm2()
+    for rid, prompt, n in REQUESTS:
+        core.add_request(rid, prompt=prompt, params=greedy(n))
+    outs = drain(core)
+    assert core.stats()["prefills"] == len(REQUESTS)
+    for rid, prompt, n in REQUESTS:
+        assert outs[rid].token_ids == direct_greedy(prompt, n), rid
+    prompts = [(f"r{i}", f"pr {i} " * 3, 14) for i in range(3)]
+    small = make_lfm2(num_pages=9, page_size=4, max_model_len=48)
+    for rid, prompt, n in prompts:
+        small.add_request(rid, prompt=prompt, params=greedy(n))
+    outs = drain(small)
+    assert small.stats()["preemptions"] >= 1
+    for rid, prompt, n in prompts:
+        assert outs[rid].token_ids == direct_greedy(prompt, n), rid
+
+
+def test_a_conv_gqa_pattern_keeps_tails_alone_beside_a_pool_of_v_and_k():
+    """The first cache place holds the 2 attention layers' rows (V then K:
+    2 x 2 heads x 16, kept in 128), the second the 7 conv layers' tails
+    (2 rows of 64 a sequence) and an empty ``S``; the dispatch span carries
+    ``state_rows`` and ``live_pages``; the plan is the paged pool's."""
+    assert hybrid.count_layers(LFM2, "gqa") == 2 and hybrid.count_layers(LFM2, "conv") == 7
+    assert hybrid.paged_rank(LFM2) == 32 and hybrid.tail_width(LFM2) == 64
+    assert hybrid.state_pool_bytes(LFM2, 129, jnp.bfloat16) == 129 * 7 * 2 * 64 * 2
+    core = make_lfm2()
+    assert core._stateful == "conv" and core._state_rows == 5
+    assert core.k_pages.shape == (2, 60, 8, 128)
+    assert core.v_pages["S"].size == 0
+    assert core.v_pages["conv"].shape == (7, 5, 2, 64)
+    core.spans.set(True)
+    for rid, prompt, n in REQUESTS[:3]:
+        core.add_request(rid, prompt=prompt, params=greedy(n))
+    drain(core)
+    stats = core.stats()
+    assert stats["decode_kernel"] == "xla"
+    steps = stats["decode_steps"]
+    # 8 expert layers, 4 slots a step, 4 experts a token, all 8 held
+    assert 0 < stats["moe_assignments_held"] <= steps * 8 * 4 * 4
+    assert 0 < stats["moe_experts_hit"] <= steps * 8 * 8
+    dispatches = [s for s in core.spans.dump()["spans"] if s["name"] == "decode_dispatch"]
+    assert dispatches and all(1 <= s["state_rows"] <= 3 for s in dispatches)
+    assert all(s["latent_pages_visited"] >= s["live_pages"] > 0 for s in dispatches)
+
+
+@pytest.mark.parametrize("option, named", REFUSED_AT_BUILD.values(), ids=REFUSED_AT_BUILD.keys())
+def test_a_conv_gqa_pattern_is_refused_for_the_reason_that_holds(option, named):
+    with pytest.raises(ValueError, match="layer pattern") as refused:
+        make_lfm2(**option)
+    assert str(refused.value).startswith(named)
+    assert "per-sequence convolution tail cannot be shared by a prefix" in str(refused.value)
+    assert "KDA" not in str(refused.value) and "latent" not in str(refused.value)
+
+
+def test_a_conv_gqa_pattern_refuses_snapshots_by_the_same_reason():
+    core = make_lfm2()
+    with pytest.raises(NotImplementedError, match="extract_all.*convolution tail"):
+        core.extract_all()
+    with pytest.raises(NotImplementedError, match="prefill role.*convolution tail"):
+        core.add_request("p", prompt="x", params=greedy(2), prefill_only=True)
+
+
+def test_worker_refuses_the_disaggregated_roles_for_a_conv_gqa_pattern(monkeypatch):
+    from llmq_tpu.workers.tpu_worker import TPUWorker
+
+    monkeypatch.setenv("LLMQ_WORKER_ROLE", "prefill")
+    monkeypatch.setenv("LLMQ_BROKER_URL", "memory://hybrid-role")
+    worker = TPUWorker("q", model="preset://lfm2-24b-a2b-pp5")
+    with pytest.raises(ValueError, match="role=prefill is not supported"):
+        worker._build_core()
+
+
+def test_a_uniform_engine_imports_nothing_of_a_layer_pattern():
+    """A ``qwen2``-like engine, built, served and asked for its stats in a
+    process of its own, has imported neither ``models/hybrid`` nor
+    ``ops/delta_rule``: nothing a layer pattern needs is on its path
+    (they are imported where ``layer_pattern`` is not None)."""
+    script = """
+import json, sys
+import jax, jax.numpy as jnp
+from llmq_tpu.engine.engine import EngineConfig, EngineCore
+from llmq_tpu.engine.sampling import SamplingParams
+from llmq_tpu.engine.tokenizer import ByteTokenizer
+from llmq_tpu.models.presets import get_preset
+from llmq_tpu.models.transformer import init_params
+from llmq_tpu.parallel import make_mesh
+cfg = get_preset("tiny")
+core = EngineCore(
+    cfg, init_params(cfg, jax.random.key(0), dtype=jnp.float32), ByteTokenizer(),
+    mesh=make_mesh(tensor_parallel=1),
+    engine_config=EngineConfig(max_num_seqs=2, max_model_len=64, page_size=8,
+                               num_pages=20, kv_dtype=jnp.float32),
+)
+core.add_request("a", prompt="hello", params=SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True))
+while core.has_work:
+    core.step()
+core.stats()
+print(json.dumps(sorted(m for m in sys.modules if m.startswith("llmq_tpu"))))
+"""
+    out = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    modules = json.loads(out.stdout.strip().splitlines()[-1])
+    assert "llmq_tpu.engine.engine" in modules
+    assert not [m for m in modules if m.endswith((".hybrid", ".delta_rule"))]

@@ -87,6 +87,30 @@ def pangu_configs(first=4, held=8, **changed):
     return ModelConfig.from_hf_config(hf), file_cfg
 
 
+# LFM2-24B-A2B's published keys at a tiny size: a dense conv layer, then
+# both periods (attention, three conv) with 8 experts of which 4 a token,
+# chosen by score + bias (seeded biases are 0.1 n: not zero).
+LFM2_HF = dict(
+    model_type="lfm2_moe", vocab_size=304, hidden_size=64, num_hidden_layers=9,
+    num_attention_heads=4, num_key_value_heads=2, intermediate_size=128,
+    rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
+    norm_eps=1e-5, conv_L_cache=3, conv_bias=False,
+    layer_types=["conv" if i < 2 or i % 4 != 2 else "full_attention" for i in range(40)],
+    kept_layers=[0, 2, 3, 4, 5, 6, 7, 8, 9], num_dense_layers=1, num_experts=8,
+    num_experts_per_tok=4, moe_intermediate_size=32, norm_topk_prob=True,
+    routed_scaling_factor=1, use_expert_bias=True,
+)
+LFM2_GROUPS = [
+    ("conv", "dense", 1), ("gqa", "moe", 1), ("conv", "moe", 3), ("gqa", "moe", 1),
+    ("conv", "moe", 3),
+]
+
+
+def lfm2_configs(**changed):
+    hf = dict(LFM2_HF, **changed)
+    return ModelConfig.from_hf_config(hf), dict(hf, architecture="lfm2_moe")
+
+
 class Family:
     """A configuration with its reference, seeded weights and model."""
 
@@ -108,6 +132,13 @@ _FAMILIES = {
         *pangu_configs(num_hidden_layers=1), [("mla", "dense", 1)]),
     "pangu_expert_layers_alone": lambda: (
         *pangu_configs(num_hidden_layers=2, first_k_dense_replace=0), [("mla", "moe", 2)]),
+    "lfm2": lambda: (*lfm2_configs(), LFM2_GROUPS),
+    "lfm2_no_selection_bias": lambda: (*lfm2_configs(use_expert_bias=False), LFM2_GROUPS),
+    "lfm2_lead_layer_alone": lambda: (
+        *lfm2_configs(num_hidden_layers=1, kept_layers=[0]), [("conv", "dense", 1)]),
+    "lfm2_one_period_alone": lambda: (
+        *lfm2_configs(num_hidden_layers=4, kept_layers=[2, 3, 4, 5], num_dense_layers=0),
+        [("gqa", "moe", 1), ("conv", "moe", 3)]),
 }
 
 
@@ -131,6 +162,8 @@ def test_tree_is_the_one_the_benchmark_describes(name):
     ("ling", 1), ("ling", 4), ("pangu", 1), ("pangu", 4), ("pangu_no_query_lora", 4),
     ("pangu_no_sandwich_norms", 4), ("pangu_lead_layer_alone", 4),
     ("pangu_expert_layers_alone", 4),
+    ("lfm2", 1), ("lfm2", 4), ("lfm2_no_selection_bias", 4), ("lfm2_lead_layer_alone", 4),
+    ("lfm2_one_period_alone", 4),
 ])
 def test_prefill_then_decode_matches_the_reference(name, rows):
     """Batches of 1 and 4 rows with padding: rows of different lengths in
@@ -464,9 +497,13 @@ PANGU_FAULTS = [
 ]
 
 
+LFM2_FAULTS = ["qk_norm_off", "bias_off", "bias_weighs", "topk_norm_off", "tail_off", "moe_drop"]
+
+
 @pytest.mark.parametrize("name, fault", [
     ("ling", "kda_reset"), ("ling", "conv_tail"), ("ling", "moe_drop"), ("ling", "moe_drop_first"),
     *(("pangu", fault) for fault in PANGU_FAULTS),
+    *(("lfm2", fault) for fault in LFM2_FAULTS),
 ])
 def test_a_planted_fault_of_the_reference_is_far_from_it(name, fault):
     """The controls that stand for a wrong cache manager or step program
@@ -482,7 +519,7 @@ def test_a_planted_fault_of_the_reference_is_far_from_it(name, fault):
     assert worst > 100 * 2e-4
 
 
-@pytest.mark.parametrize("name", ["ling", "pangu"])
+@pytest.mark.parametrize("name", ["ling", "pangu", "lfm2"])
 def test_the_diagnoses_round_and_force_the_float32_choice_of_experts(name):
     rounded = _reference_err("bf16act", name=name)
     routed = _reference_err("bf16act_routed", name=name)
@@ -509,3 +546,127 @@ def test_the_reference_takes_its_heads_a_group_at_a_time(monkeypatch):
     for control, want in whole.items():
         np.testing.assert_allclose(logits(control), want, atol=2e-5, rtol=0)
     jax.clear_caches()
+
+
+# --- gated short-convolution layers beside GQA layers (``lfm2_moe``) -------
+
+
+def test_lfm2_from_hf_config_on_the_published_keys():
+    from llmq_tpu.models.presets import _LFM2_24B_A2B
+
+    whole = ModelConfig.from_hf_config(_LFM2_24B_A2B)
+    assert whole.num_layers == 40 and whole.head_dim_ == 64 and whole.tie_word_embeddings
+    assert [a for a, _ in whole.layer_pattern].count("gqa") == 10
+    assert [m for _, m in whole.layer_pattern] == ["dense"] * 2 + ["moe"] * 38
+    assert whole.layer_pattern[2][0] == "gqa" and whole.layer_pattern[38][0] == "gqa"
+    assert (whole.short_conv_kernel_size, whole.rope_theta, whole.rms_norm_eps) == (3, 1e6, 1e-5)
+    assert whole.router_bias and whole.router_norm_eps == 1e-6 and whole.qk_norm
+    assert whole.shared_expert_intermediate_size is None and whole.experts_held_ == (0, 64)
+    stage = get_preset("lfm2-24b-a2b-pp5")
+    assert [(g.attn, g.mlp, g.count) for g in hybrid.layer_groups(stage)] == LFM2_GROUPS
+    shapes = hybrid.param_shapes(stage)
+    assert "lm_head" not in shapes and "shared_gate_proj" not in shapes["stack1"]
+    n = sum(
+        int(np.prod(shape)) for shape in jax.tree.leaves(
+            shapes, is_leaf=lambda x: isinstance(x, tuple)
+        )
+    )
+    assert round(n / 1e6) == 5178  # 10.36 GB of bf16
+    # a pool row is a token's V then K: 1,024 values, whole lane tiles
+    assert hybrid.latent_pool_width(stage) == 1024 and hybrid.paged_rank(stage) == 512
+    assert hybrid.state_pool_bytes(stage, 129, jnp.bfloat16) == 7 * 129 * 2 * 2048 * 2
+    for key, value in (("conv_bias", True), ("rope_parameters", {"rope_type": "yarn"})):
+        with pytest.raises(ValueError, match="lfm2_moe"):
+            ModelConfig.from_hf_config(dict(_LFM2_24B_A2B, **{key: value}))
+    with pytest.raises(ValueError, match="one paged kind and one state kind"):
+        hybrid.layer_groups(
+            dataclasses.replace(stage, layer_pattern=(("gqa", "moe"), ("mla", "moe")))
+        )
+
+
+@pytest.mark.parametrize("n", [9, 16, 21])
+def test_lfm2_prefill_of_n_then_one_decode_step_is_prefill_of_n_plus_one(n):
+    """A row padded inside a 4-row bucket (another row longer, one
+    padded): its tail is taken at its TRUE length and its V and K rows
+    are written up to it, so one decode step after a prefill of n tokens
+    gives the logits a prefill of n + 1 gives. n = 16: the step opens a
+    page of 8."""
+    f = family("lfm2")
+    ids = list(np.random.default_rng(n).integers(1, 300, size=n + 1))
+    other = list(np.random.default_rng(n + 1).integers(1, 300, size=27))
+
+    def bucket(row):
+        tokens = np.zeros((4, 32), np.int32)
+        tokens[0, : len(other)], tokens[2, : len(row)] = other, row
+        lengths = np.asarray([len(other), 0, len(row), 0], np.int32)
+        bt = np.zeros((4, PPS), np.int32)
+        bt[0, :4], bt[2, :3] = [1, 2, 3, 4], [5, 6, 7]
+        k, v = make_kv_pages(f.mc, PAGES, PAGE, jnp.float32)
+        logits, k, v = jax.jit(f.model.prefill)(f.params, tokens, lengths, k, v, bt)
+        return logits, k, v, bt
+
+    longer, _, _, _ = bucket(ids)
+    _, k, v, bt = bucket(ids[:n])
+    step, _, _ = jax.jit(f.model.decode)(
+        f.params, np.asarray([0, 0, ids[n], 0], np.int32), np.asarray([0, 0, n, 0], np.int32),
+        k, v, bt, np.asarray([False, False, True, False]),
+    )
+    np.testing.assert_allclose(np.asarray(step[2]), np.asarray(longer[2]), atol=2e-4, rtol=0)
+
+
+def test_lfm2_the_selection_bias_changes_the_choice_and_not_the_weights():
+    """A bias large enough to choose experts 0-3 for every token: the
+    chosen experts change, and each weighs by its sigmoid score alone
+    (over the sum of the four chosen scores + 1e-6), the bias nowhere in
+    the weights."""
+    f = family("lfm2")
+    lp = {name: w[0] for name, w in f.params["stack1"].items()}
+    x = jax.random.normal(jax.random.key(1), (6, 64), jnp.float32)
+    scores = np.asarray(jax.nn.sigmoid(x @ lp["router"]))
+    forced = dict(lp, router_bias=jnp.asarray([5.0] * 4 + [0.0] * 4))
+    plain = dict(lp, router_bias=jnp.zeros((8,)))
+
+    def by_hand(chosen):
+        out = np.zeros((6, 64), np.float32)
+        for t in range(6):
+            total = scores[t, chosen[t]].sum() + 1e-6
+            for e in chosen[t]:
+                h = jax.nn.silu(x[t] @ lp["expert_gate_proj"][e]) * (x[t] @ lp["expert_up_proj"][e])
+                out[t] += scores[t, e] / total * np.asarray(h @ lp["expert_down_proj"][e])
+        return out
+
+    largest = np.argsort(-scores, axis=1)[:, :4]
+    assert not all(set(row) == {0, 1, 2, 3} for row in largest)
+    out, counts = hybrid.moe_held(x, forced, f.mc)
+    np.testing.assert_allclose(np.asarray(out), by_hand([[0, 1, 2, 3]] * 6), atol=2e-5, rtol=0)
+    assert counts.tolist() == [24, 4]
+    out, _ = hybrid.moe_held(x, plain, f.mc)
+    np.testing.assert_allclose(np.asarray(out), by_hand(largest), atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("dense_rows", [256, 0], ids=["dense", "grouped"])
+def test_lfm2_every_expert_held_and_no_shared_expert_is_the_references_whole_layer(
+    dense_rows, monkeypatch
+):
+    """``moe_held`` with all 8 experts held and no shared leaves is the
+    reference's routed layer (its router with the seeded bias, its
+    weights, every expert), in both forms of the sum."""
+    monkeypatch.setattr(hybrid, "DENSE_EXPERT_ROWS", dense_rows)
+    f = family("lfm2")
+    assert f.mc.experts_held_ == (0, 8) and "shared_gate_proj" not in f.params["stack2"]
+    lp = {name: w[1] for name, w in f.params["stack2"].items()}
+    assert float(jnp.abs(lp["router_bias"]).max()) > 0
+    x = jax.random.normal(jax.random.key(2), (40, 64), jnp.float32)
+    z = f.arch._sizes(f.file_cfg)
+    with jax.default_matmul_precision("highest"):
+        w, picked = f.arch._route(x, lp, z, (4, 1.0, True), None)
+        want = sum(
+            w[:, e : e + 1] * f.arch._swiglu(
+                x, lp["expert_gate_proj"][e], lp["expert_up_proj"][e],
+                lp["expert_down_proj"][e], None,
+            )
+            for e in range(8)
+        )
+        out, counts = hybrid.moe_held(x, lp, f.mc)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=0)
+    assert int(counts[0]) == 40 * 4 and int(counts[1]) == int((picked.sum(axis=0) > 0).sum())

@@ -360,7 +360,7 @@ def _refusal_is_a_compile_failure_not_a_device_fault(topo, monkeypatch):
     assert classify_failure(refused.value) is None
 
 
-def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64):
+def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64, page_bytes=None):
     """The decode step and a prefill bucket of the Ling-3.0-flash cut
     at its published widths (``preset://ling-3.0-flash-ep4``: 10.46 GB of
     bf16 weights), over the pools the benchmark's cell runs with: 128
@@ -382,7 +382,17 @@ def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64):
     pool's leaves are empty), over its cell's pools: 3,200 latent pages, 32
     page places a row (``max_model_len`` 4,096). Its largest bucket, 4 x
     4,096, which expands a row at a time, compiles in 14 s to 3.2 GB of
-    temporaries and is left out of ``CASES`` likewise."""
+    temporaries and is left out of ``CASES`` likewise.
+
+    And for the LFM2-24B-A2B stage (``preset://lfm2-24b-a2b-pp5``: 10.36 GB
+    of weights, gated short-convolution layers with tails alone in the
+    state pool, two GQA layers of 32 / 8 heads of 64), over its cell's
+    pools: 6,700 pages, 64 page places a row. Heads of 64: the paged
+    pool's row is a token's V then K, 1,024 bf16 values, so a page is the
+    512 KB the widths say (``page_bytes``: nothing padded), the decode step
+    reads it with the latent pool's kernel and copies it nowhere, and the
+    1 x 4,096 and 4 x 2,048 prefills (the Pallas flash kernel at heads of
+    64 in them) hold no temporary the size of the pool."""
     slots = 128
 
     def case(topo, monkeypatch):
@@ -417,6 +427,15 @@ def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64):
         mem = compiled.memory_analysis()
         pools = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves((latent, state)))
         assert mem.alias_size_in_bytes >= pools  # both pools in place
+        if page_bytes is not None:
+            assert latent.size * latent.dtype.itemsize == pages * page_bytes
+            # (what the compiler reserves grows with what the pools leave
+            # free: 0.56 GB beside 7,500 pages, 1.29 GB beside 6,700)
+            assert mem.temp_size_in_bytes < pools // 2
+            text = compiled.as_text()
+            assert "tpu_custom_call" in text  # flash prefill, or the decode kernel
+            pool = f"bf16[{latent.shape[0]},{pages},"
+            assert not [l for l in text.splitlines() if " copy(" in l and pool in l]
         if which == "decode":
             # The latent kernel is in the step (a Mosaic call named for
             # it), reads the pool where it lies, and with the XLA loop's
@@ -445,6 +464,15 @@ CASES = {
     ),
     "latent_only_prefill_1x2048": _hybrid_step(
         (1, 2048), "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
+    ),
+    "conv_gqa_decode_128_slots_heads_of_64": _hybrid_step(
+        "decode", "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
+    ),
+    "conv_gqa_prefill_1x4096": _hybrid_step(
+        (1, 4096), "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
+    ),
+    "conv_gqa_prefill_4x2048": _hybrid_step(
+        (4, 2048), "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
     ),
     "latent_decode_live_128_heads": _latent_decode_live(128, 5, 3200, 32),
     "latent_decode_live_32_heads": _latent_decode_live(32, 1, 2305, 64),
