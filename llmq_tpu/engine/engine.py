@@ -130,7 +130,9 @@ from llmq_tpu.obs.trace import emit_trace_event
 from llmq_tpu.ops import dispatch as _dispatch
 from llmq_tpu.utils.host_mem import get_governor
 from llmq_tpu.utils.platform import on_tpu
-from llmq_tpu.ops.attention import latent_decode_pages_visited, mixed_query_grid
+from llmq_tpu.ops.attention import (
+    eva_context, eva_table_pages, latent_decode_pages_visited, mixed_query_grid,
+)
 from llmq_tpu.parallel import pipeline as pp_mod
 from llmq_tpu.parallel.mesh import (
     DP_AXIS,
@@ -658,6 +660,13 @@ _PATTERN_REFUSALS = {
         "a per-sequence convolution tail cannot be shared by a prefix, cut at "
         "a chunk or rewound, moving it between pools is not built",
     ),
+    "eva": (
+        "EVA layers over a compressed paged cache whose rows are not positions",
+        "a closed window's rows are overwritten by its summaries, so the cache "
+        "cannot be shared by a prefix, cut at a chunk, rewound by a length or "
+        "moved between pools; a step yields one token (the extra prediction "
+        "heads are not served)",
+    ),
     False: (
         "a latent cache alone, no per-sequence state",
         "chunked prefill, verify, the mixed step and moving a latent pool are "
@@ -670,7 +679,8 @@ _PATTERN_REFUSALS = {
 def _layer_pattern_refusal(what: str, stateful: "str | bool") -> str:
     """Why an option is refused for a layer pattern: the reason that holds
     for this one (``stateful``: the kind of its state layers, "kda" or
-    "conv", or False where it has none)."""
+    "conv", or "eva" for EVA layers, which keep no state but a cache that
+    cannot be cut, or False where it has neither)."""
     pattern, reason = _PATTERN_REFUSALS[stateful]
     return (
         f"{what} is not supported for a model with a layer pattern "
@@ -752,6 +762,9 @@ class EngineCore:
         # which reason a refusal gives, a state or a tail that cannot be
         # cut, or paths that are not built for a layer pattern.
         self._stateful = False
+        # EVA layers (``ops/attention.eva_row``): the cache's rows are not
+        # positions. (window, chunk), or None: a token's row is its position.
+        self._eva: Optional[Tuple[int, int]] = None
         if self._hybrid:
             from llmq_tpu.models.hybrid import STATE_KINDS
 
@@ -759,6 +772,10 @@ class EngineCore:
                 (attn for attn, _ in model_config.layer_pattern if attn in STATE_KINDS),
                 False,
             )
+            if any(attn == "eva" for attn, _ in model_config.layer_pattern):
+                self._eva = (model_config.eva_window, model_config.eva_chunk)
+                # Windows compacted by decode steps since start.
+                self.eva_windows_closed = 0
             self._refuse_for_layer_pattern(params)
         if self.pp > 1:
             if self.cfg.spec_tokens > 0:
@@ -892,6 +909,7 @@ class EngineCore:
             page_size=self.cfg.page_size,
             max_model_len=self.cfg.max_model_len,
             enable_prefix_caching=self.cfg.enable_prefix_caching,
+            table_pages=self._table_pages,
         )
         self.scheduler = Scheduler(sched_cfg)
         self.scheduler.on_preempt = self._on_scheduler_preempt
@@ -1188,7 +1206,7 @@ class EngineCore:
             ):
                 if value != off:
                     raise ValueError(
-                        _layer_pattern_refusal(f"{option}={value}", self._stateful)
+                        _layer_pattern_refusal(f"{option}={value}", self._refusal_kind)
                     )
             # Counters of the expert layers, summed over layers and decode
             # steps; they ride the pending entry and the fetch of the tokens.
@@ -1592,6 +1610,11 @@ class EngineCore:
                 self.canary_every,
             )
 
+    @property
+    def _refusal_kind(self) -> "str | bool":
+        """Which of ``_PATTERN_REFUSALS`` holds for this layer pattern."""
+        return self._stateful or ("eva" if self._eva else False)
+
     def _refuse_for_layer_pattern(self, params: Params) -> None:
         """What a layer pattern cannot do yet, refused at build by name: a
         per-sequence state cannot be shared by a prefix, cut at a chunk,
@@ -1617,7 +1640,7 @@ class EngineCore:
             (jnp.dtype(cfg.kv_dtype).itemsize < 2, f"kv_dtype={cfg.kv_dtype}"),
         ):
             if refused:
-                raise ValueError(_layer_pattern_refusal(what, self._stateful))
+                raise ValueError(_layer_pattern_refusal(what, self._refusal_kind))
 
     def _dispatch_p99(self, kind: str) -> Optional[float]:
         """Watchdog deadline source: live p99 of one dispatch kind, or
@@ -2805,17 +2828,27 @@ class EngineCore:
         self.params = jax.tree.map(reput, self.params, formats)
         self._make_jits(formats)
 
+    @property
+    def _table_pages(self):
+        """The model's row map as the scheduler asks it
+        (``SchedulerConfig.table_pages``); None: a row a position."""
+        if self._eva is None:
+            return None
+        return partial(
+            eva_table_pages, window=self._eva[0], chunk=self._eva[1],
+            page_size=self.cfg.page_size,
+        )
+
     def _auto_num_pages(self) -> int:
         """Size the KV pool from device HBM (vLLM gpu_memory_utilization
         parity, ``vllm_worker.py:107``). A CPU run (tests) has no HBM to
         read and gets a fixed small pool; a TPU that reports no
         ``bytes_limit`` is an error — a guessed pool there would hide
         the device."""
-        max_useful = (
-            self.cfg.max_num_seqs
-            * (-(-self.cfg.max_model_len // self.cfg.page_size) + 1)
-            + 1
-        )
+        per_seq = -(-self.cfg.max_model_len // self.cfg.page_size)
+        if self._eva is not None:  # the places its compressed cache reaches
+            per_seq = self._table_pages(0, self.cfg.max_model_len)
+        max_useful = self.cfg.max_num_seqs * (per_seq + 1) + 1
         if not on_tpu():
             return min(max_useful, 4096)
         device = self.mesh.devices.flat[0]
@@ -2888,7 +2921,7 @@ class EngineCore:
         if prefill_only and self._hybrid:
             raise NotImplementedError(
                 _layer_pattern_refusal(
-                    "prefill_only (the prefill role)", self._stateful
+                    "prefill_only (the prefill role)", self._refusal_kind
                 )
             )
         if not self.priority_classes:
@@ -2976,6 +3009,8 @@ class EngineCore:
         places that overlap each one's attended span (all of its context,
         or its window where every layer of the model slides)."""
         page, mc = self.cfg.page_size, self.model_config
+        if self._eva is not None:  # the pages that hold its attended rows
+            return sum(-(-n // page) for n in self._contexts(seqs))
         window = (
             mc.sliding_window if mc.sliding_window_pattern <= 1 else None
         )
@@ -2985,13 +3020,30 @@ class EngineCore:
             for s in seqs
         )
 
+    def _contexts(self, seqs: List[Sequence]) -> List[int]:
+        """Cache rows each of ``seqs`` attends in a decode step (the new
+        token's included): its tokens, or what the model's row map makes
+        of them (EVA layers: earlier windows' summaries + its own window)."""
+        if self._eva is None:
+            return [s.num_tokens for s in seqs]
+        return [eva_context(s.num_tokens, *self._eva) for s in seqs]
+
+    def _eva_rows(self, seqs: List[Sequence]) -> Dict[str, int]:
+        """Rows a decode step of ``seqs`` attends, by kind: the summaries
+        of earlier windows and the exact rows of each one's own window."""
+        own = sum((s.num_tokens - 1) % self._eva[0] + 1 for s in seqs)
+        return {
+            "summary_rows": sum(self._contexts(seqs)) - own,
+            "window_rows": own,
+        }
+
     def _latent_pages_visited(self, seqs: List[Sequence]) -> int:
         """Latent pages a decode step reads out of the pool a layer
         (beside ``_live_pages``, what is live), by the schedule the step
         runs."""
         return latent_decode_pages_visited(
             self._decode_kernel_plan(),
-            [s.num_tokens for s in seqs],
+            self._contexts(seqs),
             self.cfg.max_num_seqs,
             self._pages_per_seq,
             self.cfg.page_size,
@@ -3348,6 +3400,14 @@ class EngineCore:
                     # remaining in-block tokens are lagged garbage (the
                     # device rode them out inactive) and are discarded.
                     continue
+                if (
+                    self._eva is not None
+                    and kind == "decode"
+                    and seq.num_tokens % self._eva[0] == 0
+                ):
+                    # The step wrote position num_tokens - 1, its window's
+                    # last, and replaced the window's rows by its summaries.
+                    self.eva_windows_closed += 1
                 self._append_and_check(seq, int(k_tokens[row]), finished)
         self._processed_idx = idx
         if self.spans.on:
@@ -4269,7 +4329,7 @@ class EngineCore:
         )
         decodable = self._decodable_seqs()
         needs_pages = any(
-            -(-self._page_target(seq, lookahead) // self.cfg.page_size)
+            self.scheduler.pages_for(seq, self._page_target(seq, lookahead))
             > len(seq.pages)
             for seq in decodable
         )
@@ -4405,6 +4465,7 @@ class EngineCore:
                 k_steps=k_steps, pending=len(self._pending),
                 ahead=self._ahead,
                 **({"state_rows": len(seqs)} if self._stateful else {}),
+                **(self._eva_rows(seqs) if self._eva else {}),
                 **(
                     {"latent_pages_visited": self._latent_pages_visited(seqs)}
                     if self._hybrid else {}
@@ -4807,7 +4868,7 @@ class EngineCore:
         :meth:`insert_request` is bit-identical to never extracting."""
         if self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("extract_request", self._stateful)
+                _layer_pattern_refusal("extract_request", self._refusal_kind)
             )
         out = finished if finished is not None else []
         self._drain(out)
@@ -4830,7 +4891,7 @@ class EngineCore:
         :meth:`extract_request`."""
         if self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("extract_all", self._stateful)
+                _layer_pattern_refusal("extract_all", self._refusal_kind)
             )
         out = finished if finished is not None else []
         self._drain(out)
@@ -4993,7 +5054,7 @@ class EngineCore:
         prompt+output instead — same math, same tokens."""
         if self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("insert_request", self._stateful)
+                _layer_pattern_refusal("insert_request", self._refusal_kind)
             )
         sig, mine = dict(snap.model_sig), self._model_sig()
         if sig != mine:
@@ -5430,6 +5491,8 @@ class EngineCore:
             # an expert that is hit sees a step).
             s["moe_assignments_held"] = self.moe_assignments_held
             s["moe_experts_hit"] = self.moe_experts_hit
+        if self._eva is not None:
+            s["eva_windows_closed"] = self.eva_windows_closed
         if self.cfg.spec_tokens > 0:
             # What speculation actually dispatches: the multi-query
             # verify resolves through its own plan, not the decode one.

@@ -273,6 +273,12 @@ class SchedulerConfig:
     # exact pre-priority order, and stats() omits the per-class keys so
     # default payloads stay byte-identical.
     priority_aware: bool = False
+    # The model's row map, for a cache whose rows are not positions (a
+    # layer pattern's EVA layers, ``ops/attention.eva_table_pages``):
+    # ``table_pages(start, stop)`` is the places a block table needs to
+    # hold the rows of positions ``[start, stop)``. None: a token's row is
+    # its position, ``ceil(stop / page_size)`` places.
+    table_pages: Optional[Callable[[int, int], int]] = None
 
     @property
     def pages_per_seq(self) -> int:
@@ -459,10 +465,26 @@ class Scheduler:
     def num_running(self) -> int:
         return len(self.running)
 
+    def _table_pages(self, start: int, stop: int) -> int:
+        """Places of a block table that hold the cache rows of positions
+        ``[start, stop)``: the model's row map where it has one
+        (``SchedulerConfig.table_pages``), else a row a position."""
+        if self.config.table_pages is not None:
+            return self.config.table_pages(start, stop)
+        return -(-stop // self.config.page_size)
+
     def _pages_needed(self, num_tokens: int) -> int:
         # +1 position of headroom: the decode step writes the *next* token's
-        # KV before the host learns the sequence finished.
-        return -(-(num_tokens + 1) // self.config.page_size)
+        # KV before the host learns the sequence finished. (Under a row
+        # map the next token's row is past every row a prefill of
+        # ``num_tokens`` writes, so its place is the need.)
+        return self._table_pages(num_tokens, num_tokens + 1)
+
+    def pages_for(self, seq: Sequence, num_positions: int) -> int:
+        """Pages ``seq`` needs to cover ``num_positions`` KV slots: every
+        position a step may still write, from the one its next decode step
+        writes (``num_tokens - 1``; the rows before it have their pages)."""
+        return self._table_pages(max(seq.num_tokens - 1, 0), num_positions)
 
     # --- admission --------------------------------------------------------
     def _next_admit_index(self) -> int:
@@ -648,7 +670,7 @@ class Scheduler:
         OutOfPages otherwise."""
         cap = self.config.pages_per_seq * self.config.page_size
         num_positions = min(num_positions, cap)
-        while -(-num_positions // self.config.page_size) > len(seq.pages):
+        while self.pages_for(seq, num_positions) > len(seq.pages):
             try:
                 seq.pages.extend(self.allocator.alloc(1))
             except OutOfPages:

@@ -61,9 +61,9 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     shared_expert_intermediate_size: Optional[int] = None  # qwen2_moe only
     norm_topk_prob: bool = False  # renormalize the top-k routing weights
-    # Declared layer pattern (bailing_hybrid, pangu_ultra_moe, lfm2_moe):
-    # one (attention, mlp) pair a layer, attention "kda" | "mla" | "conv" |
-    # "gqa", mlp "dense" | "moe". None: every layer is the family's one
+    # Declared layer pattern (bailing_hybrid, pangu_ultra_moe, lfm2_moe,
+    # evabyte): one (attention, mlp) pair a layer, attention "kda" | "mla"
+    # | "conv" | "gqa" | "eva", mlp "dense" | "moe". None: every layer is the family's one
     # kind, run by ``models/transformer.py`` as before; a pattern is run by
     # ``models/hybrid.py``.
     layer_pattern: Optional[Tuple[Tuple[str, str], ...]] = None
@@ -91,6 +91,14 @@ class ModelConfig:
     # (first, count) of the routed experts this chip holds; None: all of
     # them. The router keeps its width (num_experts) either way.
     experts_held: Optional[Tuple[int, int]] = None
+    # EVA layers ("eva"): exact softmax inside the query's own window of
+    # ``eva_window`` positions, one learned summary a chunk of ``eva_chunk``
+    # for every earlier window (``ops/attention.eva_row``: the cache's rows).
+    eva_window: Optional[int] = None
+    eva_chunk: Optional[int] = None
+    # RMSNorm weights are ``1 + w`` (evabyte's ``norm_add_unit_offset``), in
+    # a layer pattern; the Gemma families are told by their ``model_type``.
+    norm_unit_offset: bool = False
     eos_token_ids: Tuple[int, ...] = ()
     bos_token_id: Optional[int] = None
     model_type: str = "llama"
@@ -126,6 +134,8 @@ class ModelConfig:
             return cls._from_pangu_ultra_moe(hf)
         if mt == "lfm2_moe":
             return cls._from_lfm2_moe(hf)
+        if mt == "evabyte":
+            return cls._from_evabyte(hf)
         eos = _as_id_list(hf.get("eos_token_id"))
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -416,6 +426,53 @@ class ModelConfig:
             router_bias=bool(hf.get("use_expert_bias", True)),
             routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
             router_norm_eps=1e-6,
+        )
+
+    @classmethod
+    def _from_evabyte(cls, hf: Dict[str, Any]) -> "ModelConfig":
+        """EvaByte (``evabyte``): every layer multi-head EVA attention
+        (``attention_class`` "eva": exact softmax inside the query's own
+        ``window_size`` positions, one learned summary a ``chunk_size``
+        positions for every earlier window) and a SwiGLU MLP, RMSNorm
+        weights ``1 + w`` (``norm_add_unit_offset``), rotary over the whole
+        head. Its float32 residual adds and logits (``fp32_skip_add``,
+        ``fp32_logits``) are what the step computes anyway: a bf16 add is
+        made in float32 and rounded, the logits are float32. Only
+        prediction head 0 is served (``num_pred_heads`` is not read)."""
+        wanted = {
+            "attention_class": "eva", "hidden_act": "silu", "rope_scaling": None,
+            "attention_bias": False, "norm_add_unit_offset": True,
+        }
+        for key, want in wanted.items():
+            if hf.get(key, want) != want:
+                raise ValueError(
+                    f"evabyte: {key}={hf[key]!r} is not built (only {want!r} is)"
+                )
+        heads = hf["num_attention_heads"]
+        if hf.get("num_key_value_heads", heads) != heads:
+            raise ValueError("evabyte: EVA layers are multi-head (kv heads = heads)")
+        window, chunk = int(hf["window_size"]), int(hf["chunk_size"])
+        if window % chunk:
+            raise ValueError(f"evabyte: window_size {window} is not whole chunks of {chunk}")
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=heads,
+            intermediate_size=hf["intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            max_position_embeddings=hf.get("max_position_embeddings", 32768),
+            rope_theta=float(hf.get("rope_theta", 100000)),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            eos_token_ids=tuple(_as_id_list(hf.get("eos_token_id"))),
+            bos_token_id=hf.get("bos_token_id"),
+            model_type="evabyte",
+            layer_pattern=(("eva", "dense"),) * int(hf["num_hidden_layers"]),
+            eva_window=window,
+            eva_chunk=chunk,
+            norm_unit_offset=True,
         )
 
     @property

@@ -1,12 +1,14 @@
 """Models with a declared layer pattern (``ModelConfig.layer_pattern``),
-three families: delta-rule (KDA) layers with a per-sequence state beside
+four families: delta-rule (KDA) layers with a per-sequence state beside
 latent (MLA) layers over a paged latent cache (``bailing_hybrid``:
 Ling-3.0); latent layers alone, every one with a query LoRA and sandwich
 norms, no state layer at all (``pangu_ultra_moe``: openPangu-Ultra-MoE);
 and gated short-convolution layers with a per-sequence tail beside
 grouped-query softmax layers over a paged K/V cache (``lfm2_moe``:
-LFM2-24B-A2B). Dense or routed-expert MLPs, the experts held by share or
-all of them.
+LFM2-24B-A2B); and EVA layers alone, exact softmax inside the query's own
+window and one learned summary a chunk for every earlier window, over a
+paged cache whose rows are no longer positions (``evabyte``: EvaByte).
+Dense or routed-expert MLPs, the experts held by share or all of them.
 
 Every other family is one uniform stack and stays on
 ``models/transformer.py``; nothing here is on its path.
@@ -25,7 +27,10 @@ paged layers (``PAGED_KINDS``) need, the SECOND what its state layers
   640). ``gqa``: the token's V then its K, every kv head side by side
   (``2 * n_kv * d``: 1,024 at 8 heads of 64, whole lane tiles where a
   ``[page, 8, 64]`` page would be padded on the chip); the first
-  ``paged_rank`` values of a row are the part decode attention sums;
+  ``paged_rank`` values of a row are the part decode attention sums.
+  ``eva``: the same ``[V ; K]`` row, but row ``r`` of a sequence is not
+  position ``r``: ``ops/attention.eva_row`` (a closed window's rows are
+  overwritten by its summaries, ``[v~ ; k~]`` a chunk);
 - ``v_pages``: the state pool, ``{"S": [L_kda, R, heads, d, d] float32,
   "conv": [L_state, R, K-1, tail_width]}``: one row a sequence, given by
   ``state_rows`` (the engine: slot + 1). A caller that passes none gets the
@@ -72,6 +77,18 @@ router chooses by score + bias and weighs by the score over the chosen
 scores' sum + ``router_norm_eps`` (1e-6 here, 1e-20 in the other two),
 has no shared expert (``shared_expert_intermediate_size`` None: no
 shared leaves, no ``llmq.moe.shared``) and every expert is held.
+
+``evabyte`` (``benchmark/configs/evabyte-6.5b-pp4.json``) brings the kind
+``eva``: multi-head q, k, v rotated over the whole head; a query attends
+with ONE softmax the exact keys of its own window of ``eva_window``
+positions and, for every chunk of ``eva_chunk`` positions of an earlier
+window, the summary ``k~ = sum_j softmax_j(s k_j . mu) k_j`` with the value
+``v~ = sum_j softmax_j(s k_j . phi) v_j`` (``mu``, ``phi`` learned, a head
+and layer). Decode reads the pool with the latent pool's kernel as "gqa"
+does; the step that writes a window's last position replaces the window's
+rows by its summaries (scope ``llmq.attn.eva_summarize``, inside
+``llmq.attn.eva_decode`` and ``llmq.attn.eva_prefill``). Norm weights are
+``1 + w`` (``norm_unit_offset``).
 """
 
 from __future__ import annotations
@@ -100,7 +117,7 @@ class LayerGroup:
     """Consecutive layers of one kind: a stacked subtree and one scan."""
 
     name: str  # key of its subtree in the params
-    attn: str  # "kda" | "mla" | "conv" | "gqa"
+    attn: str  # "kda" | "mla" | "conv" | "gqa" | "eva"
     mlp: str  # "dense" | "moe"
     count: int
     first: int  # index of its first layer in its attention kind's pool
@@ -108,7 +125,7 @@ class LayerGroup:
 
 #: What a pattern's kinds keep a sequence: "paged" kinds a row a token in
 #: the first cache place, "state" kinds a row a sequence in the second.
-PAGED_KINDS = ("mla", "gqa")
+PAGED_KINDS = ("mla", "gqa", "eva")
 STATE_KINDS = ("kda", "conv")
 
 
@@ -116,7 +133,7 @@ def layer_groups(config: ModelConfig) -> Tuple[LayerGroup, ...]:
     groups = []
     seen = dict.fromkeys(PAGED_KINDS + STATE_KINDS, 0)
     kinds = {attn for attn, _ in config.layer_pattern}
-    if set(PAGED_KINDS) <= kinds or set(STATE_KINDS) <= kinds:
+    if len(kinds & set(PAGED_KINDS)) > 1 or len(kinds & set(STATE_KINDS)) > 1:
         raise ValueError(
             f"a layer pattern has one paged kind and one state kind: {sorted(kinds)}"
         )
@@ -151,8 +168,8 @@ def kv_width(config: ModelConfig) -> int:
 def paged_rank(config: ModelConfig) -> int:
     """The first values of a pool row that are the row's VALUE part, which
     decode attention sums: MLA's latent ``c``; a "gqa" layer's V, all kv
-    heads of it (its K follows)."""
-    if count_layers(config, "gqa"):
+    heads of it (its K follows), and an "eva" layer's likewise."""
+    if count_layers(config, "gqa", "eva"):
         return kv_width(config)
     return config.kv_lora_rank
 
@@ -205,6 +222,11 @@ def group_shapes(config: ModelConfig, group: LayerGroup) -> Dict[str, tuple]:
         shapes.update(
             q_proj=(L, H, D), k_proj=(L, H, kv), v_proj=(L, H, kv),
             q_norm=(L, d), k_norm=(L, d), o_proj=(L, D, H),
+        )
+    elif group.attn == "eva":
+        shapes.update(
+            q_proj=(L, H, D), k_proj=(L, H, D), v_proj=(L, H, D),
+            eva_mu=(L, n, d), eva_phi=(L, n, d), o_proj=(L, D, H),
         )
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
@@ -267,8 +289,9 @@ _ZEROS = ("router_bias", "kda_a_log", "kda_dt_bias")
 
 
 def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
-    """Random init (tests, ``preset://``): norms 1, biases 0, matrices
-    normal over sqrt(fan-in), the fan-in the axis before the last."""
+    """Random init (tests, ``preset://``): norms 1 (0 where the weight is
+    ``1 + w``), biases 0, matrices normal over sqrt(fan-in), the fan-in
+    the axis before the last."""
     shapes = param_shapes(config)
     flat, treedef = jax.tree.flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple)
@@ -276,7 +299,9 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Param
     leaves = []
     for k, (path, shape) in zip(jax.random.split(key, len(flat)), flat):
         name = path[-1].key
-        if name in _ONES:
+        if name in _ONES and config.norm_unit_offset:
+            leaves.append(jnp.zeros(shape, dtype))
+        elif name in _ONES:
             leaves.append(jnp.ones(shape, dtype))
         elif name in _ZEROS:
             leaves.append(jnp.zeros(shape, dtype))
@@ -746,6 +771,151 @@ class HybridTransformer(Transformer):
         ).reshape(S, n, d)
         return self._gqa_out(lp, o), pool
 
+    # EVA attention ("eva"): multi-head softmax attention whose cache is
+    # compressed a window at a time. The pool's row is "gqa"'s ``[V ; K]``
+    # with every head its own kv head, and decode reads it with the same
+    # kernel and the same row-wide queries; what differs is WHICH row a
+    # token has (``attn_ops.eva_row``: the rows a query attends stay one
+    # contiguous run from 0, so the kernel's contract holds with the
+    # context length ``row + 1``) and that the step which writes a
+    # window's last position replaces the window's exact rows by one
+    # summary row a chunk (:meth:`_eva_compact`).
+    def _eva_inputs(self, lp: Params, x: jnp.ndarray, positions: jnp.ndarray):
+        """q, k, v ``[B, T, n, d]`` (q and k rotated) and the pool's row
+        ``[V ; K]`` a token."""
+        cfg = self.config
+        n, d = cfg.num_heads, cfg.head_dim_
+        B, T, _ = x.shape
+        q, k, v = (
+            qm.matmul(x, lp[f"{name}_proj"]).reshape(B, T, n, d) for name in "qkv"
+        )
+        inv_freq = compute_rope_inv_freq(cfg)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        row = jnp.concatenate([v.reshape(B, T, -1), k.reshape(B, T, -1)], axis=-1)
+        return q, k, v, row
+
+    def _eva_summary_rows(self, lp: Params, k: jnp.ndarray, v: jnp.ndarray, dtype):
+        """The summary rows ``[v~ ; k~]`` of whole chunks of keys and
+        values ``[..., T, n, d]``, as the pool holds them."""
+        cfg = self.config
+        k_sum, v_sum = attn_ops.eva_summaries(
+            k, v, lp["eva_mu"], lp["eva_phi"],
+            scale=cfg.attn_scale, chunk=cfg.eva_chunk,
+        )
+        *lead, n, d = k_sum.shape
+        rows = jnp.concatenate(
+            [v_sum.reshape(*lead, n * d), k_sum.reshape(*lead, n * d)], axis=-1
+        )
+        return rows.astype(dtype)
+
+    @jax.named_scope("llmq.attn.eva_prefill")
+    def _eva_prefill(self, lp, x, positions, lengths, pool, block_tables, li):
+        """Every complete window of the prompt is attended exactly and
+        leaves the pool its summaries alone; only the last, partial
+        window's exact rows are written."""
+        cfg = self.config
+        n, d, W, c = cfg.num_heads, cfg.head_dim_, cfg.eva_window, cfg.eva_chunk
+        B, T, _ = x.shape
+        q, k, v, row = self._eva_inputs(lp, x, positions)
+        unit = W if T > W else c  # whole windows, or whole chunks of one
+        pad = -T % unit
+        if pad:
+            q, k, v = (
+                jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v)
+            )
+            row = jnp.pad(row, ((0, 0), (0, pad), (0, 0)))
+            positions = jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1)
+        Tp, Wd = T + pad, min(W, T + pad)
+        windows = lengths // W  # [B] complete windows of each prompt
+        with jax.named_scope("llmq.attn.eva_summarize"):
+            summary = self._eva_summary_rows(lp, k, v, pool.dtype)  # [B, Tp / c, 2 n d]
+            # Chunk g of a prompt is summary row g: S w + (g - S w).
+            g = jnp.arange(Tp // c, dtype=jnp.int32)[None, :]
+            pool = attn_ops.write_latent_pages(
+                pool, summary, block_tables,
+                jnp.where(g * c // W < windows[:, None], g, -1), li,
+            )
+        with jax.named_scope("llmq.kv_write"):
+            # The exact rows of the last window alone, cut out of the
+            # bucket: the scatter is a window's rows, not the bucket's.
+            first = jnp.minimum(windows * W, Tp - Wd)
+            cut = jax.vmap(
+                lambda a, at: jax.lax.dynamic_slice_in_dim(a, at, Wd, axis=0)
+            )
+            last_pos = cut(positions, first)
+            pool = attn_ops.write_latent_pages(
+                pool, cut(row, first), block_tables,
+                jnp.where(
+                    last_pos // W == windows[:, None],
+                    attn_ops.eva_row(last_pos, W, c), -1,
+                ),
+                li,
+            )
+        D = n * d
+        o = attn_ops.eva_prefill_attention(
+            q, k, v,
+            summary[..., D:].reshape(B, -1, n, d), summary[..., :D].reshape(B, -1, n, d),
+            scale=cfg.attn_scale, lengths=lengths, window=W, chunk=c,
+        )
+        return self._gqa_out(lp, o[:, :T]), pool
+
+    @jax.named_scope("llmq.attn.eva_decode")
+    def _eva_decode(self, lp, x, positions, pool, block_tables, ctx_incl, li):
+        """``ctx_incl`` (tokens) is not what this kind attends: its rows
+        are ``[0, eva_row(position)]``."""
+        cfg = self.config
+        n, d, W, c = cfg.num_heads, cfg.head_dim_, cfg.eva_window, cfg.eva_chunk
+        S = x.shape[0]
+        q, _, _, row = self._eva_inputs(lp, x[:, None, :], positions[:, None])
+        rows = attn_ops.eva_row(positions, W, c)  # an inactive slot's: -1
+        with jax.named_scope("llmq.kv_write"):
+            pool = attn_ops.write_latent_pages(
+                pool, row, block_tables, rows[:, None], li
+            )
+        # [S, n, n, d]: head h has its query at its own keys, zeros at
+        # the other heads' (see the comment above ``_gqa_inputs``; written
+        # out here and not shared with ``_gqa_decode``, whose program has
+        # to lower as it did). The rows attended are [0, row].
+        own = jnp.eye(n, dtype=q.dtype)[None, :, :, None]
+        q_rows = (q[:, 0, :, None, :] * own).reshape(S, n, n * d)
+        o_rows = dispatch.latent_decode_attention(
+            jnp.concatenate([jnp.zeros_like(q_rows), q_rows], axis=-1),
+            pool, block_tables, rows + 1,
+            scale=cfg.attn_scale, rank=n * d, layer=li,
+            mesh=self.mesh, backend=self.attn_backend,
+        )  # [S, n, n * d]: every head's weighted sum of whole V rows
+        o = jnp.einsum("shhd->shd", o_rows.reshape(S, n, n, d))
+        with jax.named_scope("llmq.attn.eva_summarize"):
+            pool = self._eva_compact(lp, pool, block_tables, positions, li)
+        return self._gqa_out(lp, o), pool
+
+    def _eva_compact(self, lp, pool, block_tables, positions, li):
+        """For every sequence whose step wrote the LAST position of a
+        window: read the window's ``W`` exact rows and overwrite its first
+        ``W / chunk`` rows with its summaries. A sequence at a time, as
+        many turns as sequences close a window this step (most steps:
+        none), so that what is in flight is one window's rows."""
+        cfg = self.config
+        n, d, W, c = cfg.num_heads, cfg.head_dim_, cfg.eva_window, cfg.eva_chunk
+        S_w, D, page = W // c, n * d, pool.shape[2]
+        closing = (positions >= 0) & (positions % W == W - 1)
+        turn = jnp.flatnonzero(closing, size=positions.shape[0], fill_value=0)
+
+        def compact(i, pool):
+            s = turn[i]
+            rows = S_w * (positions[s] // W) + jnp.arange(W, dtype=jnp.int32)
+            phys, offset = block_tables[s, rows // page], rows % page
+            exact = pool[li, phys, offset]  # [W, width]
+            summary = self._eva_summary_rows(
+                lp, exact[:, D : 2 * D].reshape(W, n, d),
+                exact[:, :D].reshape(W, n, d), pool.dtype,
+            )
+            summary = jnp.pad(summary, ((0, 0), (0, pool.shape[3] - 2 * D)))
+            return pool.at[li, phys[:S_w], offset[:S_w]].set(summary)
+
+        return jax.lax.fori_loop(0, closing.sum(dtype=jnp.int32), compact, pool)
+
     def _mla_inputs(self, lp: Params, x: jnp.ndarray, positions: jnp.ndarray):
         """q split into its content and rotary parts, and the latent row
         ``[RMSNorm(c) ; RoPE(r)]`` that the cache holds. ``x`` is
@@ -866,19 +1036,20 @@ class HybridTransformer(Transformer):
         latent, state, li)`` returns the attention output and the two
         caches."""
         cfg = self.config
+        one_plus = cfg.norm_unit_offset
         moe = jnp.zeros((2,), jnp.int32)
         for group in layer_groups(cfg):
 
             def layer_fn(carry, xs, group=group):
                 h, latent, state, moe = carry
                 lp, li = xs
-                x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
+                x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps, one_plus=one_plus)
                 a, latent, state = attend(group, lp, x, latent, state, li)
                 if cfg.post_norms:
                     with jax.named_scope("llmq.norm.sandwich"):
                         a = rms_norm(a, lp["post_attn_norm"], cfg.rms_norm_eps)
                 h = h + a
-                x = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+                x = rms_norm(h, lp["ln2"], cfg.rms_norm_eps, one_plus=one_plus)
                 if group.mlp == "dense":
                     m = _mlp(x, lp, cfg.activation)
                 else:
@@ -923,7 +1094,7 @@ class HybridTransformer(Transformer):
         """Full-prompt forward: (last-token logits [B, V], latent pool,
         state pool), the prompt's latent rows written to its pages and
         its final state and convolution tails to its state row."""
-        _refuse(unsupported)
+        _refuse(unsupported, self.config)
         B, T = tokens.shape
         pos_grid = jnp.arange(T, dtype=jnp.int32)[None, :]
         positions = jnp.where(
@@ -965,7 +1136,7 @@ class HybridTransformer(Transformer):
         pool, state pool). An inactive slot writes the scratch page and
         the scratch state row. With ``counters`` a fourth value: [expert
         assignments held here, held experts hit], summed over layers."""
-        _refuse(unsupported)
+        _refuse(unsupported, self.config)
         positions = jnp.where(active, context_lens, -1).astype(jnp.int32)
         ctx_incl = jnp.where(active, context_lens + 1, 0)
         rows = block_tables[:, 0] if state_rows is None else state_rows
@@ -991,7 +1162,7 @@ class HybridTransformer(Transformer):
     def prefill_chunk(self, *args, **kwargs):
         raise NotImplementedError(
             "chunked prefill, verify and the mixed step are not built for a "
-            "layer pattern"
+            "layer pattern" + _eva_reason(self.config)
         )
 
     mixed = verify = prefill_chunk
@@ -1026,9 +1197,22 @@ def _state_row_access(rows, n: int, li, active):
     return read, write
 
 
-def _refuse(unsupported: Dict[str, Any]) -> None:
+def _eva_reason(config: ModelConfig) -> str:
+    """Why a pattern with EVA layers refuses what it refuses, for the end
+    of a refusal; nothing for the other patterns."""
+    if not count_layers(config, "eva"):
+        return ""
+    return (
+        " (EVA layers: the cache's rows are not positions and a closed "
+        "window's rows are overwritten by its summaries, so a cache cannot "
+        "be cut at a chunk, rewound by a length or shared by a prefix)"
+    )
+
+
+def _refuse(unsupported: Dict[str, Any], config: ModelConfig) -> None:
     given = {k: v for k, v in unsupported.items() if v is not None and v is not False}
     if given:
         raise NotImplementedError(
             f"a model with a layer pattern takes no {sorted(given)}"
+            + _eva_reason(config)
         )

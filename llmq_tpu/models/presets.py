@@ -202,6 +202,38 @@ PRESETS["lfm2-moe-tiny"] = ModelConfig.from_hf_config(
     )
 )
 
+# EvaByte 6.5B (``evabyte``), the published config: 32 layers of multi-head
+# EVA attention (32 heads of 128; exact softmax inside the query's own
+# 2,048-byte window, one learned summary a 16-byte chunk for every earlier
+# window) and a SwiGLU MLP, a vocabulary of 320 byte ids, 8 prediction
+# heads.
+_EVABYTE = dict(
+    model_type="evabyte", vocab_size=320, hidden_size=4096,
+    num_hidden_layers=32, num_attention_heads=32, num_key_value_heads=32,
+    intermediate_size=11008, max_position_embeddings=32768, rope_theta=100000,
+    rms_norm_eps=1e-05, tie_word_embeddings=False, attention_bias=False,
+    hidden_act="silu", attention_class="eva", window_size=2048, chunk_size=16,
+    norm_add_unit_offset=True, fp32_logits=True, fp32_skip_add=True,
+    num_pred_heads=8,
+)
+# One pipeline stage of four, every layer whole on its chip with all 32
+# heads: the embedding, layers 0-7 and prediction head 0, so that the
+# stage yields bytes. Every width, the window and the chunk are the
+# published ones.
+PRESETS["evabyte-6.5b-pp4"] = ModelConfig.from_hf_config(
+    dict(_EVABYTE, num_hidden_layers=8, num_pred_heads=1)
+)
+# The same block at widths a CPU test serves (byte tokenizer: ids < 304):
+# windows of 32 and chunks of 4, so that with pages of 8 or 16 windows,
+# chunks and pages all cross inside a short test.
+PRESETS["evabyte-tiny"] = ModelConfig.from_hf_config(
+    dict(
+        _EVABYTE, vocab_size=304, hidden_size=64, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=4, intermediate_size=128,
+        window_size=32, chunk_size=4, num_pred_heads=1,
+    )
+)
+
 
 def get_preset(name: str) -> ModelConfig:
     try:

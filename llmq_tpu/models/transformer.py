@@ -481,7 +481,7 @@ class Transformer:
     @jax.named_scope("llmq.lm_head")
     def _logits(self, params: Params, h: jnp.ndarray) -> jnp.ndarray:
         cfg = self.config
-        one_plus = cfg.model_type.startswith("gemma")
+        one_plus = cfg.model_type.startswith("gemma") or cfg.norm_unit_offset
         h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, one_plus=one_plus)
         head = params.get("lm_head")
         if head is None:

@@ -523,3 +523,143 @@ def blocked_prefill_attention(
     q_blocks = jnp.moveaxis(q.reshape(B, nb, size, n, q.shape[-1]), 1, 0)
     out = jax.lax.map(one_block, (q_blocks, k_pos.reshape(nb, size)))
     return jnp.moveaxis(out, 0, 1).reshape(B, T, n, v.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# EVA attention: exact keys inside the query's own window, one learned
+# summary a chunk for every earlier window. Its cache rows are not positions
+# ---------------------------------------------------------------------------
+#
+# With window ``W`` and chunk ``c``, ``S = W / c`` summaries a window. The
+# token at ``pos`` (window ``w = pos // W``, offset ``o = pos % W``) is
+# written to row ``S w + o`` of its sequence; the step that writes a
+# window's LAST byte (``o = W - 1``) then reads the window's ``W`` exact rows
+# ``[S w, S w + W)`` and overwrites rows ``[S w, S w + S)`` with its ``S``
+# summary rows, so the next byte lands on row ``S (w + 1)``, straight after
+# them. The rows a query attends are ``[0, row]``, contiguous: earlier
+# windows' summaries, then its own window's exact rows. A block table only
+# ever grows. The three functions below are the one place that knows this;
+# the model, the scheduler and the engine ask them.
+
+
+def eva_row(pos, window: int, chunk: int):
+    """The cache row of the token at position ``pos`` (an int or an
+    array; a negative position, a skipped entry, stays -1)."""
+    row = (window // chunk) * (pos // window) + pos % window
+    if isinstance(pos, int):
+        return row if pos >= 0 else -1
+    return jnp.where(pos >= 0, row, -1)
+
+
+def eva_context(n, window: int, chunk: int):
+    """Rows the query at position ``n - 1`` attends, its own included
+    (what a decode kernel takes for a context length); 0 for ``n`` 0."""
+    return eva_row(n - 1, window, chunk) + 1
+
+
+def eva_table_pages(
+    start: int, stop: int, window: int, chunk: int, page_size: int
+) -> int:
+    """Places of a block table that hold the row of every position in
+    ``[start, stop)`` (ints). A window's exact rows reach ``W - S`` rows
+    past where the next window starts, so the largest row is the last
+    position's or that of the last byte of the window before it."""
+    last = stop - 1
+    if last < 0:
+        return 0
+    start = min(max(start, 0), last)
+    top = eva_row(last, window, chunk)
+    if last // window > start // window:
+        top = max(top, eva_row(last // window * window - 1, window, chunk))
+    return top // page_size + 1
+
+
+def eva_summaries(
+    k: jnp.ndarray,  # [..., T, n, d] rotated keys, T whole chunks
+    v: jnp.ndarray,  # [..., T, n, d]
+    mu: jnp.ndarray,  # [n, d]
+    phi: jnp.ndarray,  # [n, d]
+    *,
+    scale: float,
+    chunk: int,
+):
+    """One summary key and value a chunk of ``chunk`` consecutive
+    positions: ``k~ = sum_j softmax_j(s k_j . mu) k_j`` and ``v~ = sum_j
+    softmax_j(s k_j . phi) v_j``, each softmax over the chunk's positions,
+    float32. Returns ``[..., T / chunk, n, d]`` twice, float32."""
+    *lead, T, n, d = k.shape
+    f32 = jnp.float32
+    kc = k.reshape(*lead, T // chunk, chunk, n, d).astype(f32)
+    vc = v.reshape(*lead, T // chunk, chunk, n, d).astype(f32)
+
+    def weights(by):
+        logits = jnp.einsum("...cnd,nd->...cn", kc, by.astype(f32)) * scale
+        return jax.nn.softmax(logits, axis=-2)
+
+    k_sum = jnp.einsum("...cn,...cnd->...nd", weights(mu), kc)
+    v_sum = jnp.einsum("...cn,...cnd->...nd", weights(phi), vc)
+    return k_sum, v_sum
+
+
+def eva_prefill_attention(
+    q: jnp.ndarray,  # [B, T, n, d]
+    k: jnp.ndarray,  # [B, T, n, d]
+    v: jnp.ndarray,  # [B, T, n, d]
+    k_sum: jnp.ndarray,  # [B, T / chunk, n, d] a summary a chunk
+    v_sum: jnp.ndarray,
+    *,
+    scale: float,
+    lengths: jnp.ndarray,  # [B]
+    window: int,
+    chunk: int,
+    block: int = 512,
+) -> jnp.ndarray:
+    """EVA self-attention over a right-padded prompt: the query at ``i``
+    takes ONE softmax over the exact keys of its own window up to itself
+    and the summaries of every chunk of an earlier window. ``T`` is whole
+    windows, or no longer than one. A block of query rows at a time
+    (``lax.map``), against its window's ``W`` keys and all ``T / chunk``
+    summaries; the block halves until that score matrix is at most 2**27
+    float32 values."""
+    B, T, n, d = q.shape
+    W = min(window, T)
+    G = k_sum.shape[1]
+    while block > 32 and B * n * block * (W + G) > 2**27:
+        block //= 2
+    size = next(
+        (b for b in (512, 256, 128, 64, 32) if b <= block and W % b == 0), W
+    )
+    in_row = jnp.arange(T)[None, :] < lengths[:, None]  # [B, T]
+    chunk_window = (jnp.arange(G) * chunk) // window  # [G]
+
+    def one_block(q_pos):  # [size] absolute positions, inside one window
+        first = q_pos[0] // W * W
+        q_blk = jax.lax.dynamic_slice_in_dim(q, q_pos[0], size, axis=1)
+        k_win = jax.lax.dynamic_slice_in_dim(k, first, W, axis=1)
+        v_win = jax.lax.dynamic_slice_in_dim(v, first, W, axis=1)
+        k_pos = first + jnp.arange(W)
+        exact = jnp.einsum(
+            "bqhd,bkhd->bhqk", q_blk, k_win, preferred_element_type=jnp.float32
+        ) * scale
+        mask = (k_pos[None, :] <= q_pos[:, None])[None] & jax.lax.dynamic_slice_in_dim(
+            in_row, first, W, axis=1
+        )[:, None, :]
+        exact = jnp.where(mask[:, None], exact, NEG_INF)
+        earlier = jnp.einsum(
+            "bqhd,bghd->bhqg", q_blk, k_sum, preferred_element_type=jnp.float32
+        ) * scale
+        earlier = jnp.where(
+            (chunk_window < q_pos[0] // window)[None, None, None, :], earlier, NEG_INF
+        )
+        weights = jax.nn.softmax(
+            jnp.concatenate([exact, earlier], axis=-1), axis=-1
+        ).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", weights[..., :W], v_win) + jnp.einsum(
+            "bhqg,bghd->bqhd", weights[..., W:], v_sum
+        )
+
+    positions = jnp.arange(T).reshape(T // size, size)
+    if T == size:
+        return one_block(positions[0])
+    out = jax.lax.map(one_block, positions)  # [T / size, B, size, n, d]
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, n, d)

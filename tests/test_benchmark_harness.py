@@ -58,7 +58,9 @@ def test_cell_finds_traffic_and_metric_files(cell):
 
 
 @pytest.mark.parametrize(
-    "name", ["ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16", "lfm2-24b-a2b-pp5"]
+    "name",
+    ["ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16", "lfm2-24b-a2b-pp5",
+     "evabyte-6.5b-pp4"],
 )
 def test_the_hybrid_preset_is_the_tree_its_configuration_describes(name):
     """The served tree of ``preset://<name>`` and the tree the benchmark
@@ -189,3 +191,90 @@ def test_gqa_decode_cost_by_hand_and_a_reader_with_nothing_to_read():
     assert kernel_cost_gqa.attention_layers(cfg) == 2
     ctx = SimpleNamespace(_span_join=False, peaks={}, live_kv={"tokens": 1, "sequences": 1})
     assert decode_gqa_roofline.read(ctx, program="jit_decode_step", scope="x") is None
+
+
+def test_the_evabyte_file_keeps_every_published_key_it_does_not_reduce():
+    """Every key of the published config (the catalog row's, which the
+    worker's preset carries in part) stands in the benchmark's file at its
+    published value but for the two under ``reduced``, which stand beside
+    their published value; no reduced key is a width; the file states what
+    it assumed, its deployment and the arithmetic of its 3.24 GB, and the
+    file's own mapping gives the preset."""
+    from llmq_tpu.models.config import ModelConfig
+    from llmq_tpu.models.presets import _EVABYTE as published
+    from llmq_tpu.models.presets import get_preset
+
+    cfg = json.loads((ROOT / "benchmark/configs/evabyte-6.5b-pp4.json").read_text())
+    reduced = set(cfg["reduced"])
+    assert reduced == {"num_hidden_layers", "num_pred_heads"}
+    catalog = dict(
+        published, fp32_ln=False, init_cutoff_factor=None, init_fn="v2", init_std=0.01275,
+        lazy_init=True, max_seq_length=32768, mixedp_attn=True, num_chunks=None,
+        rope_scaling=None,
+    )
+    for key, value in catalog.items():
+        if key in reduced:
+            assert cfg[f"{key}_published"] == value and cfg[key] != value, key
+        else:
+            assert cfg[key] == value, key
+    assert (cfg["num_hidden_layers"], cfg["num_pred_heads"]) == (8, 1)
+    assert set(cfg["assumed"]) >= {
+        "summaries_of_rotated_keys", "summary_scale", "alignment",
+        "summaries_only_of_complete_windows", "residual", "rope", "ids", "prediction_heads",
+    }
+    assert "1,622 M parameters, 3.24 GB" in cfg["deployment"]
+    assert "pipeline stage 0 of FOUR" in cfg["deployment"]
+    assert cfg["engine"]["max_num_seqs"] == 24 and cfg["engine"]["max_model_len"] == 12288
+    assert ModelConfig.from_hf_config(cfg) == get_preset("evabyte-6.5b-pp4")
+    traffic = json.loads((ROOT / "benchmark/traffic/decode-bytes.json").read_text())
+    assert {k: traffic[k] for k in (
+        "generator", "clients", "requests_per_client", "stagger_seconds", "warm_seconds",
+        "schedule_seed", "trace_offset_s", "trace_seconds", "check_lengths",
+    )} == {
+        "generator": "closed_loop", "clients": 24, "requests_per_client": 8,
+        "stagger_seconds": 36, "warm_seconds": 48, "schedule_seed": 42050,
+        "trace_offset_s": 5, "trace_seconds": 4, "check_lengths": [6140, 2304],
+    }
+    assert (traffic["prompt_tokens"]["min"], traffic["prompt_tokens"]["max"]) == (4096, 8192)
+    assert traffic["output_tokens"]["value"] == 3072 and "rate_rps" not in traffic
+    # the first sample's 8 compared positions cross byte 6,144: a window
+    # closes INSIDE the comparison
+    first = traffic["check_lengths"][0]
+    assert first - 1 < 3 * cfg["window_size"] - 1 < first - 1 + correct.K_TOKENS
+
+
+def test_the_evabyte_cell_lists_its_own_attention_metrics():
+    cell = "evabyte-6.5b-pp4.decode-bytes"
+    lists = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
+    for name in ("decode_eva_ms", "decode_eva_roofline", "eva_summary_rows_share_pct"):
+        assert lists[name] == [cell], name
+    for name in ("decode_step_dev_ms", "decode_mlp_ms", "decode_live_pages_mean",
+                 "tpot_p95_ms.closed", "preemptions.closed", "prefill_dev_share_pct.closed",
+                 "prefill_rows_mean.closed", "admit_hold_share_pct.closed"):
+        assert lists[name][-1] == cell, name
+    for name in ("decode_attn_ms", "decode_gqa_ms", "decode_mla_ms", "decode_moe_ms"):
+        assert cell not in lists[name], name
+    ends = {m["name"]: m.get("workloads") for m in BENCH["end_to_end"]}
+    assert ends["tpot_p50_ms"][-1] == cell and ends["out_tok_s"][-1] == cell
+
+
+def test_eva_decode_cost_by_hand_and_readers_with_nothing_to_read():
+    """``kernel_cost_eva``: one attended row and one sequence of one layer
+    at the published widths, its own copy of the row arithmetic against
+    the program's (the benchmark's own copy of this proof is
+    ``benchmark/tests/test_kernel_cost_eva.py``); and the readers leave
+    their metrics out where the program gave no spans."""
+    from types import SimpleNamespace
+
+    from benchmark import kernel_cost_eva
+    from benchmark.readers import decode_eva_roofline, span_field_share
+    from llmq_tpu.ops.attention import eva_context
+
+    kw = dict(attended=1, rows=1, layers=1, hidden=4096, heads=32, head_dim=128)
+    assert kernel_cost_eva.eva_decode_bytes(**kw) == 16_384 + 100_663_296 + 16_384
+    assert kernel_cost_eva.eva_decode_flops(**kw) == 16_384 + 100_663_296
+    for n in (0, 1, 2047, 2048, 2049, 6144, 6145, 9200, 12288):
+        assert kernel_cost_eva.attended_rows(n, window=2048, chunk=16) == eva_context(n, 2048, 16)
+    ctx = SimpleNamespace(_span_join=False, peaks={})
+    assert decode_eva_roofline.read(ctx, program="jit_decode_step", scope="x") is None
+    assert span_field_share.read(ctx, name="decode_dispatch", field="a", of=["a", "b"]) is None

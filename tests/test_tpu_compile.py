@@ -385,7 +385,9 @@ def _refusal_is_a_compile_failure_not_a_device_fault(topo, monkeypatch):
     assert classify_failure(refused.value) is None
 
 
-def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64, page_bytes=None):
+def _hybrid_step(
+    which, preset="ling-3.0-flash-ep4", pages=2700, places=64, page_bytes=None, slots=128
+):
     """The decode step and a prefill bucket of the Ling-3.0-flash cut
     at its published widths (``preset://ling-3.0-flash-ep4``: 10.46 GB of
     bf16 weights), over the pools the benchmark's cell runs with: 128
@@ -417,8 +419,19 @@ def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64, page
     512 KB the widths say (``page_bytes``: nothing padded), the decode step
     reads it with the latent pool's kernel and copies it nowhere, and the
     1 x 4,096 and 4 x 2,048 prefills (the Pallas flash kernel at heads of
-    64 in them) hold no temporary the size of the pool."""
-    slots = 128
+    64 in them) hold no temporary the size of the pool.
+
+    And for the EvaByte stage (``preset://evabyte-6.5b-pp4``: 3.24 GB of
+    weights, eight EVA layers of 32 / 32 heads of 128), over its cell's
+    pools: 24 slots, 512 pages, 96 page places a row (``max_model_len``
+    12,288). The pool's row is a token's V then K, 8,192 bf16 values: a
+    page is 2 MB a layer, 16.8 MB over the eight, where lfm2's is 512 KB
+    and openpangu's 160 KB. The decode step reads it with the latent
+    pool's kernel at ``rank`` 4,096 (one page a chunk: the kernel's two
+    page buffers are 4 MB of VMEM) and, with the window's compaction
+    inside it (a loop of as many turns as sequences close a window),
+    copies the pool nowhere; the 1 x 8,192 prefill, the bucket of the
+    cell's every prompt, holds 0.73 GB of temporaries."""
 
     def case(topo, monkeypatch):
         from llmq_tpu.models.transformer import build_model, make_kv_pages
@@ -458,7 +471,9 @@ def _hybrid_step(which, preset="ling-3.0-flash-ep4", pages=2700, places=64, page
             # free: 0.56 GB beside 7,500 pages, 1.29 GB beside 6,700)
             assert mem.temp_size_in_bytes < pools // 2
             text = compiled.as_text()
-            assert "tpu_custom_call" in text  # flash prefill, or the decode kernel
+            # flash prefill, or the decode kernel (an EVA prefill is XLA's:
+            # no kernel for window + summary keys yet)
+            assert "tpu_custom_call" in text or (which != "decode" and "eva" in preset)
             pool = f"bf16[{latent.shape[0]},{pages},"
             assert not [l for l in text.splitlines() if " copy(" in l and pool in l]
         if which == "decode":
@@ -513,6 +528,14 @@ CASES = {
     ),
     "conv_gqa_prefill_4x2048": _hybrid_step(
         (4, 2048), "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
+    ),
+    "eva_decode_24_slots_32_heads_of_128": _hybrid_step(
+        "decode", "evabyte-6.5b-pp4", pages=512, places=96, slots=24,
+        page_bytes=8 * 2 * 32 * 128 * 128 * 2,
+    ),
+    "eva_prefill_1x8192": _hybrid_step(
+        (1, 8192), "evabyte-6.5b-pp4", pages=512, places=96, slots=24,
+        page_bytes=8 * 2 * 32 * 128 * 128 * 2,
     ),
     "latent_decode_live_128_heads": _latent_decode_live(128, 5, 3200, 32),
     "latent_decode_live_32_heads": _latent_decode_live(32, 1, 2305, 64),
