@@ -106,7 +106,7 @@ from llmq_tpu.models.transformer import (
     Transformer, _mlp, apply_rope, compute_rope_inv_freq, rms_norm,
 )
 from llmq_tpu.ops import attention as attn_ops
-from llmq_tpu.ops import delta_rule, dispatch, pallas_delta_rule
+from llmq_tpu.ops import delta_rule, dispatch, pallas_delta_rule, pallas_grouped_matmul
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -404,8 +404,23 @@ KDA_PREFILL_TOKENS = 8192
 MLA_PREFILL_HEAD_TOKENS = 2**20
 
 
+#: A routed layer's expert matrices, ``[layers, held, K, N]`` in a group's
+#: stack: what :func:`expert_rows` and ``_run_groups`` keep out of a scan's
+#: slices where the grouped product reads the stack itself.
+EXPERT_LEAVES = ("expert_gate_proj", "expert_up_proj", "expert_down_proj")
+
+
+def expert_rows(rows: int, hidden: int) -> int:
+    """Rows an expert layer takes at a time of ``rows`` in all: the block
+    of :func:`moe_held`, or all of them."""
+    block = MOE_BLOCK_ROWS
+    while block > 256 and block * hidden > MOE_BLOCK_ROWS * _MOE_BLOCK_HIDDEN:
+        block //= 2
+    return block if rows > 2 * block and rows % block == 0 else rows
+
+
 def moe_held(
-    h: jnp.ndarray, lp: Params, config: ModelConfig
+    h: jnp.ndarray, lp: Params, config: ModelConfig, stack=None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The routed-expert MLP as this chip computes it: the router scores
     all ``num_experts``, and of the ``num_experts_per_tok`` chosen a token
@@ -418,7 +433,11 @@ def moe_held(
     ``[rows, held]`` matrix that is zero where an expert was not chosen
     (a decode step: the weights stream once, nothing is sorted); above,
     the assignments that land here sorted by expert and
-    ``lax.ragged_dot`` over the held experts (a prefill).
+    a grouped product over the held experts (a prefill:
+    :func:`_experts_grouped`). ``stack``: ``None``, and ``lp`` holds the
+    layer's own ``EXPERT_LEAVES``; or ``(leaves, index)``, the group's
+    whole stack of them and the layer's place in it, for the grouped
+    product that reads an expert where it lies.
 
     Returns the output and ``[assignments held, experts hit]`` (int32),
     the counters a decode step reports. Above twice ``MOE_BLOCK_ROWS`` rows
@@ -431,20 +450,18 @@ def moe_held(
     2,048 prefill held 4.2 GB otherwise, compiled for a v5e, PR 39)."""
     *lead, H = h.shape
     x = h.reshape(-1, H)
-    block = MOE_BLOCK_ROWS
-    while block > 256 and block * H > MOE_BLOCK_ROWS * _MOE_BLOCK_HIDDEN:
-        block //= 2
-    if x.shape[0] > 2 * block and x.shape[0] % block == 0:
+    block = expert_rows(x.shape[0], H)
+    if block < x.shape[0]:
         out, counts = jax.lax.map(
-            lambda rows: _moe_rows(rows, lp, config), x.reshape(-1, block, H)
+            lambda rows: _moe_rows(rows, lp, config, stack), x.reshape(-1, block, H)
         )
         counts = counts.sum(axis=0)
     else:
-        out, counts = _moe_rows(x, lp, config)
+        out, counts = _moe_rows(x, lp, config, stack)
     return out.reshape(*lead, H), counts
 
 
-def _moe_rows(x: jnp.ndarray, lp: Params, config: ModelConfig):
+def _moe_rows(x: jnp.ndarray, lp: Params, config: ModelConfig, stack=None):
     """:func:`moe_held` for rows ``[N, H]``."""
     cfg = config
     N, E, k = x.shape[0], cfg.num_experts, cfg.num_experts_per_tok
@@ -476,7 +493,7 @@ def _moe_rows(x: jnp.ndarray, lp: Params, config: ModelConfig):
         if N <= DENSE_EXPERT_ROWS:
             out, hit = _experts_dense(x, lp, local, here, top_w, held)
         else:
-            out, hit = _experts_grouped(x, lp, local, here, top_w, held)
+            out, hit = _experts_grouped(x, lp, local, here, top_w, held, stack)
     if cfg.shared_expert_intermediate_size:
         with jax.named_scope("llmq.moe.shared"):
             act = jax.nn.silu(qm.matmul(x, lp["shared_gate_proj"])) * qm.matmul(
@@ -504,27 +521,45 @@ def _experts_dense(x, lp, local, here, top_w, held):
     return out, (weight > 0).any(axis=0).sum(dtype=jnp.int32)
 
 
-def _experts_grouped(x, lp, local, here, top_w, held):
-    """Only the assignments that land here, sorted by expert, one
-    ``lax.ragged_dot`` group an expert. (``ragged_dot`` takes its matrices
-    as a whole buffer, so inside a layer scan each layer's three are
-    copied out of their stack first: 3 x 250 MB a layer at the published
-    widths, a third of a 512-token prefill. Handing it the whole stack
-    with empty groups for the other layers avoids the copy and runs
-    alone, but a 4 x 1,024 prefill step built so never came back from the
-    chip: PERF.md, PR 33.) The lint's repartition pins do not apply: the
-    engine refuses tp > 1 for a layer pattern, so nothing here is split."""
+def _experts_grouped(x, lp, local, here, top_w, held, stack=None):
+    """Only the assignments that land here, sorted by expert, and three
+    grouped products over the held experts: a group an expert, what is
+    held elsewhere sorted last and multiplied by nothing.
+
+    The product is ``lax.ragged_dot`` on the layer's own matrices
+    (``stack`` ``None``), or, where ``dispatch.grouped_experts_plan`` says
+    so, the kernel that takes the group's whole ``stack`` and the layer's
+    index (``ops/pallas_grouped_matmul.py``). ``ragged_dot`` takes its
+    matrices as one buffer, so inside a layer scan each layer's three are
+    copied out of their stack first: 3 x 503 MB a layer at ling's widths,
+    a third of a 512-token prefill (PERF.md section 6, PR 51); the kernel
+    fetches an expert's blocks from where they lie. (Handing ``ragged_dot``
+    the whole stack with empty groups for the other layers avoids the copy
+    and runs alone, but a 4 x 1,024 prefill step built so never came back
+    from the chip: PERF.md, PR 33.) The lint's repartition pins do not
+    apply: the engine refuses tp > 1 for a layer pattern, so nothing here
+    is split."""
     N, k = local.shape
     slot = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(slot)  # stable; what is held elsewhere sorts last  # llmq: ignore[unconstrained-repartition]
     token_of = order // k
     group_sizes = jnp.bincount(slot, length=held + 1)[:held].astype(jnp.int32)  # llmq: ignore[unconstrained-repartition]
+    if stack is None:
+        def product(rows, name):
+            return jax.lax.ragged_dot(rows, lp[name], group_sizes)  # llmq: ignore[unconstrained-repartition]
+    else:
+        leaves, index = stack
+
+        def product(rows, name):
+            # no scope of its own: ``llmq.moe.experts`` stays the kernel's
+            return pallas_grouped_matmul.grouped_matmul_stacked(
+                rows, leaves[name], index, group_sizes,
+                interpret=dispatch._interpret(),
+            )
     xs = x[token_of]  # [N*k, H]
-    gate = jax.lax.ragged_dot(xs, lp["expert_gate_proj"], group_sizes)  # llmq: ignore[unconstrained-repartition]
-    up = jax.lax.ragged_dot(xs, lp["expert_up_proj"], group_sizes)  # llmq: ignore[unconstrained-repartition]
-    down = jax.lax.ragged_dot(  # llmq: ignore[unconstrained-repartition]
-        jax.nn.silu(gate) * up, lp["expert_down_proj"], group_sizes
-    )
+    gate = product(xs, "expert_gate_proj")
+    up = product(xs, "expert_up_proj")
+    down = product(jax.nn.silu(gate) * up, "expert_down_proj")
     here_sorted = here.reshape(-1)[order]
     w_sorted = jnp.where(here_sorted, top_w.reshape(-1)[order], 0.0)
     down = jnp.where(here_sorted[:, None], down.astype(F32), 0.0)
@@ -1034,13 +1069,27 @@ class HybridTransformer(Transformer):
     def _run_groups(self, params, h, latent, state, attend):
         """Every group of equal layers as one scan. ``attend(group, lp, x,
         latent, state, li)`` returns the attention output and the two
-        caches."""
+        caches. Where the expert products read a group's stack itself
+        (``dispatch.grouped_experts_plan``: a prefill on a TPU) the
+        ``EXPERT_LEAVES`` stay out of the scan's slices, which would be
+        copies, and the layer takes the whole stack with its place in it."""
         cfg = self.config
         one_plus = cfg.norm_unit_offset
         moe = jnp.zeros((2,), jnp.int32)
+        rows = expert_rows(h.size // h.shape[-1], h.shape[-1])
         for group in layer_groups(cfg):
+            layers = params[group.name]
+            gate = layers.get(EXPERT_LEAVES[0])  # [layers, held, H, I]
+            stacked = gate is not None and "stacked" == dispatch.grouped_experts_plan(
+                rows, DENSE_EXPERT_ROWS, h.dtype, getattr(gate, "dtype", None),
+                *getattr(gate, "shape", (0, 0))[-2:],
+                mesh=self.mesh, backend=self.attn_backend,
+            )
+            whole = {name: layers[name] for name in EXPERT_LEAVES} if stacked else None
+            if stacked:
+                layers = {k: w for k, w in layers.items() if k not in EXPERT_LEAVES}
 
-            def layer_fn(carry, xs, group=group):
+            def layer_fn(carry, xs, group=group, whole=whole):
                 h, latent, state, moe = carry
                 lp, li = xs
                 x = rms_norm(h, lp["ln1"], cfg.rms_norm_eps, one_plus=one_plus)
@@ -1054,7 +1103,9 @@ class HybridTransformer(Transformer):
                     m = _mlp(x, lp, cfg.activation)
                 else:
                     with jax.named_scope("llmq.moe"):
-                        m, counts = moe_held(x, lp, cfg)
+                        m, counts = moe_held(
+                            x, lp, cfg, whole and (whole, li - group.first)
+                        )
                     moe = moe + counts
                 if cfg.post_norms:
                     with jax.named_scope("llmq.norm.sandwich"):
@@ -1066,14 +1117,14 @@ class HybridTransformer(Transformer):
                 # No scan of one: its slice of a stack of one layer is
                 # compiled as a copy of every weight (measured: 3 ms of a
                 # 29 ms decode step), the index 0 as a view.
-                only = jax.tree.map(lambda w: w[0], params[group.name])
+                only = jax.tree.map(lambda w: w[0], layers)
                 carry, _ = layer_fn(carry, (only, jnp.int32(group.first)))
             else:
                 carry, _ = jax.lax.scan(
                     layer_fn,
                     carry,
                     (
-                        params[group.name],
+                        layers,
                         group.first + jnp.arange(group.count, dtype=jnp.int32),
                     ),
                 )

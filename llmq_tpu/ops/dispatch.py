@@ -328,6 +328,41 @@ def kda_decode_plan(
     return "inplace"
 
 
+def grouped_experts_plan(
+    rows: int, dense_rows: int, x_dtype, w_dtype, k: int, n: int,
+    mesh: Optional[Mesh] = None, backend: str = "auto",
+) -> str:
+    """How the routed experts' products of ``rows`` rows (tokens) are
+    computed for a group of layers whose expert matrices are ``[layers,
+    held, k, n]`` and ``[layers, held, n, k]`` of ``w_dtype`` (``None``:
+    no plain array, a quantised leaf), and the only place that chooses
+    it: ``"stacked"`` (``pallas_grouped_matmul.grouped_matmul_stacked``:
+    the whole stack and the layer's index, an expert's blocks fetched from
+    where they lie) where the backend is pallas, one device holds the
+    stack whole, the rows are more than the ``dense_rows`` the dense form
+    takes (``models/hybrid.DENSE_EXPERT_ROWS``: a prefill), rows and
+    weights are bfloat16 and ``k`` and ``n`` are whole lane tiles; else
+    ``"xla"`` (the dense form, or ``lax.ragged_dot`` on each layer's own
+    matrices: a decode step, the CPU, a mesh of several devices, another
+    precision, the tiny test models' widths).
+
+    The contract of :func:`decode_kernel_plan`: a pure function of its
+    arguments and the backend, consulted at trace time."""
+    backend = resolve_backend() if backend == "auto" else backend
+    if (
+        backend != "pallas"
+        or (mesh is not None and mesh.size > 1)
+        or rows <= dense_rows
+        or jnp.dtype(x_dtype) != jnp.bfloat16
+        or w_dtype is None
+        or jnp.dtype(w_dtype) != jnp.bfloat16
+        or k % 128
+        or n % 128
+    ):
+        return "xla"
+    return "stacked"
+
+
 def latent_decode_attention(
     q: jnp.ndarray,  # [S, n_heads, W] absorbed query
     pages: jnp.ndarray,  # [L, P, page_size, Wp] the latent pool

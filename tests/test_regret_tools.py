@@ -1,6 +1,7 @@
-"""The tools PR 40's and PR 47's records rest on run end to end off the
-chip: the latent kernel's layout check and the KDA kernel's bench
-(interpreted, tiny sizes), the served-regret probe (the benchmark's CPU
+"""The tools PR 40's, PR 47's and PR 51's records rest on run end to end
+off the chip: the latent kernel's layout check, the KDA kernel's bench and
+the expert products' bench (interpreted, tiny sizes), the served-regret
+probe (the benchmark's CPU
 rehearsal) and the lowering hashes. What they read on the chip is in PERF.md; here they only have to
 keep working."""
 
@@ -78,6 +79,28 @@ def test_kda_kernel_bench_runs_interpreted():
             assert l["max_abs_out_vs_xla"] < 1e-5 and l["max_abs_state_vs_xla"] < 1e-5
 
 
+def test_expert_matmul_bench_runs_interpreted():
+    """``tools/expert_matmul_bench.py`` at a tiny shape: the dense form,
+    ``ragged_dot`` on a layer's own matrices and under a scan, then the
+    kernel on the whole stack at its own tile and at another; the kernel's
+    sum is ``ragged_dot``'s to bf16 rounding, and an interpreted run gives
+    no time."""
+    lines, _ = _run(
+        ["tools/expert_matmul_bench.py", "--interpret", "--shapes", "tiny", "--rows", "300",
+         "--tile-rows", "256", "--iters", "1"],
+        timeout=600,
+    )
+    assert [(l["form"], l["tile_rows"]) for l in lines] == [
+        ("dense", None), ("ragged", None), ("ragged_scan", None), ("stacked", 128),
+        ("stacked", 256),
+    ]
+    for l in lines:
+        assert l["ms_a_layer"] is None and (l["shape"], l["rows"]) == ("tiny", 300)
+        assert 0 < l["assignments_here"] <= 600 and l["experts_hit"] == 4
+        if l["form"] != "dense":
+            assert l["max_diff_over_spread"] < 0.05
+
+
 def test_kda_decode_ab_runs_on_the_cpu():
     """``tools/kda_decode_ab.py --tiny``: a ling-shaped decode step by the
     XLA form on a run, by the plan's form (the kernel, interpreted) and by
@@ -106,13 +129,20 @@ def test_kda_decode_ab_runs_on_the_cpu():
 def test_lowering_hash_lists_every_step_program():
     _, out = _run(["tools/lowering_hash.py"], timeout=600)
     rows = [l.split() for l in out.splitlines() if l.strip()]
-    assert len(rows) == 30 and len({tuple(r[:3]) for r in rows}) == 30
+    assert len(rows) == 36 and len({tuple(r[:3]) for r in rows}) == 36
     assert all(len(r[3]) == 16 for r in rows)
     # the one-pass KDA state update is in ling's pallas decode step alone
     assert [tuple(r[:3]) for r in rows if r[5:] == ["kda_step_inplace"]] == [
         ("ling-3.0-flash-ep4", "pallas", "decode")
     ]
-    assert all(len(r) == 5 for r in rows if r[5:] != ["kda_step_inplace"])
+    # the grouped matmul on a group's stack is in the pallas prefills of the
+    # three patterns with routed experts, and in no decode step
+    assert [tuple(r[:3]) for r in rows if r[5:] == ["grouped_matmul_stacked"]] == [
+        (preset, "pallas", program)
+        for preset in ("ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16", "lfm2-24b-a2b-pp5")
+        for program in ("prefill_1x512", "prefill_4x2048")
+    ]
+    assert sum(len(r) == 5 for r in rows) == 36 - 1 - 6
 
 
 def test_lowering_hash_for_a_described_v5e_holds_the_mosaic_kernels():

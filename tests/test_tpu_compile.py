@@ -17,6 +17,7 @@ the test, rather than through an option of the program.
 
 import dataclasses
 import os
+import re
 from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
@@ -28,6 +29,7 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from llmq_tpu.core.faults import classify_failure, is_compile_failure
+from llmq_tpu.models import hybrid
 from llmq_tpu.models.presets import get_preset
 from llmq_tpu.models.transformer import Transformer, init_params
 from llmq_tpu.ops import dispatch
@@ -398,18 +400,19 @@ def _hybrid_step(
     and weights, pools and temporaries fit the chip's 16.9 GB with room
     for the benchmark's correctness scratch (0.6 GB); and a decode step
     copies the latent pool nowhere and holds little besides the pools,
-    whatever ``max_model_len`` is. (The largest buckets, 4 x 4,096 and 4 x
-    8,192, which take rows and blocks one at a time in
+    whatever ``max_model_len`` is. (Ling's largest buckets, 4 x 4,096 and 4
+    x 8,192, which take rows and blocks one at a time in
     ``models/hybrid.py``, compile too, ``_hybrid_step((4, 8192))``: 25 s
-    of every core each, and left out of ``CASES`` for the suite's sake.)
+    of every core each, and left out of ``CASES`` for the suite's sake;
+    the harness warms 4 x 2,048 at most there.)
 
     The same for the openPangu-Ultra-MoE cut
     (``preset://openpangu-ultra-moe-718b-ep16``: 9.84 GB of weights, latent
     attention at 128 heads on all five layers, NO state layer: the state
     pool's leaves are empty), over its cell's pools: 3,200 latent pages, 32
     page places a row (``max_model_len`` 4,096). Its largest bucket, 4 x
-    4,096, which expands a row at a time, compiles in 14 s to 3.2 GB of
-    temporaries and is left out of ``CASES`` likewise.
+    4,096, which expands a row at a time, compiles in 12 s to 3.7 GB of
+    temporaries (3.2 with ``lax.ragged_dot`` and its copies).
 
     And for the LFM2-24B-A2B stage (``preset://lfm2-24b-a2b-pp5``: 10.36 GB
     of weights, gated short-convolution layers with tails alone in the
@@ -431,7 +434,19 @@ def _hybrid_step(
     page buffers are 4 MB of VMEM) and, with the window's compaction
     inside it (a loop of as many turns as sequences close a window),
     copies the pool nowhere; the 1 x 8,192 prefill, the bucket of the
-    cell's every prompt, holds 0.73 GB of temporaries."""
+    cell's every prompt, holds 0.73 GB of temporaries.
+
+    A prefill of the three patterns with routed experts (more rows than
+    the dense form takes, bf16, whole lane tiles) takes the grouped
+    matmul that reads a group's stack where it lies
+    (``dispatch.grouped_experts_plan``: ``stacked``): the kernel is in the
+    program and NO instruction but a parameter makes a buffer the size
+    of a layer's expert matrix: the three copies a scanned layer made in
+    front of ``lax.ragged_dot`` (3 x 503 MB a layer for ling) are gone,
+    checked here where no chip is needed. The largest bucket the harness
+    warms compiles beside the pool too (ling 4 x 2,048, openpangu 4 x
+    4,096, lfm2 4 x 8,192: a block of rows at a time around the
+    kernel)."""
 
     def case(topo, monkeypatch):
         from llmq_tpu.models.transformer import build_model, make_kv_pages
@@ -476,6 +491,23 @@ def _hybrid_step(
             assert "tpu_custom_call" in text or (which != "decode" and "eva" in preset)
             pool = f"bf16[{latent.shape[0]},{pages},"
             assert not [l for l in text.splitlines() if " copy(" in l and pool in l]
+        if cfg.num_experts:
+            held, H, I = cfg.experts_held_[1], cfg.hidden_size, cfg.moe_intermediate_size
+            plan = dispatch.grouped_experts_plan(
+                hybrid.expert_rows(rows if which == "decode" else rows * bucket, H),
+                hybrid.DENSE_EXPERT_ROWS, jnp.bfloat16, jnp.bfloat16, H, I, None, "pallas",
+            )
+            assert plan == ("xla" if which == "decode" else "stacked")
+            text = compiled.as_text()
+            assert ("%grouped_matmul_stacked" in text) == (plan == "stacked")
+            # a layer's expert matrix, or a stack of one layer: made by
+            # nothing (a loop's own arguments are views, not buffers)
+            matrix = re.compile(rf"= bf16\[(1,)?{held},({H},{I}|{I},{H})\]")
+            made = [
+                l.strip()[:200] for l in text.splitlines()
+                if matrix.search(l) and " parameter(" not in l and " get-tuple-element(" not in l
+            ]
+            assert bool(made) == (plan == "xla"), made[:3]
         if which == "decode":
             # The latent kernel is in the step (a Mosaic call named for
             # it), reads the pool where it lies, and with the XLA loop's
@@ -505,7 +537,9 @@ def _hybrid_step(
             pool = f"bf16[{latent.shape[0]},{pages},"
             copies = [l for l in text.splitlines() if " copy(" in l and pool in l]
             assert not copies, copies[:2]
-        if which != (4, 8192):  # there the compiler's own accounting decides
+        # (in the two largest buckets the compiler's own accounting decides:
+        # it refuses what does not fit the chip beside these pools)
+        if which not in ((4, 8192), (4, 4096)):
             assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
 
     return case
@@ -514,11 +548,15 @@ def _hybrid_step(
 CASES = {
     "hybrid_decode_128_slots": _hybrid_step("decode"),
     "hybrid_prefill_1x512": _hybrid_step((1, 512)),
+    "hybrid_prefill_4x2048": _hybrid_step((4, 2048)),
     "latent_only_decode_128_slots_128_heads": _hybrid_step(
         "decode", "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
     ),
     "latent_only_prefill_1x2048": _hybrid_step(
         (1, 2048), "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
+    ),
+    "latent_only_prefill_4x4096": _hybrid_step(
+        (4, 4096), "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
     ),
     "conv_gqa_decode_128_slots_heads_of_64": _hybrid_step(
         "decode", "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
@@ -528,6 +566,9 @@ CASES = {
     ),
     "conv_gqa_prefill_4x2048": _hybrid_step(
         (4, 2048), "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
+    ),
+    "conv_gqa_prefill_4x8192": _hybrid_step(
+        (4, 8192), "lfm2-24b-a2b-pp5", pages=6700, page_bytes=2 * 2 * 8 * 64 * 128 * 2
     ),
     "eva_decode_24_slots_32_heads_of_128": _hybrid_step(
         "decode", "evabyte-6.5b-pp4", pages=512, places=96, slots=24,

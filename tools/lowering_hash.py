@@ -8,10 +8,13 @@ A PR that says "no other configuration's program changed" runs this on its
 parent (``git archive`` into a directory, ``--root`` it) and on its own
 tree and diffs the two outputs: a line that is the same is a program that
 lowers byte-equal. The pallas backend is interpreted on the CPU, so a
-kernel's traced operations are in the text; the three layer patterns'
-programs are listed last (ling, openpangu, lfm2), for a PR that changes
-them on purpose, and a line whose text holds the one-pass KDA state update
-(``kda_step_inplace``: ling's pallas decode step) says so in a sixth word.
+kernel's traced operations are in the text; the four layer patterns'
+programs are listed last (ling, openpangu, lfm2, evabyte), for a PR that
+changes them on purpose, and a line whose text holds the one-pass KDA
+state update (``kda_step_inplace``: ling's pallas decode step) or the
+grouped expert matmul on a group's stack (``grouped_matmul_stacked``: the
+pallas prefills of the three patterns with routed experts) says so in
+further words.
 
     python3 tools/lowering_hash.py --v5e [--root <another checkout>]
 
@@ -126,7 +129,7 @@ if args.v5e:
 
 for preset in (
     "qwen2.5-3b", "qwen2.5-7b", "ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16",
-    "lfm2-24b-a2b-pp5",
+    "lfm2-24b-a2b-pp5", "evabyte-6.5b-pp4",
 ):
     cfg = get_preset(preset)
     hybrid = cfg.layer_pattern is not None
@@ -147,6 +150,6 @@ for preset in (
                 params, S((b, t), jnp.int32), S((b,), jnp.int32), kp, vp,
                 S((b, PLACES), jnp.int32), *extra).as_text()
         for name, text in texts.items():
-            kda = ("kda_step_inplace",) if "kda_step_inplace" in text else ()
+            kernels = [k for k in ("kda_step_inplace", "grouped_matmul_stacked") if k in text]
             print(preset, backend, name, hashlib.sha256(text.encode()).hexdigest()[:16],
-                  len(text), *kda, flush=True)
+                  len(text), *kernels, flush=True)
