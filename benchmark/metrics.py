@@ -74,7 +74,11 @@ class Records:
 
 
 def end_to_end(generator: str, records: Records, setup_s: float) -> Dict[str, Optional[float]]:
-    """Every end-to-end metric this kind of traffic has something for."""
+    """Every end-to-end metric this kind of traffic has something for. A
+    fixed job's throughput is ``job_tok_s``, the job's received tokens over
+    first submit to last result, and not ``out_tok_s``, a window's tokens
+    over the window: one job's length is set by how admission fell, a
+    window's rate by the device, and a name has one bound."""
     d = records.drive
     out: Dict[str, Optional[float]] = {"setup_s": setup_s}
     if generator == "open_loop":
@@ -88,7 +92,7 @@ def end_to_end(generator: str, records: Records, setup_s: float) -> Dict[str, Op
         tokens = sum(
             r["completion_tokens"] or 0 for r in records.rows if r["received"]
         )
-        out["out_tok_s"] = tokens / d.job_seconds if d.job_seconds else None
+        out["job_tok_s"] = tokens / d.job_seconds if d.job_seconds else None
     return out
 
 

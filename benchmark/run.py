@@ -174,6 +174,7 @@ async def run(args, cell, traffic, device) -> tuple:
         ctx = SimpleNamespace(
             records=records, trace=events, live_kv=tracer.live_kv,
             model=cell.config, peaks=peaks, traffic=traffic,
+            prefill_log=system.prefill_log[first_dispatch:],
         )
         result["metrics"] = read_layer_metrics(cell, ctx)
     else:

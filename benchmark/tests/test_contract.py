@@ -28,7 +28,7 @@ def test_keys_and_names():
 
 def test_end_to_end_metrics_are_medians_rates_and_setup():
     assert [m["name"] for m in BENCH["end_to_end"]] == [
-        "ttft_p50_ms", "tpot_p50_ms", "out_tok_s", "setup_s"
+        "ttft_p50_ms", "tpot_p50_ms", "out_tok_s", "job_tok_s", "setup_s"
     ]
     for m in BENCH["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
@@ -36,6 +36,31 @@ def test_end_to_end_metrics_are_medians_rates_and_setup():
     names = {m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]}
     assert not names & {"tpot_p95_ms", "ttft_p95_ms"}
     assert {"tpot_p95_ms.open", "tpot_p95_ms.closed", "ttft_p95_ms.open"} <= names
+
+
+def test_a_window_rate_and_a_job_rate_are_two_metrics():
+    """``out_tok_s`` is a window's tokens over the window (closed loop),
+    ``job_tok_s`` a fixed job's tokens over the job: each cell lists the
+    one its generator yields, and a per-layer metric of a job cell moves
+    ``job_tok_s`` under a name no closed-loop cell lists."""
+    ends = {m["name"]: m for m in BENCH["end_to_end"]}
+    kind = {}
+    for w in BENCH["workloads"]:
+        traffic = json.loads((ROOT / "benchmark" / "traffic" / f"{w['traffic']}.json").read_text())
+        kind[w["name"]] = traffic["generator"]
+    assert ends["job_tok_s"]["workloads"] == [c for c in CELLS if kind[c] == "fixed_job"]
+    assert ends["out_tok_s"]["workloads"] == [c for c in CELLS if kind[c] == "closed_loop"]
+    assert (ends["job_tok_s"]["unit"], ends["job_tok_s"]["better"]) == ("tokens/s", "higher")
+    for m in BENCH["per_layer"]:
+        kinds = {kind[c] for c in m["workloads"]}
+        assert len(kinds) == 1 or kinds == {"open_loop", "closed_loop"}, m["name"]
+        assert (m["moves"] == "job_tok_s") == (kinds == {"fixed_job"}), m["name"]
+    for name in ("prefill_dev_share_pct", "admit_hold_share_pct", "prefill_rows_mean"):
+        job, closed = (
+            json.loads((ROOT / "benchmark" / "layer_metrics" / f"{name}.{end}.json").read_text())
+            for end in ("job", "closed")
+        )
+        assert (job["reader"], job.get("args")) == (closed["reader"], closed.get("args"))
 
 
 @pytest.mark.parametrize("cell", CELLS)
