@@ -1237,6 +1237,7 @@ class EngineCore:
             self.cfg, sp=int(self.mesh.shape.get(SP_AXIS, 1)),
             quarter_steps=not self._hybrid,
         )
+        self._mla_plan = self._mla_prefill_plan()  # as built: for stats()
         # What the rows of an admission wave share (Scheduler.next_wave):
         # the bucket, i.e. the program, of a whole-prompt prefill. Chunked,
         # mixed and prefix-cached prefill have no buckets.
@@ -3092,6 +3093,26 @@ class EngineCore:
             1, S.dtype, *S.shape[-2:],
             mesh=self.mesh, backend=self.model.attn_backend,
         )
+
+    def _mla_prefill_plan(self) -> Optional[Dict[str, List[int]]]:
+        """For every prefill bucket, the form a 1-row program of it takes
+        for expanded latent attention, as ``ops/dispatch.mla_prefill_plan``
+        names it (the rows are of the embedding's dtype): ``{"flash":
+        [buckets], "xla": [buckets]}``; None for a model with no ``mla``
+        layer. Read once at build: ``stats()`` is called from heartbeats
+        while the benchmark swaps the weights (``params`` is then None)."""
+        if not self._hybrid:
+            return None
+        from llmq_tpu.models import hybrid, quant
+
+        if not hybrid.count_layers(self.model_config, "mla"):
+            return None
+        plans: Dict[str, List[int]] = {"flash": [], "xla": []}
+        embed = self.params["embed"]
+        dtype = (embed["scale"] if quant.is_quantized(embed) else embed).dtype
+        for bucket in self._buckets:
+            plans[self.model.mla_prefill_plan(bucket, dtype)].append(bucket)
+        return plans
 
     def _expire_deadlines(self, finished: List[RequestOutput]) -> None:
         """Between-steps deadline sweep: waiting or running sequences
@@ -5487,6 +5508,10 @@ class EngineCore:
             # a pattern with KDA layers only: "inplace" (the one-pass
             # kernel) or "xla"
             **({"kda_decode_plan": kda} if kda is not None else {}),
+            # a pattern with latent layers only: the prefill buckets whose
+            # expanded attention is the flash kernel, and those left to XLA
+            # (read at build: the weights may be mid-swap)
+            **({"mla_prefill_plan": self._mla_plan} if self._mla_plan is not None else {}),
             kv_dtype=str(jnp.dtype(self.cfg.kv_dtype)),
             page_size=self.cfg.page_size,
             num_pages=self.scheduler.config.num_pages,

@@ -1182,13 +1182,26 @@ class HybridTransformer(Transformer):
         cfg = self.config
         return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
 
+    def mla_prefill_plan(self, tokens: int, dtype) -> str:
+        """``dispatch.mla_prefill_plan`` for a prefill bucket of ``tokens``
+        positions a row and rows of ``dtype``: ``flash`` or ``xla``."""
+        cfg = self.config
+        return dispatch.mla_prefill_plan(
+            cfg.num_heads, tokens, dtype,
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+            mesh=self.mesh, backend=self.attn_backend,
+        )
+
     @jax.named_scope("llmq.attn.mla_prefill")
     def _mla_prefill(self, lp, x, positions, lengths, latent, block_tables, li):
         """Expanded: keys and values raised from the latent, every head
-        its own. Above ``MLA_PREFILL_HEAD_TOKENS`` a row of the batch at a
-        time: the expanded q, k and v in flight are one row's (a 4 x 4,096
-        bucket at 128 heads holds 3.2 GB of them otherwise: compiled for a
-        v5e, PR 39)."""
+        its own, the attention by the form ``dispatch.mla_prefill_plan``
+        names for the bucket (the flash kernel from ``num_heads x T`` of
+        2**17 on one TPU, else XLA's blocked form). Above
+        ``MLA_PREFILL_HEAD_TOKENS`` a row of the batch at a time: the
+        expanded q, k and v in flight are one row's (a 4 x 4,096 bucket at
+        128 heads holds 3.2 GB of them otherwise: compiled for a v5e,
+        PR 39)."""
 
         def write(latent, row):
             with jax.named_scope("llmq.kv_write"):
@@ -1213,20 +1226,16 @@ class HybridTransformer(Transformer):
 
     def _mla_expanded(self, lp, x, q_c, q_r, row, lengths):
         """Attention of a prefill over keys and values raised from the
-        latent rows of the batch itself, and the output projection."""
+        latent rows of the batch itself, by the form the plan names for
+        the bucket (a row at a time or not), and the output projection."""
         cfg = self.config
         n = cfg.num_heads
         B, T, _ = x.shape
         c, r = row[..., : cfg.kv_lora_rank], row[..., cfg.kv_lora_rank :]
         kv = qm.matmul(c, lp["mla_kvb_proj"]).reshape(B, T, n, -1)
-        k_c, v = kv[..., : cfg.qk_nope_head_dim], kv[..., cfg.qk_nope_head_dim :]
-        k = jnp.concatenate(
-            [k_c, jnp.broadcast_to(r[:, :, None, :], (B, T, n, r.shape[-1]))],
-            axis=-1,
-        )
-        o = attn_ops.blocked_prefill_attention(
-            jnp.concatenate([q_c, q_r], axis=-1), k, v,
-            scale=self._mla_scale(), lengths=lengths,
+        o = dispatch.mla_prefill_attention(
+            q_c, q_r, kv, r, scale=self._mla_scale(), lengths=lengths,
+            plan=self.mla_prefill_plan(T, x.dtype),
         )
         return self._mla_out(lp, x, o)
 

@@ -363,6 +363,84 @@ def grouped_experts_plan(
     return "stacked"
 
 
+#: ``num_heads x T`` of a padded prefill bucket from which expanded latent
+#: attention is the flash kernel. Measured on a v5e (PERF.md section 6, PRs
+#: 53-57; ``tools/mla_prefill_bench.py``): from here the kernel saves a
+#: twentieth or more of the shape's whole 1 x T prefill program in both
+#: presets that have the layer (openpangu's 128 x 1,024: 23.5 %; ling's
+#: 32 x 4,096: 4.1 %); a step under it (2**16) ling's 32 x 2,048 saves half
+#: a percent, a kernel instance in every program a cell warms for nothing
+#: a user sees (PR 54), and openpangu's 128 x 512 a tenth of 30 ms.
+MLA_FLASH_HEAD_TOKENS = 2**17
+
+
+def mla_prefill_plan(
+    num_heads: int, tokens: int, dtype, d_content: int, d_rope: int, d_v: int,
+    mesh: Optional[Mesh] = None, backend: str = "auto",
+) -> str:
+    """How a prefill of ``tokens`` padded positions a row computes the
+    attention of expanded latent attention (``num_heads`` heads, scores
+    over ``d_content + d_rope``, values over ``d_v``), and the only place
+    that chooses it: ``"flash"``
+    (``pallas_attention.mla_flash_prefill_attention``) where the backend is
+    pallas, one device holds the rows whole, they are bfloat16, the content
+    and value parts of a head are one size in whole lane tiles (the kernel
+    reads them as blocks of lanes of one row) and the rotary part half
+    tiles, AND ``num_heads x tokens`` reaches
+    ``MLA_FLASH_HEAD_TOKENS``; else ``"xla"``
+    (``ops/attention.blocked_prefill_attention``: the CPU, a mesh of
+    several devices, another precision, the tiny test models' heads, and
+    every shape in which the attention is too small a part of its program
+    to pay for a kernel instance: ling's 32 heads under 4,096 positions).
+    One algorithm engaged by size, not by model: the rows a program takes
+    at a time do not enter.
+
+    The contract of :func:`decode_kernel_plan`: a pure function of its
+    arguments and the backend, consulted at trace time."""
+    backend = resolve_backend() if backend == "auto" else backend
+    if (
+        backend != "pallas"
+        or (mesh is not None and mesh.size > 1)
+        or jnp.dtype(dtype) != jnp.bfloat16
+        or d_content % 128
+        or d_v != d_content
+        or d_rope % 64
+        or num_heads * tokens < MLA_FLASH_HEAD_TOKENS
+    ):
+        return "xla"
+    return "flash"
+
+
+def mla_prefill_attention(
+    q_c: jnp.ndarray,  # [B, T, n, d_content]
+    q_r: jnp.ndarray,  # [B, T, n, d_rope]
+    kv: jnp.ndarray,  # [B, T, n, d_content + d_v]: a head's keys, then its values
+    k_r: jnp.ndarray,  # [B, T, d_rope] the rotary key all heads share
+    *,
+    scale: float,
+    lengths: jnp.ndarray,  # [B]
+    plan: str,
+) -> jnp.ndarray:
+    """Causal attention of expanded latent attention over a right-padded
+    prompt by the form :func:`mla_prefill_plan` named. No
+    ``jax.named_scope`` of its own: the caller's
+    (``llmq.attn.mla_prefill``) stays the kernel's innermost scope."""
+    if plan == "flash":
+        return pk.mla_flash_prefill_attention(
+            q_c, q_r, kv, k_r, lengths, scale=scale, interpret=_interpret()
+        )
+    B, T, n, _ = kv.shape
+    d_content = q_c.shape[-1]
+    k_c, v = kv[..., :d_content], kv[..., d_content:]
+    k = jnp.concatenate(
+        [k_c, jnp.broadcast_to(k_r[:, :, None, :], (B, T, n, k_r.shape[-1]))],
+        axis=-1,
+    )
+    return xla_ops.blocked_prefill_attention(
+        jnp.concatenate([q_c, q_r], axis=-1), k, v, scale=scale, lengths=lengths
+    )
+
+
 def latent_decode_attention(
     q: jnp.ndarray,  # [S, n_heads, W] absorbed query
     pages: jnp.ndarray,  # [L, P, page_size, Wp] the latent pool

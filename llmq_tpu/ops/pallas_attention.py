@@ -1298,3 +1298,206 @@ def flash_prefill_attention_pallas(
         vt,
     )
     return out[:, :, :T, :].transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Flash prefill of latent attention's expanded form
+# ---------------------------------------------------------------------------
+#
+# Expanded MLA: every head has its own content keys and values, raised from
+# the latent, and all heads share one rotary key. Scores run over
+# ``d_c + d_r`` (128 + 64), values over ``d_v`` (128). A sibling of
+# ``_flash_prefill_kernel``, not that kernel given a second head size: the
+# programs that run that one lower as they did.
+
+
+def _mla_flash_prefill_kernel(
+    len_ref,  # scalar prefetch: [B] int32, valid prompt lengths
+    qc_ref,  # [1, 1, bq, d_c] content part of a head's queries
+    qr_ref,  # [1, 1, bq, d_r] rotary part
+    kc_ref,  # [1, bk, d_c] a head's content keys, where ``W_kvb`` put them
+    kr_ref,  # [1, bk, d_r] the rotary key all heads share
+    v_ref,  # [1, bk, d_v] its values, beside the keys
+    o_ref,  # [1, bq, d_v] into the row ``o_proj`` takes
+    m_ref,  # [bq, LANES] f32
+    l_ref,  # [bq, LANES] f32
+    acc_ref,  # [bq, d_v] f32
+    *,
+    scale: float,
+    block_q: int,
+    block_kv: int,
+    num_kv_blocks: int,
+):
+    b, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    length = len_ref[b]
+    q_start, k_start = iq * block_q, ik * block_kv
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def fold(masked: bool):
+        q = jnp.concatenate([qc_ref[0, 0], qr_ref[0, 0]], axis=-1)
+        k = jnp.concatenate([kc_ref[0], kr_ref[0]], axis=-1)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [bq, bk] f32
+        if masked:
+            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            scores = jnp.where(
+                jnp.logical_and(kpos <= qpos, kpos < length), scores, NEG_INF
+            )
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.exp(scores - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(probs, axis=1, keepdims=True), l_ref.shape
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            probs.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    # A block some query of a live row attends; rows past the prompt are
+    # padding and read as zeros. A block wholly under the diagonal and
+    # inside the prompt needs no mask: the softmax, not the MXU, is what a
+    # block costs at these head sizes.
+    live = jnp.logical_and(
+        k_start <= q_start + block_q - 1,
+        jnp.logical_and(k_start < length, q_start < length),
+    )
+    whole = jnp.logical_and(
+        k_start + block_kv - 1 <= q_start, k_start + block_kv <= length
+    )
+    pl.when(jnp.logical_and(live, whole))(functools.partial(fold, False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(
+        functools.partial(fold, True)
+    )
+
+    @pl.when(ik == num_kv_blocks - 1)
+    def _finish():
+        l = l_ref[:, :1]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _mla_last_key_block(iq, length, block_q: int, block_kv: int):
+    """The last key block that query block ``iq`` of a prompt of ``length``
+    positions attends: under the diagonal and inside the prompt (block 0
+    for an empty row)."""
+    causal = (iq * block_q + block_q - 1) // block_kv
+    return jnp.minimum(causal, jnp.maximum(length - 1, 0) // block_kv)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block_q", "block_kv", "interpret")
+)
+def mla_flash_prefill_attention(
+    q_c: jnp.ndarray,  # [B, T, n, d_c]
+    q_r: jnp.ndarray,  # [B, T, n, d_r]
+    kv: jnp.ndarray,  # [B, T, n, d_c + d_v]: a head's content keys, then its values
+    k_r: jnp.ndarray,  # [B, T, d_r]
+    lengths: jnp.ndarray,  # [B] int32
+    *,
+    scale: float,
+    block_q: int = 512,
+    block_kv: int = 1024,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal flash attention over a right-padded prompt for expanded
+    latent attention: scores over a head's content part and the shared
+    rotary part (never concatenated in HBM), values over ``d_v``. Products
+    in the rows' own dtype into the MXU, float32 running max, sum and
+    accumulator. Key blocks past a query block's diagonal or past
+    ``lengths[b]`` are skipped, their arithmetic and (the index map stays
+    on the last block that is attended) their copies; query rows past
+    ``lengths[b]`` read as zeros where their whole block is past it.
+    ``[B, T, n, d_v]``.
+
+    Keys and values are read where ``W_kvb``'s product left them, a
+    head's ``d_c`` keys then its ``d_v`` values in a row of ``n (d_c +
+    d_v)``, a block of lanes each (``d_c == d_v``: one lane tile apiece),
+    and the output is written into the row ``o_proj`` multiplies: no
+    transposed copy of either. The queries
+    go heads first; their slices and rotary are copies anyway. The blocks
+    are the chip's reading (``tools/mla_prefill_bench.py --blocks``,
+    PERF.md section 6, PRs 53-57): 512 x 1,024 is the fastest over prompts
+    that fill three quarters of a 2,048 bucket (query blocks past the
+    prompt are skipped, which 1,024 rows a block seldom are), 2.3 MB of a
+    step's 16."""
+    B, T, n, d_c = q_c.shape
+    d_r, d_v = q_r.shape[-1], kv.shape[-1] - d_c
+    assert d_c == d_v, (d_c, d_v)  # keys and values a block of lanes each
+    block_q = min(block_q, max(T, 8))
+    block_kv = min(block_kv, max(T, 8))
+    step = max(block_q, block_kv)
+    t_pad = -(-T // step) * step
+    nq, nk = t_pad // block_q, t_pad // block_kv
+
+    def heads_first(a):  # [B, n, t_pad, d]: T on sublanes, contiguous tiles
+        return jnp.pad(
+            a.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, t_pad - T), (0, 0))
+        )
+
+    def rows(a):  # [B, t_pad, width]
+        return jnp.pad(a.reshape(B, T, -1), ((0, 0), (0, t_pad - T), (0, 0)))
+
+    kv = rows(kv)
+
+    def q_map(b, h, iq, ik, ln):
+        return (b, h, iq, 0)
+
+    def key_block(b, iq, ik, ln):
+        # a skipped step stays on the last block its query block attends,
+        # so that nothing is copied for it
+        return jnp.minimum(ik, _mla_last_key_block(iq, ln[b], block_q, block_kv))
+
+    kernel = functools.partial(
+        _mla_flash_prefill_kernel,
+        scale=scale, block_q=block_q, block_kv=block_kv, num_kv_blocks=nk,
+    )
+    out = pl.pallas_call(
+        kernel,
+        name="mla_flash_prefill_attention",
+        out_shape=jax.ShapeDtypeStruct((B, t_pad, n * d_v), q_c.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, n, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, d_c), q_map),
+                pl.BlockSpec((1, 1, block_q, d_r), q_map),
+                pl.BlockSpec(  # head h's keys: lanes [2 h d, 2 h d + d)
+                    (1, block_kv, d_c),
+                    lambda b, h, iq, ik, ln: (b, key_block(b, iq, ik, ln), 2 * h),
+                ),
+                pl.BlockSpec(
+                    (1, block_kv, d_r),
+                    lambda b, h, iq, ik, ln: (b, key_block(b, iq, ik, ln), 0),
+                ),
+                pl.BlockSpec(  # its values: the next block of lanes
+                    (1, block_kv, d_v),
+                    lambda b, h, iq, ik, ln: (b, key_block(b, iq, ik, ln), 2 * h + 1),
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, block_q, d_v), lambda b, h, iq, ik, ln: (b, iq, h)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, d_v), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4
+        ),
+        interpret=interpret,
+    )(
+        lengths.astype(jnp.int32),
+        heads_first(q_c), heads_first(q_r), kv, rows(k_r), kv,
+    )
+    return out[:, :T].reshape(B, T, n, d_v)

@@ -366,6 +366,63 @@ def test_kda_states_the_kernel_updates_in_place_are_served_by_it(monkeypatch):
     assert "kda_decode_plan" not in make_stateless().stats()
 
 
+def test_stats_name_the_plan_of_every_prefill_bucket(monkeypatch):
+    """``stats()["mla_prefill_plan"]`` (a pattern with latent layers
+    only) names, for every prefill bucket the engine has, the form a 1-row
+    program of it takes for expanded latent attention
+    (``dispatch.mla_prefill_plan``): the flash kernel where the backend is
+    pallas, the rows bf16, the head sizes whole lane tiles and ``num_heads
+    x bucket`` at or above the threshold; XLA's blocked form for the tiny
+    heads, float32 rows, the CPU's backend, and the buckets under it; read
+    once at build, so a heartbeat during a swap of the weights finds it.
+    What is served under the flash plan (interpreted here) is what the XLA
+    plan serves."""
+    import dataclasses
+
+    from llmq_tpu.ops import dispatch
+
+    stateless = make_stateless()
+    buckets = stateless._buckets
+    assert buckets == sorted(buckets) and len(buckets) > 1
+    assert stateless.stats()["mla_prefill_plan"] == {"flash": [], "xla": buckets}
+    assert make_core().stats()["mla_prefill_plan"] == {"flash": [], "xla": buckets}
+    json.dumps(stateless.stats()["mla_prefill_plan"])  # goes out in heartbeats
+    gqa = get_preset("lfm2-moe-tiny")
+    no_latent = make_core(
+        params=init_params(gqa, jax.random.key(0), dtype=jnp.float32), cfg=gqa
+    )
+    assert "mla_prefill_plan" not in no_latent.stats()
+
+    cfg = dataclasses.replace(
+        STATELESS, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=128,
+    )
+    monkeypatch.setattr(dispatch, "MLA_FLASH_HEAD_TOKENS", cfg.num_heads * buckets[1])
+    served = {}
+    for backend, dtype, plans in (
+        ("xla", jnp.bfloat16, {"flash": [], "xla": buckets}),
+        ("pallas", jnp.float32, {"flash": [], "xla": buckets}),
+        ("pallas", jnp.bfloat16, {"flash": buckets[1:], "xla": buckets[:1]}),
+    ):
+        monkeypatch.setenv("LLMQ_ATTN_BACKEND", backend)
+        core = make_core(
+            params=init_params(cfg, jax.random.key(1), dtype=dtype), cfg=cfg,
+            kv_dtype=jnp.bfloat16,
+        )
+        assert core.stats()["mla_prefill_plan"] == plans
+        # the benchmark swaps the weights under a live worker, whose
+        # heartbeats call stats() meanwhile: nothing there reads them
+        core.params, held = None, core.params
+        assert core.stats()["mla_prefill_plan"] == plans
+        core.params = held
+        if dtype == jnp.bfloat16:
+            for rid, prompt, n in REQUESTS[:3]:
+                core.add_request(rid, prompt=prompt, params=greedy(n))
+            served[backend] = {rid: out.token_ids for rid, out in drain(core).items()}
+    assert all(len(ids) == n for ids, (_, _, n) in zip(served["pallas"].values(), REQUESTS))
+    # r1 and r2 are prefilled in a bucket the kernel takes
+    assert served["pallas"] == served["xla"]
+
+
 @pytest.mark.parametrize(
     "mesh", [dict(tensor_parallel=2), dict(tensor_parallel=1, pipeline_parallel=2)],
     ids=["tp", "pp"],

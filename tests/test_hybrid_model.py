@@ -691,3 +691,93 @@ def test_lfm2_every_expert_held_and_no_shared_expert_is_the_references_whole_lay
         out, counts = hybrid.moe_held(x, lp, f.mc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=0)
     assert int(counts[0]) == 40 * 4 and int(counts[1]) == int((picked.sum(axis=0) > 0).sum())
+
+
+@pytest.mark.parametrize("preset, rows, bucket, kernels", [
+    ("ling-3.0-flash-ep4", 1, 512, 0),
+    ("ling-3.0-flash-ep4", 4, 2048, 0),
+    ("openpangu-ultra-moe-718b-ep16", 1, 2048, 2),
+])
+def test_the_flash_prefill_kernel_is_in_the_programs_its_plan_names(
+    preset, rows, bucket, kernels
+):
+    """``dispatch.mla_prefill_plan`` engages the flash kernel of expanded
+    latent attention by ``num_heads x T``: the lowered text of ling's
+    prefills at the shapes its cell warms (32 heads under 4,096 positions)
+    holds no call of it, openpangu's 1 x 2,048 (128 heads) one a group of
+    latent layers (the lead layer's and the expert layers')."""
+    from llmq_tpu.models.transformer import init_params
+
+    cfg = get_preset(preset)
+    model = build_model(cfg, attn_backend="pallas")
+    S = jax.ShapeDtypeStruct
+    params = jax.eval_shape(partial(init_params, cfg, dtype=jnp.bfloat16), jax.random.key(0))
+    kp, vp = jax.eval_shape(lambda: make_kv_pages(cfg, 64, 128, jnp.bfloat16, state_rows=5))
+    text = jax.jit(model.prefill).lower(
+        params, S((rows, bucket), jnp.int32), S((rows,), jnp.int32), kp, vp,
+        S((rows, 32), jnp.int32), S((rows,), jnp.int32),
+    ).as_text()
+    assert model.mla_prefill_plan(bucket, jnp.bfloat16) == ("flash" if kernels else "xla")
+    assert text.count("call @mla_flash_prefill_attention") == kernels
+    assert kernels == sum(g.attn == "mla" for g in hybrid.layer_groups(cfg)) * bool(kernels)
+
+
+@pytest.fixture(scope="module")
+def pangu_block():
+    """A pangu block at the head sizes the kernel takes (128 + 64 and 128),
+    a padded batch of four rows, and its prefill in float32 by XLA's form:
+    (cfg, bf16 params, tokens, lengths, block tables, reference logits)."""
+    from llmq_tpu.models.transformer import init_params
+
+    cfg = dataclasses.replace(
+        get_preset("openpangu-ultra-moe-tiny"),
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=128,
+    )
+    params = init_params(cfg, jax.random.key(5), dtype=jnp.bfloat16)
+    rng = np.random.default_rng(4)
+    lengths = np.asarray([30, 9, 0, 17], np.int32)
+    tokens = np.zeros((4, 32), np.int32)
+    for r, n in enumerate(lengths):
+        tokens[r, :n] = rng.integers(1, 300, size=n)
+    bt = np.zeros((4, PPS), np.int32)
+    bt[0, :4], bt[1, :2], bt[3, :3] = [1, 2, 3, 4], [5, 6], [7, 8, 9]
+    k, v = make_kv_pages(cfg, PAGES, PAGE, jnp.float32)
+    f32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    want, _, _ = jax.jit(build_model(cfg, attn_backend="xla").prefill)(
+        f32, tokens, lengths, k, v, bt
+    )
+    return cfg, params, tokens, lengths, bt, np.asarray(want, np.float32)
+
+
+@pytest.mark.parametrize("by_row", [False, True], ids=["one_pass", "row_at_a_time"])
+@pytest.mark.parametrize("plan", ["xla", "flash"])
+def test_each_plan_serves_the_float32_reference_logits(pangu_block, monkeypatch, plan, by_row):
+    """The block's prefill in bf16 under each plan forced (the threshold
+    out of the way; the kernel interpreted here), in one pass and a row at
+    a time as ``_mla_prefill`` takes a bucket above
+    ``MLA_PREFILL_HEAD_TOKENS``: the float32 reference's logits to bf16's
+    rounding, the same latent rows to the bit under both plans (they are
+    written before any attention), and nothing but numbers in the rows
+    that are padding."""
+    from llmq_tpu.ops import dispatch
+
+    cfg, params, tokens, lengths, bt, want = pangu_block
+    monkeypatch.setattr(dispatch, "MLA_FLASH_HEAD_TOKENS", 0)
+    if by_row:
+        monkeypatch.setattr(hybrid, "MLA_PREFILL_HEAD_TOKENS", 16)
+
+    def run(backend):
+        model = build_model(cfg, attn_backend=backend)
+        k, v = make_kv_pages(cfg, PAGES, PAGE, jnp.bfloat16)
+        assert model.mla_prefill_plan(32, jnp.bfloat16) == ("flash" if backend == "pallas" else "xla")
+        logits, k, _ = jax.jit(model.prefill)(params, tokens, lengths, k, v, bt)
+        return np.asarray(logits, np.float32), np.asarray(k, np.float32)
+
+    logits, latent = run("pallas" if plan == "flash" else "xla")
+    live = lengths > 0
+    assert np.isfinite(logits).all() and np.isfinite(latent).all()
+    np.testing.assert_allclose(logits[live], want[live], atol=0.12, rtol=0.05)
+    if plan == "flash":
+        other, other_latent = run("xla")
+        np.testing.assert_allclose(logits[live], other[live], atol=0.06, rtol=0.05)
+        np.testing.assert_array_equal(latent[0], other_latent[0])  # before any attention

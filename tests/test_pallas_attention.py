@@ -1019,3 +1019,193 @@ def test_latent_schedule_comes_from_the_page_bytes_and_the_heads(
     assert G == pages
     # two chunks of pages, the score tile and the accumulator fit VMEM's default limit
     assert 2 * G * page_size * 640 * itemsize + n_heads * (G * page_size + 512) * 4 < 12 * 2**20
+
+
+# ---------------------------------------------------------------------------
+# Flash prefill of expanded latent attention
+# ---------------------------------------------------------------------------
+
+
+def _mla_rows(B, T, n, seed=0):
+    """q_c, q_r, kv (a head's 128 content keys, then its 128 values), k_r."""
+    keys = jax.random.split(jax.random.key(seed), 4)
+    shapes = ((B, T, n, 128), (B, T, n, 64), (B, T, n, 256), (B, T, 64))
+    return [
+        jax.random.normal(k, s, jnp.float32).astype(jnp.bfloat16)
+        for k, s in zip(keys, shapes)
+    ]
+
+
+@pytest.mark.parametrize(
+    "n,T,lengths,blocks,by_row",
+    [
+        (32, 128, [128], (64, 64), False),  # ling's heads, a full prompt
+        (128, 128, [100], (64, 64), False),  # openpangu's heads, a ragged one
+        (4, 200, [200, 77, 1], (64, 64), False),  # T no multiple of the block
+        (4, 192, [130, 192], (128, 64), False),  # two key blocks a query block
+        (4, 192, [60, 192], (64, 128), False),  # two query blocks a key block
+        (2, 96, [96, 50], (512, 512), False),  # one block holds the bucket
+        (4, 128, [128, 3, 90, 0], (64, 64), True),  # B = 4, a row at a time
+    ],
+    ids=["heads32", "heads128", "ragged_T200", "bq128_bk64", "bq64_bk128",
+         "one_block", "four_rows_row_at_a_time"],
+)
+def test_mla_flash_prefill_matches_the_blocked_reference(n, T, lengths, blocks, by_row):
+    """The flash kernel of expanded latent attention (scores over 128 +
+    64, values over 128, bf16 into the MXU, float32 softmax state) against
+    ``blocked_prefill_attention`` over every row of each prompt; rows past
+    a prompt's length are padding, and finite. Keys and values go in as
+    ``W_kvb``'s product has them, side by side a head."""
+    from llmq_tpu.ops import dispatch
+
+    B = len(lengths)
+    rows = _mla_rows(B, T, n, seed=n + T)
+    lens = jnp.asarray(lengths, jnp.int32)
+    scale = 192**-0.5
+    want = dispatch.mla_prefill_attention(*rows, scale=scale, lengths=lens, plan="xla")
+    flash = functools.partial(
+        pk.mla_flash_prefill_attention, scale=scale,
+        block_q=blocks[0], block_kv=blocks[1], interpret=True,
+    )
+    if by_row:  # as ``_mla_prefill`` takes a bucket above MLA_PREFILL_HEAD_TOKENS
+        got = jax.lax.map(
+            lambda args: flash(*(a[None] for a in args))[0], (*rows, lens)
+        )
+    else:
+        got = flash(*rows, lens)
+    assert got.shape == want.shape == (B, T, n, 128) and got.dtype == jnp.bfloat16
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    for b, length in enumerate(lengths):
+        np.testing.assert_allclose(
+            np.asarray(got[b, :length], np.float32),
+            np.asarray(want[b, :length], np.float32),
+            atol=2e-2, rtol=2e-2,
+        )
+
+
+@pytest.mark.parametrize("lengths", [[96], [72], [1], [0], [96, 72, 1, 0]],
+                         ids=["full", "three_quarters", "one", "empty", "four_rows"])
+def test_mla_flash_prefill_rows_past_a_length_hold_numbers(lengths):
+    """Rows past a prompt's length are padding: whatever they hold goes
+    through ``o_proj`` and the next layer's norm, so it has to be numbers
+    (a block wholly past the prompt is never folded: its running sum is 0
+    and it reads as zeros), for a full prompt, one of three quarters of the
+    bucket, one token, and an empty row of a padded batch."""
+    T = 96
+    rows = _mla_rows(len(lengths), T, 4, seed=11)
+    got = pk.mla_flash_prefill_attention(
+        *rows, jnp.asarray(lengths, jnp.int32), scale=192**-0.5,
+        block_q=32, block_kv=32, interpret=True,
+    ).astype(jnp.float32)
+    assert bool(jnp.isfinite(got).all())
+    for b, length in enumerate(lengths):
+        whole_blocks_past = -(-length // 32) * 32
+        assert not np.asarray(got[b, whole_blocks_past:]).any()
+
+
+def test_mla_flash_prefill_under_a_layer_scan_with_a_traced_layer_index():
+    """As a scanned group of latent layers runs it: the keys and values
+    raised inside the scan's body from the latent rows by the layer's own
+    ``W_kvb``, taken from the stack at a traced index, the output carried
+    to the next layer. The kernel's every layer is the blocked
+    reference's."""
+    from llmq_tpu.ops import dispatch
+
+    L, T, n, rank = 3, 96, 2, 64
+    keys = jax.random.split(jax.random.key(3), 3)
+    q_c, q_r, _, k_r = _mla_rows(1, T, n, seed=5)
+    c = jax.random.normal(keys[0], (1, T, rank), jnp.float32).astype(jnp.bfloat16)
+    w_kvb = (jax.random.normal(keys[1], (L, rank, n * 256), jnp.float32) * rank**-0.5).astype(jnp.bfloat16)
+    lens = jnp.asarray([80], jnp.int32)
+
+    def stack(plan):
+        def layer(carry, li):
+            w = jax.lax.dynamic_index_in_dim(w_kvb, li, keepdims=False)
+            kv = (c @ w).reshape(1, T, n, 256)
+            o = dispatch.mla_prefill_attention(
+                q_c + carry, q_r, kv, k_r, scale=192**-0.5, lengths=lens, plan=plan
+            )
+            return o, o
+
+        return jax.jit(lambda: jax.lax.scan(layer, jnp.zeros_like(q_c), jnp.arange(L))[1])()
+
+    want, got = stack("xla"), stack("flash")
+    assert got.shape == (L, 1, T, n, 128)
+    np.testing.assert_allclose(
+        np.asarray(got[:, :, :80], np.float32), np.asarray(want[:, :, :80], np.float32),
+        atol=4e-2, rtol=4e-2,
+    )
+
+
+@pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 128)])
+def test_mla_flash_prefill_copies_no_block_it_skips(bq, bk):
+    """A skipped step's index map stays on the last key block its query
+    block attends (some key of it at or under a query of the block AND
+    inside the prompt), so the pipeline copies nothing for it: the block
+    the maps clamp to is that one, for every query block and length."""
+    T = 512
+    for length in (0, 1, 63, 64, 65, 200, 511, 512):
+        for iq in range(T // bq):
+            attended = [
+                ik for ik in range(T // bk)
+                if ik * bk <= iq * bq + bq - 1 and ik * bk < length
+            ]
+            assert int(pk._mla_last_key_block(iq, length, bq, bk)) == max(attended, default=0)
+    grids = _pallas_grids(
+        pk.mla_flash_prefill_attention, *_mla_rows(3, T, 2), jnp.asarray([T, 100, 0], jnp.int32),
+        scale=0.1, block_q=bq, block_kv=bk, interpret=True,
+    )
+    assert grids == [(3, 2, T // bq, T // bk)]  # heads are a grid axis
+
+
+#: ling's cell warms 1 and 4 rows of 256 ... 2,048 at 32 heads, openpangu's 1
+#: and 4 rows of 1,024 ... 4,096 at 128 (``benchmark/run_helpers.warm_shapes``).
+_LING_WARMED = [(32, t) for t in (256, 512, 1024, 2048)]
+_PANGU_WARMED = [(128, t) for t in (1024, 2048, 4096)]
+
+
+@pytest.mark.parametrize(
+    "heads,tokens,dtype,dims,tp,backend,plan",
+    [(n, t, jnp.bfloat16, (128, 64, 128), 1, "pallas", "xla") for n, t in _LING_WARMED]
+    + [(n, t, jnp.bfloat16, (128, 64, 128), 1, "pallas", "flash") for n, t in _PANGU_WARMED]
+    + [
+        (32, 4096, jnp.bfloat16, (128, 64, 128), 1, "pallas", "flash"),  # ling above 2**17
+        (32, 8192, jnp.bfloat16, (128, 64, 128), 1, "pallas", "flash"),
+        (128, 512, jnp.bfloat16, (128, 64, 128), 1, "pallas", "xla"),  # openpangu under it
+        (128, 2048, jnp.float32, (128, 64, 128), 1, "pallas", "xla"),  # another precision
+        (128, 2048, jnp.bfloat16, (128, 64, 128), 2, "pallas", "xla"),  # a mesh
+        (128, 2048, jnp.bfloat16, (128, 64, 128), 1, "xla", "xla"),  # the CPU
+        (128, 2048, jnp.bfloat16, (16, 8, 16), 1, "pallas", "xla"),  # the tiny models' heads
+        (128, 2048, jnp.bfloat16, (128, 32, 128), 1, "pallas", "xla"),
+        (128, 2048, jnp.bfloat16, (128, 64, 64), 1, "pallas", "xla"),
+    ],
+)
+def test_mla_prefill_plan_names_what_runs(heads, tokens, dtype, dims, tp, backend, plan):
+    """The plan is a function of the backend, the mesh, the rows' dtype,
+    the head sizes and ``num_heads x T`` of the bucket, whatever the rows
+    of the batch: every shape ling's cell warms keeps the XLA form, every
+    shape openpangu's warms takes the kernel."""
+    from llmq_tpu.ops import dispatch
+    from llmq_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(tensor_parallel=tp, devices=jax.devices()[:tp])
+    assert dispatch.mla_prefill_plan(heads, tokens, dtype, *dims, mesh, backend) == plan
+    assert dispatch.MLA_FLASH_HEAD_TOKENS == 2**17
+    rows = [jnp.zeros(s, dtype) for s in (
+        (1, 64, 2, dims[0]), (1, 64, 2, dims[1]), (1, 64, 2, dims[0] + dims[2]), (1, 64, dims[1]),
+    )]
+    grids = _pallas_grids(
+        dispatch.mla_prefill_attention, *rows,
+        scale=0.07, lengths=jnp.asarray([64], jnp.int32), plan=plan,
+    )
+    assert grids == ([(1, 2, 1, 1)] if plan == "flash" else [])
+
+
+def test_mla_prefill_plan_follows_the_one_backend_variable(monkeypatch):
+    from llmq_tpu.ops import dispatch
+
+    monkeypatch.setenv("LLMQ_ATTN_BACKEND", "xla")
+    assert dispatch.mla_prefill_plan(128, 2048, jnp.bfloat16, 128, 64, 128) == "xla"
+    monkeypatch.setenv("LLMQ_ATTN_BACKEND", "pallas")
+    assert dispatch.mla_prefill_plan(128, 2048, jnp.bfloat16, 128, 64, 128) == "flash"
+    assert dispatch.mla_prefill_plan(32, 2048, jnp.bfloat16, 128, 64, 128) == "xla"

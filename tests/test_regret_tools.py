@@ -1,6 +1,7 @@
-"""The tools PR 40's, PR 47's and PR 51's records rest on run end to end
-off the chip: the latent kernel's layout check, the KDA kernel's bench and
-the expert products' bench (interpreted, tiny sizes), the served-regret
+"""The tools PR 40's, PR 47's, PR 51's and PR 55's records rest on run end
+to end off the chip: the latent kernel's layout check, the KDA kernel's
+bench, the expert products' bench and the latent prefill attention's
+bench (interpreted, tiny sizes), the served-regret
 probe (the benchmark's CPU
 rehearsal) and the lowering hashes. What they read on the chip is in PERF.md; here they only have to
 keep working."""
@@ -101,6 +102,31 @@ def test_expert_matmul_bench_runs_interpreted():
             assert l["max_diff_over_spread"] < 0.05
 
 
+def test_mla_prefill_bench_runs_interpreted():
+    """``tools/mla_prefill_bench.py`` at tiny shapes: the XLA form and the
+    flash kernel at two pairs of blocks over a full and a ragged prompt,
+    then a tiny preset's whole prefill under both settings of the
+    threshold (its heads are no lane tiles: XLA's form twice); the two
+    forms agree to bf16 rounding, and an interpreted run gives no time."""
+    lines, _ = _run(
+        ["tools/mla_prefill_bench.py", "--interpret", "--heads", "2", "--tokens", "96",
+         "--blocks", "32x32,64x32", "--iters", "1", "--programs", "openpangu-ultra-moe-tiny:64",
+         "--out", os.devnull],
+        timeout=600,
+    )
+    full, ragged, program = lines
+    for l, length in ((full, 96), (ragged, 72)):
+        assert (l["line"], l["heads"], l["tokens"], l["length"], l["head_tokens"]) == (
+            "attention", 2, 96, length, 192)
+        assert l["xla_ms"] is None and l["flash_32x32_ms"] is None
+        assert l["flash_32x32_max_diff"] < 0.05 and l["flash_64x32_max_diff"] < 0.05
+    assert (program["line"], program["tokens"], program["xla_plan"], program["flash_plan"]) == (
+        "program", 64, "xla", "xla")
+    assert program["xla_ms"] is None and "saved_pct" not in program
+    # what each plan's program took to trace and lower, and to compile
+    assert all(program[f"{plan}_{what}_s"] > 0 for plan in ("xla", "flash") for what in ("trace", "compile"))
+
+
 def test_kda_decode_ab_runs_on_the_cpu():
     """``tools/kda_decode_ab.py --tiny``: a ling-shaped decode step by the
     XLA form on a run, by the plan's form (the kernel, interpreted) and by
@@ -127,23 +153,45 @@ def test_kda_decode_ab_runs_on_the_cpu():
 
 
 def test_lowering_hash_lists_every_step_program():
-    _, out = _run(["tools/lowering_hash.py"], timeout=600)
+    _, out = _run(["tools/lowering_hash.py"], timeout=900)
     rows = [l.split() for l in out.splitlines() if l.strip()]
-    assert len(rows) == 42 and len({tuple(r[:3]) for r in rows}) == 42
+    # seven presets x two backends x (decode, 1 x 512, 4 x 2,048), and for
+    # the two patterns with latent layers every other (rows, bucket) their
+    # cells warm: ling 6 a backend, openpangu 5
+    assert len(rows) == 42 + 2 * (6 + 5) and len({tuple(r[:3]) for r in rows}) == len(rows)
     assert all(len(r[3]) == 16 for r in rows)
+    ling, pangu = "ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16"
+    programs = lambda preset, backend="pallas": [
+        r[2] for r in rows if r[0] == preset and r[1] == backend
+    ]
+    assert programs(ling)[:3] == programs(pangu)[:3] == ["decode", "prefill_1x512", "prefill_4x2048"]
+    assert programs(ling)[3:] == [
+        f"prefill_{b}x{t}" for t in (256, 512, 1024, 2048) for b in (1, 4)
+        if (b, t) not in ((1, 512), (4, 2048))
+    ]
+    assert programs(pangu)[3:] == [
+        f"prefill_{b}x{t}" for t in (1024, 2048, 4096) for b in (1, 4) if (b, t) != (4, 2048)
+    ]
+    assert programs(ling, "xla") == programs(ling) and programs(pangu, "xla") == programs(pangu)
+    marked = lambda kernel: [tuple(r[:3]) for r in rows if kernel in r[5:]]
     # the one-pass KDA state update is in ling's pallas decode step alone
-    assert [tuple(r[:3]) for r in rows if r[5:] == ["kda_step_inplace"]] == [
-        ("ling-3.0-flash-ep4", "pallas", "decode")
+    assert marked("kda_step_inplace") == [(ling, "pallas", "decode")]
+    assert [r[5:] for r in rows if tuple(r[:3]) == (ling, "pallas", "decode")] == [["kda_step_inplace"]]
+    # the grouped matmul on a group's stack is in every pallas prefill of
+    # the four patterns with routed experts but ling's 1 x 256 (as many
+    # rows as the dense form takes), and in no decode step
+    assert marked("grouped_matmul_stacked") == [
+        (r[0], "pallas", r[2]) for r in rows
+        if r[0] in (ling, pangu, "lfm2-24b-a2b-pp5", "laguna-s-2.1-ep4")
+        and r[1] == "pallas" and r[2] not in ("decode", "prefill_1x256")
     ]
-    # the grouped matmul on a group's stack is in the pallas prefills of the
-    # four patterns with routed experts, and in no decode step
-    assert [tuple(r[:3]) for r in rows if r[5:] == ["grouped_matmul_stacked"]] == [
-        (preset, "pallas", program)
-        for preset in ("ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16",
-                       "lfm2-24b-a2b-pp5", "laguna-s-2.1-ep4")
-        for program in ("prefill_1x512", "prefill_4x2048")
+    # the flash prefill of expanded latent attention is in openpangu's
+    # pallas prefills from 1,024 positions (128 heads x 1,024 = 2**17), in
+    # none of the shapes ling's cell warms, and in no other line
+    assert marked("mla_flash_prefill_attention") == [
+        (pangu, "pallas", p) for p in programs(pangu) if p not in ("decode", "prefill_1x512")
     ]
-    assert sum(len(r) == 5 for r in rows) == 42 - 1 - 8
+    assert sum(len(r) == 5 for r in rows) == len(rows) - len(marked("grouped_matmul_stacked")) - 1
 
 
 def test_lowering_hash_for_a_described_v5e_holds_the_mosaic_kernels():

@@ -208,6 +208,34 @@ def _kda_step_inplace(topo, monkeypatch):
     )
 
 
+def _mla_flash_prefill(n_heads, T):
+    """The flash prefill of expanded latent attention alone at openpangu's
+    heads (128 + 64 and 128, bf16) over one row of a bucket: the plan names
+    it for the shape, it asks for no more fast memory than a kernel has by
+    default (PR 51: one granted 18 MB hung two prefill programs), and no
+    float32 score matrix leaves it (the XLA form's is ``n_heads x 512 x T``
+    a block, 1 GB here)."""
+
+    def case(topo, monkeypatch):
+        s = _Shapes(topo)
+        assert dispatch.mla_prefill_plan(
+            n_heads, T, jnp.bfloat16, 128, 64, 128, None, "pallas"
+        ) == "flash"
+        compiled = _compile(
+            partial(pk.mla_flash_prefill_attention, scale=192**-0.5),
+            s((1, T, n_heads, 128), jnp.bfloat16), s((1, T, n_heads, 64), jnp.bfloat16),
+            s((1, T, n_heads, 256), jnp.bfloat16), s((1, T, 64), jnp.bfloat16),
+            s((1,), jnp.int32),
+        )
+        text = compiled.as_text()
+        calls = [l for l in text.splitlines() if "custom-call(" in l and "mla_flash_prefill_attention" in l]
+        assert len(calls) == 1 and "vmem_limit_bytes" not in calls[0]
+        # the queries heads first and the output: nothing the size of a score block
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * T * n_heads * 192 * 2
+
+    return case
+
+
 def _flash_prefill(T):
     def case(topo, monkeypatch):
         s = _Shapes(topo)
@@ -524,6 +552,23 @@ def _hybrid_step(
                 if matrix.search(l) and " parameter(" not in l and " get-tuple-element(" not in l
             ]
             assert bool(made) == (plan == "xla"), made[:3]
+        if which != "decode" and hybrid.count_layers(cfg, "mla"):
+            # Expanded latent attention from ``num_heads x T`` of 2**17 is
+            # the flash kernel (``dispatch.mla_prefill_plan``), once a
+            # group of latent layers; then no ``[B, heads, block, T]``
+            # float32 score matrix is made, and the kernel's call asks for
+            # no more fast memory than a kernel has (PR 51: one granted 18
+            # MB hung two of ling's programs on the chip and compiled
+            # here). Under it XLA's blocked form stays.
+            plan = model.mla_prefill_plan(bucket, jnp.bfloat16)
+            assert plan == ("flash" if cfg.num_heads * bucket >= 2**17 else "xla")
+            text = compiled.as_text()
+            calls = [l for l in text.splitlines() if "mla_flash_prefill_attention" in l and "custom-call(" in l]
+            groups = sum(1 for g in hybrid.layer_groups(cfg) if g.attn == "mla")
+            assert len(calls) == (groups if plan == "flash" else 0), calls[:2]
+            assert not [l for l in calls if "vmem_limit_bytes" in l]
+            scores = re.compile(rf"f32\[\d+,{cfg.num_heads},\d+,{bucket}\]")
+            assert plan == "xla" or not scores.search(text)
         if which == "decode":
             # The latent kernel is in the step (a Mosaic call named for
             # it), reads the pool where it lies, and with the XLA loop's
@@ -565,6 +610,7 @@ CASES = {
     "hybrid_decode_128_slots": _hybrid_step("decode"),
     "hybrid_prefill_1x512": _hybrid_step((1, 512)),
     "hybrid_prefill_4x2048": _hybrid_step((4, 2048)),
+    "hybrid_prefill_1x8192_above_the_flash_threshold": _hybrid_step((1, 8192)),
     "latent_only_decode_128_slots_128_heads": _hybrid_step(
         "decode", "openpangu-ultra-moe-718b-ep16", pages=3200, places=32
     ),
@@ -605,6 +651,7 @@ CASES = {
     "latent_decode_live_128_heads": _latent_decode_live(128, 5, 3200, 32),
     "latent_decode_live_32_heads": _latent_decode_live(32, 1, 2305, 64),
     "kda_step_inplace_128_rows_32_heads": _kda_step_inplace,
+    "mla_flash_prefill_128_heads_T4096": _mla_flash_prefill(128, 4096),
     "decode_live": _decode(pk.paged_decode_attention_live),
     "decode_fp8_pool_2_kv_heads_takes_v1": (
         _decode_fp8_pool_2_kv_heads_takes_v1

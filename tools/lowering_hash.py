@@ -11,10 +11,16 @@ lowers byte-equal. The pallas backend is interpreted on the CPU, so a
 kernel's traced operations are in the text; the five layer patterns'
 programs are listed last (ling, openpangu, lfm2, evabyte, laguna), for a PR that
 changes them on purpose, and a line whose text holds the one-pass KDA
-state update (``kda_step_inplace``: ling's pallas decode step) or the
+state update (``kda_step_inplace``: ling's pallas decode step), the
 grouped expert matmul on a group's stack (``grouped_matmul_stacked``: the
-pallas prefills of the three patterns with routed experts) says so in
-further words.
+pallas prefills of the three patterns with routed experts) or the flash
+prefill of expanded latent attention (``mla_flash_prefill_attention``:
+the pallas prefills of a pattern with latent layers from ``num_heads x T``
+of 2**17, openpangu's from 1,024 positions, ling's from 4,096) says so in
+further words. The two patterns with latent layers have, after their
+three lines, one for every (rows, bucket) their cells warm
+(``benchmark/run_helpers.warm_shapes``): ling 1 and 4 x 256 ... 2,048,
+openpangu 1 and 4 x 1,024 ... 4,096.
 
     python3 tools/lowering_hash.py --v5e [--root <another checkout>]
 
@@ -127,6 +133,14 @@ if args.v5e:
     described_v5e()
     sys.exit(0)
 
+#: The prefill buckets a cell warms, at 1 and 4 rows each, for the presets
+#: whose latent layers take a kernel by the bucket's size.
+WARMED = {
+    "ling-3.0-flash-ep4": (256, 512, 1024, 2048),
+    "openpangu-ultra-moe-718b-ep16": (1024, 2048, 4096),
+}
+KERNELS = ("kda_step_inplace", "grouped_matmul_stacked", "mla_flash_prefill_attention")
+
 for preset in (
     "qwen2.5-3b", "qwen2.5-7b", "ling-3.0-flash-ep4", "openpangu-ultra-moe-718b-ep16",
     "lfm2-24b-a2b-pp5", "evabyte-6.5b-pp4", "laguna-s-2.1-ep4",
@@ -144,12 +158,14 @@ for preset in (
                 params, S((ROWS,), jnp.int32), S((ROWS,), jnp.int32), kp, vp,
                 S((ROWS, PLACES), jnp.int32), S((ROWS,), jnp.bool_)).as_text(),
         }
-        for b, t in ((1, 512), (4, 2048)):
+        shapes = [(1, 512), (4, 2048)]
+        shapes += [(b, t) for t in WARMED.get(preset, ()) for b in (1, 4) if (b, t) not in shapes]
+        for b, t in shapes:
             extra = (S((b,), jnp.int32),) if hybrid else ()
             texts[f"prefill_{b}x{t}"] = jax.jit(model.prefill).lower(
                 params, S((b, t), jnp.int32), S((b,), jnp.int32), kp, vp,
                 S((b, PLACES), jnp.int32), *extra).as_text()
         for name, text in texts.items():
-            kernels = [k for k in ("kda_step_inplace", "grouped_matmul_stacked") if k in text]
+            kernels = [k for k in KERNELS if k in text]
             print(preset, backend, name, hashlib.sha256(text.encode()).hexdigest()[:16],
                   len(text), *kernels, flush=True)

@@ -3,7 +3,7 @@
 process on the chip (the worker is built once; the weights are swapped).
 
     python3 tools/served_regret_probe.py --workload <cell> --seeds 7,11,... \
-        [--repeats 1] [--out chiprun_out/regret.jsonl]
+        [--repeats 1] [--check-only] [--out chiprun_out/regret.jsonl]
 
 ``benchmark/correct.py`` scores the tokens the engine served under load by
 the logits of a second compilation of the same model (``jax.jit`` of
@@ -320,6 +320,8 @@ async def one_seed(args, cell, traffic, system, seed: int) -> None:
         positions=sum(len(t) for _, t in seqs),
         missed={i: m for i, m in missed.items() if m})
 
+    if args.check_only:
+        return
     prompts = [ids for ids, _ in kept["samples"]]
     for n in range(max(1, args.repeats)):
         served, fillers, dispatches, load = await correct.serve_under_load(system, seed, prompts)
@@ -392,6 +394,8 @@ def main() -> None:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True, help="comma-separated")
     ap.add_argument("--repeats", type=int, default=1)
+    ap.add_argument("--check-only", action="store_true", help="the ``check`` line alone: for "
+                    "a change to a prefill, which the other two legs do not run")
     ap.add_argument("--out", default=None, help="append the lines to this file too")
     ap.add_argument("--dump-hlo", default=None, metavar="PREFIX", help="write the two "
                     "executables' compiled text to PREFIX.{engine,direct}.hlo.txt")
