@@ -281,18 +281,6 @@ class Config(BaseModel):
         "only scheduling order, never a sequence's token stream.",
     )
 
-    interactive_decode_block: int = Field(
-        default_factory=lambda: _env_int(
-            "LLMQ_INTERACTIVE_DECODE_BLOCK", default=0
-        ),
-        description="Fused-decode K for steps whose batch contains an "
-        "interactive row: the engine compiles a second small-K decode "
-        "executable and dispatches it whenever interactive work is "
-        "resident, so interactive ITL is bounded by K_small iterations "
-        "while pure-batch steps keep the big fused decode_block. "
-        "0 = off (every step uses decode_block).",
-    )
-
     serve_port: int = Field(
         default_factory=lambda: _env_int("LLMQ_SERVE_PORT", default=8100),
         description="HTTP port for the OpenAI-compatible streaming "

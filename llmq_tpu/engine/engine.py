@@ -111,12 +111,12 @@ from llmq_tpu.engine.scheduler import (
     mixed_token_budget,
 )
 from llmq_tpu.engine.tokenizer import Tokenizer
+from llmq_tpu.models.cache import cache_layout
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.transformer import (
     Params,
     Transformer,
     build_model,
-    make_kv_pages,
 )
 from llmq_tpu.obs.metrics import (
     DEFAULT_BUCKETS,
@@ -130,9 +130,7 @@ from llmq_tpu.obs.trace import emit_trace_event
 from llmq_tpu.ops import dispatch as _dispatch
 from llmq_tpu.utils.host_mem import get_governor
 from llmq_tpu.utils.platform import on_tpu
-from llmq_tpu.ops.attention import (
-    eva_context, eva_table_pages, latent_decode_pages_visited, mixed_query_grid,
-)
+from llmq_tpu.ops.attention import mixed_query_grid
 from llmq_tpu.parallel import pipeline as pp_mod
 from llmq_tpu.parallel.mesh import (
     DP_AXIS,
@@ -141,7 +139,7 @@ from llmq_tpu.parallel.mesh import (
     make_mesh,
     mesh_pp,
 )
-from llmq_tpu.parallel.sharding import kv_page_pspec, param_shardings
+from llmq_tpu.parallel.sharding import param_shardings
 
 logger = logging.getLogger(__name__)
 
@@ -430,14 +428,6 @@ class EngineConfig:
     # (rides preempt_mode: swap gathers the victim's KV to host, else
     # recompute). LLMQ_PRIORITY_PREEMPT pins over this.
     priority_preempt: bool = True
-    # Small-K interactive decode: when > 0 (and < decode_block) the
-    # engine compiles a SECOND fused decode/verify executable at this
-    # many scan iterations and dispatches it whenever an interactive
-    # row is resident, so interactive ITL is bounded by the small K
-    # while pure-batch steps keep the big fused decode_block. 0 = off
-    # (every step uses decode_block — the pre-priority executables,
-    # bit-for-bit). LLMQ_INTERACTIVE_DECODE_BLOCK pins over this.
-    interactive_decode_block: int = 0
     # Host-RAM prefix cold tier (GiB of host blobs; 0 = off; requires
     # enable_prefix_caching): cache-registered pages evicted from the
     # device pool park in host RAM keyed by their chain digest, and a
@@ -523,12 +513,6 @@ class EngineConfig:
         if self.preempt_mode not in ("recompute", "swap"):
             raise ValueError(
                 f"preempt_mode={self.preempt_mode!r} (want recompute|swap)"
-            )
-        self.interactive_decode_block = int(self.interactive_decode_block)
-        if self.interactive_decode_block < 0:
-            raise ValueError(
-                f"interactive_decode_block={self.interactive_decode_block} "
-                f"(want >= 0)"
             )
         self.prefix_host_gb = float(self.prefix_host_gb)
         if self.prefix_host_gb < 0:
@@ -623,78 +607,6 @@ def _prefill_buckets(
     return sorted(set(rounded))
 
 
-def kv_page_bytes_per_device(
-    model_config: ModelConfig, page_size: int, kv_dtype, kv_format
-) -> int:
-    """HBM one KV page (K and V, all layers) takes on each device, as the
-    compiler lays the pool out — asked of the compiler, not computed
-    from the shape: the kv-head axis sits on the sublanes, and a shard
-    left with fewer heads than one packed tile holds (one bf16 head at
-    tp == num_kv_heads, two fp8 heads) is padded to it, i.e. twice the
-    bytes the shape says (tests/test_tpu_compile.py)."""
-    probe_pages = 8
-    shape = (
-        model_config.num_layers,
-        probe_pages,
-        page_size,
-        model_config.num_kv_heads,
-        model_config.head_dim_,
-    )
-    alloc = jax.jit(
-        lambda: jnp.zeros(shape, kv_dtype), out_shardings=kv_format
-    )
-    pool = alloc.lower().compile().memory_analysis().output_size_in_bytes
-    return 2 * pool // probe_pages
-
-
-# What a layer pattern is and why it refuses an option, by the kind of its
-# state layers (False: it has none).
-_PATTERN_REFUSALS = {
-    "kda": (
-        "per-sequence KDA state beside a latent cache",
-        "the state cannot be shared by a prefix, cut at a chunk, rewound by a "
-        "length or moved between pools",
-    ),
-    "conv": (
-        "gated short-convolution layers beside a K/V paged cache",
-        "a per-sequence convolution tail cannot be shared by a prefix, cut at "
-        "a chunk or rewound, moving it between pools is not built",
-    ),
-    "swa": (
-        "sliding-window layers over a per-sequence ring beside full-attention "
-        "layers over a K/V paged cache",
-        "a ring of the last window's rows cannot be shared by a prefix, cut at "
-        "a chunk or rewound by a length without storing it, moving it between "
-        "pools is not built",
-    ),
-    "eva": (
-        "EVA layers over a compressed paged cache whose rows are not positions",
-        "a closed window's rows are overwritten by its summaries, so the cache "
-        "cannot be shared by a prefix, cut at a chunk, rewound by a length or "
-        "moved between pools; a step yields one token (the extra prediction "
-        "heads are not served)",
-    ),
-    False: (
-        "a latent cache alone, no per-sequence state",
-        "chunked prefill, verify, the mixed step and moving a latent pool are "
-        "not built for a layer pattern (HybridTransformer has whole-prompt "
-        "prefill and decode)",
-    ),
-}
-
-
-def _layer_pattern_refusal(what: str, stateful: "str | bool") -> str:
-    """Why an option is refused for a layer pattern: the reason that holds
-    for this one (``stateful``: the kind of its state layers, "kda",
-    "conv" or "swa", or "eva" for EVA layers, which keep no state but a cache that
-    cannot be cut, or False where it has neither)."""
-    pattern, reason = _PATTERN_REFUSALS[stateful]
-    return (
-        f"{what} is not supported for a model with a layer pattern "
-        f"({pattern}): {reason}, and its experts are held whole on one device"
-    )
-
-
 # Turns a bucket of the record of long turns: the engine remembers the two
 # longest of this bucket and of the one before (3-7 s of 13 ms steps).
 _TURN_BUCKET = 256
@@ -760,29 +672,24 @@ class EngineCore:
         # hidden states).
         self.full_mesh = self.mesh
         self.pp = mesh_pp(self.mesh)
-        # A declared layer pattern (models/hybrid.py): a per-sequence
-        # state beside a paged latent or K/V cache. ``_state_rows`` sizes
-        # the state pool: a row a slot, and row 0 scratch.
+        # What a sequence keeps on the device (models/cache.py): the pools,
+        # their cost, the row map, the span's fields, the refusals.
+        self.cache = cache_layout(
+            model_config,
+            page_size=self.cfg.page_size,
+            max_model_len=self.cfg.max_model_len,
+            max_num_seqs=self.cfg.max_num_seqs,
+            kv_dtype=self.cfg.kv_dtype,
+        )
+        # A declared layer pattern (models/hybrid.py): its step programs
+        # return the expert layers' counters beside the tokens, and whole
+        # engine paths are refused for it.
         self._hybrid = model_config.layer_pattern is not None
-        self._state_rows = self.cfg.max_num_seqs + 1 if self._hybrid else None
-        # The kind of the pattern's state layers ("kda", "conv", "swa") or False:
-        # which reason a refusal gives, a state or a tail that cannot be
-        # cut, or paths that are not built for a layer pattern.
-        self._stateful = False
-        # EVA layers (``ops/attention.eva_row``): the cache's rows are not
-        # positions. (window, chunk), or None: a token's row is its position.
-        self._eva: Optional[Tuple[int, int]] = None
+        # Where the decode step that writes a window's last position
+        # compacts it (EVA): the window, and the windows compacted so far.
+        self._closing_window = self.cache.closing_window
+        self.eva_windows_closed = 0
         if self._hybrid:
-            from llmq_tpu.models.hybrid import STATE_KINDS
-
-            self._stateful = next(
-                (attn for attn, _ in model_config.layer_pattern if attn in STATE_KINDS),
-                False,
-            )
-            if any(attn == "eva" for attn, _ in model_config.layer_pattern):
-                self._eva = (model_config.eva_window, model_config.eva_chunk)
-                # Windows compacted by decode steps since start.
-                self.eva_windows_closed = 0
             self._refuse_for_layer_pattern(params)
         if self.pp > 1:
             if self.cfg.spec_tokens > 0:
@@ -868,46 +775,13 @@ class EngineCore:
                 "chunked prefill can start mid-prompt (the bucketed "
                 "executables always compute positions 0..T)"
             )
-        # Pin the KV pool to row-major layout at every jit boundary. Left
-        # to itself XLA picks a different parameter layout than the Pallas
-        # custom call's required default, then inserts FOUR full-pool
-        # transpose copies per step in the entry computation (~12 ms/step
-        # at 3B — measured round 2; dwarfs the attention kernel itself).
         # Under pp each stage owns its own pool holding just that stage's
         # [hi-lo] layer slab; every pool shares one page-index space (the
         # scheduler's), so block tables replicate across stages verbatim.
-        self._kv_shardings = [
-            NamedSharding(m, kv_page_pspec(model_config, m.shape[TP_AXIS]))
-            for m in self._stage_meshes
-        ]
-        # A model that takes the XLA attention path on a TPU (the shape
-        # rules of ops/dispatch._tp_heads_ok) runs no custom call, and
-        # the pin would only force the compiler's own compact layout
-        # through a padded copy: its pool stays in the default layout.
-        kernel = _dispatch.decode_kernel_plan(
-            model_config.num_heads,
-            model_config.num_kv_heads,
-            self.cfg.kv_dtype,
-            mesh=self.mesh,
-            backend=self.model.attn_backend,
+        self._kv_formats = self.cache.placements(
+            self._stage_meshes,
+            pin=not (on_tpu() and self._decode_kernel_plan() == "xla"),
         )
-        pin = not (on_tpu() and kernel == "xla")
-        self._kv_formats = [
-            Format(Layout(tuple(range(5))), sh) if pin else sh
-            for sh in self._kv_shardings
-        ]
-        if self._hybrid:
-            # Latent pool and state pool differ in rank: one placement
-            # that fits both (tp = 1: whole on the device), and no layout
-            # pin: the latent pool's rows are whole lane tiles
-            # (``hybrid.latent_pool_width``), so the runtime's default
-            # layout is the row-major one the step computes in. (A pin
-            # at the jit boundary worked until a program came back from
-            # the compile cache and handed the pool on in the default
-            # layout: my chip run, PR 33, PERF.md section 6.)
-            self._kv_shardings = [NamedSharding(self.mesh, P())]
-            self._kv_formats = list(self._kv_shardings)
-        self._kv_sharding = self._kv_shardings[-1]
         self._kv_format = self._kv_formats[-1]
         num_pages = self.cfg.num_pages or self._auto_num_pages()
         sched_cfg = SchedulerConfig(
@@ -916,9 +790,8 @@ class EngineCore:
             page_size=self.cfg.page_size,
             max_model_len=self.cfg.max_model_len,
             enable_prefix_caching=self.cfg.enable_prefix_caching,
-            table_pages=self._table_pages,
         )
-        self.scheduler = Scheduler(sched_cfg)
+        self.scheduler = Scheduler(sched_cfg, self.cache)
         self.scheduler.on_preempt = self._on_scheduler_preempt
         self._pages_per_seq = sched_cfg.pages_per_seq
 
@@ -927,13 +800,8 @@ class EngineCore:
             self.v_pages = []
             total_bytes = 0
             for s, (lo, hi) in enumerate(self._stage_ranges):
-                k_s, v_s = make_kv_pages(
-                    model_config,
-                    num_pages,
-                    self.cfg.page_size,
-                    dtype=self.cfg.kv_dtype,
-                    num_layers=hi - lo,
-                    placement=self._kv_formats[s],
+                k_s, v_s = self.cache.allocate(
+                    num_pages, self._kv_formats[s], num_layers=hi - lo
                 )
                 self.k_pages.append(k_s)
                 self.v_pages.append(v_s)
@@ -949,13 +817,8 @@ class EngineCore:
                 self.cfg.max_num_seqs,
             )
         else:
-            self.k_pages, self.v_pages = make_kv_pages(
-                model_config,
-                num_pages,
-                self.cfg.page_size,
-                dtype=self.cfg.kv_dtype,
-                placement=self._kv_format,
-                state_rows=self._state_rows,
+            self.k_pages, self.v_pages = self.cache.allocate(
+                num_pages, self._kv_format
             )
             self.kv_pool_bytes = sum(
                 x.size * x.dtype.itemsize
@@ -1056,9 +919,7 @@ class EngineCore:
         else:
             self.preempt_mode = self.cfg.preempt_mode
         # SLO priority classes: env pins over config like the knobs
-        # above. interactive_decode_block is a trace-time constant (it
-        # sizes the second small-K executable), so it must resolve
-        # before _build_steps below.
+        # above.
         pcls = os.environ.get("LLMQ_PRIORITY_CLASSES", "").lower()
         if pcls in ("0", "false", "no", "off"):
             self.priority_classes = False
@@ -1073,20 +934,6 @@ class EngineCore:
             self.priority_preempt = True
         else:
             self.priority_preempt = self.cfg.priority_preempt
-        ik = self.cfg.interactive_decode_block
-        env_ik = os.environ.get("LLMQ_INTERACTIVE_DECODE_BLOCK", "").strip()
-        if env_ik:
-            try:
-                ik = int(env_ik)
-            except ValueError:
-                raise ValueError(
-                    f"LLMQ_INTERACTIVE_DECODE_BLOCK={env_ik!r} is not an int"
-                ) from None
-        if ik < 0:
-            raise ValueError(
-                f"interactive_decode_block={ik} (want >= 0)"
-            )
-        self.interactive_decode_block = ik if self.priority_classes else 0
         # Host-RAM prefix cold tier: env pins over config like the knobs
         # above. Resolved before hook attachment so the scheduler's
         # eviction path demotes from the very first request.
@@ -1119,7 +966,7 @@ class EngineCore:
             self.prefix_store = PrefixStore(
                 int(host_gb * 2**30),
                 page_size=self.cfg.page_size,
-                model_sig=self._model_sig(),
+                model_sig=self.cache.snapshot_sig(),
             )
             self.scheduler.on_demote = self._demote_page
             self.scheduler.host_lookup = self._host_prefix_lookup
@@ -1212,9 +1059,7 @@ class EngineCore:
                 ("prefix_host_gb", self.prefix_host_gb, 0),
             ):
                 if value != off:
-                    raise ValueError(
-                        _layer_pattern_refusal(f"{option}={value}", self._refusal_kind)
-                    )
+                    raise ValueError(self.cache.refusal(f"{option}={value}"))
             # Counters of the expert layers, summed over layers and decode
             # steps; they ride the pending entry and the fetch of the tokens.
             self.moe_assignments_held = 0
@@ -1244,10 +1089,6 @@ class EngineCore:
         self._admit_bucket = (
             None if self.cfg.prefill_chunk_size else self._wave_bucket
         )
-        # Small-K interactive decode executables; _make_jits populates
-        # this when interactive_decode_block is on (pp=1 only — the pp
-        # drivers keep the single big-K pipeline).
-        self._decode_jits_small: Optional[Dict[str, Any]] = None
         self._build_steps()
 
         # Host-side mirrors of the device decode state, rebuilt wholesale
@@ -1618,11 +1459,6 @@ class EngineCore:
                 self.canary_every,
             )
 
-    @property
-    def _refusal_kind(self) -> "str | bool":
-        """Which of ``_PATTERN_REFUSALS`` holds for this layer pattern."""
-        return self._stateful or ("eva" if self._eva else False)
-
     def _refuse_for_layer_pattern(self, params: Params) -> None:
         """What a layer pattern cannot do yet, refused at build by name: a
         per-sequence state cannot be shared by a prefix, cut at a chunk,
@@ -1648,7 +1484,7 @@ class EngineCore:
             (jnp.dtype(cfg.kv_dtype).itemsize < 2, f"kv_dtype={cfg.kv_dtype}"),
         ):
             if refused:
-                raise ValueError(_layer_pattern_refusal(what, self._refusal_kind))
+                raise ValueError(self.cache.refusal(what))
 
     def _dispatch_p99(self, kind: str) -> Optional[float]:
         """Watchdog deadline source: live p99 of one dispatch kind, or
@@ -1775,7 +1611,7 @@ class EngineCore:
                 return (out, g), kp, vp, new_st
             return out, kp, vp, new_st
 
-        def decode_block_step(params, kp, vp, st, *, mode, k=None):
+        def decode_block_step(params, kp, vp, st, *, mode):
             """``decode_block`` fused decode iterations in ONE XLA
             computation: a ``lax.scan`` over ``decode_step`` carrying
             (kv pools, decode state) and stacking the per-iteration
@@ -1789,9 +1625,6 @@ class EngineCore:
             (positions route to -1 / ctx_incl 0). Rows that finish at
             iteration j still ride out iterations j+1..K-1 inactive —
             the host discards those tokens when it processes the block.
-            ``k`` overrides the scan length (the SLO scheduler's small-K
-            interactive executable); the host side is shape-driven, so
-            a [k, S] block processes exactly like a [K, S] one.
             """
 
             def body(carry, _):
@@ -1803,7 +1636,7 @@ class EngineCore:
                 body,
                 (kp, vp, st),
                 None,
-                length=self.cfg.decode_block if k is None else k,
+                length=self.cfg.decode_block,
             )
             return outs, kp, vp, st
 
@@ -1948,12 +1781,11 @@ class EngineCore:
                 return (ys, g), kp, vp, st
             return ys, kp, vp, st
 
-        def verify_block_step(params, kp, vp, st, *, mode, k=None):
+        def verify_block_step(params, kp, vp, st, *, mode):
             """decode_block fused verify iterations in one XLA
             computation, mirroring decode_block_step. Always a lax.scan
             (even K=1) so the output block is uniformly ([K, S, Q]
-            tokens, [K, S] accept counts). ``k`` overrides the scan
-            length for the small-K interactive executable."""
+            tokens, [K, S] accept counts)."""
 
             def body(carry, _):
                 kp, vp, st = carry
@@ -1964,7 +1796,7 @@ class EngineCore:
                 body,
                 (kp, vp, st),
                 None,
-                length=self.cfg.decode_block if k is None else k,
+                length=self.cfg.decode_block,
             )
             return outs, kp, vp, st
 
@@ -2280,40 +2112,6 @@ class EngineCore:
             )
             for mode in ("greedy", "stochastic", "filtered")
         }
-        # SLO small-K interactive variant: the SAME block/verify scan at
-        # interactive_decode_block iterations — a second executable with
-        # identical sharding and donation contracts (out specs carry no
-        # shapes, so the [k, S] block reuses the big-K specs; the host
-        # side is shape-driven and processes either). Dispatch picks it
-        # whenever an interactive row is resident. Token parity per
-        # request holds by construction: the scan body is the identical
-        # decode_step, only the host-visit cadence changes.
-        self._decode_jits_small = None
-        ik = self.interactive_decode_block
-        if 0 < ik < self.cfg.decode_block:
-            s_fn = (
-                self._verify_block_fn
-                if self.cfg.spec_tokens > 0
-                else self._decode_block_fn
-            )
-            s_out0 = (
-                (self._spec_out, self._block1)
-                if self.cfg.spec_tokens > 0
-                else self._block1
-            )
-            if self._hybrid:
-                s_out0 = (s_out0, repl)
-            if g_on:
-                s_out0 = (s_out0, guard_sh)
-            self._decode_jits_small = {
-                mode: _StepProgram(
-                    partial(s_fn, mode=mode, k=ik),
-                    in_shardings=(param_spec, kv, kv, st_sh),
-                    out_shardings=(s_out0, kv, kv, st_sh),
-                    donate_argnums=(1, 2, 3),
-                )
-                for mode in ("greedy", "stochastic", "filtered")
-            }
         # Prefill data args grow by one (the per-row history) under
         # speculation; the trailing decode-state arg shifts with them.
         nP = len(self._prefill_arg_shardings)  # 13 if spec else 12
@@ -2836,27 +2634,13 @@ class EngineCore:
         self.params = jax.tree.map(reput, self.params, formats)
         self._make_jits(formats)
 
-    @property
-    def _table_pages(self):
-        """The model's row map as the scheduler asks it
-        (``SchedulerConfig.table_pages``); None: a row a position."""
-        if self._eva is None:
-            return None
-        return partial(
-            eva_table_pages, window=self._eva[0], chunk=self._eva[1],
-            page_size=self.cfg.page_size,
-        )
-
     def _auto_num_pages(self) -> int:
         """Size the KV pool from device HBM (vLLM gpu_memory_utilization
         parity, ``vllm_worker.py:107``). A CPU run (tests) has no HBM to
         read and gets a fixed small pool; a TPU that reports no
         ``bytes_limit`` is an error — a guessed pool there would hide
         the device."""
-        per_seq = -(-self.cfg.max_model_len // self.cfg.page_size)
-        if self._eva is not None:  # the places its compressed cache reaches
-            per_seq = self._table_pages(0, self.cfg.max_model_len)
-        max_useful = self.cfg.max_num_seqs * (per_seq + 1) + 1
+        max_useful = self.cache.max_useful_pages
         if not on_tpu():
             return min(max_useful, 4096)
         device = self.mesh.devices.flat[0]
@@ -2870,26 +2654,10 @@ class EngineCore:
         budget = int(limit * self.cfg.hbm_utilization) - stats.get(
             "bytes_in_use", 0
         )
-        if self._hybrid:
-            # The state pool comes out of the same budget, before pages.
-            from llmq_tpu.models import hybrid
-
-            budget -= hybrid.state_pool_bytes(
-                self.model_config, self._state_rows, self.cfg.kv_dtype
-            )
-            page_bytes = hybrid.latent_page_bytes_per_device(
-                self.model_config, self.cfg.page_size, self.cfg.kv_dtype,
-                self._kv_format,
-            )
-            return int(min(max(2, budget // page_bytes), max_useful))
-        page_bytes = kv_page_bytes_per_device(
-            self.model_config,
-            self.cfg.page_size,
-            self.cfg.kv_dtype,
-            self._kv_format,
-        )
-        num = max(2, budget // page_bytes)
-        return int(min(num, max_useful))
+        # A state pool comes out of the same budget, before pages.
+        budget -= self.cache.fixed_bytes
+        page_bytes = self.cache.page_bytes(self._kv_format)
+        return int(min(max(2, budget // page_bytes), max_useful))
 
     # --- request intake ---------------------------------------------------
     def add_request(
@@ -2928,9 +2696,7 @@ class EngineCore:
             )
         if prefill_only and self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal(
-                    "prefill_only (the prefill role)", self._refusal_kind
-                )
+                self.cache.refusal("prefill_only (the prefill role)")
             )
         if not self.priority_classes:
             priority = "batch"  # classes disabled: everything is FIFO batch
@@ -3012,86 +2778,23 @@ class EngineCore:
         mid-prefill rows are in ``running`` but have no decode state)."""
         return [s for s in self.scheduler.running.values() if s.prefilled]
 
-    def _live_pages(self, seqs: List[Sequence]) -> int:
-        """KV pages the decode kernel visits a layer for ``seqs``: the page
-        places that overlap each one's attended span (all of its context,
-        or its window where every layer of the model slides)."""
-        page, mc = self.cfg.page_size, self.model_config
-        if self._eva is not None:  # the pages that hold its attended rows
-            return sum(-(-n // page) for n in self._contexts(seqs))
-        window = (
-            mc.sliding_window if mc.sliding_window_pattern <= 1 else None
-        )
-        return sum(
-            -(-s.num_tokens // page)
-            - (max(s.num_tokens - window, 0) // page if window else 0)
-            for s in seqs
-        )
-
-    def _contexts(self, seqs: List[Sequence]) -> List[int]:
-        """Cache rows each of ``seqs`` attends in a decode step (the new
-        token's included): its tokens, or what the model's row map makes
-        of them (EVA layers: earlier windows' summaries + its own window)."""
-        if self._eva is None:
-            return [s.num_tokens for s in seqs]
-        return [eva_context(s.num_tokens, *self._eva) for s in seqs]
-
-    def _eva_rows(self, seqs: List[Sequence]) -> Dict[str, int]:
-        """Rows a decode step of ``seqs`` attends, by kind: the summaries
-        of earlier windows and the exact rows of each one's own window."""
-        own = sum((s.num_tokens - 1) % self._eva[0] + 1 for s in seqs)
-        return {
-            "summary_rows": sum(self._contexts(seqs)) - own,
-            "window_rows": own,
-        }
-
-    def _window_rows(self, seqs: List[Sequence]) -> int:
-        """Ring rows a sliding-window layer of a pattern attends in a
-        decode step of ``seqs``: each one's tokens or the window."""
-        window = self.model_config.swa_window
-        return sum(min(s.num_tokens, window) for s in seqs)
-
-    def _latent_pages_visited(self, seqs: List[Sequence]) -> int:
-        """Latent pages a decode step reads out of the pool a layer
-        (beside ``_live_pages``, what is live), by the schedule the step
-        runs."""
-        return latent_decode_pages_visited(
-            self._decode_kernel_plan(),
-            self._contexts(seqs),
-            self.cfg.max_num_seqs,
-            self._pages_per_seq,
-            self.cfg.page_size,
-        )
-
     def _decode_kernel_plan(self) -> str:
         """The decode-attention schedule of this engine's pool, as
-        ``ops/dispatch`` names it: a layer pattern's is its paged pool's
-        (latent rows, or a token's V and K side by side; state layers have
-        no attention kernel), any other model's its K/V pool's."""
-        mc = self.model_config
-        if self._hybrid:
-            from llmq_tpu.models import hybrid
-
-            return _dispatch.latent_decode_kernel_plan(
-                hybrid.paged_rank(mc), *self.k_pages.shape[2:], self.k_pages.dtype,
-                mesh=self.mesh, backend=self.model.attn_backend,
-            )
-        return _dispatch.decode_kernel_plan(
-            mc.num_heads, mc.num_kv_heads, self.cfg.kv_dtype,
-            mesh=self.mesh, backend=self.model.attn_backend,
+        ``ops/dispatch`` names it."""
+        plan, shape = self.cache.decode_plan
+        return getattr(_dispatch, plan)(
+            *shape, mesh=self.mesh, backend=self.model.attn_backend
         )
 
     def _kda_decode_plan(self) -> Optional[str]:
         """How a decode step updates the KDA state rows, as
-        ``ops/dispatch.kda_decode_plan`` names it (the step hands the
-        model the int ``1``: a slot's row is its index + 1); None for a
-        model with no KDA layer."""
-        S = self.v_pages["S"] if self._hybrid else None
-        if S is None or not S.shape[0]:
+        ``ops/dispatch.kda_decode_plan`` names it; None for a model with
+        no KDA layer."""
+        shape = self.cache.kda_plan_args
+        if shape is None:
             return None
         return _dispatch.kda_decode_plan(
-            1, S.dtype, *S.shape[-2:],
-            mesh=self.mesh, backend=self.model.attn_backend,
+            *shape, mesh=self.mesh, backend=self.model.attn_backend
         )
 
     def _mla_prefill_plan(self) -> Optional[Dict[str, List[int]]]:
@@ -3101,12 +2804,11 @@ class EngineCore:
         [buckets], "xla": [buckets]}``; None for a model with no ``mla``
         layer. Read once at build: ``stats()`` is called from heartbeats
         while the benchmark swaps the weights (``params`` is then None)."""
-        if not self._hybrid:
+        pattern = self.model_config.layer_pattern or ()
+        if not any(attn == "mla" for attn, _ in pattern):
             return None
-        from llmq_tpu.models import hybrid, quant
+        from llmq_tpu.models import quant
 
-        if not hybrid.count_layers(self.model_config, "mla"):
-            return None
         plans: Dict[str, List[int]] = {"flash": [], "xla": []}
         embed = self.params["embed"]
         dtype = (embed["scale"] if quant.is_quantized(embed) else embed).dtype
@@ -3435,9 +3137,9 @@ class EngineCore:
                     # device rode them out inactive) and are discarded.
                     continue
                 if (
-                    self._eva is not None
+                    self._closing_window is not None
                     and kind == "decode"
-                    and seq.num_tokens % self._eva[0] == 0
+                    and seq.num_tokens % self._closing_window == 0
                 ):
                     # The step wrote position num_tokens - 1, its window's
                     # last, and replaced the window's rows by its summaries.
@@ -3710,7 +3412,7 @@ class EngineCore:
         from llmq_tpu.engine import prefix_store as prefix_mod
 
         out: List[str] = []
-        sig = self._model_sig()
+        sig = self.cache.snapshot_sig()
         for hx in digests_hex:
             try:
                 key = bytes.fromhex(hx)
@@ -3745,7 +3447,7 @@ class EngineCore:
         from llmq_tpu.engine import prefix_store as prefix_mod
 
         n = 0
-        sig = self._model_sig()
+        sig = self.cache.snapshot_sig()
         for c in chunks_b64:
             key, k, v, chunk_sig, page_size = prefix_mod.chunk_from_bytes(
                 prefix_mod.chunk_from_b64(c)
@@ -4442,7 +4144,7 @@ class EngineCore:
         named = {s.get("program") for s in dump["spans"]}
         scopes: Dict[str, Dict[str, Dict[str, str]]] = {}
         for jits in (
-            self._decode_jits, self._decode_jits_small, self._prefill_jits,
+            self._decode_jits, self._prefill_jits,
             self._chunkfill_jits, getattr(self, "_mixedfill_jits", None),
         ):
             for mode, prog in (jits or {}).items():
@@ -4479,41 +4181,19 @@ class EngineCore:
             return
         t0 = time.monotonic()
         kind = "verify" if self.cfg.spec_tokens > 0 else "decode_block"
-        jits, k_steps = self._decode_jits, self.cfg.decode_block
-        if self._decode_jits_small is not None and any(
-            seq.prefilled and seq.priority == "interactive"
-            for seq in self.scheduler.running.values()
-        ):
-            # An interactive row is resident: dispatch the small-K
-            # executable so its tokens reach the host (and the stream)
-            # every interactive_decode_block iterations instead of every
-            # decode_block. Pure-batch steps keep the big fused K.
-            jits, k_steps = self._decode_jits_small, self.interactive_decode_block
-            kind += "_small"
+        jit = self._decode_jits[self._mode]
         if self.spans.on:
-            seqs = self._decodable_seqs()
+            lengths = [s.num_tokens for s in self._decodable_seqs()]
             self.spans.begin(
-                "decode_dispatch", program=getattr(jits[self._mode], "name", ""),
+                "decode_dispatch", program=getattr(jit, "name", ""),
                 mode=self._mode, variant="",
-                rows=len(seqs), live_pages=self._live_pages(seqs),
-                k_steps=k_steps, pending=len(self._pending),
-                ahead=self._ahead,
-                **({"state_rows": len(seqs)} if self._stateful else {}),
-                **(
-                    {"window_rows": self._window_rows(seqs)}
-                    if self._stateful == "swa" else {}
-                ),
-                **(self._eva_rows(seqs) if self._eva else {}),
-                **(
-                    {"latent_pages_visited": self._latent_pages_visited(seqs)}
-                    if self._hybrid else {}
-                ),
+                rows=len(lengths), k_steps=self.cfg.decode_block,
+                pending=len(self._pending), ahead=self._ahead,
+                **self.cache.decode_span(lengths, self._decode_kernel_plan),
             )
         with self._wd(kind):
-            out, self.k_pages, self.v_pages, self._dev_state = (
-                jits[self._mode](
-                    self.params, self.k_pages, self.v_pages, self._dev_state
-                )
+            out, self.k_pages, self.v_pages, self._dev_state = jit(
+                self.params, self.k_pages, self.v_pages, self._dev_state
             )
             now = time.monotonic()
             self._record_dispatch(kind, now - t0)
@@ -4534,7 +4214,7 @@ class EngineCore:
                 self._turn_n = 0
                 worst[:] = 0.0, 0.0, worst[0], worst[1]
                 self._full[:] = False, self._full[0]
-        self.decode_steps += k_steps
+        self.decode_steps += self.cfg.decode_block
         self.decode_dispatches += 1
         out, g = self._split_guard(out)
         # A layer pattern's ``out`` is (tokens, the expert layers' counters):
@@ -4822,19 +4502,6 @@ class EngineCore:
         )
 
     # --- snapshot plane ---------------------------------------------------
-    def _model_sig(self) -> Dict[str, Any]:
-        """The shape contract a snapshot's KV pages must match. Weights are
-        deliberately NOT part of the signature — the handoff plane assumes
-        peers serve the same checkpoint (same queue, same model), which is
-        also what the prefix cache and greedy bit-exactness already rely
-        on."""
-        return {
-            "num_layers": int(self.model_config.num_layers),
-            "num_kv_heads": int(self.model_config.num_kv_heads),
-            "head_dim": int(self.model_config.head_dim_),
-            "kv_dtype": str(jnp.dtype(self.cfg.kv_dtype)),
-        }
-
     def _snapshot_seq(self, seq: Sequence) -> RequestSnapshot:
         """Host-serializable state of one unfinished sequence. KV pages
         come from the sequence's pending host restore (swap-preempted),
@@ -4857,7 +4524,7 @@ class EngineCore:
                 kv_valid = 0
         return RequestSnapshot(
             rid=seq.rid,
-            model_sig=self._model_sig(),
+            model_sig=self.cache.snapshot_sig(),
             page_size=self.cfg.page_size,
             prompt_ids=list(seq.prompt_ids),
             output_ids=list(seq.output_ids),
@@ -4906,7 +4573,7 @@ class EngineCore:
         :meth:`insert_request` is bit-identical to never extracting."""
         if self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("extract_request", self._refusal_kind)
+                self.cache.refusal("extract_request")
             )
         out = finished if finished is not None else []
         self._drain(out)
@@ -4929,7 +4596,7 @@ class EngineCore:
         :meth:`extract_request`."""
         if self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("extract_all", self._refusal_kind)
+                self.cache.refusal("extract_all")
             )
         out = finished if finished is not None else []
         self._drain(out)
@@ -5092,9 +4759,9 @@ class EngineCore:
         prompt+output instead — same math, same tokens."""
         if self._hybrid:
             raise NotImplementedError(
-                _layer_pattern_refusal("insert_request", self._refusal_kind)
+                self.cache.refusal("insert_request")
             )
-        sig, mine = dict(snap.model_sig), self._model_sig()
+        sig, mine = dict(snap.model_sig), self.cache.snapshot_sig()
         if sig != mine:
             raise SnapshotCompatError(
                 f"snapshot model signature {sig} does not match engine "
@@ -5418,13 +5085,10 @@ class EngineCore:
                 except Exception:  # noqa: BLE001
                     dead = True
                 if dead:
-                    self.k_pages[s], self.v_pages[s] = make_kv_pages(
-                        self.model_config,
+                    self.k_pages[s], self.v_pages[s] = self.cache.allocate(
                         self.scheduler.config.num_pages,
-                        self.cfg.page_size,
-                        dtype=self.cfg.kv_dtype,
+                        self._kv_formats[s],
                         num_layers=hi - lo,
-                        placement=self._kv_formats[s],
                     )
             return
         try:
@@ -5432,13 +5096,8 @@ class EngineCore:
         except Exception:  # noqa: BLE001
             dead = True
         if dead:
-            self.k_pages, self.v_pages = make_kv_pages(
-                self.model_config,
-                self.scheduler.config.num_pages,
-                self.cfg.page_size,
-                dtype=self.cfg.kv_dtype,
-                placement=self._kv_format,
-                state_rows=self._state_rows,
+            self.k_pages, self.v_pages = self.cache.allocate(
+                self.scheduler.config.num_pages, self._kv_format
             )
 
     # --- metrics ----------------------------------------------------------
@@ -5533,15 +5192,9 @@ class EngineCore:
             # an expert that is hit sees a step).
             s["moe_assignments_held"] = self.moe_assignments_held
             s["moe_experts_hit"] = self.moe_experts_hit
-        if self._eva is not None:
+        if self._closing_window is not None:
             s["eva_windows_closed"] = self.eva_windows_closed
-        if self._stateful == "swa":
-            # What a page costs: the layers it spans (the full-attention
-            # ones alone), and the window layers' rings as allocated.
-            s["kv_pool_layers"] = int(self.k_pages.shape[0])
-            s["swa_ring_bytes"] = int(
-                self.v_pages["ring"].size * self.v_pages["ring"].dtype.itemsize
-            )
+        s.update(self.cache.stats())
         if self.cfg.spec_tokens > 0:
             # What speculation actually dispatches: the multi-query
             # verify resolves through its own plan, not the decode one.
@@ -5582,7 +5235,6 @@ class EngineCore:
         # byte-identical stats).
         if self._priority_enabled:
             s["priority_preemptions"] = self.priority_preemptions
-            s["interactive_decode_block"] = self.interactive_decode_block
             s["ttft_p50_ms_interactive"] = to_ms(
                 self.ttft_hist_interactive.percentile(0.50)
             )

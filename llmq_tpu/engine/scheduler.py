@@ -273,12 +273,6 @@ class SchedulerConfig:
     # exact pre-priority order, and stats() omits the per-class keys so
     # default payloads stay byte-identical.
     priority_aware: bool = False
-    # The model's row map, for a cache whose rows are not positions (a
-    # layer pattern's EVA layers, ``ops/attention.eva_table_pages``):
-    # ``table_pages(start, stop)`` is the places a block table needs to
-    # hold the rows of positions ``[start, stop)``. None: a token's row is
-    # its position, ``ceil(stop / page_size)`` places.
-    table_pages: Optional[Callable[[int, int], int]] = None
 
     @property
     def pages_per_seq(self) -> int:
@@ -288,8 +282,11 @@ class SchedulerConfig:
 class Scheduler:
     """Slot/page bookkeeping for the continuous batch."""
 
-    def __init__(self, config: SchedulerConfig) -> None:
+    def __init__(self, config: SchedulerConfig, layout) -> None:
         self.config = config
+        # The cache's row map (``models/cache.CacheLayout``): the places a
+        # block table needs for the rows of positions ``[start, stop)``.
+        self._table_pages = layout.table_pages
         self.allocator = PageAllocator(config.num_pages)
         self.slots: List[Optional[Sequence]] = [None] * config.max_num_seqs
         self.waiting: Deque[Sequence] = deque()
@@ -464,14 +461,6 @@ class Scheduler:
     @property
     def num_running(self) -> int:
         return len(self.running)
-
-    def _table_pages(self, start: int, stop: int) -> int:
-        """Places of a block table that hold the cache rows of positions
-        ``[start, stop)``: the model's row map where it has one
-        (``SchedulerConfig.table_pages``), else a row a position."""
-        if self.config.table_pages is not None:
-            return self.config.table_pages(start, stop)
-        return -(-stop // self.config.page_size)
 
     def _pages_needed(self, num_tokens: int) -> int:
         # +1 position of headroom: the decode step writes the *next* token's

@@ -15,33 +15,22 @@ Every other family is one uniform stack and stays on
 
 Layout. Consecutive equal layers are one *group*, stacked on a leading
 layer axis under ``params["stack<i>"]`` and run as one ``lax.scan``. Two
-kinds of cache ride through the scans, in the places the uniform model
+kinds of cache (``models/cache.py`` declares their leaves, widths and
+cost) ride through the scans, in the places the uniform model
 has its K and V pools, so that the engine's step programs pass, donate
 and return them as they do those. The FIRST holds what the pattern's
 paged layers (``PAGED_KINDS``) need, the SECOND what its state layers
 (``STATE_KINDS``) need; a pattern has one kind of each at most:
 
-- ``k_pages``: the paged pool ``[L_paged, P, page, width]``, one row a
-  token, addressed through the block table (no V pool). ``mla``: the
-  latent row, ``kv_lora_rank + rope`` values in whole lane tiles (576 in
-  640). ``gqa``: the token's V then its K, every kv head side by side
-  (``2 * n_kv * d``: 1,024 at 8 heads of 64, whole lane tiles where a
-  ``[page, 8, 64]`` page would be padded on the chip); the first
-  ``paged_rank`` values of a row are the part decode attention sums.
-  ``eva``: the same ``[V ; K]`` row, but row ``r`` of a sequence is not
-  position ``r``: ``ops/attention.eva_row`` (a closed window's rows are
-  overwritten by its summaries, ``[v~ ; k~]`` a chunk);
-- ``v_pages``: the state pool, ``{"S": [L_kda, R, heads, d, d] float32,
-  "conv": [L_state, R, K-1, tail_width]}``: one row a sequence, given by
-  ``state_rows`` (the engine: slot + 1). A caller that passes none gets the
-  row of the sequence's first page (``block_tables[:, 0]``). Row 0 is
-  scratch, as page 0 is: padded prefill rows and inactive decode rows
+- ``k_pages``: the paged pool, one row a token, addressed through the
+  block table (no V pool); under ``eva`` row ``r`` of a sequence is not
+  position ``r`` (``ops/attention.eva_row``);
+- ``v_pages``: the state pool, a leaf a kind of state, one row a sequence,
+  given by ``state_rows`` (the engine: slot + 1; a caller that passes none
+  gets the row of the sequence's first page, ``block_tables[:, 0]``). Row
+  0 is scratch, as page 0 is: padded prefill rows and inactive decode rows
   write there. A prefill overwrites its row whole, so a row needs no
-  clearing between sequences. ``kda``: the state matrix and the tails of
-  its q|k|v convolution; ``conv``: the tails alone, K-1 = 2 rows of
-  ``hidden_size`` values, and ``S`` has no layers. A pattern with no
-  state layer has both leaves empty: they cost no HBM and ride along
-  untouched.
+  clearing between sequences.
 
 The block (published; what the configuration does not settle is listed
 under ``assumed`` in ``benchmark/configs/ling-3.0-flash-ep4.json``):
@@ -123,6 +112,9 @@ import jax
 import jax.numpy as jnp
 
 from llmq_tpu.models import quant as qm
+from llmq_tpu.models.cache import (
+    PAGED_KINDS, STATE_KINDS, count_layers, kv_width, latent_width, ring_pages,
+)
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.transformer import (
     Transformer, _mlp, apply_rope, compute_rope_inv_freq, rms_norm,
@@ -143,12 +135,6 @@ class LayerGroup:
     mlp: str  # "dense" | "moe"
     count: int
     first: int  # index of its first layer in its attention kind's pool
-
-
-#: What a pattern's kinds keep a sequence: "paged" kinds a row a token in
-#: the first cache place, "state" kinds a row a sequence in the second.
-PAGED_KINDS = ("mla", "gqa", "eva")
-STATE_KINDS = ("kda", "conv", "swa")
 
 
 def layer_groups(config: ModelConfig) -> Tuple[LayerGroup, ...]:
@@ -173,66 +159,10 @@ def layer_groups(config: ModelConfig) -> Tuple[LayerGroup, ...]:
     return tuple(groups)
 
 
-def count_layers(config: ModelConfig, *attn: str) -> int:
-    return sum(1 for a, _ in config.layer_pattern if a in attn)
-
-
-def latent_width(config: ModelConfig) -> int:
-    return config.kv_lora_rank + config.qk_rope_head_dim
-
-
-def kv_width(config: ModelConfig) -> int:
-    """Values of a token's keys (or values) in a "gqa" layer: every kv
-    head's, side by side."""
-    return config.num_kv_heads * config.head_dim_
-
-
-def paged_rank(config: ModelConfig) -> int:
-    """The first values of a pool row that are the row's VALUE part, which
-    decode attention sums: MLA's latent ``c``; a "gqa" layer's V, all kv
-    heads of it (its K follows), and an "eva" layer's likewise."""
-    if count_layers(config, "gqa", "eva"):
-        return kv_width(config)
-    return config.kv_lora_rank
-
-
 def heads_of(config: ModelConfig, attn: str) -> int:
     """Query heads of a layer of kind ``attn``: a pattern's "swa" layers
     have a count of their own."""
     return config.swa_num_heads if attn == "swa" else config.num_heads
-
-
-def ring_pages(config: ModelConfig) -> Tuple[int, int]:
-    """(rows a page, pages a sequence) of the "swa" layers' ring: pages of
-    128 rows where the window is whole pages (what the latent kernel
-    walks), else the window as one page."""
-    W = config.swa_window
-    page = 128 if W % 128 == 0 else W
-    return page, W // page
-
-
-def latent_pool_width(config: ModelConfig) -> int:
-    """A pool row: the latent row (a "gqa" pattern's: a token's V then K,
-    see :meth:`HybridTransformer._gqa_decode`) in whole lane tiles of 128
-    (576 -> 640, zeros beyond; 2 x 8 x 64 = 1,024 as it is). A row-major
-    pool takes that room on the chip anyway, and for a width that is not
-    whole tiles the TPU runtime's default layout puts the tokens minor
-    instead: every step then copied the whole pool into row-major order
-    and back (2.8 ms of a 28.7 ms decode step at 2,305 pages, my chip run,
-    PR 33)."""
-    if count_layers(config, "mla"):
-        width = latent_width(config)
-    else:  # with no paged layer at all the pool has no layers
-        width = 2 * kv_width(config)
-    return -(-width // 128) * 128
-
-
-def tail_width(config: ModelConfig) -> int:
-    """Values of one row of a sequence's convolution tail: KDA's q|k|v
-    before the convolution, a "conv" layer's gated input ``B * u``."""
-    if count_layers(config, "conv"):
-        return config.hidden_size
-    return 3 * config.num_heads * config.head_dim_
 
 
 def group_shapes(config: ModelConfig, group: LayerGroup) -> Dict[str, tuple]:
@@ -351,90 +281,6 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Param
                 (jax.random.normal(k, shape, F32) / math.sqrt(fan_in)).astype(dtype)
             )
     return jax.tree.unflatten(treedef, leaves)
-
-
-def make_state_pools(
-    config: ModelConfig,
-    num_pages: int,
-    page_size: int,
-    dtype=jnp.bfloat16,
-    *,
-    placement: Any = None,
-    state_rows: Optional[int] = None,
-) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """(paged pool, state pool) in the places of the K and V pools. A
-    caller that gives no ``state_rows`` gets one state row a page, so
-    that a sequence's first page can name its row."""
-    n, d = config.num_heads, config.head_dim_
-    R = num_pages if state_rows is None else state_rows
-    paged = count_layers(config, *PAGED_KINDS)
-    shapes = (
-        ((paged, num_pages, page_size, latent_pool_width(config)), dtype),
-        ((count_layers(config, "kda"), R, n, d, d), F32),
-        (
-            (
-                count_layers(config, "kda", "conv"), R,
-                config.short_conv_kernel_size - 1, tail_width(config),
-            ),
-            dtype,
-        ),
-    )
-    swa = count_layers(config, "swa")
-
-    def alloc():
-        latent, S, conv = (jnp.zeros(shape, dt) for shape, dt in shapes)
-        state = {"S": S, "conv": conv}
-        if swa:  # a leaf of its own, only where the pattern has the kind
-            page, per = ring_pages(config)
-            state["ring"] = jnp.zeros(
-                (swa, R * per, page, latent_pool_width(config)), dtype
-            )
-        return latent, state
-
-    if placement is None:
-        return alloc()
-    return jax.jit(alloc, out_shardings=placement)()
-
-
-def latent_page_bytes_per_device(
-    config: ModelConfig, page_size: int, dtype, placement
-) -> int:
-    """HBM one page of the paged pool (all MLA layers' latent rows, or all
-    "gqa" layers' V and K) takes as the compiler lays the pool out: a row
-    of 576 values is padded to whole lane tiles."""
-    probe = 8
-    shape = (
-        count_layers(config, *PAGED_KINDS), probe, page_size,
-        latent_pool_width(config),
-    )
-    alloc = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=placement)
-    return alloc.lower().compile().memory_analysis().output_size_in_bytes // probe
-
-
-def state_pool_bytes(config: ModelConfig, rows: int, dtype) -> int:
-    """Bytes of ``rows`` sequences' KDA state and convolution tails (a
-    "conv" layer has the tail alone), or of their "swa" layers' rings."""
-    n, d = config.num_heads, config.head_dim_
-    tail = (
-        (config.short_conv_kernel_size - 1) * tail_width(config)
-        * jnp.dtype(dtype).itemsize
-    )
-    return rows * (
-        count_layers(config, "kda") * n * d * d * 4
-        + count_layers(config, "kda", "conv") * tail
-    ) + ring_bytes(config, rows, dtype)
-
-
-def ring_bytes(config: ModelConfig, rows: int, dtype) -> int:
-    """Bytes of ``rows`` sequences' rings: ``swa_window`` pool rows a
-    "swa" layer each, whatever ``max_model_len`` is."""
-    swa = count_layers(config, "swa")
-    if not swa:
-        return 0
-    return (
-        rows * swa * config.swa_window * latent_pool_width(config)
-        * jnp.dtype(dtype).itemsize
-    )
 
 
 # ---------------------------------------------------------------------------

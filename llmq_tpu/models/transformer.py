@@ -28,6 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from llmq_tpu.models import quant as qm
+# The pools' allocator lives with the rest of the cache's shape; the
+# benchmark and the tools import it from here.
+from llmq_tpu.models.cache import make_kv_pages  # noqa: F401
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.ops import attention as attn_ops
 from llmq_tpu.ops import collective_matmul as cm
@@ -978,44 +981,3 @@ def init_params(
         params["lm_head"] = w(next(keys), (H, cfg.vocab_size), H, q=True,
                               top=True)
     return params
-
-
-def make_kv_pages(
-    config: ModelConfig,
-    num_pages: int,
-    page_size: int,
-    dtype=jnp.bfloat16,
-    *,
-    num_layers: Optional[int] = None,
-    placement: Any = None,
-    state_rows: Optional[int] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Allocate the paged KV cache: [L, P, page, n_kv, d] ×2.
-
-    A model with a layer pattern gets, in the same two places, its latent
-    pool and its per-sequence state pool of ``state_rows`` rows
-    (``models/hybrid.make_state_pools``; none given: one row a page).
-
-    ``num_layers`` overrides the leading depth for per-stage pools under
-    pipeline parallelism (each stage caches only its own layers).
-    ``placement`` (a sharding or layout ``Format``) creates the pools
-    already placed: a tp-sharded pool is sized per device and, whole,
-    would not fit the one device an unplaced ``zeros`` lands on."""
-    if config.layer_pattern is not None:
-        from llmq_tpu.models import hybrid
-
-        return hybrid.make_state_pools(
-            config, num_pages, page_size, dtype,
-            placement=placement, state_rows=state_rows,
-        )
-    shape = (
-        config.num_layers if num_layers is None else num_layers,
-        num_pages,
-        page_size,
-        config.num_kv_heads,
-        config.head_dim_,
-    )
-    if placement is None:
-        return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-    alloc = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=placement)
-    return alloc(), alloc()

@@ -13,6 +13,8 @@ from llmq_tpu.core.config import Config
 from llmq_tpu.engine.engine import EngineConfig, _prefill_buckets
 from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.engine.scheduler import Scheduler, SchedulerConfig, Sequence
+from llmq_tpu.models.cache import cache_layout
+from llmq_tpu.models.presets import get_preset
 
 ROOT = Path(__file__).resolve().parents[1]
 RUN_SECONDS = 40  # BENCHMARK.json's run_seconds: the size of a fixed job
@@ -33,10 +35,16 @@ def replay(traffic, max_model_len, slots, grouped):
     requests = iter(make_schedule(load_traffic(
         ROOT / "benchmark" / "traffic" / f"{traffic}.json"), RUN_SECONDS))
     depth = max(Config().queue_prefetch, slots + slots // 2) - slots
-    sched = Scheduler(SchedulerConfig(
-        max_num_seqs=cfg.max_prefill_batch, num_pages=4096, page_size=128,
-        max_model_len=max_model_len,
-    ))
+    sched = Scheduler(
+        SchedulerConfig(
+            max_num_seqs=cfg.max_prefill_batch, num_pages=4096, page_size=128,
+            max_model_len=max_model_len,
+        ),
+        cache_layout(
+            get_preset("tiny"), page_size=128, max_model_len=max_model_len,
+            max_num_seqs=cfg.max_prefill_batch,
+        ),
+    )
 
     def bucket_of(seq):
         return next(b for b in buckets if b >= seq.num_tokens)

@@ -16,7 +16,7 @@ from benchmark.architectures import evabyte as reference
 from llmq_tpu.engine.engine import EngineConfig, EngineCore
 from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.engine.tokenizer import ByteTokenizer
-from llmq_tpu.models import hybrid
+from llmq_tpu.models import cache, hybrid
 from llmq_tpu.models.presets import _EVABYTE, get_preset
 from llmq_tpu.models.transformer import build_model, make_kv_pages
 from llmq_tpu.ops.attention import eva_context, eva_row, eva_table_pages
@@ -251,7 +251,9 @@ def test_the_engine_serves_the_reference_and_counts_pages_by_the_row_map():
     def pages_follow_the_row_map(core):
         # the span's fields are the benchmark's own count of attended rows
         seqs = core._decodable_seqs()
-        rows = core._eva_rows(seqs)
+        rows = core.cache.decode_span(
+            [s.num_tokens for s in seqs], core._decode_kernel_plan
+        )
         assert rows["summary_rows"] + rows["window_rows"] == sum(
             kernel_cost_eva.attended_rows(s.num_tokens, window=W, chunk=C) for s in seqs
         )
@@ -334,7 +336,7 @@ def test_the_published_pool_takes_the_latent_kernel():
     from llmq_tpu.ops import dispatch
 
     big = get_preset("evabyte-6.5b-pp4")
-    assert (hybrid.paged_rank(big), hybrid.latent_pool_width(big)) == (4096, 8192)
+    assert (cache.paged_rank(big), cache.latent_pool_width(big)) == (4096, 8192)
     assert dispatch.latent_decode_kernel_plan(
         4096, 128, 8192, jnp.bfloat16, None, "pallas"
     ) == "latent_live"

@@ -19,7 +19,7 @@ from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.engine.snapshot import RequestSnapshot
 from llmq_tpu.engine.tokenizer import ByteTokenizer
 from llmq_tpu.models import quant as qm
-from llmq_tpu.models import hybrid
+from llmq_tpu.models import cache, hybrid
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.presets import get_preset
 from llmq_tpu.models.transformer import build_model, init_params, make_kv_pages
@@ -201,9 +201,9 @@ def test_a_pattern_without_state_layers_has_an_empty_state_pool():
     sizing takes nothing out of the budget for them, and the engine's
     cache is the latent pool alone."""
     assert hybrid.count_layers(STATELESS, "kda") == 0
-    assert hybrid.state_pool_bytes(STATELESS, 129, jnp.bfloat16) == 0
+    assert sum(cache.state_bytes(STATELESS, 129, jnp.bfloat16).values()) == 0
     core = make_stateless()
-    assert core._state_rows == 5 and not core._stateful
+    assert core.cache.state_rows == 5 and core.cache.state_kind is None
     assert {k: v.shape[0] for k, v in core.v_pages.items()} == {"S": 0, "conv": 0}
     assert all(v.size == 0 for v in core.v_pages.values())
     latent = core.k_pages
@@ -541,10 +541,11 @@ def test_a_conv_gqa_pattern_keeps_tails_alone_beside_a_pool_of_v_and_k():
     (2 rows of 64 a sequence) and an empty ``S``; the dispatch span carries
     ``state_rows`` and ``live_pages``; the plan is the paged pool's."""
     assert hybrid.count_layers(LFM2, "gqa") == 2 and hybrid.count_layers(LFM2, "conv") == 7
-    assert hybrid.paged_rank(LFM2) == 32 and hybrid.tail_width(LFM2) == 64
-    assert hybrid.state_pool_bytes(LFM2, 129, jnp.bfloat16) == 129 * 7 * 2 * 64 * 2
+    assert cache.paged_rank(LFM2) == 32
+    assert cache.state_leaves(LFM2, 129, jnp.bfloat16)["conv"][0][-1] == 64
+    assert sum(cache.state_bytes(LFM2, 129, jnp.bfloat16).values()) == 129 * 7 * 2 * 64 * 2
     core = make_lfm2()
-    assert core._stateful == "conv" and core._state_rows == 5
+    assert core.cache.state_kind == "conv" and core.cache.state_rows == 5
     assert core.k_pages.shape == (2, 60, 8, 128)
     assert core.v_pages["S"].size == 0
     assert core.v_pages["conv"].shape == (7, 5, 2, 64)

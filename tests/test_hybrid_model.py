@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from benchmark import architectures, weights
-from llmq_tpu.models import hybrid
+from llmq_tpu.models import cache, hybrid
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.presets import _LING_3_FLASH, _OPENPANGU_ULTRA_MOE, get_preset
 from llmq_tpu.models.transformer import build_model, make_kv_pages
@@ -594,8 +594,8 @@ def test_lfm2_from_hf_config_on_the_published_keys():
     )
     assert round(n / 1e6) == 5178  # 10.36 GB of bf16
     # a pool row is a token's V then K: 1,024 values, whole lane tiles
-    assert hybrid.latent_pool_width(stage) == 1024 and hybrid.paged_rank(stage) == 512
-    assert hybrid.state_pool_bytes(stage, 129, jnp.bfloat16) == 7 * 129 * 2 * 2048 * 2
+    assert cache.latent_pool_width(stage) == 1024 and cache.paged_rank(stage) == 512
+    assert sum(cache.state_bytes(stage, 129, jnp.bfloat16).values()) == 7 * 129 * 2 * 2048 * 2
     for key, value in (("conv_bias", True), ("rope_parameters", {"rope_type": "yarn"})):
         with pytest.raises(ValueError, match="lfm2_moe"):
             ModelConfig.from_hf_config(dict(_LFM2_24B_A2B, **{key: value}))

@@ -24,7 +24,7 @@ from benchmark import architectures, weights
 from llmq_tpu.engine.engine import EngineConfig, EngineCore
 from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.engine.tokenizer import ByteTokenizer
-from llmq_tpu.models import hybrid
+from llmq_tpu.models import cache, hybrid
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.presets import _LAGUNA_S_2_1, get_preset
 from llmq_tpu.models.transformer import (
@@ -428,20 +428,20 @@ def test_the_ring_does_not_grow_with_max_model_len_and_a_page_spans_the_full_lay
     alone, and ``stats()`` says both."""
     short, long = make_core(max_model_len=96), make_core(max_model_len=480, num_pages=300)
     for core in (short, long):
-        assert core._stateful == "swa" and core._state_rows == 5
+        assert core.cache.state_kind == "swa" and core.cache.state_rows == 5
         assert core.v_pages["ring"].shape == (3, 5, 8, 128)
         assert core.k_pages.shape[0] == 2 and core.k_pages.shape[2:] == (8, 128)
         stats = core.stats()
         assert stats["kv_pool_layers"] == 2
         assert stats["swa_ring_bytes"] == 3 * 5 * 8 * 128 * 4
-        assert stats["swa_ring_bytes"] == hybrid.ring_bytes(TINY, 5, jnp.float32)
-        assert hybrid.state_pool_bytes(TINY, 5, jnp.float32) == stats["swa_ring_bytes"]
+        assert stats["swa_ring_bytes"] == cache.state_bytes(TINY, 5, jnp.float32)["ring"]
+        assert core.cache.fixed_bytes == stats["swa_ring_bytes"]
     assert long._pages_per_seq == 60 and short._pages_per_seq == 12
     big = get_preset("laguna-s-2.1-ep4")
-    assert hybrid.ring_pages(big) == (128, 4)
-    assert hybrid.ring_bytes(big, 129, jnp.bfloat16) == 129 * 3 * 512 * 4096  # 0.81 GB
-    assert hybrid.latent_pool_width(big) == 2048 and hybrid.paged_rank(big) == 1024
-    assert hybrid.count_layers(big, *hybrid.PAGED_KINDS) == 2  # 128 x 2 x 4,096 B a page
+    assert cache.ring_pages(big) == (128, 4)
+    assert cache.state_bytes(big, 129, jnp.bfloat16)["ring"] == 129 * 3 * 512 * 4096  # 0.81 GB
+    assert cache.latent_pool_width(big) == 2048 and cache.paged_rank(big) == 1024
+    assert cache.count_layers(big, *cache.PAGED_KINDS) == 2  # 128 x 2 x 4,096 B a page
 
 
 def test_the_dispatch_span_counts_the_window_layers_rows_beside_the_full_layers_pages():

@@ -13,6 +13,8 @@ from llmq_tpu.engine.scheduler import (
     SchedulerConfig,
     Sequence,
 )
+from llmq_tpu.models.cache import cache_layout
+from llmq_tpu.models.presets import get_preset
 
 
 def make_seq(rid, prompt_len=10, max_tokens=100):
@@ -30,7 +32,11 @@ def make_sched(slots=4, pages=32, page_size=4, max_len=64):
             num_pages=pages,
             page_size=page_size,
             max_model_len=max_len,
-        )
+        ),
+        cache_layout(
+            get_preset("tiny"), page_size=page_size, max_model_len=max_len,
+            max_num_seqs=slots,
+        ),
     )
 
 
@@ -186,7 +192,11 @@ class TestPrefixCaching:
             enable_prefix_caching=True,
         )
         cfg.update(over)
-        return Scheduler(SchedulerConfig(**cfg))
+        layout = cache_layout(
+            get_preset("tiny"), page_size=cfg["page_size"],
+            max_model_len=cfg["max_model_len"], max_num_seqs=cfg["max_num_seqs"],
+        )
+        return Scheduler(SchedulerConfig(**cfg), layout)
 
     def _seq(self, rid, ids, max_tokens=4):
         from llmq_tpu.engine.sampling import SamplingParams

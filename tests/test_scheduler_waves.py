@@ -16,6 +16,8 @@ from llmq_tpu.engine.scheduler import (
     SchedulerConfig,
     Sequence,
 )
+from llmq_tpu.models.cache import cache_layout
+from llmq_tpu.models.presets import get_preset
 
 WAVE = 4
 LADDER = (16, 32, 64, 128)
@@ -42,7 +44,10 @@ def make_sched(slots=4, pages=4096, priority_aware=False):
             page_size=8,
             max_model_len=128,
             priority_aware=priority_aware,
-        )
+        ),
+        cache_layout(
+            get_preset("tiny"), page_size=8, max_model_len=128, max_num_seqs=slots
+        ),
     )
 
 

@@ -22,6 +22,7 @@ from llmq_tpu.engine.engine import (
 from llmq_tpu.engine.sampling import SamplingParams
 from llmq_tpu.engine.scheduler import Sequence
 from llmq_tpu.engine.tokenizer import ByteTokenizer
+from llmq_tpu.models.cache import cache_layout
 from llmq_tpu.models.config import ModelConfig
 from llmq_tpu.models.transformer import init_params
 from llmq_tpu.obs import get_registry
@@ -330,14 +331,19 @@ def test_live_pages_follow_the_window_of_a_model_that_slides():
         Sequence(rid=f"w{n}", prompt_ids=[1] * n, params=greedy())
         for n in (1, 8, 9, 40)
     ]
-    assert core._live_pages(seqs) == 1 + 1 + 2 + 5
-    core.model_config = dataclasses.replace(CFG, sliding_window=10)
+    lengths = [s.num_tokens for s in seqs]
+    sizes = dict(
+        page_size=core.cfg.page_size, max_model_len=core.cfg.max_model_len,
+        max_num_seqs=core.cfg.max_num_seqs,
+    )
+    assert core.cache.live_pages(lengths) == 1 + 1 + 2 + 5
+    slides = cache_layout(dataclasses.replace(CFG, sliding_window=10), **sizes)
     # ctx 40, window 10: positions 30..39, pages 3 and 4 of 0..4.
-    assert core._live_pages(seqs) == 1 + 1 + 2 + 2
-    core.model_config = dataclasses.replace(
-        CFG, sliding_window=10, sliding_window_pattern=2
+    assert slides.live_pages(lengths) == 1 + 1 + 2 + 2
+    every_second = cache_layout(
+        dataclasses.replace(CFG, sliding_window=10, sliding_window_pattern=2), **sizes
     )  # every second layer sees the whole context: count those
-    assert core._live_pages(seqs) == 1 + 1 + 2 + 5
+    assert every_second.live_pages(lengths) == 1 + 1 + 2 + 5
 
 
 def test_the_benchmarks_span_stat_reads_live_pages(traced, monkeypatch):

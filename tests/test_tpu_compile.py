@@ -29,7 +29,7 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from llmq_tpu.core.faults import classify_failure, is_compile_failure
-from llmq_tpu.models import hybrid
+from llmq_tpu.models import cache, hybrid
 from llmq_tpu.models.presets import get_preset
 from llmq_tpu.models.transformer import Transformer, init_params
 from llmq_tpu.ops import dispatch
@@ -351,14 +351,17 @@ def _pool_page_bytes_come_from_the_compiler(topo, monkeypatch):
     own layout (unpadded) and its attention to XLA. qwen2.5-3b on one chip
     (two heads) is unpadded; its fp8 pool is padded back to the bf16
     size."""
-    from llmq_tpu.engine.engine import kv_page_bytes_per_device
+    from llmq_tpu.models.cache import cache_layout
     from llmq_tpu.parallel.sharding import kv_page_pspec
 
     def page_bytes(cfg, tp, dtype, pinned=True):
         mesh = make_mesh(tensor_parallel=tp, devices=topo.devices[:tp])
         sharding = NamedSharding(mesh, kv_page_pspec(cfg, tp))
         fmt = Format(Layout(tuple(range(5))), sharding) if pinned else sharding
-        return kv_page_bytes_per_device(cfg, PAGE, dtype, fmt)
+        layout = cache_layout(
+            cfg, page_size=PAGE, max_model_len=2048, max_num_seqs=128, kv_dtype=dtype
+        )
+        return layout.page_bytes(fmt)
 
     def by_shape(cfg, tp, itemsize):
         return (
@@ -534,7 +537,7 @@ def _hybrid_step(
         if "ring" in state:  # the window layers' rings, in place like the pool
             ring = "bf16[" + ",".join(map(str, state["ring"].shape[:2])) + ","
             assert not [l for l in compiled.as_text().splitlines() if " copy(" in l and ring in l]
-            assert state["ring"].size * 2 == hybrid.ring_bytes(cfg, slots + 1, jnp.bfloat16)
+            assert state["ring"].size * 2 == cache.state_bytes(cfg, slots + 1, jnp.bfloat16)["ring"]
         if cfg.num_experts:
             held, H, I = cfg.experts_held_[1], cfg.hidden_size, cfg.moe_intermediate_size
             plan = dispatch.grouped_experts_plan(

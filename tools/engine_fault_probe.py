@@ -24,6 +24,7 @@ identically — faults are injected via the engine's dispatch hook.
 import asyncio
 import os
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -108,8 +109,9 @@ def check_parity(outs: dict, baseline: dict, leg: str) -> None:
 
 
 async def run_hang_leg(baseline: dict):
-    # Deadline = max(2.0, p99 * 2): the ~0.7 s CPU compile of the first
-    # dispatch stays under it, the injected 4.5 s sleep does not.
+    # Deadline = max(2.0, p99 * 2): a first dispatch that reads its
+    # program from the compile cache (main) stays under it, the injected
+    # 4.5 s sleep does not.
     make = lambda: build_core(watchdog_mult=2.0, watchdog_min_s=2.0)  # noqa: E731
     engine = AsyncEngine(make())
     engine.rebuild_core = make
@@ -218,11 +220,19 @@ async def run_xla_error_leg(baseline: dict):
 def main():
     from llmq_tpu.utils.platform import enable_compile_cache
 
-    enable_compile_cache()  # before the first compile
-    baseline = run_baseline()
-    asyncio.run(run_hang_leg(baseline))
-    asyncio.run(run_oom_ladder_leg(baseline))
-    asyncio.run(run_xla_error_leg(baseline))
+    with tempfile.TemporaryDirectory(prefix="llmq_fault_probe_") as scratch:
+        # A rebuilt core compiles its programs again. A worker's reads
+        # them from the persistent cache; a CPU run has none, and there a
+        # prefill's compile outlasts the hang leg's 2 s floor, so that the
+        # rebuilt engine trips in its turn, without end. So a CPU run gets
+        # a cache for the length of the probe, which the baseline warms.
+        if enable_compile_cache() is None:  # before the first compile
+            jax.config.update("jax_compilation_cache_dir", scratch)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        baseline = run_baseline()
+        asyncio.run(run_hang_leg(baseline))
+        asyncio.run(run_oom_ladder_leg(baseline))
+        asyncio.run(run_xla_error_leg(baseline))
     print("metric: engine_fault_probe_ok legs=3")
 
 
